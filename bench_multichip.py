@@ -23,12 +23,14 @@ real 8-chip hardware), ``parallel_efficiency`` (speedup / devices), and
 the ``comms.*`` byte estimates recorded by the solves so RunReport's
 comms fraction stays honest.
 
-Self-provisioning: when the current process sees fewer than N devices
-(single-chip bench hosts), the script re-execs itself under
-``JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=N``
-— the same recipe as tests/conftest.py and the MULTICHIP dryrun. CPU-mesh
-runs mark ``"simulated": true`` and do NOT assert the speedup (8 virtual
-CPU devices share one socket; the ratio measures the host, not ICI).
+Where it runs is decided before jax is imported, from the environment
+alone (a process that has asked jax for its devices holds the chip):
+with ``JAX_PLATFORMS=cpu`` — a rehearsal, asked for by name — the script
+re-execs itself once under ``XLA_FLAGS=--xla_force_host_platform_device_
+count=N`` and every line says ``"simulated": true`` (N virtual CPU devices
+share one socket; the ratio measures the host, not ICI). Otherwise it
+runs in this process on the devices jax reports and exits non-zero when
+there are fewer than two: it never moves itself off a chip in silence.
 
 Budget: honors ``PHOTON_BENCH_BUDGET_S`` — metrics skipped past the
 deadline emit valid ``{"truncated": true}`` JSON (bench_suite recipe).
@@ -135,17 +137,23 @@ def check_game_10b_headroom(n_devices: int) -> None:
         )
 
 
-def _provisioned(n_devices: int) -> bool:
-    import jax
-
-    return len(jax.devices()) >= n_devices
+def _needs_virtual_devices(n_devices: int) -> bool:
+    """True when the environment asks for the CPU backend and has not yet
+    given it ``n_devices`` virtual devices. Reads the environment only:
+    no jax import, so the parent of a re-exec never holds a device."""
+    if os.environ.get("JAX_PLATFORMS", "").strip().lower() != "cpu":
+        return False
+    return not any(
+        f.startswith("--xla_force_host_platform_device_count=")
+        and int(f.split("=", 1)[1]) >= n_devices
+        for f in os.environ.get("XLA_FLAGS", "").split()
+    )
 
 
 def _reexec_forced(n_devices: int) -> int:
     """Re-exec under a forced n-device virtual CPU platform and forward
     the child's metric lines (the dryrun_multichip recipe)."""
     env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
     flags = [
         f
         for f in env.get("XLA_FLAGS", "").split()
@@ -153,7 +161,6 @@ def _reexec_forced(n_devices: int) -> int:
     ]
     flags.append(f"--xla_force_host_platform_device_count={n_devices}")
     env["XLA_FLAGS"] = " ".join(flags)
-    env["PHOTON_MULTICHIP_NO_REEXEC"] = "1"
     here = os.path.abspath(__file__)
     proc = subprocess.run(
         [sys.executable, here],
@@ -173,9 +180,8 @@ def _reexec_forced(n_devices: int) -> int:
 
 
 def _timed_rate(run, units: float) -> tuple[float, dict]:
-    """PERF_NOTES timing recipe: ``run(salt)`` returns a scalar device
-    value; warm with one salt, time a different one, sync by scalar
-    fetch."""
+    """``run(salt)`` returns a scalar device value; warm with one salt,
+    time a different one, wait for it by fetching the scalar."""
     from photon_ml_tpu import telemetry
 
     float(telemetry.sync_fetch(run(0), label="warmup"))
@@ -428,12 +434,6 @@ def bench_game_10b(n_devices: int, simulated: bool) -> dict:
 #: memo: the steps loop calls one step per metric).
 _FLEET_OBS_CACHE: dict[str, dict] = {}
 
-#: Simulated per-chip peak FLOP/s handed to CPU fleet workers so their
-#: per-member MFU (and thus the spread) is computable at all — the
-#: NUMBER is meaningless off-TPU (marked simulated), the plumbing is
-#: what the gate protects.
-_SIMULATED_PEAK_FLOPS = 1.0e12
-
 
 def _fleet_observability_lines(simulated: bool) -> dict[str, dict]:
     """Run one supervised 2-process gloo fleet with per-member telemetry
@@ -445,11 +445,11 @@ def _fleet_observability_lines(simulated: bool) -> dict[str, dict]:
     These two lines are ALWAYS ``simulated: true``, regardless of the
     host platform: the supervised workers force JAX_PLATFORMS=cpu + gloo
     by harness design (tools/fleet._worker_env), so even on a TPU box
-    this measures the CPU fleet — the plumbing, not the hardware. For
-    the same reason the per-chip peak is injected (when the operator set
-    none) so per-member MFU, and thus fleet_mfu_spread, is computable at
-    all. A failed run is memoized too: the second metric step must not
-    repeat a known-failing (up to 420 s) fleet launch."""
+    this measures the CPU fleet — the plumbing, not the hardware. The
+    CPU has no row in the peaks table, so per-member MFU, and with it
+    fleet_mfu_spread, is null ("unknown") here. A failed run is memoized
+    too: the second metric step must not repeat a known-failing (up to
+    420 s) fleet launch."""
     import shutil
     import tempfile
 
@@ -463,20 +463,13 @@ def _fleet_observability_lines(simulated: bool) -> dict[str, dict]:
         return _FLEET_OBS_CACHE
     workdir = tempfile.mkdtemp(prefix="bench_fleet_obs_")
     try:
-        injected_peak = "PHOTON_PEAK_FLOPS" not in os.environ
-        if injected_peak:
-            os.environ["PHOTON_PEAK_FLOPS"] = str(_SIMULATED_PEAK_FLOPS)
-        try:
-            report = fleet.run_fleet(fleet.FleetSpec(
-                workdir=workdir,
-                num_processes=2,
-                devices_per_process=2,
-                progress_heartbeat_every_s=0.5,
-                timeout_s=420.0,
-            ))
-        finally:
-            if injected_peak:
-                del os.environ["PHOTON_PEAK_FLOPS"]
+        report = fleet.run_fleet(fleet.FleetSpec(
+            workdir=workdir,
+            num_processes=2,
+            devices_per_process=2,
+            progress_heartbeat_every_s=0.5,
+            timeout_s=420.0,
+        ))
         if not report.get("ok"):
             raise RuntimeError(
                 f"fleet observability run failed: "
@@ -505,8 +498,6 @@ def _fleet_observability_lines(simulated: bool) -> dict[str, dict]:
             for m in fleet_report.members
         },
     }
-    if injected_peak:
-        detail["simulated_peak_flops"] = _SIMULATED_PEAK_FLOPS
     # the aggregates are extracted; repeated gated bench runs must not
     # accumulate full fleet workdirs (checkpoints + traces) in tempdir
     shutil.rmtree(workdir, ignore_errors=True)
@@ -543,6 +534,12 @@ def run_multichip(deadline=None) -> dict[str, float | None]:
         os.environ.get("PHOTON_MULTICHIP_DEVICES", str(DEFAULT_DEVICES))
     )
     n_devices = min(n_devices, len(jax.devices()))
+    if n_devices < 2:
+        raise SystemExit(
+            f"bench_multichip needs at least 2 devices; jax reports "
+            f"{jax.devices()} — it does not move itself onto virtual CPU "
+            "devices (ask for that rehearsal by name: JAX_PLATFORMS=cpu)"
+        )
     simulated = jax.devices()[0].platform != "tpu"
     steps = (
         ("multichip_glm_rows_per_sec", lambda: bench_glm(n_devices, simulated)),
@@ -596,10 +593,7 @@ def main() -> int:
     n_devices = int(
         os.environ.get("PHOTON_MULTICHIP_DEVICES", str(DEFAULT_DEVICES))
     )
-    if (
-        not _provisioned(n_devices)
-        and os.environ.get("PHOTON_MULTICHIP_NO_REEXEC") != "1"
-    ):
+    if _needs_virtual_devices(n_devices):  # decided before any jax import
         return _reexec_forced(n_devices)
     from bench_suite import budget_deadline
 

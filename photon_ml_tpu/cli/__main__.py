@@ -21,6 +21,9 @@ def main(argv=None) -> int:
         print("  report --trace <jsonl> [--telemetry <jsonl>] [--compare <json>]")
         print("  profile --profile-dir <dir> -- <command...>  profiler capture around any run")
         return 0 if argv else 2
+    from photon_ml_tpu.utils import enable_compile_cache
+
+    enable_compile_cache()  # every subcommand, before its first compile
     cmd, rest = argv[0], argv[1:]
     if cmd == "train":
         from photon_ml_tpu.cli.train import main as train_main

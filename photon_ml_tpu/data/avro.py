@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import io
 import json
+import logging
 import os
 import struct
 import zlib
@@ -33,6 +34,9 @@ import numpy as np
 from photon_ml_tpu.data.index_map import INTERCEPT_KEY, IndexMap, feature_key
 from photon_ml_tpu.game.dataset import GameDataset, build_game_dataset
 from photon_ml_tpu.ops.sparse import SparseBatch
+from photon_ml_tpu.telemetry import metrics
+
+logger = logging.getLogger("photon_ml_tpu.data.avro")
 
 _MAGIC = b"Obj\x01"
 
@@ -754,8 +758,14 @@ def read_game_dataset_from_avro(
     )
     if fast is not None:
         ds, maps = fast
+        metrics.counter("avro.native_rows").inc(ds.num_rows)
         return (ds, maps) if return_index_maps else ds
 
+    logger.warning(
+        "reading %d Avro file(s) with the pure-Python decoder (native "
+        "library unavailable or input it does not support): ~60x slower",
+        len(file_list),
+    )
     if index_maps is None:
         index_maps = {
             shard: build_index_map_from_avro(
@@ -816,6 +826,7 @@ def read_game_dataset_from_avro(
 
     if row == 0:
         raise ValueError(f"no records in {file_list}")
+    metrics.counter("avro.python_rows").inc(row)
 
     shards = {}
     for shard in feature_shards:

@@ -3,9 +3,10 @@
 The reference's ingestion hot loops run on Spark executors (JVM); here
 they are host-side, so the text-parsing inner loop lives in
 native/fast_parse.cpp behind a C ABI (the environment has no pybind11 —
-ctypes is the binding layer). The library is compiled on demand with g++
-and cached; every caller must keep a pure-Python fallback, so the native
-path is a transparent accelerator, never a requirement.
+ctypes is the binding layer). The library is built from the committed
+``native/*.cpp`` on first use into the git-ignored ``.so``. Every caller
+keeps a pure-Python path with the same semantics, ~60x slower on Avro
+decode — so losing the library is logged as a WARNING, never in silence.
 """
 
 from __future__ import annotations
@@ -42,25 +43,37 @@ def _source_paths() -> list[str]:
 
 def _build() -> bool:
     srcs = _source_paths()
-    if not srcs:
-        return False
-    attempts = [srcs]
+    attempts = [srcs] if srcs else []
     if len(srcs) > 1:
         # avro_decode.cpp needs zlib; if that link fails (no libz on the
         # host), still build fast_parse alone so the libsvm accelerator
         # survives
         attempts.append(srcs[:1])
+    # build beside the target and rename: a concurrent first use in
+    # another process must never load a half-written library
+    tmp = f"{_LIB_PATH}.{os.getpid()}.tmp"
     for attempt in attempts:
         cmd = ["g++", "-O3", "-fPIC", "-shared", "-std=c++17", "-pthread",
-               "-o", _LIB_PATH, *attempt]
+               "-o", tmp, *attempt]
         if any("avro_decode" in s for s in attempt):
             cmd.append("-lz")
         try:
             subprocess.run(cmd, check=True, capture_output=True, timeout=240)
+            os.replace(tmp, _LIB_PATH)
             return True
         except (OSError, subprocess.SubprocessError) as e:
-            logger.info("native build failed for %s (%s)", attempt, e)
-    logger.info("native build unavailable; using pure python")
+            logger.warning(
+                "native build failed for %s (%s: %s)",
+                [os.path.basename(a) for a in attempt], e,
+                (getattr(e, "stderr", b"") or b"").decode(errors="replace")[-400:],
+            )
+        finally:
+            if os.path.exists(tmp):
+                os.remove(tmp)
+    logger.warning(
+        "native library unavailable: ingest falls back to the pure-Python "
+        "decoders (~60x slower on Avro)"
+    )
     return False
 
 
@@ -88,7 +101,10 @@ def load_native() -> Optional[ctypes.CDLL]:
     try:
         lib = ctypes.CDLL(_LIB_PATH)
     except OSError as e:
-        logger.info("native library load failed (%s)", e)
+        logger.warning(
+            "native library load failed (%s): ingest falls back to the "
+            "pure-Python decoders (~60x slower on Avro)", e
+        )
         return None
     lib.libsvm_count.restype = ctypes.c_int
     lib.libsvm_count.argtypes = [

@@ -53,6 +53,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import Mesh, PartitionSpec as P
 
 from photon_ml_tpu.ops.losses import get_loss
 from photon_ml_tpu.ops.sparse import SparseBatch, validate_coo_indices
@@ -426,6 +427,12 @@ class TiledBatch:
     / labels / offsets / weights / num_features / num_rows), so it drops into
     every existing solve path unchanged. ``num_rows`` is padded to a multiple
     of 128; padded rows carry weight 0.
+
+    ``shard`` is set by ``parallel.sharding.place_batch`` when the tile
+    leaves are committed ``NamedSharding(mesh, P(axis))``: the Mosaic
+    compiler refuses to partition a kernel under GSPMD, so every kernel
+    below then runs per shard inside ``jax.shard_map`` over ``axis``
+    (:meth:`_run`). Everything outside the kernels stays GSPMD.
     """
 
     vals: Array      # f32[T, 1, S] slot values (0 in padding)
@@ -436,6 +443,9 @@ class TiledBatch:
     offsets3: Array  # f32[T, 1, 128]
     weights3: Array  # f32[T, 1, 128]; 0 for padded rows
     num_features: int = dataclasses.field(metadata=dict(static=True))
+    # (mesh, batch axis name) the tile leaves are sharded over, else None
+    shard: Optional[tuple[Mesh, str]] = dataclasses.field(
+        default=None, metadata=dict(static=True))
 
     # -- shape views --------------------------------------------------------
 
@@ -590,43 +600,80 @@ class TiledBatch:
     def _slot_args(self):
         return (self.vals, self.hi, self.lo, self.rlo)
 
+    def _run(self, make_call, tile_args, rep_args, reduce: bool):
+        """Run ``make_call(T)`` — a pallas_call over T tiles — on this
+        batch. ``tile_args`` lead with the tile dim, ``rep_args`` (the
+        coefficient grids and shifts) are whole on every device.
+
+        Under ``shard`` each device runs the kernel on its own T/n tiles
+        inside ``jax.shard_map``; with ``reduce`` the outputs are feature-
+        space accumulators and are ``psum``med over the batch axis,
+        otherwise they are per-row and stay sharded like the tiles."""
+        if self.shard is None:
+            return make_call(self.num_tiles)(*tile_args, *rep_args)
+        mesh, axis = self.shard
+        call = make_call(self.num_tiles // mesh.shape[axis])
+
+        def local(*args):
+            out = call(*args)
+            return jax.lax.psum(out, axis) if reduce else out
+
+        return jax.shard_map(
+            local,
+            mesh=mesh,
+            in_specs=(P(axis),) * len(tile_args) + (P(),) * len(rep_args),
+            out_specs=P() if reduce else P(axis),
+            check_vma=False,  # pallas_call carries no vma rule
+        )(*tile_args, *rep_args)
+
     def margins(self, w: Array, shift: Array | float = 0.0) -> Array:
         """Per-row margins z_i = x_i . w + shift + offset_i."""
-        T, _, S = self.vals.shape
-        call = _margins_call(T, S, self.num_blocks, True, False, _interpret())
+        S, B = self.vals.shape[2], self.num_blocks
         sh = jnp.stack([jnp.asarray(shift, jnp.float32), jnp.float32(0)])
-        z = call(*self._slot_args(), self.offsets3, self._w2(w),
-                 sh.reshape(1, 2))
+        z = self._run(
+            lambda T: _margins_call(T, S, B, True, False, _interpret()),
+            (*self._slot_args(), self.offsets3),
+            (self._w2(w), sh.reshape(1, 2)), reduce=False)
         return z.reshape(-1)
 
     def dot_rows(self, w: Array) -> Array:
         """Per-row raw dot products x_i . w (no offset/shift)."""
-        T, _, S = self.vals.shape
-        call = _margins_call(T, S, self.num_blocks, False, False, _interpret())
-        sh = jnp.zeros((1, 2), jnp.float32)
-        z = call(*self._slot_args(), self.offsets3, self._w2(w), sh)
+        S, B = self.vals.shape[2], self.num_blocks
+        z = self._run(
+            lambda T: _margins_call(T, S, B, False, False, _interpret()),
+            (*self._slot_args(), self.offsets3),
+            (self._w2(w), jnp.zeros((1, 2), jnp.float32)), reduce=False)
         return z.reshape(-1)
 
     def margins_pair(
         self, w: Array, shift, p: Array, p_shift
     ) -> tuple[Array, Array]:
         """(margins(w, shift), dot_rows(p) + p_shift) in one fused sweep."""
-        T, _, S = self.vals.shape
-        call = _margins_call(T, S, self.num_blocks, True, True, _interpret())
+        S, B = self.vals.shape[2], self.num_blocks
         sh = jnp.stack([
             jnp.asarray(shift, jnp.float32), jnp.asarray(p_shift, jnp.float32)
         ])
-        z, u = call(*self._slot_args(), self.offsets3, self._w2(w),
-                    self._w2(p), sh.reshape(1, 2))
+        z, u = self._run(
+            lambda T: _margins_call(T, S, B, True, True, _interpret()),
+            (*self._slot_args(), self.offsets3),
+            (self._w2(w), self._w2(p), sh.reshape(1, 2)), reduce=False)
         return z.reshape(-1), u.reshape(-1)
 
-    def _scatter(self, per_row: Array, square: bool) -> Array:
-        T, _, S = self.vals.shape
-        call = _scatter_call(T, S, self.num_blocks, square, _interpret())
-        pr3 = per_row.astype(jnp.float32).reshape(T, 1, ROWS_PER_TILE)
-        g = call(*self._slot_args(), pr3)
-        # accumulator is [LANE, B]; feature f = b*128 + j lives at [j, b]
+    def _features(self, g: Array) -> Array:
+        """[LANE, B] accumulator -> [F]: feature b*128 + j lives at [j, b]."""
         return g.T.reshape(-1)[: self.num_features]
+
+    def _rows3(self, per_row: Array) -> Array:
+        """[n_pad] per-row vector -> the [T, 1, 128] tile grid."""
+        return per_row.astype(jnp.float32).reshape(
+            self.num_tiles, 1, ROWS_PER_TILE)
+
+    def _scatter(self, per_row: Array, square: bool) -> Array:
+        S, B = self.vals.shape[2], self.num_blocks
+        g = self._run(
+            lambda T: _scatter_call(T, S, B, square, _interpret()),
+            (*self._slot_args(), self._rows3(per_row)), (), reduce=True)
+        return self._features(g)
 
     def scatter_features(self, per_row: Array) -> Array:
         """sum_i per_row[i] * x_i as a dense feature-space vector."""
@@ -645,13 +692,14 @@ class TiledBatch:
         the caller applies normalization back-transform and regularization
         (GLMObjective.value_and_grad fast path).
         """
-        T, _, S = self.vals.shape
-        call = _value_grad_call(
-            T, S, self.num_blocks, loss_name, True, _interpret())
+        S, B = self.vals.shape[2], self.num_blocks
         sh = jnp.stack([jnp.asarray(shift, jnp.float32), jnp.float32(0)])
-        sums, g = call(*self._slot_args(), self.labels3, self.weights3,
-                       self.offsets3, self._w2(w), sh.reshape(1, 2))
-        return sums[0, 0], g.T.reshape(-1)[: self.num_features], sums[0, 1]
+        sums, g = self._run(
+            lambda T: _value_grad_call(
+                T, S, B, loss_name, True, _interpret()),
+            (*self._slot_args(), self.labels3, self.weights3, self.offsets3),
+            (self._w2(w), sh.reshape(1, 2)), reduce=True)
+        return sums[0, 0], self._features(g), sums[0, 1]
 
     def fused_hessian_vector(
         self, w: Array, shift, v: Array, v_shift, loss_name: str
@@ -659,15 +707,15 @@ class TiledBatch:
         """(raw Hv scatter sum_i wgt_i*l''(z_i)*(x_i.v)*x_i, sum of the
         per-row q = wgt*l''*u terms) in ONE fused sweep (TRON CG fast path).
         Caller applies normalization back-transform and the L2 term."""
-        T, _, S = self.vals.shape
-        call = _hv_call(T, S, self.num_blocks, loss_name, True, _interpret())
+        S, B = self.vals.shape[2], self.num_blocks
         sh = jnp.stack([
             jnp.asarray(shift, jnp.float32), jnp.asarray(v_shift, jnp.float32)
         ])
-        sums, g = call(*self._slot_args(), self.labels3, self.weights3,
-                       self.offsets3, self._w2(w), self._w2(v),
-                       sh.reshape(1, 2))
-        return g.T.reshape(-1)[: self.num_features], sums[0, 0]
+        sums, g = self._run(
+            lambda T: _hv_call(T, S, B, loss_name, True, _interpret()),
+            (*self._slot_args(), self.labels3, self.weights3, self.offsets3),
+            (self._w2(w), self._w2(v), sh.reshape(1, 2)), reduce=True)
+        return self._features(g), sums[0, 0]
 
     def fused_hv_at(
         self, d2_row: Array, v_eff: Array, v_shift
@@ -675,13 +723,13 @@ class TiledBatch:
         """(raw Hv scatter, sum q) with the row curvature d2 = wgt*l''(z)
         precomputed: ONE pass doing gather u + scatter q (TRON CG holds z
         fixed across its inner loop)."""
-        T, _, S = self.vals.shape
-        call = _hv_at_call(T, S, self.num_blocks, _interpret())
-        d2_3 = d2_row.astype(jnp.float32).reshape(T, 1, ROWS_PER_TILE)
+        S, B = self.vals.shape[2], self.num_blocks
         sh = jnp.stack([jnp.asarray(v_shift, jnp.float32), jnp.float32(0)])
-        sums, g = call(*self._slot_args(), d2_3, self._w2(v_eff),
-                       sh.reshape(1, 2))
-        return g.T.reshape(-1)[: self.num_features], sums[0, 0]
+        sums, g = self._run(
+            lambda T: _hv_at_call(T, S, B, _interpret()),
+            (*self._slot_args(), self._rows3(d2_row)),
+            (self._w2(v_eff), sh.reshape(1, 2)), reduce=True)
+        return self._features(g), sums[0, 0]
 
     def feature_moment_sums(self) -> tuple[Array, Array, Array]:
         """Per-feature (sum x, sum x^2, count nonzero) over valid rows."""

@@ -12,8 +12,8 @@ inserts the collectives). This module keeps:
     ([num_shards, ...] leaves with LOCAL row indices) that multi-host
     workers assemble from process-local rows and feed to
     ``distributed_solve`` (flattened back inside the jit);
-  - :func:`shard_map_compat` — the cross-version ``shard_map`` shim, for
-    callers that genuinely need explicit SPMD.
+  - :func:`shard_map_compat` — ``jax.shard_map`` for callers that
+    genuinely need explicit SPMD.
 """
 
 from __future__ import annotations
@@ -31,27 +31,9 @@ ENTITY_AXIS = "entity"
 
 
 def shard_map_compat(f, mesh: Mesh, in_specs, out_specs, check: bool = False):
-    """``jax.shard_map`` across jax versions: newer jax exposes it at the
-    top level with ``check_vma``; older releases only ship
-    ``jax.experimental.shard_map.shard_map`` with ``check_rep``. Every
-    framework shard_map goes through here so the distributed solvers run
-    on both."""
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(
-                f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                check_vma=check,
-            )
-        except TypeError:  # older keyword spelling on this jax
-            return jax.shard_map(
-                f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                check_rep=check,
-            )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check,
+    """``jax.shard_map`` with this repo's default of ``check_vma=False``."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check
     )
 
 

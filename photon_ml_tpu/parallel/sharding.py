@@ -381,8 +381,18 @@ def place_batch(batch, mesh: Mesh, axis: Optional[str] = None):
     ``NamedSharding(mesh, P(axis))`` on its leading dim. The returned
     batch feeds :func:`photon_ml_tpu.parallel.distributed.gspmd_solve`
     directly — the whole optimizer while-loop then runs under one jit with
-    GSPMD-inserted psums."""
+    GSPMD-inserted psums (a TiledBatch's pallas kernels under
+    ``jax.shard_map`` over the same axis)."""
+    import dataclasses
+
+    from photon_ml_tpu.ops.tiled import TiledBatch
+
     axis = axis or data_axis(mesh)
     sharding = batch_sharding(mesh, axis)
     padded = pad_batch_rows(batch, axis_size(mesh, axis))
-    return jax.tree.map(lambda x: jax.device_put(x, sharding), padded)
+    placed = jax.tree.map(lambda x: jax.device_put(x, sharding), padded)
+    if isinstance(placed, TiledBatch):
+        # Mosaic kernels cannot be partitioned by GSPMD: the batch carries
+        # its mesh so each kernel runs per shard (TiledBatch._run)
+        placed = dataclasses.replace(placed, shard=(mesh, axis))
+    return placed

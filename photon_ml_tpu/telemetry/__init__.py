@@ -30,8 +30,8 @@ Five layers (ISSUE 1 gave emission; ISSUE 3 the interpretation):
   Nth honestly timed (fetch-synchronized through ``sync_fetch``),
   yielding per-executable exclusive seconds, MFU, arithmetic intensity,
   and a roofline bound class — the run report's "Hot executables" table
-  and the heartbeat's ``hot_exec`` field. Armed at import; sampled, so
-  steady-state overhead stays under 2%.
+  and the heartbeat's ``hot_exec`` field. Armed at import; sampled (one
+  dispatch in 64), so steady state pays the fetch rarely.
 - :mod:`photon_ml_tpu.telemetry.identity` / ``.fleet_report`` — fleet
   observability (ISSUE 13): per-member artifact suffixing
   (``trace.proc-0.jsonl``), process identity + epoch anchors in every
@@ -149,8 +149,10 @@ def flush_metrics(path: str) -> dict:
     """Append the metrics snapshot to ``path`` (``metrics.flush_jsonl``),
     after flushing the executable profiler's lazily-published derived
     gauges (MFU, bound class, ...) so offline report loads rebuild the
-    Hot-executables table from the JSONL alone."""
+    Hot-executables table from the JSONL alone, and after one last
+    per-device memory probe."""
     profile.publish_metrics()
+    memory.record_device_memory()  # end-of-run per-device HBM gauges
     return metrics.flush_jsonl(path)
 
 

@@ -88,9 +88,10 @@ def hbm_stats(device=None) -> Optional[dict[str, int]]:
 
 
 def record_device_memory(devices: Optional[Sequence] = None) -> dict[str, int]:
-    """Publish ``memory.device.<id>.bytes_in_use`` / ``.bytes_limit``
-    gauges for every device that exposes stats; returns the total in-use
-    bytes per device id (empty on statless backends)."""
+    """Publish ``memory.device.<id>.bytes_in_use`` / ``.bytes_limit`` /
+    ``.peak_bytes_in_use`` gauges for every device that exposes stats;
+    returns the total in-use bytes per device id (empty on statless
+    backends)."""
     if devices is None:
         try:
             import jax
@@ -109,6 +110,11 @@ def record_device_memory(devices: Optional[Sequence] = None) -> dict[str, int]:
         if "bytes_limit" in stats:
             metrics.gauge(f"memory.device.{did}.bytes_limit").set(
                 int(stats["bytes_limit"])
+            )
+        if "peak_bytes_in_use" in stats:
+            # the allocator's own high-watermark, not a sampled one
+            metrics.gauge(f"memory.device.{did}.peak_bytes_in_use").set(
+                int(stats["peak_bytes_in_use"])
             )
         out[str(did)] = in_use
     return out

@@ -23,10 +23,11 @@ Three pieces:
   structured warning at ``RECOMPILE_WARN_THRESHOLD`` distinct signatures
   — the recompile-storm detector.
 - roofline peaks — :func:`device_peaks` resolves the device's peak FLOP/s
-  and HBM bandwidth (known TPU generations; ``PHOTON_PEAK_FLOPS`` /
-  ``PHOTON_PEAK_HBM_GBPS`` env overrides; :func:`set_peaks` for tests)
-  and publishes them as ``device.peak_*`` gauges so reports loaded from a
-  metrics JSONL can compute MFU offline.
+  and HBM bandwidth from one table keyed by the exact ``device_kind``
+  (:func:`set_peaks` for tests; a kind the table does not know is
+  "unknown", never a neighbour's numbers) and publishes them as
+  ``device.peak_*`` gauges so reports loaded from a metrics JSONL can
+  compute MFU offline.
 - collective estimates — :func:`record_collective` turns mesh sharding
   specs into estimated wire bytes (ring psum moves ``2(n-1)/n`` of the
   payload per device; all-gather ``(n-1)/n``), exposed as ``comms.*``
@@ -35,16 +36,17 @@ Three pieces:
 
 Everything degrades gracefully: backends without cost/memory analysis
 leave those record fields ``None`` (rendered "unknown"), an executable
-that cannot be AOT-compiled falls back to plain ``jax.jit`` dispatch
-(``xla.fallback_calls``), and analysis is injectable for deterministic
-tests via :func:`set_analysis_provider`.
+that cannot be AOT-compiled falls back to plain ``jax.jit`` dispatch —
+logged as a WARNING with the compiler's text and counted in
+``xla.fallback_calls``, so a run can assert it never happened — and
+analysis is injectable for deterministic tests via
+:func:`set_analysis_provider`.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import logging
-import os
 import threading
 import time
 from typing import Any, Callable, Mapping, Optional, Sequence
@@ -75,19 +77,24 @@ logger = logging.getLogger("photon_ml_tpu.telemetry.xla")
 RECOMPILE_WARN_THRESHOLD = 3
 
 # Peak per-chip dense-matmul FLOP/s (bf16) and HBM bandwidth (bytes/s) by
-# device_kind substring, most specific first. Used for MFU / bandwidth
-# utilization denominators; unknown kinds yield None ("unknown" in
-# reports). Sources: published TPU system specs per generation.
-_PEAK_TABLE: tuple[tuple[str, float, float], ...] = (
-    ("TPU v6", 918e12, 1640e9),  # Trillium / v6e
-    ("TPU v5p", 459e12, 2765e9),
-    ("TPU v5 lite", 197e12, 819e9),  # v5e
-    ("TPU v5e", 197e12, 819e9),
-    ("TPU v5", 459e12, 2765e9),
-    ("TPU v4", 275e12, 1228e9),
-    ("TPU v3", 123e12, 900e9),
-    ("TPU v2", 45e12, 700e9),
-)
+# the EXACT ``device_kind`` jax reports (both spellings jax itself knows
+# per generation, jax/_src/pallas/mosaic/tpu_info.py). Denominators of MFU
+# and bandwidth utilization; a kind that is not a key is "unknown" (None).
+# Source: Google Cloud TPU documentation, system architecture per version.
+_V5E = (197e12, 819e9)
+_V5P = (459e12, 2765e9)
+_V6E = (918e12, 1640e9)
+_PEAK_TABLE: dict[str, tuple[float, float]] = {
+    "TPU v2": (45e12, 700e9),
+    "TPU v3": (123e12, 900e9),
+    "TPU v4": (275e12, 1228e9),
+    "TPU v5 lite": _V5E,
+    "TPU v5e": _V5E,
+    "TPU v5": _V5P,
+    "TPU v5p": _V5P,
+    "TPU v6 lite": _V6E,
+    "TPU v6e": _V6E,
+}
 
 # test/override hooks (cleared by reset(); plain attribute swaps — set
 # from the main/test thread, read racily by design: a torn read returns
@@ -140,36 +147,16 @@ def _publish_peaks(
 
 def device_peaks() -> tuple[Optional[float], Optional[float]]:
     """``(peak_flops, peak_hbm_bytes_per_sec)`` for device 0, or ``None``s
-    when unknown (CPU, unrecognized kinds). Resolution order: injected
-    override, ``PHOTON_PEAK_FLOPS``/``PHOTON_PEAK_HBM_GBPS`` env vars,
-    the known-TPU table. Publishes ``device.peak_*`` gauges when known so
-    offline report loads can compute MFU from the metrics JSONL."""
+    when its ``device_kind`` is not a key of the table (CPU, a generation
+    nobody entered). An injected :func:`set_peaks` override wins.
+    Publishes ``device.peak_*`` gauges when known so offline report loads
+    can compute MFU from the metrics JSONL."""
     if _peaks_override is not None:
         return _peaks_override
-    def _env_float(name: str, scale: float = 1.0) -> Optional[float]:
-        raw = os.environ.get(name)
-        if not raw:
-            return None
-        try:
-            return float(raw) * scale
-        except ValueError:  # malformed override: unknown, never a crash
-            logger.warning("ignoring malformed %s=%r", name, raw)
-            return None
+    import jax
 
-    flops = _env_float("PHOTON_PEAK_FLOPS")
-    bw = _env_float("PHOTON_PEAK_HBM_GBPS", scale=1e9)
-    if flops is None or bw is None:
-        try:
-            import jax
-
-            kind = str(jax.devices()[0].device_kind)
-        except Exception:  # noqa: BLE001 — accounting must never fail
-            kind = ""
-        for sub, table_flops, table_bw in _PEAK_TABLE:
-            if sub.lower() in kind.lower():
-                flops = table_flops if flops is None else flops
-                bw = table_bw if bw is None else bw
-                break
+    flops, bw = _PEAK_TABLE.get(
+        str(jax.devices()[0].device_kind), (None, None))
     _publish_peaks(flops, bw)
     return flops, bw
 
@@ -190,12 +177,7 @@ def set_analysis_provider(provider: Optional[Callable]) -> None:
 
 
 def _cost_mapping(raw: Any) -> Optional[Mapping[str, float]]:
-    """Normalize ``cost_analysis()`` output: jax returns a dict on recent
-    versions and a one-element list of dicts on older ones."""
-    if raw is None:
-        return None
-    if isinstance(raw, (list, tuple)):
-        raw = raw[0] if raw else None
+    """``cost_analysis()`` output as a mapping, else None."""
     return raw if isinstance(raw, Mapping) else None
 
 
@@ -230,6 +212,21 @@ def _analyze(compiled: Any) -> tuple[Optional[Mapping], Any]:
     except Exception:  # noqa: BLE001
         mem = None
     return cost, mem
+
+
+def _mosaic_kernels(compiled: Any) -> Optional[int]:
+    """How many Mosaic kernels the compiled module calls — the proof that a
+    pallas path really lowered for the TPU and not through the
+    interpreter. None when the executable offers no text. Only a TPU
+    program can hold one: elsewhere the text is not even rendered."""
+    import jax
+
+    if jax.default_backend() != "tpu":
+        return 0
+    try:
+        return compiled.as_text().count('custom_call_target="tpu_custom_call"')
+    except Exception:  # noqa: BLE001 — accounting must never fail a compile
+        return None
 
 
 # ---------------------------------------------------------------------------
@@ -357,6 +354,9 @@ class ExecutableRecord:
     argument_bytes: Optional[int] = None
     output_bytes: Optional[int] = None
     generated_code_bytes: Optional[int] = None
+    # Mosaic (pallas TPU) kernels in the compiled module: 0 on a backend
+    # where pallas runs in interpret mode, None when there is no text
+    mosaic_kernels: Optional[int] = None
     calls: int = 0
 
     def to_dict(self) -> dict[str, Any]:
@@ -386,6 +386,7 @@ class ExecutableRegistry:
         cost: Optional[Mapping],
         mem: Any,
         multi_shape: bool = False,
+        mosaic_kernels: Optional[int] = None,
     ) -> ExecutableRecord:
         """Insert (or refresh) the record for a freshly compiled
         executable, publish its compile metrics, and attribute a
@@ -412,6 +413,7 @@ class ExecutableRegistry:
             generated_code_bytes=_mem_field(
                 mem, "generated_code_size_in_bytes"
             ),
+            mosaic_kernels=mosaic_kernels,
         )
         with self._lock:
             self._records[(name, signature)] = rec
@@ -440,6 +442,10 @@ class ExecutableRegistry:
             )
         if rec.temp_bytes is not None:
             metrics.gauge(f"xla.exec.{name}.temp_bytes").set(rec.temp_bytes)
+        if rec.mosaic_kernels:
+            metrics.gauge(f"xla.exec.{name}.mosaic_kernels").set(
+                rec.mosaic_kernels
+            )
         if prior and multi_shape:
             # expected shape set: registered and accounted, not a storm
             logger.info(
@@ -638,7 +644,7 @@ class InstrumentedFunction:
             # safe even with donated arguments. Runtime errors (OOM,
             # XlaRuntimeError) propagate — re-executing after a partial
             # run could read already-donated buffers.
-            logger.debug(
+            logger.warning(
                 "AOT dispatch of '%s' failed; falling back to jax.jit",
                 self.name,
                 exc_info=True,
@@ -656,19 +662,25 @@ class InstrumentedFunction:
         try:
             lowered = self._jit.lower(*args, **kwargs)
             compiled = lowered.compile()
-        except Exception:  # noqa: BLE001 — backends/args AOT cannot handle
-            logger.debug(
-                "AOT compile of '%s' unavailable; using jax.jit dispatch",
+        except Exception as e:  # noqa: BLE001 — backends/args AOT cannot handle
+            # a compiler refusal (e.g. Mosaic) lands here too: say so NOW,
+            # with its text — the jit re-dispatch will raise it again from
+            # somewhere less obvious
+            logger.warning(
+                "AOT compile of '%s' failed (%s: %s); using jax.jit dispatch",
                 self.name,
-                exc_info=True,
+                type(e).__name__,
+                e,
             )
             metrics.counter("xla.fallback_calls").inc()
         dt = time.monotonic() - t0
+        mosaic = None
         if compiled is not None:
             cost, mem = _analyze(compiled)
+            mosaic = _mosaic_kernels(compiled)
         rec = XLA_REGISTRY.record_compile(
             self.name, leaf_sig, structure, dt, cost, mem,
-            multi_shape=self._multi_shape,
+            multi_shape=self._multi_shape, mosaic_kernels=mosaic,
         )
         trace.add_event(
             "xla_compile",

@@ -646,3 +646,55 @@ def test_merge_sweep_flags_shared_helper():
     )
     assert merged["grid"] == ["lambda=2"]
     assert merged["policy"] == "parsimonious"
+
+
+# -- compile cache: one rule for every entry point ----------------------------
+
+
+def test_compile_cache_env_places_it_and_code_sets_nothing(monkeypatch):
+    import jax
+
+    from photon_ml_tpu.utils import enable_compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/placed")
+    assert enable_compile_cache() == "/somewhere/placed"
+    assert jax.config.jax_compilation_cache_dir == before  # untouched
+
+
+def test_compile_cache_default_is_fixed_inside_the_checkout(monkeypatch):
+    import getpass
+    import subprocess
+    import tempfile
+
+    import jax
+
+    from photon_ml_tpu.utils import compile_cache
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        assert compile_cache.enable_compile_cache() == compile_cache.DEFAULT_DIR
+        assert jax.config.jax_compilation_cache_dir == compile_cache.DEFAULT_DIR
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    # a path that never moves: no temp dir, user name or pid in it
+    assert compile_cache.DEFAULT_DIR == os.path.join(repo, ".jax_cache")
+    assert not compile_cache.DEFAULT_DIR.startswith(tempfile.gettempdir())
+    assert getpass.getuser() not in os.path.relpath(
+        compile_cache.DEFAULT_DIR, repo)
+    if os.path.isdir(os.path.join(repo, ".git")):
+        ignored = subprocess.run(
+            ["git", "check-ignore", "-q", ".jax_cache/x"], cwd=repo)
+        assert ignored.returncode == 0
+
+
+def test_cli_dispatcher_enables_the_cache_for_every_subcommand(monkeypatch):
+    from photon_ml_tpu import utils
+    from photon_ml_tpu.cli.__main__ import main
+
+    calls = []
+    monkeypatch.setattr(utils, "enable_compile_cache", lambda: calls.append(1))
+    assert main(["no-such-command"]) == 2
+    assert calls == [1]

@@ -366,3 +366,31 @@ def test_native_parser_edge_semantics_match_python(tmp_path):
     compare("1 2:3#comment\n")    # attached '#': both must REJECT
     compare("1 2:3\r-1 4:5\r")    # CR-only line endings: two rows
     compare("+1 1:0.5 # ok\n")    # standalone trailing comment token
+
+
+def test_native_build_failure_is_a_warning_and_leaves_nothing(
+    monkeypatch, caplog, tmp_path
+):
+    """Losing the native library costs ~60x on Avro decode: it must be
+    said at WARNING level, and a failed build must not leave a
+    half-written library behind for the next process to load."""
+    import logging
+    import subprocess
+
+    from photon_ml_tpu.data import native
+
+    target = tmp_path / "libphoton_native.so"
+    monkeypatch.setattr(native, "_LIB_PATH", str(target))
+
+    def no_compiler(cmd, **kwargs):
+        # what g++ leaves when it dies mid-link
+        open(cmd[cmd.index("-o") + 1], "wb").write(b"partial")
+        raise subprocess.CalledProcessError(1, cmd, stderr=b"ld: no -lz")
+
+    monkeypatch.setattr(native.subprocess, "run", no_compiler)
+    with caplog.at_level(logging.WARNING, "photon_ml_tpu.native"):
+        assert native._build() is False
+    text = " ".join(r.getMessage() for r in caplog.records)
+    assert "native build failed" in text and "no -lz" in text
+    assert "falls back to the pure-Python" in text
+    assert list(tmp_path.iterdir()) == []

@@ -1,12 +1,8 @@
-"""parallel/mesh.py + parallel/sharding.py unit coverage.
-
-The ``shard_map_compat`` shim unbroke the 7 seed-failing distributed
-tests (PR 5) but its two API branches were never directly tested: newer
-jax exposes top-level ``jax.shard_map`` with ``check_vma`` (and some
-releases spell it ``check_rep``), older jax only ships
-``jax.experimental.shard_map.shard_map`` with ``check_rep``. Both
-branches are pinned here via monkeypatched availability, plus one real
-collective through whichever branch the installed jax provides.
+"""parallel/mesh.py + parallel/sharding.py unit coverage: the
+``shard_map_compat`` spelling, the sharding primitives, and the tiled
+layout's kernels running per shard under ``jax.shard_map`` (the Mosaic
+compiler refuses GSPMD partitioning, so ``place_batch`` tags the batch
+with its mesh) against the same kernels on one device.
 """
 
 import jax
@@ -25,7 +21,7 @@ def mesh(multichip):
 
 
 # ---------------------------------------------------------------------------
-# shard_map_compat: real execution through the installed branch
+# shard_map_compat
 # ---------------------------------------------------------------------------
 
 
@@ -39,11 +35,6 @@ def test_compat_executes_a_psum(mesh):
     assert float(jax.jit(f)(x)) == float(np.sum(np.arange(8.0)))
 
 
-# ---------------------------------------------------------------------------
-# shard_map_compat: branch selection via monkeypatched availability
-# ---------------------------------------------------------------------------
-
-
 def _call_through(mesh, check=False):
     return shard_map_compat(
         lambda x: x, mesh, in_specs=P("data"), out_specs=P("data"),
@@ -51,7 +42,7 @@ def _call_through(mesh, check=False):
     )
 
 
-def test_top_level_branch_uses_check_vma(monkeypatch, mesh):
+def test_compat_passes_check_as_check_vma(monkeypatch, mesh):
     seen = {}
 
     def fake_shard_map(f, mesh, in_specs, out_specs, **kwargs):
@@ -61,36 +52,6 @@ def test_top_level_branch_uses_check_vma(monkeypatch, mesh):
     monkeypatch.setattr(jax, "shard_map", fake_shard_map, raising=False)
     assert _call_through(mesh, check=True)() == "top-level"
     assert seen == {"check_vma": True}
-
-
-def test_top_level_branch_falls_back_to_check_rep_spelling(monkeypatch, mesh):
-    calls = []
-
-    def fake_shard_map(f, mesh, in_specs, out_specs, **kwargs):
-        if "check_vma" in kwargs:
-            raise TypeError("got an unexpected keyword argument 'check_vma'")
-        calls.append(kwargs)
-        return lambda *a: "old-keyword"
-
-    monkeypatch.setattr(jax, "shard_map", fake_shard_map, raising=False)
-    assert _call_through(mesh)() == "old-keyword"
-    assert calls == [{"check_rep": False}]
-
-
-def test_experimental_branch_uses_check_rep(monkeypatch, mesh):
-    # no top-level jax.shard_map at all -> the jax.experimental path
-    monkeypatch.delattr(jax, "shard_map", raising=False)
-    import jax.experimental.shard_map as esm
-
-    seen = {}
-
-    def fake_shard_map(f, mesh, in_specs, out_specs, **kwargs):
-        seen.update(kwargs)
-        return lambda *a: "experimental"
-
-    monkeypatch.setattr(esm, "shard_map", fake_shard_map)
-    assert _call_through(mesh, check=True)() == "experimental"
-    assert seen == {"check_rep": True}
 
 
 # ---------------------------------------------------------------------------
@@ -170,6 +131,97 @@ def test_place_batch_pads_tiles(rng, multichip):
     z_ref = np.asarray(tb.dot_rows(wvec))
     z = np.asarray(placed.dot_rows(wvec))
     np.testing.assert_allclose(z[: len(z_ref)], z_ref, rtol=1e-5, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# tiled kernels per shard (shard_map over the batch axis) vs one device
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture
+def tiled_pair(rng, multichip):
+    """(one-device TiledBatch, the same design placed over batch=4) with
+    313 rows: 3 tiles pad to 4, so one shard is all padding."""
+    from photon_ml_tpu.ops.tiled import TiledBatch
+
+    n, d = 313, 150
+    X = rng.normal(size=(n, d)) * (rng.random((n, d)) < 0.2)
+    y = (rng.random(n) > 0.5).astype(float)
+    tb = TiledBatch.from_dense(
+        X, y, offsets=rng.normal(size=n) * 0.1, weights=rng.random(n) + 0.5
+    )
+    mesh = make_mesh({"batch": 4, "model": 2})
+    placed = psharding.place_batch(tb, mesh)
+    assert placed.shard == (mesh, "batch")
+    assert placed.vals.sharding.spec == P("batch")
+    return tb, placed
+
+
+def _rows(placed, per_row):
+    pad = placed.num_rows - per_row.shape[0]
+    return jnp.pad(per_row, (0, pad))
+
+
+_KERNEL_CASES = {
+    "margins": lambda b, w, v, r: b.margins(w, 0.3),
+    "dot_rows": lambda b, w, v, r: b.dot_rows(w),
+    "margins_pair": lambda b, w, v, r: b.margins_pair(w, 0.3, v, -0.2),
+    "scatter": lambda b, w, v, r: b.scatter_features(_rows(b, r)),
+    "scatter_sq": lambda b, w, v, r: b.scatter_features_sq(_rows(b, r)),
+    "value_grad": lambda b, w, v, r: b.fused_value_grad(w, 0.3, "logistic"),
+    "hv": lambda b, w, v, r: b.fused_hessian_vector(
+        w, 0.3, v, -0.2, "logistic"),
+    "hv_at": lambda b, w, v, r: b.fused_hv_at(_rows(b, jnp.abs(r)), v, -0.2),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_KERNEL_CASES))
+def test_sharded_tiled_kernel_matches_one_device(case, tiled_pair, rng):
+    tb, placed = tiled_pair
+    d = tb.num_features
+    w = jnp.asarray(rng.normal(size=d) * 0.1, jnp.float32)
+    v = jnp.asarray(rng.normal(size=d) * 0.1, jnp.float32)
+    r = jnp.asarray(rng.normal(size=tb.num_rows), jnp.float32)
+    fn = _KERNEL_CASES[case]
+    ref = jax.tree.leaves(fn(tb, w, v, r))
+    got = jax.tree.leaves(jax.jit(lambda b: fn(b, w, v, r))(placed))
+    assert len(ref) == len(got)
+    for a, b in zip(ref, got):
+        a, b = np.asarray(a), np.asarray(b)
+        if b.shape != a.shape:  # per-row outputs carry the padded tiles
+            assert np.all(b[a.shape[0]:] == b[a.shape[0]]), case
+            b = b[: a.shape[0]]
+        np.testing.assert_allclose(b, a, rtol=2e-5, atol=2e-5)
+
+
+def test_sharded_tiled_solve_matches_one_device(tiled_pair):
+    """The whole LBFGS while-loop over the sharded tiles (gspmd_solve) lands
+    on the one-device optimum, replicated over the mesh."""
+    from photon_ml_tpu.optim import (
+        OptimizerConfig, RegularizationContext, RegularizationType,
+    )
+    from photon_ml_tpu.optim.adapter import glm_adapter
+    from photon_ml_tpu.optim.factory import build_objective, dispatch_solve
+    from photon_ml_tpu.parallel.distributed import gspmd_solve
+
+    tb, placed = tiled_pair
+    cfg = OptimizerConfig(
+        max_iterations=30, tolerance=1e-8,
+        regularization=RegularizationContext(RegularizationType.L2),
+        regularization_weight=1.0,
+    )
+    w0 = jnp.zeros((tb.num_features,), jnp.float32)
+    obj = build_objective("logistic", cfg)
+    ref = jax.jit(
+        lambda b: dispatch_solve(glm_adapter(obj, b), w0, cfg, jnp.float32(0))
+    )(tb)
+    mesh = placed.shard[0]
+    res = gspmd_solve("logistic", placed, cfg, w0, mesh)
+    assert res.w.sharding.is_fully_replicated
+    # f32 sums in another order: same optimum value, iterates a hair apart
+    np.testing.assert_allclose(float(res.value), float(ref.value), rtol=1e-5)
+    np.testing.assert_allclose(
+        np.asarray(res.w), np.asarray(ref.w), rtol=1e-3, atol=2e-3)
 
 
 def test_pad_count():

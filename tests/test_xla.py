@@ -210,23 +210,65 @@ def test_aot_failure_falls_back_to_plain_jit(fake_analysis):
 # -- peaks / collectives ------------------------------------------------------
 
 
-def test_device_peaks_injection_and_env(monkeypatch):
+def test_device_peaks_injection():
     assert xla.device_peaks() == (None, None)  # CPU: unknown
-    monkeypatch.setenv("PHOTON_PEAK_FLOPS", "2e12")
-    monkeypatch.setenv("PHOTON_PEAK_HBM_GBPS", "100")
-    flops, bw = xla.device_peaks()
-    assert flops == 2e12 and bw == 100e9
-    g = telemetry.snapshot()["gauges"]
-    assert g["device.peak_flops"] == 2e12
-    assert g["device.peak_hbm_bytes_per_sec"] == 100e9
-    # an explicit injection wins over env
     xla.set_peaks(1e12, 5e10)
     assert xla.device_peaks() == (1e12, 5e10)
-    # malformed env overrides degrade to unknown, never crash
+    g = telemetry.snapshot()["gauges"]
+    assert g["device.peak_flops"] == 1e12
+    assert g["device.peak_hbm_bytes_per_sec"] == 5e10
     xla.reset()
-    monkeypatch.setenv("PHOTON_PEAK_FLOPS", "not-a-number")
-    monkeypatch.setenv("PHOTON_PEAK_HBM_GBPS", "819GB")
     assert xla.device_peaks() == (None, None)
+
+
+class _Kind:
+    def __init__(self, kind):
+        self.device_kind = kind
+
+
+@pytest.mark.parametrize("kind, peaks", [
+    ("TPU v5 lite", (197e12, 819e9)),  # what jax calls the v5e
+    ("TPU v5e", (197e12, 819e9)),
+    ("TPU v5p", (459e12, 2765e9)),
+    ("TPU v4", (275e12, 1228e9)),
+    # not keys of the table: unknown, never a neighbour's numbers
+    ("TPU v5 lite pod", (None, None)),
+    ("TPU v5x", (None, None)),
+    ("TPU7x", (None, None)),
+    ("cpu", (None, None)),
+])
+def test_device_peaks_match_the_exact_kind(monkeypatch, kind, peaks):
+    import jax
+
+    monkeypatch.setattr(jax, "devices", lambda *a: [_Kind(kind)])
+    monkeypatch.setenv("PHOTON_PEAK_FLOPS", "2e12")  # no longer read
+    assert xla.device_peaks() == peaks
+
+
+def test_aot_compile_failure_is_logged_with_its_text(caplog):
+    """A swallowed AOT refusal must name itself at WARNING level: the jit
+    re-dispatch raises it again from a less obvious place."""
+    import logging
+
+    f = telemetry.instrumented_jit(lambda x: x + 1.0, name="loud")
+
+    class _LowerBoom:
+        def lower(self, *a, **k):
+            raise NotImplementedError(
+                "Mosaic kernels cannot be automatically partitioned")
+
+        def __call__(self, *a, **k):
+            return f._fn(*a, **k)
+
+    f._jit = _LowerBoom()
+    with caplog.at_level(logging.WARNING, "photon_ml_tpu.telemetry.xla"):
+        f(np.ones((2,), np.float32))
+    assert any(
+        "loud" in r.getMessage()
+        and "cannot be automatically partitioned" in r.getMessage()
+        for r in caplog.records
+    )
+    assert telemetry.snapshot()["counters"]["xla.fallback_calls"] == 1
 
 
 def test_collective_bytes_math():
