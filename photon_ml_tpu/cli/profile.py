@@ -6,16 +6,16 @@
 Everything after ``--`` is a normal CLI invocation (train, score, glm,
 serve, report, ...). The wrapped run executes inside a profiler capture:
 ``--profile-dir`` receives the xplane/TensorBoard artifacts (open with
-TensorBoard's profile plugin or xprof), and every telemetry span is
-mirrored as a ``jax.profiler.TraceAnnotation`` so our span tree
-(``fit > cd_iteration > coordinate:<name>``) lines up with the XLA
-executable timeline — the "which executable ran inside which phase"
-question BENCH_r05 could not answer.
+TensorBoard's profile plugin or xprof). Every telemetry span mirrors
+itself as a ``jax.profiler.TraceAnnotation`` named ``photon:<span>``
+(``telemetry/trace.py``, always on), so the capture holds our span tree
+(``fit > coordinate_descent > cd_iteration > coordinate:<name> > update``)
+on the clock of the XLA executable timeline — the "which executable ran
+inside which phase" question BENCH_r05 could not answer.
 
 Degrades gracefully: a backend that cannot start the profiler logs a
 warning and runs the wrapped command unprofiled (exit code is the wrapped
-command's either way); ``--no-annotations`` disables the span mirror for
-overhead-sensitive captures.
+command's either way).
 """
 
 from __future__ import annotations
@@ -45,11 +45,6 @@ def main(argv: Optional[list] = None) -> int:
         required=True,
         help="directory for the xplane/TensorBoard profiler capture",
     )
-    parser.add_argument(
-        "--no-annotations",
-        action="store_true",
-        help="do not mirror telemetry spans as profiler annotations",
-    )
     args = parser.parse_args(own)
     if not wrapped:
         parser.error(
@@ -60,10 +55,7 @@ def main(argv: Optional[list] = None) -> int:
     import jax
 
     from photon_ml_tpu.cli.__main__ import main as cli_main
-    from photon_ml_tpu.telemetry import trace
 
-    if not args.no_annotations:
-        trace.set_annotation_factory(jax.profiler.TraceAnnotation)
     started = False
     try:
         jax.profiler.start_trace(args.profile_dir)
@@ -90,8 +82,6 @@ def main(argv: Optional[list] = None) -> int:
                     f"warning: profiler capture failed to finalize: {e}",
                     file=sys.stderr,
                 )
-        if not args.no_annotations:
-            trace.set_annotation_factory(None)
     return rc
 
 

@@ -12,6 +12,18 @@ Scores live as [n_pad] device arrays keyed by coordinate name — the
 KeyValueScore analog, where "+" is vector addition instead of an RDD join.
 The loop itself is host-side Python (as in the reference); all per-step
 compute is jit-compiled device work.
+
+Span tree of one call (every caller — ``GameEstimator.fit``, sweeps, the
+incremental path, a benchmark — gets it, because it opens here)::
+
+    coordinate_descent
+      initial_scores          ends on a 1-element fetch per coordinate
+      cd_iteration
+        coordinate:<name>     history ``seconds`` = its start -> score fetch
+          residual            only with more than one coordinate
+          update              to the end of the tracker's and guard's fetch
+          score               coord.score + its 1-element fetch
+          validate            validation scoring, evaluators, their fetches
 """
 
 from __future__ import annotations
@@ -84,6 +96,12 @@ def padded_validation_arrays(
 
 
 def _evaluate(model: GameModel, spec: ValidationSpec) -> dict[str, float]:
+    """Validation metrics of ``model``: one accounted fetch per evaluator
+    (the host's wait on validation scoring and the evaluator's program)."""
+
+    def fetch(value, spec_str: str) -> float:
+        return float(telemetry.sync_fetch(value, label=f"evaluate:{spec_str}"))
+
     scores = model.score(spec.data)
     n = spec.data.num_rows
     n_pad = scores.shape[0]
@@ -94,7 +112,9 @@ def _evaluate(model: GameModel, spec: ValidationSpec) -> dict[str, float]:
     for spec_str in spec.evaluators:
         kind, group_col, k = parse_evaluator(spec_str)
         if kind in EVALUATORS:
-            out[spec_str] = float(EVALUATORS[kind](full_scores, labels, weights))
+            out[spec_str] = fetch(
+                EVALUATORS[kind](full_scores, labels, weights), spec_str
+            )
             continue
         col = next(
             (c for c in spec.data.id_columns if c.lower() == group_col), None
@@ -109,14 +129,16 @@ def _evaluate(model: GameModel, spec: ValidationSpec) -> dict[str, float]:
             np.pad(idc.codes, (0, n_pad - n)), jnp.int32
         )
         if kind == "sharded_auc":
-            out[spec_str] = float(
-                sharded_auc(full_scores, labels, weights, gids, idc.num_entities)
+            out[spec_str] = fetch(
+                sharded_auc(full_scores, labels, weights, gids, idc.num_entities),
+                spec_str,
             )
         else:
-            out[spec_str] = float(
+            out[spec_str] = fetch(
                 sharded_precision_at_k(
                     full_scores, labels, weights, gids, idc.num_entities, k
-                )
+                ),
+                spec_str,
             )
     return out
 
@@ -234,162 +256,187 @@ def run_coordinate_descent(
       turns true a final checkpoint is written and TrainingInterrupted is
       raised (the graceful-preemption handshake).
     """
-    names = list(coordinates)
-    models = {
-        name: (
-            initial_models[name]
-            if initial_models and name in initial_models
-            else coordinates[name].initialize_model()
-        )
-        for name in names
-    }
+    with telemetry.span(
+        "coordinate_descent",
+        num_iterations=num_iterations,
+        num_coordinates=len(coordinates),
+    ):
+        names = list(coordinates)
+        models = {
+            name: (
+                initial_models[name]
+                if initial_models and name in initial_models
+                else coordinates[name].initialize_model()
+            )
+            for name in names
+        }
 
-    best_model: Optional[GameModel] = None
-    best_metric: Optional[float] = None
-    history: list[dict] = []
-    start_step = 0
-    if checkpoint is not None:
-        restored = checkpoint.restore()
-        if restored is not None:
-            if list(restored.model.models) != names:
-                raise CheckpointError(
-                    f"checkpoint at {checkpoint.spec.directory} was written "
-                    f"by a fit with coordinates "
-                    f"{list(restored.model.models)}, not {names}"
+        best_model: Optional[GameModel] = None
+        best_metric: Optional[float] = None
+        history: list[dict] = []
+        start_step = 0
+        if checkpoint is not None:
+            restored = checkpoint.restore()
+            if restored is not None:
+                if list(restored.model.models) != names:
+                    raise CheckpointError(
+                        f"checkpoint at {checkpoint.spec.directory} was written "
+                        f"by a fit with coordinates "
+                        f"{list(restored.model.models)}, not {names}"
+                    )
+                models = dict(restored.model.models)
+                best_model = restored.best_model
+                best_metric = restored.best_metric
+                history = list(restored.history)
+                start_step = restored.step + 1
+        # scores recomputed from the (possibly restored) models — checkpoints
+        # persist models only; scores are derived state
+        with telemetry.span("initial_scores"):
+            scores = {}
+            for name in names:
+                scores[name] = coordinates[name].score(models[name])
+                # wait for them, or the first `update` span holds their
+                # device time (dispatch does not wait)
+                telemetry.sync_fetch(
+                    scores[name][0], label=f"initial_scores:{name}"
                 )
-            models = dict(restored.model.models)
-            best_model = restored.best_model
-            best_metric = restored.best_metric
-            history = list(restored.history)
-            start_step = restored.step + 1
-    # scores recomputed from the (possibly restored) models — checkpoints
-    # persist models only; scores are derived state
-    scores = {name: coordinates[name].score(models[name]) for name in names}
 
-    # guard bookkeeping survives resume: a coordinate already proved
-    # divergent must not re-burn its retries every remaining iteration.
-    # Restored ONLY when a guard is active — resuming with guard=None is
-    # an explicit request to train every coordinate again.
-    frozen: set[str] = set()
-    consecutive_rollbacks = {name: 0 for name in names}
-    if guard is not None and checkpoint is not None and restored is not None:
-        frozen = {n for n in restored.frozen if n in consecutive_rollbacks}
-        for n, count in (restored.consecutive_rollbacks or {}).items():
-            if n in consecutive_rollbacks:
-                consecutive_rollbacks[n] = int(count)
-    last_ckpt_path: Optional[str] = None
+        # guard bookkeeping survives resume: a coordinate already proved
+        # divergent must not re-burn its retries every remaining iteration.
+        # Restored ONLY when a guard is active — resuming with guard=None is
+        # an explicit request to train every coordinate again.
+        frozen: set[str] = set()
+        consecutive_rollbacks = {name: 0 for name in names}
+        if guard is not None and checkpoint is not None and restored is not None:
+            frozen = {n for n in restored.frozen if n in consecutive_rollbacks}
+            for n, count in (restored.consecutive_rollbacks or {}).items():
+                if n in consecutive_rollbacks:
+                    consecutive_rollbacks[n] = int(count)
+        last_ckpt_path: Optional[str] = None
 
-    for it in range(num_iterations):
-        with telemetry.span("cd_iteration", iteration=it):
-            for idx, name in enumerate(names):
-                step = it * len(names) + idx
-                if step < start_step:
-                    continue  # completed before the restored checkpoint
-                if name in frozen:
-                    continue  # divergent coordinate: last good model stands
-                coord = coordinates[name]
-                with telemetry.span(f"coordinate:{name}", iteration=it) as sp:
-                    residual = None
-                    if len(names) > 1:
-                        residual = sum(
-                            (scores[o] for o in names if o != name),
-                            start=jnp.zeros_like(scores[name]),
-                        )
-                        if guard is not None:
-                            # a NaN-scoring coordinate (e.g. rolled back to
-                            # zeros over NaN features) must not poison its
-                            # neighbors' solves through the residual
-                            residual = jnp.nan_to_num(
-                                residual, nan=0.0, posinf=0.0, neginf=0.0
+        for it in range(num_iterations):
+            with telemetry.span("cd_iteration", iteration=it):
+                for idx, name in enumerate(names):
+                    step = it * len(names) + idx
+                    if step < start_step:
+                        continue  # completed before the restored checkpoint
+                    if name in frozen:
+                        continue  # divergent coordinate: last good model stands
+                    coord = coordinates[name]
+                    with telemetry.span(f"coordinate:{name}", iteration=it) as sp:
+                        residual = None
+                        if len(names) > 1:
+                            with telemetry.span("residual"):
+                                residual = sum(
+                                    (scores[o] for o in names if o != name),
+                                    start=jnp.zeros_like(scores[name]),
+                                )
+                                if guard is not None:
+                                    # a NaN-scoring coordinate (e.g. rolled
+                                    # back to zeros over NaN features) must
+                                    # not poison its neighbors' solves
+                                    # through the residual
+                                    residual = jnp.nan_to_num(
+                                        residual, nan=0.0, posinf=0.0,
+                                        neginf=0.0,
+                                    )
+                        rolled_back = False
+                        attempts = 0
+                        with telemetry.span("update"):
+                            if guard is None:
+                                models[name] = coord.update_model(
+                                    models[name], residual
+                                )
+                            else:
+                                models[name], attempts, rolled_back = (
+                                    _guarded_update(
+                                        coord, models[name], residual, guard,
+                                        name,
+                                    )
+                                )
+                        with telemetry.span("score"):
+                            if not rolled_back:
+                                # a rolled-back model is unchanged; its
+                                # scores stand
+                                scores[name] = coord.score(models[name])
+                            # wait for the scores before stopping the clock:
+                            # a 1-element fetch through the accounted crossing
+                            telemetry.sync_fetch(
+                                scores[name][0], label=f"coordinate:{name}"
                             )
-                    rolled_back = False
-                    attempts = 0
-                    if guard is None:
-                        models[name] = coord.update_model(models[name], residual)
+
+                        entry = {
+                            "iteration": it,
+                            "coordinate": name,
+                            "seconds": telemetry.trace.TRACER.now() - sp.ts,
+                        }
+                        if guard is not None and (attempts or rolled_back):
+                            entry["solve_retries"] = attempts
+                            entry["rolled_back"] = rolled_back
+                        tracker = getattr(coord, "last_tracker", None)
+                        if tracker is not None and not rolled_back:
+                            # per-update optimization telemetry (the reference's
+                            # OptimizationTracker surfaced in CD logs)
+                            entry["tracker"] = tracker.to_summary_string()
+                        if validation is not None:
+                            game_model = GameModel(task=task, models=dict(models))
+                            with telemetry.span("validate"):
+                                metrics = _evaluate(game_model, validation)
+                            entry["metrics"] = metrics
+                            primary = validation.evaluators[0]
+                            value = metrics[primary]
+                            if best_metric is None or better_than(
+                                primary, value, best_metric
+                            ):
+                                best_metric = value
+                                best_model = game_model
+                            logger.info(
+                                "CD iter %d coord %s: %s (%.2fs)", it, name,
+                                metrics, entry["seconds"],
+                            )
+                        sp.set_attr(seconds=round(entry["seconds"], 6))
+                        _record_step_progress(
+                            coord, models[name], name, entry["seconds"]
+                        )
+                    history.append(entry)
+                    if on_step is not None:
+                        on_step(entry)
+
+                    if rolled_back:
+                        consecutive_rollbacks[name] += 1
+                        if consecutive_rollbacks[name] >= guard.freeze_after:
+                            frozen.add(name)
+                            telemetry.counter("solves.frozen").inc()
+                            logger.warning(
+                                "coordinate %s frozen after %d consecutive "
+                                "rollbacks; its last good model keeps scoring",
+                                name, consecutive_rollbacks[name],
+                            )
                     else:
-                        models[name], attempts, rolled_back = _guarded_update(
-                            coord, models[name], residual, guard, name
-                        )
-                    if not rolled_back:
-                        # a rolled-back model is unchanged; its scores stand
-                        scores[name] = coord.score(models[name])
-                    # wait for the scores before stopping the clock: a
-                    # 1-element fetch through the accounted crossing
-                    telemetry.sync_fetch(
-                        scores[name][0], label=f"coordinate:{name}"
-                    )
+                        consecutive_rollbacks[name] = 0
 
-                    entry = {
-                        "iteration": it,
-                        "coordinate": name,
-                        "seconds": telemetry.trace.TRACER.now() - sp.ts,
-                    }
-                    if guard is not None and (attempts or rolled_back):
-                        entry["solve_retries"] = attempts
-                        entry["rolled_back"] = rolled_back
-                    tracker = getattr(coord, "last_tracker", None)
-                    if tracker is not None and not rolled_back:
-                        # per-update optimization telemetry (the reference's
-                        # OptimizationTracker surfaced in CD logs)
-                        entry["tracker"] = tracker.to_summary_string()
-                    if validation is not None:
-                        game_model = GameModel(task=task, models=dict(models))
-                        metrics = _evaluate(game_model, validation)
-                        entry["metrics"] = metrics
-                        primary = validation.evaluators[0]
-                        value = metrics[primary]
-                        if best_metric is None or better_than(
-                            primary, value, best_metric
-                        ):
-                            best_metric = value
-                            best_model = game_model
-                        logger.info(
-                            "CD iter %d coord %s: %s (%.2fs)", it, name,
-                            metrics, entry["seconds"],
+                    faults.fault_point(_FP_STEP_BOUNDARY)
+                    stop = should_stop is not None and should_stop()
+                    if checkpoint is not None and (
+                        stop or checkpoint.should_save(step)
+                    ):
+                        last_ckpt_path = checkpoint.save(
+                            CheckpointState(
+                                step=step,
+                                model=GameModel(task=task, models=dict(models)),
+                                best_model=best_model,
+                                best_metric=best_metric,
+                                history=history,
+                                frozen=sorted(frozen),
+                                consecutive_rollbacks=dict(consecutive_rollbacks),
+                            )
                         )
-                    sp.set_attr(seconds=round(entry["seconds"], 6))
-                    _record_step_progress(
-                        coord, models[name], name, entry["seconds"]
-                    )
-                history.append(entry)
-                if on_step is not None:
-                    on_step(entry)
+                    if stop:
+                        raise TrainingInterrupted(step, last_ckpt_path)
 
-                if rolled_back:
-                    consecutive_rollbacks[name] += 1
-                    if consecutive_rollbacks[name] >= guard.freeze_after:
-                        frozen.add(name)
-                        telemetry.counter("solves.frozen").inc()
-                        logger.warning(
-                            "coordinate %s frozen after %d consecutive "
-                            "rollbacks; its last good model keeps scoring",
-                            name, consecutive_rollbacks[name],
-                        )
-                else:
-                    consecutive_rollbacks[name] = 0
-
-                faults.fault_point(_FP_STEP_BOUNDARY)
-                stop = should_stop is not None and should_stop()
-                if checkpoint is not None and (
-                    stop or checkpoint.should_save(step)
-                ):
-                    last_ckpt_path = checkpoint.save(
-                        CheckpointState(
-                            step=step,
-                            model=GameModel(task=task, models=dict(models)),
-                            best_model=best_model,
-                            best_metric=best_metric,
-                            history=history,
-                            frozen=sorted(frozen),
-                            consecutive_rollbacks=dict(consecutive_rollbacks),
-                        )
-                    )
-                if stop:
-                    raise TrainingInterrupted(step, last_ckpt_path)
-
-    final = GameModel(task=task, models=dict(models))
-    if best_model is None:
-        best_model = final
-    return CoordinateDescentResult(
-        model=final, best_model=best_model, best_metric=best_metric, history=history
-    )
+        final = GameModel(task=task, models=dict(models))
+        if best_model is None:
+            best_model = final
+        return CoordinateDescentResult(
+            model=final, best_model=best_model, best_metric=best_metric, history=history
+        )

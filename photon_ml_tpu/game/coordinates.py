@@ -51,7 +51,9 @@ from photon_ml_tpu.optim.factory import OptimizerConfig, dispatch_solve
 from photon_ml_tpu.optim.guard import damped_objective, solve_health
 from photon_ml_tpu.parallel.distributed import gspmd_solve
 from photon_ml_tpu.parallel import sharding as psharding
+from photon_ml_tpu.telemetry.device import accounted_upload
 from photon_ml_tpu.telemetry.metrics import gauge
+from photon_ml_tpu.telemetry.trace import span
 from photon_ml_tpu.telemetry.xla import instrumented_jit, record_collective
 
 Array = jax.Array
@@ -129,8 +131,13 @@ class FixedEffectCoordinate:
         self._use_tiled = self.layout == "tiled" or (
             self.layout == "auto" and jax.default_backend() == "tpu"
         )
+        # the design is laid out on the host (span `layout`), then placed
+        # (span `upload`): the two never overlap, and the host copy dies
+        # with this method
+        host_tiled = None
         if self._use_tiled:
-            self._tiled = TiledBatch.from_batch(self._base_batch)
+            with span("layout"):
+                host_tiled = TiledBatch.pack_batch(self._base_batch)
         # fresh sample per update_model (runWithSampling parity: the reference
         # re-samples on every coordinate update, DistributedOptimizationProblem
         # .scala:113-125); counter salts the rng so updates differ
@@ -195,19 +202,23 @@ class FixedEffectCoordinate:
             self._axis = psharding.data_axis(self.mesh)
             self._n_shards = psharding.axis_size(self.mesh, self._axis)
             self._row_sharding = psharding.batch_sharding(self.mesh, self._axis)
-            self._solve_batch = psharding.place_batch(
-                self._tiled if self._use_tiled else self._base_batch,
-                self.mesh,
-                self._axis,
+            self._solve_batch = accounted_upload(
+                lambda: psharding.place_batch(
+                    host_tiled if self._use_tiled else self._base_batch,
+                    self.mesh,
+                    self._axis,
+                )
             )
             if self._use_tiled:
-                # ONE resident design: drop the unsharded copy; scoring
-                # goes through the sharded tiles too
+                # ONE resident design, placed from the host straight onto
+                # its shards; scoring goes through the sharded tiles too
                 self._tiled = self._solve_batch
-        elif not self._use_tiled:
+        elif self._use_tiled:
+            self._tiled = accounted_upload(host_tiled.device)
+        else:
             # single-device COO solve path: upload the design ONCE; per-row
             # updates (offsets/weights) are swapped onto this device copy
-            self._solve_batch = self._base_batch.device()
+            self._solve_batch = accounted_upload(self._base_batch.device)
         design = self._tiled if self._use_tiled else self._solve_batch
         _record_placement(f"{self.name}.design", jax.tree.leaves(design)[0])
 

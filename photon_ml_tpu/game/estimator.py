@@ -224,14 +224,15 @@ class GameEstimator:
             return hit[1]
         if len(self._re_datasets) >= 8:  # bound growth on long-lived estimators
             self._re_datasets.pop(next(iter(self._re_datasets)))
-        red = build_random_effect_dataset(
-            data,
-            c.id_name,
-            c.shard_name,
-            active_rows_per_entity=c.active_rows_per_entity,
-            min_rows_per_entity=c.min_rows_per_entity,
-            features_to_samples_ratio=ratio,
-        )
+        with telemetry.span("layout"):  # host grouping + bucketing
+            red = build_random_effect_dataset(
+                data,
+                c.id_name,
+                c.shard_name,
+                active_rows_per_entity=c.active_rows_per_entity,
+                min_rows_per_entity=c.min_rows_per_entity,
+                features_to_samples_ratio=ratio,
+            )
         self._re_datasets[key] = (data, red)
         return red
 
@@ -242,6 +243,23 @@ class GameEstimator:
         opt_overrides: Optional[Mapping[str, OptimizerConfig]] = None,
         only: Optional[set] = None,
     ) -> dict:
+        """The fit's coordinates, built or reused, under a
+        ``build_coordinates`` span: every caller (``fit``, sweeps, the
+        incremental path, a benchmark) gets it. A build runs under a
+        ``build:<coordinate>`` span whose ``layout`` / ``upload`` children
+        the coordinate and its datasets open."""
+        with telemetry.span("build_coordinates") as sp:
+            coords, built = self._build_or_reuse(
+                data, mesh, opt_overrides or {}, only
+            )
+            # cached: every coordinate was a reuse — no layout, no upload
+            sp.set_attr(cached=not built, built=built)
+        return coords
+
+    def _build_or_reuse(
+        self, data: GameDataset, mesh: Optional[Mesh], overrides, only
+    ) -> tuple[dict, list]:
+        """(coordinates, names of those built anew)."""
         # Meshes with named batch/model axes (the GSPMD vocabulary,
         # parallel.sharding; `--mesh batch=N,model=M`) are used AS GIVEN:
         # each coordinate resolves its own axis, so FE rows shard over
@@ -270,7 +288,6 @@ class GameEstimator:
                 devices = mesh.devices.reshape(-1)
                 data_mesh = Mesh(devices, (DATA_AXIS,))
                 entity_mesh = Mesh(devices, (ENTITY_AXIS,))
-        overrides = opt_overrides or {}
         # the caches serve REPEATED fits over the same data (benchmarks,
         # grid sweeps, warm-started re-fits); entries for other datasets are
         # dropped so device-resident design matrices never pin old data
@@ -281,6 +298,7 @@ class GameEstimator:
             k: v for k, v in self._re_datasets.items() if v[0] is data
         }
         coords = {}
+        built = []
         for name, c in self.config.coordinates.items():
             if only is not None and name not in only:
                 continue
@@ -307,75 +325,77 @@ class GameEstimator:
                     coord.last_health = None
                 coords[name] = coord
                 continue
-            if isinstance(c, FixedEffectConfig):
-                norm = self._normalization_for(data, c)
-                coords[name] = FixedEffectCoordinate(
-                    name=name,
-                    data=data,
-                    shard_name=c.shard_name,
-                    loss_name=self.config.task,
-                    config=opt or c.optimizer,
-                    seed=c.down_sampling_seed,
-                    normalization=norm,
-                    mesh=data_mesh,
-                    layout=c.layout,
-                )
-            elif isinstance(c, RandomEffectConfig):
-                red = self._re_dataset(data, c)
-                _record_table_estimate(
-                    name, red, dim=c.projected_dim
-                    if c.projector == "random" else None,
-                )
-                if c.projector == "random":
-                    # fixed Gaussian projection: per-entity solves in the
-                    # shared projected space (RandomEffectCoordinateIn
-                    # ProjectedSpace + ProjectorType.RANDOM analog)
+            with telemetry.span(f"build:{name}"):
+                if isinstance(c, FixedEffectConfig):
+                    norm = self._normalization_for(data, c)
+                    coords[name] = FixedEffectCoordinate(
+                        name=name,
+                        data=data,
+                        shard_name=c.shard_name,
+                        loss_name=self.config.task,
+                        config=opt or c.optimizer,
+                        seed=c.down_sampling_seed,
+                        normalization=norm,
+                        mesh=data_mesh,
+                        layout=c.layout,
+                    )
+                elif isinstance(c, RandomEffectConfig):
+                    red = self._re_dataset(data, c)
+                    _record_table_estimate(
+                        name, red, dim=c.projected_dim
+                        if c.projector == "random" else None,
+                    )
+                    if c.projector == "random":
+                        # fixed Gaussian projection: per-entity solves in the
+                        # shared projected space (RandomEffectCoordinateIn
+                        # ProjectedSpace + ProjectorType.RANDOM analog)
+                        coords[name] = FactoredRandomEffectCoordinate(
+                            name=name,
+                            data=data,
+                            re_data=red,
+                            loss_name=self.config.task,
+                            re_config=opt or c.optimizer,
+                            latent_config=opt or c.optimizer,
+                            latent_dim=c.projected_dim,
+                            refit_projection=False,
+                            projection_intercept_index=c.projection_intercept_index,
+                            seed=c.projection_seed,
+                            mesh=entity_mesh,
+                        )
+                    else:
+                        coords[name] = RandomEffectCoordinate(
+                            name=name,
+                            data=data,
+                            re_data=red,
+                            loss_name=self.config.task,
+                            config=opt or c.optimizer,
+                            mesh=entity_mesh,
+                            compute_variances=c.compute_variances,
+                        )
+                elif isinstance(c, FactoredRandomEffectConfig):
+                    red = self._re_dataset(data, c)
+                    _record_table_estimate(name, red, dim=c.latent_dim)
                     coords[name] = FactoredRandomEffectCoordinate(
                         name=name,
                         data=data,
                         re_data=red,
                         loss_name=self.config.task,
-                        re_config=opt or c.optimizer,
-                        latent_config=opt or c.optimizer,
-                        latent_dim=c.projected_dim,
-                        refit_projection=False,
-                        projection_intercept_index=c.projection_intercept_index,
-                        seed=c.projection_seed,
+                        re_config=opt or c.re_optimizer,
+                        latent_config=c.latent_optimizer,
+                        latent_dim=c.latent_dim,
+                        mf_iterations=c.mf_iterations,
+                        seed=c.seed,
                         mesh=entity_mesh,
                     )
                 else:
-                    coords[name] = RandomEffectCoordinate(
-                        name=name,
-                        data=data,
-                        re_data=red,
-                        loss_name=self.config.task,
-                        config=opt or c.optimizer,
-                        mesh=entity_mesh,
-                        compute_variances=c.compute_variances,
+                    raise TypeError(
+                        f"coordinate '{name}': unknown config {type(c).__name__}"
                     )
-            elif isinstance(c, FactoredRandomEffectConfig):
-                red = self._re_dataset(data, c)
-                _record_table_estimate(name, red, dim=c.latent_dim)
-                coords[name] = FactoredRandomEffectCoordinate(
-                    name=name,
-                    data=data,
-                    re_data=red,
-                    loss_name=self.config.task,
-                    re_config=opt or c.re_optimizer,
-                    latent_config=c.latent_optimizer,
-                    latent_dim=c.latent_dim,
-                    mf_iterations=c.mf_iterations,
-                    seed=c.seed,
-                    mesh=entity_mesh,
-                )
-            else:
-                raise TypeError(
-                    f"coordinate '{name}': unknown config {type(c).__name__}"
-                )
+            built.append(name)
             if len(self._coordinates) >= 16:
                 self._coordinates.pop(next(iter(self._coordinates)))
             self._coordinates[cache_key] = (data, coords[name])
-        return coords
+        return coords, built
 
     @staticmethod
     def _normalization_for(
@@ -435,8 +455,7 @@ class GameEstimator:
             task=self.config.task,
             num_coordinates=len(self.config.coordinates),
         ):
-            with telemetry.span("build_coordinates"):
-                coordinates = self._build_coordinates(data, mesh)
+            coordinates = self._build_coordinates(data, mesh)
             telemetry_memory.record_phase_memory("build_coordinates")
             validation = None
             if validation_data is not None:

@@ -34,6 +34,8 @@ import numpy as np
 
 from photon_ml_tpu.game.dataset import GameDataset
 from photon_ml_tpu.ops.sparse import SparseBatch
+from photon_ml_tpu.telemetry.device import accounted_upload
+from photon_ml_tpu.telemetry.trace import span
 
 Array = jax.Array
 
@@ -125,14 +127,14 @@ class RandomEffectDataset:
             ).get(i)
             b = self.buckets[i]
             if stripped is not None:
+                coo = accounted_upload(
+                    lambda: jax.device_put((b.values, b.rows, b.cols))
+                )
                 hit = dataclasses.replace(
-                    stripped,
-                    values=jax.device_put(b.values),
-                    rows=jax.device_put(b.rows),
-                    cols=jax.device_put(b.cols),
+                    stripped, values=coo[0], rows=coo[1], cols=coo[2]
                 )
             else:
-                hit = jax.device_put(b)
+                hit = accounted_upload(lambda: jax.device_put(b))
             memo[i] = hit
         return hit
 
@@ -147,15 +149,22 @@ class RandomEffectDataset:
         """Per-bucket PACKED dense device designs as [E, R*K] rows
         (row-major per entity; solvers reshape inside jit — see
         coordinates._packed_dense_batch), or None where the COO layout
-        wins — built host-side once, cached like device_buckets."""
+        wins — built host-side once, cached like device_buckets. Bucket by
+        bucket: densify on the host (span ``layout``), place (span
+        ``upload``), drop the host copy."""
         from photon_ml_tpu.game.coordinates import _bucket_dense_design
 
         cached = self.__dict__.get("_dense_designs")
         if cached is None:
-            cached = tuple(
-                None if x is None else jax.device_put(x)
-                for x in (_bucket_dense_design(b) for b in self.buckets)
-            )
+            designs = []
+            for b in self.buckets:
+                with span("layout"):
+                    x = _bucket_dense_design(b)
+                designs.append(
+                    None if x is None
+                    else accounted_upload(lambda: jax.device_put(x))
+                )
+            cached = tuple(designs)
             object.__setattr__(self, "_dense_designs", cached)
         return cached
 
@@ -190,9 +199,11 @@ class RandomEffectDataset:
                 # at 138K entities
                 stub = np.zeros((1, 1), np.float32)
                 stub_i = np.zeros((1, 1), np.int32)
-                stripped = jax.device_put(
-                    dataclasses.replace(
-                        b, values=stub, rows=stub_i, cols=stub_i
+                stripped = accounted_upload(
+                    lambda: jax.device_put(
+                        dataclasses.replace(
+                            b, values=stub, rows=stub_i, cols=stub_i
+                        )
                     )
                 )
                 smemo[i] = stripped  # later full requests reuse the leaves

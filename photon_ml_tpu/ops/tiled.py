@@ -311,6 +311,12 @@ def _hv_at_kernel(*refs):
     _scatter_accum(out_g_ref, per_slot, mask_hi, mask_lo)
 
 
+# Every pallas_call below carries a ``name``: it becomes the custom call's
+# HLO instruction name (``%tiled_margins.1 = ... custom-call(...)``), which
+# is what a device event's name starts with in a profiler trace — the one
+# handle by which a reduction tells these kernels apart.
+
+
 def _spec_s(S):
     return pl.BlockSpec((1, 1, S), lambda i: (i, 0, 0), memory_space=pltpu.VMEM)
 
@@ -345,6 +351,7 @@ def _margins_call(T, S, B, use_offsets, pair, interpret):
         out_specs=out_specs if pair else out_specs[0],
         out_shape=out_shape if pair else out_shape[0],
         interpret=interpret,
+        name="tiled_margins",
     )
 
 
@@ -358,6 +365,7 @@ def _scatter_call(T, S, B, square, interpret):
         out_specs=_spec_acc((LANE, B)),
         out_shape=jax.ShapeDtypeStruct((LANE, B), jnp.float32),
         interpret=interpret,
+        name="tiled_scatter",
     )
 
 
@@ -375,6 +383,7 @@ def _hv_call(T, S, B, loss_name, use_offsets, interpret):
             jax.ShapeDtypeStruct((LANE, B), jnp.float32),
         ],
         interpret=interpret,
+        name="tiled_hv",
     )
 
 
@@ -391,6 +400,7 @@ def _hv_at_call(T, S, B, interpret):
             jax.ShapeDtypeStruct((LANE, B), jnp.float32),
         ],
         interpret=interpret,
+        name="tiled_hv_at",
     )
 
 
@@ -408,6 +418,7 @@ def _value_grad_call(T, S, B, loss_name, use_offsets, interpret):
             jax.ShapeDtypeStruct((LANE, B), jnp.float32),
         ],
         interpret=interpret,
+        name="tiled_value_grad",
     )
 
 
@@ -484,7 +495,7 @@ class TiledBatch:
     # -- construction -------------------------------------------------------
 
     @staticmethod
-    def from_coo(
+    def pack_coo(
         values: np.ndarray,
         rows: np.ndarray,
         cols: np.ndarray,
@@ -493,7 +504,8 @@ class TiledBatch:
         offsets: Optional[np.ndarray] = None,
         weights: Optional[np.ndarray] = None,
     ) -> "TiledBatch":
-        """Host-side layout build: group nnz by row tile, pad to max."""
+        """Host-side layout build: group nnz by row tile, pad to max. The
+        leaves stay HOST numpy arrays; :meth:`device` places them."""
         n = int(len(labels))
         R = ROWS_PER_TILE
         T = max(-(-n // R), 1)
@@ -538,24 +550,35 @@ class TiledBatch:
 
         shp = (T, 1, S)
         return TiledBatch(
-            vals=jnp.asarray(vals2.reshape(shp)),
-            hi=jnp.asarray(hi2.reshape(shp)),
-            lo=jnp.asarray(lo2.reshape(shp)),
-            rlo=jnp.asarray(rlo2.reshape(shp)),
-            labels3=jnp.asarray(lab.reshape(T, 1, R)),
-            offsets3=jnp.asarray(off.reshape(T, 1, R)),
-            weights3=jnp.asarray(wgt.reshape(T, 1, R)),
+            vals=vals2.reshape(shp),
+            hi=hi2.reshape(shp),
+            lo=lo2.reshape(shp),
+            rlo=rlo2.reshape(shp),
+            labels3=lab.reshape(T, 1, R),
+            offsets3=off.reshape(T, 1, R),
+            weights3=wgt.reshape(T, 1, R),
             num_features=int(num_features),
         )
 
+    def device(self) -> "TiledBatch":
+        """Place the leaves on the default device (host -> device copy of a
+        :meth:`pack_coo` / :meth:`pack_batch` layout)."""
+        return jax.tree.map(jnp.asarray, self)
+
     @staticmethod
-    def from_batch(batch: SparseBatch) -> "TiledBatch":
-        """Convert a padded-COO SparseBatch (drops its padding slots)."""
+    def from_coo(*args, **kwargs) -> "TiledBatch":
+        """:meth:`pack_coo`, placed on the device."""
+        return TiledBatch.pack_coo(*args, **kwargs).device()
+
+    @staticmethod
+    def pack_batch(batch: SparseBatch) -> "TiledBatch":
+        """Host-side layout of a padded-COO SparseBatch (drops its padding
+        slots); leaves stay host numpy arrays."""
         vals = np.asarray(batch.values)
         rows = np.asarray(batch.rows)
         cols = np.asarray(batch.cols)
         keep = vals != 0
-        return TiledBatch.from_coo(
+        return TiledBatch.pack_coo(
             values=vals[keep],
             rows=rows[keep],
             cols=cols[keep],
@@ -564,6 +587,11 @@ class TiledBatch:
             offsets=np.asarray(batch.offsets),
             weights=np.asarray(batch.weights),
         )
+
+    @staticmethod
+    def from_batch(batch: SparseBatch) -> "TiledBatch":
+        """:meth:`pack_batch`, placed on the device."""
+        return TiledBatch.pack_batch(batch).device()
 
     @staticmethod
     def from_dense(X, labels, offsets=None, weights=None) -> "TiledBatch":

@@ -12,11 +12,31 @@ bincounts — no RDD reduce.
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import numpy as np
 
 from photon_ml_tpu.optim.common import CONVERGENCE_REASON_NAMES
 from photon_ml_tpu.telemetry import metrics as _metrics
+
+
+@functools.lru_cache(maxsize=1)
+def _fe_packer():
+    """(iterations, reason, value, grad_norms[iterations]) as ONE vector in
+    the solve's float dtype (the two small ints are exact in it): one
+    executable, so that the tracker costs one fetch and no eager ops."""
+    import jax.numpy as jnp
+
+    from photon_ml_tpu.telemetry import instrumented_jit
+
+    def pack(iterations, reason, value, grad_norms):
+        dt = jnp.result_type(value, grad_norms)
+        return jnp.stack([
+            iterations.astype(dt), reason.astype(dt), value.astype(dt),
+            grad_norms[iterations].astype(dt),
+        ])
+
+    return instrumented_jit(pack, name="fe_tracker_pack", multi_shape=True)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -30,14 +50,27 @@ class FixedEffectOptimizationTracker:
 
     @staticmethod
     def from_result(res) -> "FixedEffectOptimizationTracker":
-        it = int(res.iterations)
+        """ONE packed host fetch (the wait on the solve), accounted by
+        ``telemetry.sync_fetch`` like the random-effect tracker's."""
+        import jax.numpy as jnp
+
+        from photon_ml_tpu.telemetry import sync_fetch
+
+        packed = sync_fetch(
+            _fe_packer()(
+                jnp.asarray(res.iterations), jnp.asarray(res.reason),
+                jnp.asarray(res.value), jnp.asarray(res.grad_norms),
+            ),
+            label="fe_tracker",
+        )
+        it = int(packed[0])
         _metrics.counter("fe_solves").inc()
         _metrics.histogram("fe_solve_iterations").observe(it)
         return FixedEffectOptimizationTracker(
             iterations=it,
-            reason=CONVERGENCE_REASON_NAMES.get(int(res.reason), "Unknown"),
-            final_value=float(res.value),
-            final_grad_norm=float(res.grad_norms[it]),
+            reason=CONVERGENCE_REASON_NAMES.get(int(packed[1]), "Unknown"),
+            final_value=float(packed[2]),
+            final_grad_norm=float(packed[3]),
         )
 
     def to_summary_string(self) -> str:

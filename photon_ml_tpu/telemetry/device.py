@@ -15,7 +15,13 @@ Two costs a wall clock cannot attribute become metrics here:
   feeds the ``jit_compile_seconds`` histogram, and shows up as a named
   ``compile`` event on whatever span was open; every program served from
   or written to the persistent compilation cache increments
-  ``jit_cache_hits`` / ``jit_cache_writes``.
+  ``jit_cache_hits`` / ``jit_cache_writes``. A compile that fires while
+  no ``instrumented_jit`` compile is in flight on its thread is an
+  unaccounted program — an eager one-op dispatch, a bare ``jax.jit`` — and
+  also counts into ``jit_compiles_eager`` / ``jit_compile_seconds_eager``.
+- Host->device placement of a design is the other crossing:
+  :func:`accounted_upload` runs it under an ``upload`` span that ends on a
+  one-element fetch of the last array placed, and counts ``upload.bytes``.
 
 Metric names emitted:
 
@@ -23,20 +29,30 @@ Metric names emitted:
   (counters) and ``device_fetch_seconds`` (histogram)
 - ``jit_compiles`` / ``jit_compile_seconds`` (counter) and
   ``jit_compile_seconds`` (histogram)
+- ``jit_compiles_eager`` / ``jit_compile_seconds_eager`` (counters)
 - ``jit_cache_hits`` / ``jit_cache_writes`` (counters)
+- ``upload.bytes`` (counter)
 """
 
 from __future__ import annotations
 
 import threading
 import time
-from typing import Any, Optional
+from contextlib import contextmanager
+from typing import Any, Callable, Iterator, Optional, TypeVar
 
 import numpy as np
 
 from photon_ml_tpu.telemetry import metrics, trace
 
-__all__ = ["sync_fetch", "install_compile_hooks"]
+__all__ = [
+    "sync_fetch",
+    "accounted_upload",
+    "accounted_compile",
+    "install_compile_hooks",
+]
+
+T = TypeVar("T")
 
 # jax.monitoring duration events counted as compiles: the backend (XLA)
 # compile is the expensive one; trace/lowering durations are recorded
@@ -50,6 +66,22 @@ _CACHE_EVENTS = {
 
 _hooks_lock = threading.Lock()
 _hooks_installed = False
+
+# depth of instrumented_jit compiles in flight on this thread: a backend
+# compile that fires at depth 0 belongs to no accounted executable
+_accounted = threading.local()
+
+
+@contextmanager
+def accounted_compile() -> Iterator[None]:
+    """Marks this thread's backend compiles as an ``instrumented_jit``'s own
+    (``telemetry.xla`` wraps its lower+compile in it), so the compile hook
+    can tell them from eager one-op programs."""
+    _accounted.depth = getattr(_accounted, "depth", 0) + 1
+    try:
+        yield
+    finally:
+        _accounted.depth -= 1
 
 
 def sync_fetch(x: Any, label: Optional[str] = None) -> np.ndarray:
@@ -81,6 +113,29 @@ def sync_fetch(x: Any, label: Optional[str] = None) -> np.ndarray:
     return out
 
 
+def accounted_upload(place: Callable[[], T]) -> T:
+    """Run ``place()`` — a host->device placement returning a pytree of
+    device arrays — under an ``upload`` span. The span closes on a
+    one-element :func:`sync_fetch` of the last non-empty array placed
+    (transfers queue in order), and counter ``upload.bytes`` rises by the
+    bytes placed. Host code only: never call it inside a traced function."""
+    import jax
+
+    with trace.span("upload") as sp:
+        out = place()
+        leaves = [
+            x for x in jax.tree.leaves(out)
+            if isinstance(x, jax.Array) and x.size
+        ]
+        nbytes = sum(int(x.nbytes) for x in leaves)
+        metrics.counter("upload.bytes").inc(nbytes)
+        sp.set_attr(bytes=nbytes)
+        if leaves:
+            last = leaves[-1]
+            sync_fetch(last[(0,) * last.ndim], label="upload")
+    return out
+
+
 def install_compile_hooks() -> bool:
     """Subscribe the compile and cache counters to ``jax.monitoring``
     (idempotent; returns True).
@@ -103,7 +158,12 @@ def install_compile_hooks() -> bool:
                 metrics.counter("jit_compiles").inc()
                 metrics.counter("jit_compile_seconds").inc(duration)
                 metrics.histogram("jit_compile_seconds").observe(duration)
-                trace.add_event("compile", seconds=round(duration, 6))
+                eager = not getattr(_accounted, "depth", 0)
+                if eager:
+                    metrics.counter("jit_compiles_eager").inc()
+                    metrics.counter("jit_compile_seconds_eager").inc(duration)
+                trace.add_event(
+                    "compile", seconds=round(duration, 6), eager=eager)
             except Exception:  # noqa: BLE001 — never fail a compile
                 pass
 

@@ -7,7 +7,7 @@ finish line. The :class:`Heartbeat` is a daemon thread that every
 ``photon_ml_tpu.telemetry.progress`` logger and (optionally) a JSONL sink:
 
     {"type": "heartbeat", "seq": 3, "uptime_s": 90.1,
-     "span": "fit > cd_iteration > coordinate:per-user",
+     "span": "fit > coordinate_descent > cd_iteration > coordinate:per-user > update",
      "rows_per_s": 812345.0, "coeffs_per_s": 104321.0,
      "rows_total": 2.4e7, "coeffs_total": 3.1e6,
      "hbm_bytes_in_use": 7516192768, "checkpoint_age_s": 41.0,

@@ -5,8 +5,9 @@ accounting) and PR 2 durable state (checkpoint manifests) — but reading a
 run still meant loading a trace into Perfetto by hand. :class:`RunReport`
 merges the three exhaust streams into one document:
 
-- **phase-time breakdown**: the aggregated ``fit > cd_iteration >
-  coordinate:<name>`` span tree with per-phase count/total/self time;
+- **phase-time breakdown**: the aggregated ``fit > coordinate_descent >
+  cd_iteration > coordinate:<name> > update`` span tree with per-phase
+  count/total/self time;
 - **top-k costs** and **fetch/recompile accounting** (host waits on the
   device and silent-recompile counters, summarized instead of eyeballed);
 - **per-coordinate convergence and guard history** from the newest

@@ -24,6 +24,13 @@ Span JSONL schema (one line per completed span)::
 
 ``ts`` is seconds since the tracer's monotonic anchor; ``events[].ts``
 shares the same timebase.
+
+Every span is also entered as a ``jax.profiler.TraceAnnotation`` named
+``photon:<span name>`` (a flag check while no profiler capture runs), so a
+capture started by anyone — ``cli profile``, ``cli train --xprof-dir``, a
+benchmark's ``start_trace`` — holds the span tree on its host plane, beside
+the device planes (whose stamps ran 1-3 ms ahead of the host's in the v5e
+traces on record: ``benchmark/program_trace.py`` aligns them, PERF.md).
 """
 
 from __future__ import annotations
@@ -50,13 +57,34 @@ __all__ = [
     "configure",
     "reset",
     "finished_spans",
-    "set_annotation_factory",
     "to_chrome_trace",
     "export_chrome_trace",
     "perfetto_path",
 ]
 
 DEFAULT_BUFFER_LIMIT = 50_000
+
+#: prefix of a span's mirror in a profiler capture
+ANNOTATION_PREFIX = "photon:"
+
+_trace_annotation = None  # jax.profiler.TraceAnnotation; False = no jax
+
+
+def _annotation(name: str):
+    """The span's mirror in the profiler's trace, or None without jax.
+    jax is resolved at the first span, not at import: this module stays
+    importable (and the tracer usable) where jax is not."""
+    global _trace_annotation
+    if _trace_annotation is None:
+        try:
+            from jax.profiler import TraceAnnotation
+
+            _trace_annotation = TraceAnnotation
+        except Exception:  # noqa: BLE001 — tracing must never fail a run
+            _trace_annotation = False
+    if not _trace_annotation:
+        return None
+    return _trace_annotation(ANNOTATION_PREFIX + name)
 
 
 class Span:
@@ -130,10 +158,6 @@ class Tracer:
         self._sink_path: Optional[str] = None
         self._sink_fh = None
         self._wall_anchor: Optional[str] = None
-        # optional per-span mirror: a context-manager factory (e.g.
-        # jax.profiler.TraceAnnotation) entered/exited with every span so
-        # the span tree aligns with xprof timelines (`cli profile`)
-        self._annotation_factory = None
 
     # -- configuration -------------------------------------------------------
 
@@ -184,19 +208,11 @@ class Tracer:
         self._sink_fh = None
         self._sink_path = None
 
-    def set_annotation_factory(self, factory) -> None:
-        """Mirror every span into ``factory(name)`` context managers —
-        ``jax.profiler.TraceAnnotation`` makes the span tree line up with
-        xprof timelines during a ``cli profile`` capture. ``None``
-        disables. Annotation failures never fail the span."""
-        self._annotation_factory = factory
-
     def reset(self) -> None:
         """Drop all finished spans, close the sink, clear EVERY thread's
         open-span stack (test isolation; a span left open on a worker
         thread must not parent post-reset spans), and restore the
-        constructor-default buffer limit, drop accounting, and the span
-        annotation mirror."""
+        constructor-default buffer limit and drop accounting."""
         with self._lock:
             self._finished.clear()
             self._close_sink_locked()
@@ -204,7 +220,6 @@ class Tracer:
                 stack.clear()
             self._buffer_limit = self._default_buffer_limit
             self.dropped_spans = 0
-            self._annotation_factory = None
 
     # -- span lifecycle ------------------------------------------------------
 
@@ -234,8 +249,9 @@ class Tracer:
         return max(stacks, key=lambda s: s[-1].ts)
 
     def active_span_path(self, sep: str = " > ") -> str:
-        """``"fit > cd_iteration > coordinate:fixed"`` for the deepest
-        open span path, or ``""`` when nothing is open."""
+        """``"fit > coordinate_descent > cd_iteration > coordinate:fixed >
+        update"`` for the deepest open span path, or ``""`` when nothing
+        is open."""
         return sep.join(s.name for s in self.open_spans())
 
     def now(self) -> float:
@@ -255,14 +271,12 @@ class Tracer:
             attrs=dict(attrs),
         )
         stack.append(s)
-        annotation = None
-        factory = self._annotation_factory
-        if factory is not None:
-            try:
-                annotation = factory(name)
+        try:
+            annotation = _annotation(name)
+            if annotation is not None:
                 annotation.__enter__()
-            except Exception:  # noqa: BLE001 — mirroring must never fail
-                annotation = None
+        except Exception:  # noqa: BLE001 — mirroring must never fail
+            annotation = None
         try:
             yield s
         finally:
@@ -354,7 +368,6 @@ active_span_path = TRACER.active_span_path
 configure = TRACER.configure
 reset = TRACER.reset
 finished_spans = TRACER.finished_spans
-set_annotation_factory = TRACER.set_annotation_factory
 
 
 # -- Chrome trace (Perfetto) export ------------------------------------------

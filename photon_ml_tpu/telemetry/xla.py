@@ -46,12 +46,14 @@ analysis is injectable for deterministic tests via
 from __future__ import annotations
 
 import dataclasses
+import functools
 import logging
 import threading
 import time
 from typing import Any, Callable, Mapping, Optional, Sequence
 
 from photon_ml_tpu.telemetry import metrics, trace
+from photon_ml_tpu.telemetry.device import accounted_compile
 
 __all__ = [
     "ExecutableRecord",
@@ -581,7 +583,9 @@ class InstrumentedFunction:
 
         self._fn = fn
         self.name = name
-        self._jit = jax.jit(fn, **jit_kwargs)
+        # the compiled module is called jit_<name> (a profiler trace's
+        # `XLA Modules` line), not after whatever `fn` happens to be called
+        self._jit = jax.jit(_named(fn, name), **jit_kwargs)
         self._instance = _next_instance(name)
         self._multi_shape = multi_shape
         self._compiled: dict[tuple, tuple[Any, ExecutableRecord]] = {}
@@ -660,8 +664,9 @@ class InstrumentedFunction:
         compiled = None
         cost = mem = None
         try:
-            lowered = self._jit.lower(*args, **kwargs)
-            compiled = lowered.compile()
+            with accounted_compile():
+                lowered = self._jit.lower(*args, **kwargs)
+                compiled = lowered.compile()
         except Exception as e:  # noqa: BLE001 — backends/args AOT cannot handle
             # a compiler refusal (e.g. Mosaic) lands here too: say so NOW,
             # with its text — the jit re-dispatch will raise it again from
@@ -689,6 +694,23 @@ class InstrumentedFunction:
             flops=rec.flops,
         )
         return compiled, rec
+
+
+def _named(fn: Callable, name: str) -> Callable:
+    """``fn`` under the ``__name__`` ``name`` and inside
+    ``jax.named_scope(name)``: jax names a jitted function's module
+    ``jit_<__name__>``, and the scope groups its ops in xprof's op view.
+    ``functools.wraps`` keeps the signature jax resolves
+    ``static_argnames`` / ``donate_argnames`` against."""
+    import jax
+
+    @functools.wraps(fn)
+    def named(*args, **kwargs):
+        with jax.named_scope(name):
+            return fn(*args, **kwargs)
+
+    named.__name__ = named.__qualname__ = name
+    return named
 
 
 _instance_lock = threading.Lock()

@@ -14,6 +14,7 @@ import time (or in ``conftest.py``) would make the other workers fail.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -109,18 +110,50 @@ def _kernel_cases():
     }
 
 
-@pytest.mark.parametrize("name", [
-    "margins", "dot_rows", "margins_pair", "scatter", "scatter_sq",
-    "value_grad", "hv", "hv_at",
+@pytest.mark.parametrize("name,kernel", [
+    ("margins", "tiled_margins"), ("dot_rows", "tiled_margins"),
+    ("margins_pair", "tiled_margins"), ("scatter", "tiled_scatter"),
+    ("scatter_sq", "tiled_scatter"), ("value_grad", "tiled_value_grad"),
+    ("hv", "tiled_hv"), ("hv_at", "tiled_hv_at"),
 ])
-def test_kernel_compiles_for_v5e(name, one_chip):
+def test_kernel_compiles_for_v5e(name, kernel, one_chip):
     call, shapes = _kernel_cases()[name]
     args = [
         jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
         for shape, dtype in shapes
     ]
-    compiled = jax.jit(call).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = jax.jit(call).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    # the custom call's INSTRUCTION carries the pallas_call's `name`: a
+    # device event's name in a profiler trace starts with it
+    assert re.search(
+        rf"%{kernel}(\.\d+)? = [^\n]*custom_call_target=\"tpu_custom_call\"",
+        text)
+
+
+def test_fe_solver_module_and_kernels_are_named_for_v5e(one_chip,
+                                                        on_chip_kernels):
+    """The FE coordinate's own solver (``instrumented_jit(...,
+    name="fe_solve")``): its module is ``jit_fe_solve`` (a trace's `XLA
+    Modules` line) and every Mosaic call in it is a named tiled kernel."""
+    from photon_ml_tpu.game.coordinates import _fe_solver
+    from photon_ml_tpu.ops.objective import make_objective
+    from photon_ml_tpu.optim import OptimizerConfig, OptimizerType
+
+    cfg = OptimizerConfig(
+        optimizer_type=OptimizerType.LBFGS, max_iterations=8, tolerance=0.0)
+    obj = make_objective("logistic", l2_weight=1.0)
+    w0 = jax.ShapeDtypeStruct((NUM_FEATURES,), jnp.float32, sharding=one_chip)
+    text = _fe_solver(cfg, "logistic").lower(
+        obj, _batch(T, one_chip), w0, jnp.float32(0.0), None
+    ).compile().as_text()
+    assert re.match(r"HloModule jit_fe_solve\b", text)
+    calls = re.findall(
+        r"%([\w\-]+?)(?:\.\d+)? = [^\n]*custom_call_target=\"tpu_custom_call\"",
+        text)
+    assert calls and set(calls) <= {"tiled_margins", "tiled_scatter",
+                                    "tiled_value_grad"}
+    assert "op_name=\"jit(fe_solve)/fe_solve/" in text
 
 
 def test_whole_lbfgs_solve_compiles_for_v5e(one_chip, on_chip_kernels):

@@ -483,3 +483,133 @@ def test_re_grouping_memoized_per_model_and_dataset(rng):
     )
     other.score(gds)
     assert counters().get("scoring.code_cache.misses", 0) == 2
+
+
+# -- the span tree of a fit (ISSUE 24) ----------------------------------------
+
+
+def _span_children(spans):
+    kids = {}
+    for s in sorted(spans, key=lambda s: s.ts):
+        kids.setdefault(s.parent_id, []).append(s)
+    return kids
+
+
+def _contained_in_order(parent, children):
+    """Children lie inside the parent, one after the other, and their
+    durations sum to the parent's less its (non-negative) self time."""
+    cursor = parent.ts
+    for c in children:
+        assert c.ts >= cursor - 1e-6
+        cursor = c.ts + c.dur
+    assert cursor <= parent.ts + parent.dur + 1e-6
+    assert sum(c.dur for c in children) <= parent.dur + 1e-6
+
+
+def test_fit_span_tree_two_coordinates(rng):
+    """GameEstimator.fit over FE + per-user RE: exactly the tree README's
+    span schema names — one ``initial_scores``, one ``residual`` /
+    ``update`` / ``score`` / ``validate`` per step under its
+    ``coordinate:<name>``, ``layout`` apart from ``upload`` under each
+    ``build:<name>`` — and history ``seconds`` still ends at the score
+    fetch."""
+    from photon_ml_tpu import telemetry
+    from photon_ml_tpu.game import (
+        FixedEffectConfig,
+        GameConfig,
+        GameEstimator,
+        RandomEffectConfig,
+    )
+
+    gds, *_ = _glmix_data(rng, n=200, n_users=8)
+    opt = OptimizerConfig(
+        optimizer_type=OptimizerType.LBFGS, max_iterations=4, tolerance=1e-6,
+        regularization=RegularizationContext(RegularizationType.L2),
+        regularization_weight=1.0,
+    )
+    est = GameEstimator(GameConfig(
+        task="logistic",
+        coordinates={
+            "fixed": FixedEffectConfig(
+                shard_name="global", optimizer=opt, layout="tiled"),
+            "per-user": RandomEffectConfig(
+                shard_name="user", id_name="userId", optimizer=opt),
+        },
+        num_iterations=2,
+        evaluators=["auc"],
+    ))
+    telemetry.reset()
+    result = est.fit(gds, validation_data=gds)
+    spans = telemetry.finished_spans()
+    kids = _span_children(spans)
+    names = lambda sid: [s.name for s in kids.get(sid, [])]  # noqa: E731
+
+    (fit,) = kids[None]
+    assert fit.name == "fit"
+    build, cd = kids[fit.span_id]
+    assert (build.name, cd.name) == ("build_coordinates", "coordinate_descent")
+    assert build.attrs["cached"] is False
+    assert build.attrs["built"] == ["fixed", "per-user"]
+    assert names(build.span_id) == ["build:fixed", "build:per-user"]
+    for b in kids[build.span_id]:
+        parts = kids[b.span_id]
+        assert {p.name for p in parts} == {"layout", "upload"}
+        _contained_in_order(b, parts)  # layout and upload never overlap
+        assert parts[0].name == "layout" and parts[-1].name == "upload"
+        for p in parts:
+            if p.name == "upload":
+                assert p.attrs["bytes"] > 0
+                assert [e["attrs"]["label"] for e in p.events
+                        if e["name"] == "device_fetch"] == ["upload"]
+    assert telemetry.snapshot()["counters"]["upload.bytes"] == sum(
+        s.attrs["bytes"] for s in spans if s.name == "upload")
+
+    assert names(cd.span_id) == ["initial_scores", "cd_iteration",
+                                 "cd_iteration"]
+    _contained_in_order(cd, kids[cd.span_id])
+    steps = []
+    for it in kids[cd.span_id][1:]:
+        assert names(it.span_id) == ["coordinate:fixed", "coordinate:per-user"]
+        _contained_in_order(it, kids[it.span_id])
+        steps.extend(kids[it.span_id])
+    assert len(steps) == len(result.history) == 4
+    for step, entry in zip(steps, result.history):
+        parts = kids[step.span_id]
+        assert [p.name for p in parts] == [
+            "residual", "update", "score", "validate"]
+        assert all(not kids.get(p.span_id) for p in parts)  # leaves
+        _contained_in_order(step, parts)
+        score = parts[2]
+        assert entry["seconds"] == pytest.approx(
+            score.ts + score.dur - step.ts, abs=5e-3)
+        assert entry["seconds"] <= step.dur
+
+    # a second build over the same data reuses both coordinates
+    est._build_coordinates(gds, mesh=None)
+    again = telemetry.finished_spans("build_coordinates")[-1]
+    assert again.attrs["cached"] is True and again.parent_id is None
+    assert not [s for s in telemetry.finished_spans()
+                if s.parent_id == again.span_id]
+    telemetry.reset()
+
+
+def test_cd_span_tree_single_coordinate_called_directly(rng):
+    """Callers that bypass GameEstimator.fit (benchmarks, sweeps) get the
+    same tree, rooted at ``coordinate_descent``; one coordinate has no
+    ``residual``, and without validation no ``validate``."""
+    from photon_ml_tpu import telemetry
+
+    gds, *_ = _glmix_data(rng, n=120, n_users=5)
+    telemetry.reset()
+    coord = FixedEffectCoordinate("fixed", gds, "global", "logistic", _CFG)
+    before = len(telemetry.finished_spans())
+    run_coordinate_descent({"fixed": coord}, task="logistic", num_iterations=1)
+    spans = telemetry.finished_spans()[before:]
+    kids = _span_children(spans)
+    (cd,) = kids[None]
+    assert cd.name == "coordinate_descent"
+    init, it = kids[cd.span_id]
+    assert (init.name, it.name) == ("initial_scores", "cd_iteration")
+    (step,) = kids[it.span_id]
+    assert [p.name for p in kids[step.span_id]] == ["update", "score"]
+    telemetry.reset()

@@ -1062,3 +1062,71 @@ def test_cli_report_compare_notes_and_skips_exec_metrics(
     # the one-sided rows were skipped, not compared
     assert "exec.new_kernel.mfu" not in cmp_md
     assert "exec.old_kernel.mfu" not in cmp_md
+
+
+# -- the update / score / validate split (ISSUE 24) ---------------------------
+
+
+def _fe_cd(on_update=None):
+    """A one-coordinate CD run over tiny data; ``on_update`` fires inside
+    the coordinate's update (where a monitor thread's beat would land)."""
+    from photon_ml_tpu.game import (
+        FixedEffectCoordinate,
+        ValidationSpec,
+        build_game_dataset,
+        run_coordinate_descent,
+    )
+    from photon_ml_tpu.ops.sparse import SparseBatch
+    from photon_ml_tpu.optim import OptimizerConfig
+
+    rng = np.random.default_rng(11)
+    X = rng.normal(size=(64, 4))
+    y = (rng.random(64) < 0.5).astype(float)
+    gds = build_game_dataset(
+        response=y, feature_shards={"g": SparseBatch.from_dense(X, y)})
+    coord = FixedEffectCoordinate(
+        "fixed", gds, "g", "logistic", OptimizerConfig(max_iterations=3))
+    if on_update is not None:
+        solve = coord.update_model
+
+        def update_model(model, residual):
+            on_update()
+            return solve(model, residual)
+
+        coord.update_model = update_model
+    return run_coordinate_descent(
+        {"fixed": coord}, task="logistic", num_iterations=1,
+        validation=ValidationSpec(data=gds, evaluators=["auc"]))
+
+
+def test_heartbeat_span_field_names_the_update():
+    telemetry.reset()
+    hb = Heartbeat(interval=60)
+    lines = []
+    _fe_cd(on_update=lambda: lines.append(hb.beat()))
+    (line,) = lines
+    assert line["span"] == (
+        "coordinate_descent > cd_iteration > coordinate:fixed > update")
+    telemetry.reset()
+
+
+def test_phase_tree_splits_a_coordinate_and_self_times_add_up():
+    telemetry.reset()
+    _fe_cd()
+    root = build_phase_tree([s.to_dict() for s in telemetry.finished_spans()])
+    cd = root.children["coordinate_descent"]
+    step = cd.children["cd_iteration"].children["coordinate:fixed"]
+    assert set(step.children) == {"update", "score", "validate"}
+    assert set(cd.children) == {"initial_scores", "cd_iteration"}
+
+    def self_sum(node):
+        return node.self_s + sum(self_sum(c) for c in node.children.values())
+
+    # nothing is counted twice and nothing is lost: the self times of the
+    # whole subtree are the root span's seconds
+    assert self_sum(cd) == pytest.approx(cd.total_s, abs=1e-6)
+    assert step.self_s < step.total_s
+    md = RunReport(spans=[s.to_dict() for s in telemetry.finished_spans()],
+                   snapshot=telemetry.snapshot()).to_markdown()
+    assert "update" in md and "validate" in md and "initial_scores" in md
+    telemetry.reset()

@@ -650,3 +650,133 @@ def test_reset_restores_configure_from_env_state(tmp_path, monkeypatch):
     assert memory.hbm_stats() == {"bytes_in_use": 1, "bytes_limit": 2}
     telemetry.reset()
     assert memory._stats_provider is None
+
+
+# -- spans on the profiler's clock, counters that mean what they say ----------
+
+
+def _tiny_fe_fit(validation_evaluators=None, guard=None):
+    from photon_ml_tpu.game import (
+        FixedEffectCoordinate,
+        ValidationSpec,
+        build_game_dataset,
+        run_coordinate_descent,
+    )
+    from photon_ml_tpu.ops.sparse import SparseBatch
+    from photon_ml_tpu.optim import OptimizerConfig, OptimizerType
+
+    rng = np.random.default_rng(3)
+    X = rng.normal(size=(96, 5))
+    y = (rng.random(96) < 0.5).astype(float)
+    gds = build_game_dataset(
+        response=y, feature_shards={"g": SparseBatch.from_dense(X, y)})
+    coord = FixedEffectCoordinate(
+        "fixed", gds, "g", "logistic",
+        OptimizerConfig(optimizer_type=OptimizerType.LBFGS, max_iterations=3))
+    validation = None
+    if validation_evaluators:
+        validation = ValidationSpec(data=gds, evaluators=validation_evaluators)
+    return lambda: run_coordinate_descent(
+        {"fixed": coord}, task="logistic", num_iterations=1,
+        validation=validation, guard=guard)
+
+
+def test_profiler_capture_holds_the_span_tree(tmp_path):
+    """Every span mirrors itself as a ``photon:<name>`` TraceAnnotation with
+    no switch to turn on: a capture started by anyone holds the CD tree on
+    its host plane, children inside their parents on the trace's clock."""
+    import jax
+    from jax.profiler import ProfileData
+
+    fit = _tiny_fe_fit(["auc"])
+    fit()  # compile outside the capture
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        fit()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    found = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("photon:"):
+                    found.setdefault(e.name[7:], []).append(
+                        (e.start_ns, e.start_ns + e.duration_ns))
+    assert set(found) >= {
+        "coordinate_descent", "initial_scores", "cd_iteration",
+        "coordinate:fixed", "update", "score", "validate"}
+    (cd,) = found["coordinate_descent"]
+    for name in ("initial_scores", "cd_iteration", "coordinate:fixed",
+                 "update", "score", "validate"):
+        for a, b in found[name]:
+            assert cd[0] <= a and b <= cd[1], name
+    (step,) = found["coordinate:fixed"]
+    for name in ("update", "score", "validate"):
+        (inner,) = found[name]
+        assert step[0] <= inner[0] and inner[1] <= step[1]
+
+
+def test_spans_work_with_no_capture_running():
+    # the mirror is unconditional: no factory to set, nothing to tear down
+    assert not hasattr(ttrace.TRACER, "set_annotation_factory")
+    assert not hasattr(ttrace, "set_annotation_factory")
+    with telemetry.span("plain") as s:
+        pass
+    assert s.dur is not None and ttrace.ANNOTATION_PREFIX == "photon:"
+
+
+@pytest.mark.parametrize("evaluators,guarded,expected", [
+    (None, False, 3),            # initial scores, tracker, score
+    (["auc"], False, 4),         # + one per evaluator
+    (["auc", "logistic_loss"], True, 6),  # + the guard's health fetch
+])
+def test_device_fetches_per_update_and_evaluator(
+        monkeypatch, evaluators, guarded, expected):
+    """``device_fetch_seconds`` is the host's whole blocked time on the
+    training path because every crossing goes through ``sync_fetch``: per
+    fit one per coordinate's initial scores; per FE update ONE packed
+    tracker fetch (+1 under a guard); one per score; one per evaluator."""
+    from photon_ml_tpu.optim.guard import GuardSpec
+    from photon_ml_tpu.telemetry import xla
+
+    # the dispatch sampler's own fetches (first dispatch of an executable)
+    # are not the training path's
+    monkeypatch.setattr(xla, "_dispatch_profiler", None)
+    fit = _tiny_fe_fit(evaluators, GuardSpec() if guarded else None)
+    before = telemetry.snapshot()["counters"].get("device_fetches", 0)
+    fit()
+    after = telemetry.snapshot()["counters"]["device_fetches"]
+    assert after - before == expected
+    labels = [
+        e["attrs"]["label"] for s in telemetry.finished_spans()
+        for e in s.events if e["name"] == "device_fetch"]
+    assert labels.count("fe_tracker") == 1
+    assert labels.count("initial_scores:fixed") == 1
+    assert sum(x.startswith("evaluate:") for x in labels) == len(
+        evaluators or [])
+    (update,) = telemetry.finished_spans("update")
+    assert "fe_tracker" in [e["attrs"].get("label") for e in update.events]
+
+
+def test_eager_compile_counter_skips_instrumented_jit():
+    import jax.numpy as jnp
+
+    def count(name):
+        return telemetry.snapshot()["counters"].get(name, 0)
+
+    x = jnp.ones((3, 5, 11))
+    eager0, all0 = count("jit_compiles_eager"), count("jit_compiles")
+    _ = jnp.tanh(x) * 1.25  # eager one-op programs of an unusual shape
+    eager1, all1 = count("jit_compiles_eager"), count("jit_compiles")
+    assert eager1 > eager0 and eager1 - eager0 == all1 - all0
+    assert count("jit_compile_seconds_eager") > 0
+
+    salt = 23.5
+    fresh = telemetry.instrumented_jit(
+        lambda v: jnp.tanh(v) * salt + v, name="eager_counter_probe")
+    fresh(x)
+    assert count("jit_compiles") > all1
+    assert count("jit_compiles_eager") == eager1  # accounted, not eager

@@ -230,8 +230,9 @@ def test_sweep_on_mesh_matches_single_device(rng):
 def test_game_fit_finish_event_carries_telemetry_snapshot(rng, tmp_path):
     """A toy GameEstimator.fit emits TrainingFinishEvent with the metrics
     snapshot attached — nonzero device_fetches, compile counters, and a
-    JSONL span tree nesting fit > cd_iteration > coordinate:<name> that the
-    Perfetto exporter converts without error (ISSUE 1 acceptance)."""
+    JSONL span tree nesting fit > coordinate_descent > cd_iteration >
+    coordinate:<name> that the Perfetto exporter converts without error
+    (ISSUE 1 acceptance; ``coordinate_descent`` since ISSUE 24)."""
     import json
 
     from photon_ml_tpu import telemetry
@@ -277,16 +278,19 @@ def test_game_fit_finish_event_carries_telemetry_snapshot(rng, tmp_path):
         assert "jit_compiles" in snap["counters"]
         assert snap["histograms"]["re_solve_iterations"]["count"] > 0
 
-        # per-coordinate span names, nested fit > cd_iteration > coordinate:*
+        # per-coordinate span names, nested fit > coordinate_descent >
+        # cd_iteration > coordinate:*
         spans = telemetry.finished_spans()
         by_id = {s.span_id: s for s in spans}
         names = {s.name for s in spans}
-        assert {"fit", "cd_iteration", "coordinate:fixed",
-                "coordinate:perUser"} <= names
+        assert {"fit", "coordinate_descent", "cd_iteration",
+                "coordinate:fixed", "coordinate:perUser"} <= names
         for cname in ("coordinate:fixed", "coordinate:perUser"):
             (coord,) = [s for s in spans if s.name == cname]
-            cd = by_id[coord.parent_id]
-            assert cd.name == "cd_iteration"
+            it = by_id[coord.parent_id]
+            assert it.name == "cd_iteration"
+            cd = by_id[it.parent_id]
+            assert cd.name == "coordinate_descent"
             assert by_id[cd.parent_id].name == "fit"
 
         # the JSONL sink saw the same tree; the Perfetto export round-trips

@@ -527,10 +527,12 @@ def test_e2e_fit_report_device_utilization(tmp_path):
 
 def test_cli_profile_wraps_a_train_run(tmp_path):
     """`cli profile -- train ...` produces a profiler capture dir next to
-    the span trace, mirrors spans as annotations, and returns the wrapped
-    command's exit code."""
+    the span trace, the capture holds the span tree (every span mirrors
+    itself as a ``photon:`` annotation, no switch), and the wrapped
+    command's exit code comes back."""
+    from jax.profiler import ProfileData
+
     from photon_ml_tpu.cli.__main__ import main as cli_main
-    from photon_ml_tpu.telemetry import trace as trace_mod
 
     rng = np.random.default_rng(7)
     lib = tmp_path / "train.libsvm"
@@ -574,8 +576,23 @@ def test_cli_profile_wraps_a_train_run(tmp_path):
     ]
     assert captured, "profiler capture dir is empty"
     assert trace_out.exists()
-    # the annotation mirror was torn down after the run
-    assert trace_mod.TRACER._annotation_factory is None
+    # the capture holds the program's spans, on the host plane
+    (xplane,) = [p for p in captured if p.endswith(".xplane.pb")]
+    mirrored = {
+        e.name
+        for plane in ProfileData.from_file(xplane).planes
+        if plane.name == "/host:CPU"
+        for line in plane.lines for e in line.events
+        if e.name.startswith("photon:")
+    }
+    assert {"photon:fit", "photon:build_coordinates",
+            "photon:coordinate_descent", "photon:coordinate:fixed",
+            "photon:update"} <= mirrored
+    # the knob that used to turn the mirror off is gone
+    with pytest.raises(SystemExit):
+        cli_main(["profile", "--profile-dir", str(prof_dir),
+                  "--no-annotations", "--", "train", "--config",
+                  str(cfg_path)])
 
 
 def test_cli_profile_requires_wrapped_command(tmp_path):
