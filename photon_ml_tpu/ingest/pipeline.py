@@ -386,10 +386,16 @@ class ChunkStream:
     def _decode_loop(self) -> None:
         try:
             while not self._stop.is_set():
+                # buffer first, plan second: whoever holds a slot takes the
+                # LOWEST undecoded chunk. The other order lets a decoder
+                # that holds chunk k wait for a slot while its peers fill
+                # every slot with chunks > k, which the in-order uploader
+                # never drains (seen as a hang of the out-of-core fit).
+                buf = self._ring.acquire()
                 plan = self._next_plan()
                 if plan is None:
+                    self._ring.release(buf)
                     return
-                buf = self._ring.acquire()
                 # converge lagging slots to the stream-global capacity
                 # while the buffer is provably free
                 with self._lock:

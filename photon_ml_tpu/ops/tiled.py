@@ -8,30 +8,56 @@ instead by removing ALL random access:
   - Rows are grouped into tiles of R=128 consecutive rows. Each tile's nnz
     become a fixed-length slot list of (value, col_hi, col_lo, row_local)
     where ``col = col_hi * 128 + col_lo`` and ``row_local = row % 128``.
-  - The coefficient vector lives as a [B, 128] grid (B = ceil(F/128)).
-  - Gathering w[col] per slot = one-hot(col_hi) @ w2, then a masked
-    product with one-hot(col_lo) reduced BY MATVEC against a ones vector.
-  - Scattering per-slot contributions into feature space = the transposed
-    one-hot matmul into a [128, B] accumulator (the [S, B] mask side is
-    the smaller elementwise operand).
-  - EVERY reduction and row broadcast rides the MXU: these kernels are
-    VPU-bound (mask construction + elementwise chains saturate the vector
-    unit while the MXU idles at ~3% — PERF_NOTES.md roofline), so lane
-    shuffle-reduces and [S, 128] row-mask broadcasts are replaced by
-    matmuls against the TRANSPOSED row one-hot mask_rT [R, S]. Measured:
-    margins 75 -> 39 ms, fused value+grad 91 -> 62 ms (v5e, config below).
+  - The coefficient vector lives as a [B8, 128] grid (B8 = B rounded up to
+    the bfloat16 sublane tile of 16; rows from B on are zero).
+  - SLOTS STAY ON LANES. The slot arrays arrive lane-major ([1, S] a tile)
+    and every per-slot quantity keeps that shape from the block's load to
+    the matmul that consumes it; the one-hot axes go on sublanes. The three
+    masks are transposed one-hots built by comparing a [1, S] row with an
+    iota over dim 0 (a sublane broadcast, no relayout): ``hit`` [B8, S] of
+    col_hi, ``lot`` [128, S] of col_lo, ``rt`` [R, S] of row_local. A
+    per-slot vector is S/128 vregs as a [1, S] row and S/8 as an [S, 1]
+    column; the column-shaped kernels this replaces spent 3.0 of their
+    4.9-6.7 us a tile turning rows into columns and building masks from
+    them (PERF.md, Findings PR 25: the stubbed-kernel table).
+  - Gathering w[col] per slot = ``[w_hi; w_lo] @ lot`` ([2*B8, S]: every
+    column block's w[., lo_s] in slot s's lane), then ``where(hit, ., 0)``
+    summed over sublanes: one nonzero a column, so the sum is exact. The
+    per-row sum is an NT contraction over the lane axis of the per-slot
+    rows and ``rt``.
+  - Scattering per-slot contributions into feature space = the per-slot
+    product split once as a row, each half placed in its column block by
+    ``where(hit, ., 0)`` ([2*B8, S]), contracted over the lane axis
+    against ``lot`` into a [B8, 128] accumulator laid out like the grid.
+  - ``hit`` only feeds selects and stays boolean; ``lot`` and ``rt`` only
+    feed the MXU. Every pass of a one-hot through the MXU is S/128 weight
+    tiles, each streamed by the other operand's rows, and the kernels'
+    times are their passes: at the shape below ~26 ms a call for a pass
+    that streams the 2*B8 = 160 table rows (a gather, a scatter) and ~14 ms
+    for one that streams 16 (the row sum, the rows-to-slots broadcast).
+    Margins and scatter are one of each (40), pair two and one (67),
+    value+grad and hv_at two and two (80, 78), hv three and two (105).
+    Building ``lot`` and ``rt`` and margins' two passes ALONE take 38.9 of
+    its 40.4 ms: every select and sum hides under them. A grid step alone
+    is 0.20 us of a tile's 0.86. Lane chunks of S, the ``wT @ hit``
+    contraction order (256 rows streamed), a ``where`` + reduce row sum
+    and the accumulator the other way up were all timed and are slower
+    (PERF.md, Findings PR 25).
   - f32 exactness comes from bf16x2 splits (x = hi + lo in bfloat16,
     products against 0/1 masks are exact, MXU accumulates in f32). The
     split MUST happen inside the kernel: XLA's
     ``--xla_allow_excess_precision`` folds ``bf16(x - f32(bf16(x)))`` to
-    zero, silently degrading the pass to single-bf16 (measured 2e-3
-    gradient error; in-kernel split measures ~5e-6). Mosaic's
-    precision=HIGHEST f32 matmul measures 5e-3 — not a substitute.
+    zero, silently degrading the pass to single-bf16 (2e-3 against 3.4e-6
+    measured). Mosaic's precision=HIGHEST f32 matmul is not a substitute.
+    Contracting the minor axis of both operands (the ``q @ k.T`` form)
+    compiles on the jax this tree runs (0.9.0).
 
-Measured on TPU v5e (1M rows x 10K features, 20 nnz/row): one fused
-value+grad pass ~62 ms vs ~650 ms for the XLA gather/scatter path (~10x);
-the margin-carrying LBFGS iteration is one dot_rows (~39 ms) plus one
-scatter pass.
+Measured alone on one TPU v5 lite at 6M rows x 10K features, 20 nnz/row
+(T = 46,875, S = 2,560, B = 79; PERF.md Findings PR 25, "my chip run"), new
+against the column-shaped kernels: margins 40.4 ms (232.3), scatter 40.3
+(315.7), margins_pair 67.0 (338.0), fused value+grad 79.5 (372.9), fused Hv
+105.4 (497.4), hv_at 77.4 (349.8). Relative L2 error against float64 at that
+shape: margins 3.4e-6 (3.4e-6), scatter 3.5e-6 (3.9e-6).
 
 This replaces the hot loop the reference distributes over a Spark cluster
 (ValueAndGradientAggregator.scala:132-153) with on-chip matmuls.
@@ -75,92 +101,101 @@ def _split_bf16(x):
     return hi, lo
 
 
-def _mm2(a, bh, bl):
-    """Exact a @ (bh + bl): bf16 one-hot x bf16x2 table, f32 accumulation."""
-    x = jax.lax.dot_general(
-        a, bh, dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    return x + jax.lax.dot_general(
-        a, bl, dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+_NN = (((1,), (0,)), ((), ()))   # a @ b
+_NT = (((1,), (1,)), ((), ()))   # a @ b.T: contracts the lane axis of both
 
 
-def _mmT2(a, bh, bl):
-    """Exact a^T @ (bh + bl) (contract slot dim 0)."""
-    x = jax.lax.dot_general(
-        a, bh, dimension_numbers=(((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    return x + jax.lax.dot_general(
-        a, bl, dimension_numbers=(((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+def _dot(a, b, dims):
+    """bf16 x bf16 on the MXU, float32 accumulation."""
+    return jax.lax.dot_general(
+        a, b, dimension_numbers=dims, preferred_element_type=jnp.float32)
 
 
-def _slot_contrib(vals, w_ref, mask_hi, mask_lo):
-    """Per-slot vals_s * w[col_s] as an [S, 1] f32 column.
-
-    All reductions ride the MXU: the lane pick + sum is a masked-product
-    matvec against a ones vector instead of a 128-lane shuffle reduce
-    (measured ~30% kernel time on v5e; the VPU is this kernel family's
-    critically saturated unit — see PERF_NOTES roofline)."""
-    w = w_ref[:]
-    whi, wlo = _split_bf16(w)
-    wrow = _mm2(mask_hi, whi, wlo)                    # [S, 128] f32
-    e = (wrow * mask_lo) * vals[:, None]              # one lane nonzero
-    eh, el = _split_bf16(e)
-    ones = jnp.ones((LANE, 1), jnp.bfloat16)
-    g = jax.lax.dot_general(
-        eh, ones, dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    return g + jax.lax.dot_general(
-        el, ones, dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)           # [S, 1]
+def _table_rows(B: int) -> int:
+    """Rows of a coefficient grid inside the kernels: B rounded up to the
+    bfloat16 sublane tile, so the stacked halves concatenate aligned."""
+    return -(-B // 16) * 16
 
 
-def _rowsum_mxu(contrib_col, mask_rT):
-    """[S, 1] per-slot contributions -> [1, R] per-row sums via the
-    TRANSPOSED row one-hot ON THE MXU ([R,S] @ [S,1], bf16x2 exact).
-    Both row ops use mask_rT so Mosaic sees only (1,0)-contractions."""
-    ch, cl = _split_bf16(contrib_col)
-    return _mm2(mask_rT, ch, cl).reshape(1, -1)       # [R, 1] -> [1, R]
+def _onehot_t(idx_row, n: int):
+    """[1, S] int32 -> bool [n, S]: row k is ``idx == k``. The indices stay
+    on the lane axis (a sublane broadcast against an iota, no relayout)."""
+    iota = jax.lax.broadcasted_iota(jnp.int32, (n, idx_row.shape[1]), 0)
+    return idx_row == iota
 
 
-def _row_margins(vals, mask_rT, w_ref, mask_hi, mask_lo):
-    """Per-row margin sums [1, R] for one tile (shared kernel body)."""
-    return _rowsum_mxu(_slot_contrib(vals, w_ref, mask_hi, mask_lo), mask_rT)
+def _tile_masks(hi_ref, lo_ref, rlo_ref, B8: int):
+    """The three transposed one-hots of one tile, slots on lanes.
+
+    ``hit`` [B8, S] only ever feeds a ``where`` and stays boolean. A padding
+    slot carries the sentinel ``hi == B``: where B8 > B that is row B of
+    ``hit``, which is a zero row of every table (:meth:`TiledBatch._w2`)
+    and an accumulator row :meth:`TiledBatch._features` drops; where
+    B8 == B it matches no row. ``lot`` [128, S] and ``rt`` [R, S] only
+    ever feed the MXU and are converted once."""
+    hit = _onehot_t(hi_ref[0], B8)
+    lot = _onehot_t(lo_ref[0], LANE).astype(jnp.bfloat16)
+    rt = _onehot_t(rlo_ref[0], ROWS_PER_TILE).astype(jnp.bfloat16)
+    return hit, lot, rt
 
 
-def _slots_of_rows(per_row, mask_rT):
-    """Broadcast a [1, R] per-row vector to slots ([S, 1]) via the
-    transposed row one-hot matvec (exact: per_row splits bf16x2)."""
-    ph, plo = _split_bf16(per_row)
-    s_row = jax.lax.dot_general(
-        ph, mask_rT, dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    s_row = s_row + jax.lax.dot_general(
-        plo, mask_rT, dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)           # [1, S]
-    return s_row.reshape(-1, 1)
+def _table2(w_ref):
+    """[B8, 128] f32 grid -> its bf16x2 halves stacked [2*B8, 128], so one
+    matmul (one set of weight loads) gathers both."""
+    return jnp.concatenate(_split_bf16(w_ref[:]), axis=0)
 
 
-def _scatter_accum(out_ref, per_slot, mask_hi, mask_lo):
-    """Accumulate sum_s per_slot[s]*onehot(col_s) into the TRANSPOSED
-    [LANE, B] accumulator: tmp = per_slot ⊙ mask_hi is [S, B] (the smaller
-    mask side), then mask_lo^T @ tmp on the MXU (bf16x2 exact)."""
-    tmp = per_slot * mask_hi                          # [S, B]
-    th, tl = _split_bf16(tmp)
-    out_ref[:] = out_ref[:] + _mmT2(mask_lo, th, tl)  # [LANE, B]
+def _stack16(rows):
+    """k <= 8 float32 [1, N] rows -> bf16 [16, N] whose row j is the high
+    bf16 half of ``rows[j]`` and row 8 + j its low half: the LHS of one
+    MXU pass that is exact for all of them (bf16x2, f32 accumulation).
+    Rows past k repeat the last one and are never read."""
+    n = rows[0].shape[1]
+    iota = jax.lax.broadcasted_iota(jnp.int32, (8, n), 0)
+    blocks = []
+    for half in zip(*(_split_bf16(r) for r in rows)):
+        half = [h.astype(jnp.float32) for h in half]
+        blk = jnp.broadcast_to(half[-1], (8, n))
+        for j in range(len(rows) - 2, -1, -1):
+            blk = jnp.where(iota == j, half[j], blk)
+        blocks.append(blk)
+    return jnp.concatenate(blocks, axis=0).astype(jnp.bfloat16)
 
 
-def _masks(hi_ref, lo_ref, rlo_ref, S: int, B: int):
-    iota_b = jax.lax.broadcasted_iota(jnp.int32, (S, B), 1)
-    iota_l = jax.lax.broadcasted_iota(jnp.int32, (S, LANE), 1)
-    iota_r = jax.lax.broadcasted_iota(jnp.int32, (LANE, S), 0)
-    mask_hi = (hi_ref[0, 0, :][:, None] == iota_b).astype(jnp.bfloat16)
-    mask_lo = (lo_ref[0, 0, :][:, None] == iota_l).astype(jnp.bfloat16)
-    # row one-hot in TRANSPOSED [R, S] orientation: every use is then a
-    # standard (1,0) MXU contraction (Mosaic rejects dim-1 contractions)
-    mask_rT = (rlo_ref[0, 0, :][None, :] == iota_r).astype(jnp.bfloat16)
-    return mask_hi, mask_lo, mask_rT
+def _row_sums(tabs, vals, hit, lot, rt):
+    """Per-row sums of vals_s * table[col_s], one row of the [8, R] result
+    per table in ``tabs`` (stacked :func:`_table2` grids).
+
+    Gather: ``[w_hi; w_lo] @ lot`` puts w[., lo_s] of every column block in
+    slot s's lane; ``where(hit, ., 0)`` keeps the slot's own block and the
+    sublane sum has ONE nonzero, so it is exact. Times ``vals`` as a [1, S]
+    row. Row sum: an NT contraction over the lane axis of the per-slot rows
+    and ``rt``, all tables in one :func:`_stack16` LHS."""
+    B8 = hit.shape[0]
+    per_slot = []
+    for tab in tabs:
+        g = _dot(tab, lot, _NN)                        # [2*B8, S]
+        g = jnp.where(hit, g[:B8] + g[B8:], 0.0)
+        per_slot.append(jnp.sum(g, axis=0, keepdims=True) * vals)
+    z = _dot(_stack16(per_slot), rt, _NT)              # [16, R]
+    return z[:8] + z[8:]
+
+
+def _scatter_accum(out_ref, per_row, vals, hit, lot, rt):
+    """out[B8, 128] += sum_s per_row[row_s] * vals_s * onehot(col_s).
+
+    ``per_row`` [1, R] reaches the slots through ``rt`` on the MXU (exact:
+    bf16x2); the per-slot product is split ONCE as a [1, S] row, each half
+    is placed in the slot's column block by a select (0/1 mask times a
+    split value IS a select), and one NT contraction over the lane axis
+    against ``lot`` lands both halves in the accumulator."""
+    B8 = hit.shape[0]
+    s = _dot(_stack16([per_row]), rt, _NN)             # [16, S]
+    p = (s[:8] + s[8:])[0:1] * vals                    # [1, S]
+    halves = [jnp.where(hit, h.astype(jnp.float32), 0.0).astype(jnp.bfloat16)
+              for h in _split_bf16(p)]
+    d = _dot(jnp.concatenate(halves, axis=0), lot, _NT)   # [2*B8, 128]
+    out_ref[:] = out_ref[:] + (d[:B8] + d[B8:])
 
 
 # ---------------------------------------------------------------------------
@@ -173,28 +208,27 @@ def _margins_kernel(use_offsets: bool, pair: bool,
     """z = per-row sum of vals * w[col] (+offsets +shift).
 
     With ``pair`` a second table v is gathered in the same sweep (shares all
-    masks): used for (margins(w), dot_rows(p)) in one pass per LBFGS line
-    search, and for (margins(w), dot_rows(v)) in Hessian-vector products.
+    masks and the row-sum matmul): used for (margins(w), dot_rows(p)) in one
+    pass per LBFGS line search, and for (margins(w), dot_rows(v)) in
+    Hessian-vector products.
     """
     if pair:
         (vals_ref, hi_ref, lo_ref, rlo_ref, off_ref, w_ref, v_ref,
          shift_ref, out_z_ref, out_u_ref) = refs
+        tabs = [_table2(w_ref), _table2(v_ref)]
     else:
         (vals_ref, hi_ref, lo_ref, rlo_ref, off_ref, w_ref,
          shift_ref, out_z_ref) = refs
-    S = vals_ref.shape[2]
-    B = w_ref.shape[0]
-    mask_hi, mask_lo, mask_rT = _masks(hi_ref, lo_ref, rlo_ref, S, B)
-    vals = vals_ref[0, 0, :]
+        tabs = [_table2(w_ref)]
+    hit, lot, rt = _tile_masks(hi_ref, lo_ref, rlo_ref, w_ref.shape[0])
+    sums = _row_sums(tabs, vals_ref[0], hit, lot, rt)
 
-    z = _row_margins(vals, mask_rT, w_ref, mask_hi, mask_lo) + shift_ref[0, 0]
+    z = sums[0:1] + shift_ref[0, 0]
     if use_offsets:
         z = z + off_ref[0, :, :]
     out_z_ref[0, :, :] = z
-
     if pair:
-        u = _row_margins(vals, mask_rT, v_ref, mask_hi, mask_lo)
-        out_u_ref[0, :, :] = u + shift_ref[0, 1]
+        out_u_ref[0, :, :] = sums[1:2] + shift_ref[0, 1]
 
 
 def _scatter_kernel(square: bool, *refs):
@@ -206,15 +240,11 @@ def _scatter_kernel(square: bool, *refs):
     def _():
         out_g_ref[:] = jnp.zeros_like(out_g_ref)
 
-    S = vals_ref.shape[2]
-    B = out_g_ref.shape[1]
-    mask_hi, mask_lo, mask_rT = _masks(hi_ref, lo_ref, rlo_ref, S, B)
-    vals = vals_ref[0, 0, :]
+    hit, lot, rt = _tile_masks(hi_ref, lo_ref, rlo_ref, out_g_ref.shape[0])
+    vals = vals_ref[0]
     if square:
         vals = vals * vals
-
-    per_slot = _slots_of_rows(pr_ref[0, :, :], mask_rT) * vals[:, None]
-    _scatter_accum(out_g_ref, per_slot, mask_hi, mask_lo)
+    _scatter_accum(out_g_ref, pr_ref[0], vals, hit, lot, rt)
 
 
 def _value_grad_kernel(loss_name: str, use_offsets: bool, *refs):
@@ -228,12 +258,10 @@ def _value_grad_kernel(loss_name: str, use_offsets: bool, *refs):
         out_s_ref[:] = jnp.zeros_like(out_s_ref)
         out_g_ref[:] = jnp.zeros_like(out_g_ref)
 
-    S = vals_ref.shape[2]
-    B = w_ref.shape[0]
-    mask_hi, mask_lo, mask_rT = _masks(hi_ref, lo_ref, rlo_ref, S, B)
-    vals = vals_ref[0, 0, :]
+    hit, lot, rt = _tile_masks(hi_ref, lo_ref, rlo_ref, w_ref.shape[0])
+    vals = vals_ref[0]
 
-    z = _row_margins(vals, mask_rT, w_ref, mask_hi, mask_lo) + shift_ref[0, 0]
+    z = _row_sums([_table2(w_ref)], vals, hit, lot, rt)[0:1] + shift_ref[0, 0]
     if use_offsets:
         z = z + off_ref[0, :, :]
 
@@ -245,8 +273,7 @@ def _value_grad_kernel(loss_name: str, use_offsets: bool, *refs):
     sums = jnp.stack([jnp.sum(wgt * l), jnp.sum(g_row)]).reshape(1, 2)
     out_s_ref[:] = out_s_ref[:] + sums
 
-    per_slot = _slots_of_rows(g_row, mask_rT) * vals[:, None]
-    _scatter_accum(out_g_ref, per_slot, mask_hi, mask_lo)
+    _scatter_accum(out_g_ref, g_row, vals, hit, lot, rt)
 
 
 def _hv_kernel(loss_name: str, use_offsets: bool, *refs):
@@ -263,23 +290,21 @@ def _hv_kernel(loss_name: str, use_offsets: bool, *refs):
         out_s_ref[:] = jnp.zeros_like(out_s_ref)
         out_g_ref[:] = jnp.zeros_like(out_g_ref)
 
-    S = vals_ref.shape[2]
-    B = w_ref.shape[0]
-    mask_hi, mask_lo, mask_rT = _masks(hi_ref, lo_ref, rlo_ref, S, B)
-    vals = vals_ref[0, 0, :]
+    hit, lot, rt = _tile_masks(hi_ref, lo_ref, rlo_ref, w_ref.shape[0])
+    vals = vals_ref[0]
 
-    z = _row_margins(vals, mask_rT, w_ref, mask_hi, mask_lo) + shift_ref[0, 0]
+    sums = _row_sums([_table2(w_ref), _table2(v_ref)], vals, hit, lot, rt)
+    z = sums[0:1] + shift_ref[0, 0]
     if use_offsets:
         z = z + off_ref[0, :, :]
-    u = _row_margins(vals, mask_rT, v_ref, mask_hi, mask_lo) + shift_ref[0, 1]
+    u = sums[1:2] + shift_ref[0, 1]
 
     loss = get_loss(loss_name)
     q_row = wgt_ref[0, :, :] * loss.d2z(z, lab_ref[0, :, :]) * u   # [1, R]
     out_s_ref[:] = out_s_ref[:] + jnp.stack(
         [jnp.sum(q_row), jnp.float32(0.0)]).reshape(1, 2)
 
-    per_slot = _slots_of_rows(q_row, mask_rT) * vals[:, None]
-    _scatter_accum(out_g_ref, per_slot, mask_hi, mask_lo)
+    _scatter_accum(out_g_ref, q_row, vals, hit, lot, rt)
 
 
 def _hv_at_kernel(*refs):
@@ -297,18 +322,15 @@ def _hv_at_kernel(*refs):
         out_s_ref[:] = jnp.zeros_like(out_s_ref)
         out_g_ref[:] = jnp.zeros_like(out_g_ref)
 
-    S = vals_ref.shape[2]
-    B = v_ref.shape[0]
-    mask_hi, mask_lo, mask_rT = _masks(hi_ref, lo_ref, rlo_ref, S, B)
-    vals = vals_ref[0, 0, :]
+    hit, lot, rt = _tile_masks(hi_ref, lo_ref, rlo_ref, v_ref.shape[0])
+    vals = vals_ref[0]
 
-    u = _row_margins(vals, mask_rT, v_ref, mask_hi, mask_lo) + shift_ref[0, 0]
+    u = _row_sums([_table2(v_ref)], vals, hit, lot, rt)[0:1] + shift_ref[0, 0]
     q_row = d2_ref[0, :, :] * u  # [1, R]
     out_s_ref[:] = out_s_ref[:] + jnp.stack(
         [jnp.sum(q_row), jnp.float32(0.0)]).reshape(1, 2)
 
-    per_slot = _slots_of_rows(q_row, mask_rT) * vals[:, None]
-    _scatter_accum(out_g_ref, per_slot, mask_hi, mask_lo)
+    _scatter_accum(out_g_ref, q_row, vals, hit, lot, rt)
 
 
 # Every pallas_call below carries a ``name``: it becomes the custom call's
@@ -326,12 +348,17 @@ def _spec_r():
                         memory_space=pltpu.VMEM)
 
 
-def _spec_w(B):
-    return pl.BlockSpec((B, LANE), lambda i: (0, 0), memory_space=pltpu.VMEM)
-
-
-def _spec_acc(shape):
+def _spec_whole(shape):
     return pl.BlockSpec(shape, lambda i: (0, 0), memory_space=pltpu.VMEM)
+
+
+def _spec_w(B):
+    """A coefficient grid or a feature-space accumulator: [B8, 128]."""
+    return _spec_whole((_table_rows(B), LANE))
+
+
+def _shape_w(B):
+    return jax.ShapeDtypeStruct((_table_rows(B), LANE), jnp.float32)
 
 
 @functools.lru_cache(maxsize=None)
@@ -362,8 +389,8 @@ def _scatter_call(T, S, B, square, interpret):
         kern,
         grid=(T,),
         in_specs=[_spec_s(S)] * 4 + [_spec_r()],
-        out_specs=_spec_acc((LANE, B)),
-        out_shape=jax.ShapeDtypeStruct((LANE, B), jnp.float32),
+        out_specs=_spec_w(B),
+        out_shape=_shape_w(B),
         interpret=interpret,
         name="tiled_scatter",
     )
@@ -377,11 +404,8 @@ def _hv_call(T, S, B, loss_name, use_offsets, interpret):
         grid=(T,),
         in_specs=[_spec_s(S)] * 4 + [_spec_r()] * 3 + [_spec_w(B)] * 2
         + [pl.BlockSpec((1, 2), lambda i: (0, 0), memory_space=pltpu.SMEM)],
-        out_specs=[_spec_acc((1, 2)), _spec_acc((LANE, B))],
-        out_shape=[
-            jax.ShapeDtypeStruct((1, 2), jnp.float32),
-            jax.ShapeDtypeStruct((LANE, B), jnp.float32),
-        ],
+        out_specs=[_spec_whole((1, 2)), _spec_w(B)],
+        out_shape=[jax.ShapeDtypeStruct((1, 2), jnp.float32), _shape_w(B)],
         interpret=interpret,
         name="tiled_hv",
     )
@@ -394,11 +418,8 @@ def _hv_at_call(T, S, B, interpret):
         grid=(T,),
         in_specs=[_spec_s(S)] * 4 + [_spec_r()] + [_spec_w(B)]
         + [pl.BlockSpec((1, 2), lambda i: (0, 0), memory_space=pltpu.SMEM)],
-        out_specs=[_spec_acc((1, 2)), _spec_acc((LANE, B))],
-        out_shape=[
-            jax.ShapeDtypeStruct((1, 2), jnp.float32),
-            jax.ShapeDtypeStruct((LANE, B), jnp.float32),
-        ],
+        out_specs=[_spec_whole((1, 2)), _spec_w(B)],
+        out_shape=[jax.ShapeDtypeStruct((1, 2), jnp.float32), _shape_w(B)],
         interpret=interpret,
         name="tiled_hv_at",
     )
@@ -412,11 +433,8 @@ def _value_grad_call(T, S, B, loss_name, use_offsets, interpret):
         grid=(T,),
         in_specs=[_spec_s(S)] * 4 + [_spec_r()] * 3 + [_spec_w(B)]
         + [pl.BlockSpec((1, 2), lambda i: (0, 0), memory_space=pltpu.SMEM)],
-        out_specs=[_spec_acc((1, 2)), _spec_acc((LANE, B))],
-        out_shape=[
-            jax.ShapeDtypeStruct((1, 2), jnp.float32),
-            jax.ShapeDtypeStruct((LANE, B), jnp.float32),
-        ],
+        out_specs=[_spec_whole((1, 2)), _spec_w(B)],
+        out_shape=[jax.ShapeDtypeStruct((1, 2), jnp.float32), _shape_w(B)],
         interpret=interpret,
         name="tiled_value_grad",
     )
@@ -620,10 +638,12 @@ class TiledBatch:
     # -- device kernels ------------------------------------------------------
 
     def _w2(self, w: Array) -> Array:
-        """Pad a [F] vector to the [B, 128] coefficient grid."""
-        B = self.num_blocks
-        pad = B * LANE - self.num_features
-        return jnp.pad(w.astype(jnp.float32), (0, pad)).reshape(B, LANE)
+        """Pad a [F] vector to the kernels' [B8, 128] coefficient grid
+        (feature b*128 + j at [b, j]; rows from B on are zero, which is
+        what a padding slot's ``hi == B`` sentinel gathers)."""
+        B8 = _table_rows(self.num_blocks)
+        pad = B8 * LANE - self.num_features
+        return jnp.pad(w.astype(jnp.float32), (0, pad)).reshape(B8, LANE)
 
     def _slot_args(self):
         return (self.vals, self.hi, self.lo, self.rlo)
@@ -688,8 +708,9 @@ class TiledBatch:
         return z.reshape(-1), u.reshape(-1)
 
     def _features(self, g: Array) -> Array:
-        """[LANE, B] accumulator -> [F]: feature b*128 + j lives at [j, b]."""
-        return g.T.reshape(-1)[: self.num_features]
+        """[B8, 128] accumulator -> [F]: feature b*128 + j lives at [b, j]
+        (rows from B on hold only what padding slots scattered)."""
+        return g.reshape(-1)[: self.num_features]
 
     def _rows3(self, per_row: Array) -> Array:
         """[n_pad] per-row vector -> the [T, 1, 128] tile grid."""

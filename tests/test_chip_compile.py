@@ -79,11 +79,16 @@ def _batch(num_tiles, sharding, shard=None):
     )
 
 
-def _kernel_cases():
+# a second shape for every kernel family: S is three lane tiles and the
+# coefficient table is narrower than its 16-row bfloat16 tile
+S_SMALL, B_SMALL = 384, 3
+
+
+def _kernel_cases(S=S, B=B):
     """name -> (pallas_call built with interpret=False, argument shapes)."""
     slot = [((T, 1, S), jnp.float32)] + [((T, 1, S), jnp.int32)] * 3
     row = ((T, 1, ROWS_PER_TILE), jnp.float32)
-    w2 = ((B, LANE), jnp.float32)
+    w2 = ((tiled._table_rows(B), LANE), jnp.float32)
     sh = ((1, 2), jnp.float32)
     return {
         "margins": (
@@ -110,14 +115,7 @@ def _kernel_cases():
     }
 
 
-@pytest.mark.parametrize("name,kernel", [
-    ("margins", "tiled_margins"), ("dot_rows", "tiled_margins"),
-    ("margins_pair", "tiled_margins"), ("scatter", "tiled_scatter"),
-    ("scatter_sq", "tiled_scatter"), ("value_grad", "tiled_value_grad"),
-    ("hv", "tiled_hv"), ("hv_at", "tiled_hv_at"),
-])
-def test_kernel_compiles_for_v5e(name, kernel, one_chip):
-    call, shapes = _kernel_cases()[name]
+def _compiles_as(kernel, call, shapes, one_chip):
     args = [
         jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
         for shape, dtype in shapes
@@ -129,6 +127,26 @@ def test_kernel_compiles_for_v5e(name, kernel, one_chip):
     assert re.search(
         rf"%{kernel}(\.\d+)? = [^\n]*custom_call_target=\"tpu_custom_call\"",
         text)
+
+
+@pytest.mark.parametrize("name,kernel", [
+    ("margins", "tiled_margins"), ("dot_rows", "tiled_margins"),
+    ("margins_pair", "tiled_margins"), ("scatter", "tiled_scatter"),
+    ("scatter_sq", "tiled_scatter"), ("value_grad", "tiled_value_grad"),
+    ("hv", "tiled_hv"), ("hv_at", "tiled_hv_at"),
+])
+def test_kernel_compiles_for_v5e(name, kernel, one_chip):
+    _compiles_as(kernel, *_kernel_cases()[name], one_chip)
+
+
+@pytest.mark.parametrize("name,kernel", [
+    ("margins_pair", "tiled_margins"), ("scatter_sq", "tiled_scatter"),
+    ("value_grad", "tiled_value_grad"), ("hv", "tiled_hv"),
+    ("hv_at", "tiled_hv_at"),
+])
+def test_kernel_family_compiles_at_a_narrow_shape_for_v5e(name, kernel,
+                                                         one_chip):
+    _compiles_as(kernel, *_kernel_cases(S_SMALL, B_SMALL)[name], one_chip)
 
 
 def test_fe_solver_module_and_kernels_are_named_for_v5e(one_chip,
