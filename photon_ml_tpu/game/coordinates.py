@@ -44,7 +44,8 @@ from photon_ml_tpu.data.normalization import NormalizationContext
 from photon_ml_tpu.game.random_effect_data import RandomEffectDataset
 from photon_ml_tpu.ops.objective import make_objective
 from photon_ml_tpu.ops.sparse import SparseBatch
-from photon_ml_tpu.ops.tiled import ROWS_PER_TILE, TiledBatch
+from photon_ml_tpu.ops.panels import PanelBatch, pack_design
+from photon_ml_tpu.ops.tiled import ROWS_PER_TILE
 from photon_ml_tpu.optim.adapter import glm_adapter
 from photon_ml_tpu.optim.common import BoxConstraints
 from photon_ml_tpu.optim.factory import OptimizerConfig, dispatch_solve
@@ -96,9 +97,30 @@ def _record_placement(label: str, array: Array) -> None:
 @lru_cache(maxsize=1)
 def _tiled_scorer():
     def score(batch, w):
-        return batch.dot_rows(w.astype(jnp.float32))
+        return batch.dot_rows(_to_design(batch, w.astype(jnp.float32)))
 
     return instrumented_jit(score, name="fe_score_tiled", multi_shape=True)
+
+
+def _to_design(design, vec):
+    """A feature-space vector in the order the design's kernels index it: a
+    :class:`PanelBatch` numbers its columns by frequency rank, every other
+    layout by feature. ``None`` stays None."""
+    if vec is None or not isinstance(design, PanelBatch):
+        return vec
+    return jnp.asarray(vec)[design.order]
+
+
+@lru_cache(maxsize=1)
+def _design_permuter():
+    """(to rank order, back to feature order) as named executables, for the
+    coefficients that cross a PanelBatch coordinate's edge every update."""
+    return (
+        instrumented_jit(
+            lambda w, order: w[order], name="fe_to_ranks", multi_shape=True),
+        instrumented_jit(
+            lambda w, rank: w[rank], name="fe_from_ranks", multi_shape=True),
+    )
 
 
 @dataclasses.dataclass
@@ -123,9 +145,10 @@ class FixedEffectCoordinate:
     def __post_init__(self):
         self.config.validate(self.loss_name)
         self._base_batch = self.data.batch_for(self.shard_name)
-        # "auto": the tiled one-hot-matmul layout is the TPU fast path
-        # (~6x over COO gather/scatter, ops/tiled.py); elsewhere pallas
-        # falls back to interpret mode, so COO is faster
+        # "auto": the tiled one-hot-matmul layouts are the TPU fast path
+        # (ops/tiled.py, and ops/panels.py for wide designs: PERF.md has
+        # both against COO's gather / scatter-add on the chip); elsewhere
+        # pallas falls back to interpret mode, so COO is faster
         if self.layout not in ("auto", "tiled", "coo"):
             raise ValueError(f"unknown layout '{self.layout}'")
         self._use_tiled = self.layout == "tiled" or (
@@ -135,9 +158,18 @@ class FixedEffectCoordinate:
         # (span `upload`): the two never overlap, and the host copy dies
         # with this method
         host_tiled = None
+        if self.mesh is not None and psharding.data_axis(self.mesh) is None:
+            # an entity-only mesh has no row axis to data-parallel over;
+            # the FE block runs single-device (its RE siblings still shard)
+            self.mesh = None
         if self._use_tiled:
             with span("layout"):
-                host_tiled = TiledBatch.pack_batch(self._base_batch)
+                # plain tiles or column panels: the design's own width and
+                # column histogram decide (ops/panels.py::pack_design)
+                host_tiled = pack_design(
+                    self._base_batch,
+                    shards=1 if self.mesh is None else psharding.axis_size(
+                        self.mesh, psharding.data_axis(self.mesh)))
         # fresh sample per update_model (runWithSampling parity: the reference
         # re-samples on every coordinate update, DistributedOptimizationProblem
         # .scala:113-125); counter salts the rng so updates differ
@@ -178,21 +210,29 @@ class FixedEffectCoordinate:
                         "enforced under shift normalization (the intercept "
                         "absorbs -w.shift at back-transform)"
                     )
+        # a PanelBatch solves in frequency-rank order: bounds and
+        # normalization are renumbered here, coefficients in update_model
+        # and score, so nothing outside this class sees a rank
+        self._factors = _to_design(
+            host_tiled, None if norm is None else norm.factors)
+        self._shifts = _to_design(
+            host_tiled, None if norm is None else norm.shifts)
+        if self._constraints is not None:
+            self._constraints = type(self._constraints)(
+                lower=_to_design(host_tiled, self._constraints.lower),
+                upper=_to_design(host_tiled, self._constraints.upper),
+            )
         self._obj = make_objective(
             self.loss_name,
             l2_weight=self.config.regularization.l2_weight(
                 self.config.regularization_weight
             ),
-            factors=None if norm is None else norm.factors,
-            shifts=None if norm is None else norm.shifts,
+            factors=self._factors,
+            shifts=self._shifts,
         )
         self._l1 = jnp.float32(
             self.config.regularization.l1_weight(self.config.regularization_weight)
         )
-        if self.mesh is not None and psharding.data_axis(self.mesh) is None:
-            # an entity-only mesh has no row axis to data-parallel over;
-            # the FE block runs single-device (its RE siblings still shard)
-            self.mesh = None
         if self.mesh is not None:
             # GSPMD path: the FLAT design (tiles or COO slots) is committed
             # with NamedSharding(mesh, P(batch)) ONCE; per-update offsets
@@ -221,6 +261,8 @@ class FixedEffectCoordinate:
             self._solve_batch = accounted_upload(self._base_batch.device)
         design = self._tiled if self._use_tiled else self._solve_batch
         _record_placement(f"{self.name}.design", jax.tree.leaves(design)[0])
+        # set where coefficients cross this class's edge in rank order
+        self._panels = design if isinstance(design, PanelBatch) else None
 
     def _downsampled_weights(self, batch, update_index: int):
         rate = self.config.down_sampling_rate
@@ -261,14 +303,17 @@ class FixedEffectCoordinate:
             a = jnp.pad(a, (0, self._solve_batch.num_rows - a.shape[0]))
         return jax.device_put(a, self._row_sharding)
 
-    def _tiled_rows(self, per_row: Array, reshape: bool = True) -> Array:
-        """Pad a global [n_pad] per-row array to the tiled row count
-        (multiple of 128), optionally into the [T, 1, 128] grid."""
+    def _tiled_rows(self, per_row: Array) -> Array:
+        """Pad a global [n_pad] per-row array to the tiled row count."""
         a = jnp.asarray(per_row, jnp.float32)
-        a = jnp.pad(a, (0, self._tiled.num_rows - a.shape[0]))
-        if reshape:
-            a = a.reshape(self._tiled.num_tiles, 1, ROWS_PER_TILE)
-        return a
+        return jnp.pad(a, (0, self._tiled.num_rows - a.shape[0]))
+
+    def _with_rows(self, batch, field: str, per_row: Array):
+        """``batch`` with its per-row ``offsets`` or ``weights`` replaced
+        (the tiled layouts keep them as a [T, 1, 128] grid)."""
+        if self._use_tiled:
+            return getattr(batch, "with_" + field)(per_row)
+        return dataclasses.replace(batch, **{field: per_row})
 
     def initialize_model(self) -> FixedEffectModel:
         d = self._base_batch.num_features
@@ -291,27 +336,19 @@ class FixedEffectCoordinate:
         # damped retry (optim.guard): l2_weight is a traced leaf, so the
         # compiled solver is reused unchanged
         obj = damped_objective(self._obj, self.extra_l2)
-        off_field = "offsets3" if self._use_tiled else "offsets"
-        wgt_field = "weights3" if self._use_tiled else "weights"
+        if self._panels is not None:
+            w0 = _design_permuter()[0](w0, self._panels.order)
         if self.mesh is not None:
             # DP path (FixedEffectCoordinate.scala:136-147): rows committed
             # P(batch), whole while-loop in ONE GSPMD jit, grads psum'd by
             # the compiler. Only changed per-row arrays are re-placed.
             batch = self._solve_batch
             if residual_scores is not None:
-                batch = dataclasses.replace(
-                    batch,
-                    **{off_field: self._place_rows(
-                        self._base_batch.offsets + residual_scores
-                    )},
-                )
+                batch = self._with_rows(batch, "offsets", self._place_rows(
+                    self._base_batch.offsets + residual_scores))
             if self.config.down_sampling_rate < 1.0:
-                batch = dataclasses.replace(
-                    batch,
-                    **{wgt_field: self._place_rows(
-                        self._downsampled_weights(self._base_batch, update_index)
-                    )},
-                )
+                batch = self._with_rows(batch, "weights", self._place_rows(
+                    self._downsampled_weights(self._base_batch, update_index)))
             res = gspmd_solve(
                 self.loss_name,
                 batch,
@@ -320,26 +357,18 @@ class FixedEffectCoordinate:
                 self.mesh,
                 axis=self._axis,
                 constraints=self._constraints,
-                factors=None if norm is None else norm.factors,
-                shifts=None if norm is None else norm.shifts,
+                factors=self._factors,
+                shifts=self._shifts,
                 extra_l2=self.extra_l2,
             )
         elif self._use_tiled:
             batch = self._tiled
             if self.config.down_sampling_rate < 1.0:
-                batch = dataclasses.replace(
-                    batch,
-                    weights3=self._tiled_rows(
-                        self._downsampled_weights(self._base_batch, update_index)
-                    ),
-                )
+                batch = batch.with_weights(self._tiled_rows(
+                    self._downsampled_weights(self._base_batch, update_index)))
             if residual_scores is not None:
-                batch = batch.with_offsets(
-                    self._tiled_rows(
-                        self._base_batch.offsets + residual_scores,
-                        reshape=False,
-                    )
-                )
+                batch = batch.with_offsets(self._tiled_rows(
+                    self._base_batch.offsets + residual_scores))
             res = self._solver(obj, batch, w0, self._l1, self._constraints)
         else:
             batch = self._solve_batch
@@ -358,6 +387,8 @@ class FixedEffectCoordinate:
                 )
             res = self._solver(obj, batch, w0, self._l1, self._constraints)
         w = res.w
+        if self._panels is not None:
+            w = _design_permuter()[1](w, self._panels.rank)
         from photon_ml_tpu.optim.trackers import FixedEffectOptimizationTracker
 
         self.last_tracker = FixedEffectOptimizationTracker.from_result(res)

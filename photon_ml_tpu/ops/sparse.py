@@ -32,10 +32,11 @@ def _round_up(n: int, multiple: int) -> int:
     return max(((n + multiple - 1) // multiple) * multiple, multiple)
 
 
-def _pad(a: np.ndarray, total: int, fill=0) -> np.ndarray:
-    """Pad a 1-D host array to ``total`` entries with ``fill``."""
+def _pad(a: np.ndarray, total: int, fill=0, dtype=None) -> np.ndarray:
+    """Pad a 1-D host array to ``total`` entries with ``fill``, as
+    ``dtype`` (the array's own by default): one pass, no wider copy."""
     a = np.asarray(a)
-    out = np.full((total,), fill, dtype=a.dtype)
+    out = np.full((total,), fill, dtype=a.dtype if dtype is None else dtype)
     out[: a.shape[0]] = a
     return out
 
@@ -147,11 +148,9 @@ class SparseBatch:
         # PCIe link entirely.
         np_dtype = np.dtype(dtype)
         return SparseBatch(
-            values=_pad(np.asarray(values, np.float64), nnz_pad).astype(np_dtype),
-            rows=_pad(rows.astype(np.int64), nnz_pad, fill=n_pad - 1).astype(
-                np.int32
-            ),
-            cols=_pad(cols.astype(np.int64), nnz_pad).astype(np.int32),
+            values=_pad(values, nnz_pad, dtype=np_dtype),
+            rows=_pad(rows, nnz_pad, fill=n_pad - 1, dtype=np.int32),
+            cols=_pad(cols, nnz_pad, dtype=np.int32),
             labels=labels_p.astype(np_dtype),
             offsets=offsets_p.astype(np_dtype),
             weights=weights_p.astype(np_dtype),

@@ -62,10 +62,12 @@ shape: margins 3.4e-6 (3.4e-6), scatter 3.5e-6 (3.9e-6).
 This replaces the hot loop the reference distributes over a Spark cluster
 (ValueAndGradientAggregator.scala:132-153) with on-chip matmuls.
 
-Skew note: the slot-list length S is the max nnz over tiles; heavily skewed
-row lengths inflate padding. The layout builder reports waste; callers with
-pathological rows should pre-shuffle rows (any order is fine — tiles are
-independent).
+Width and skew: a pass costs slots x B (the [2*B8, S] intermediates above),
+so this layout is for designs of up to ~128 column blocks; S is the fullest
+tile's count, so ragged row lengths pad. ``ops/panels.py::pack_design``
+chooses between this layout and the column panels from the design's own
+width and column histogram, uses these kernels unchanged for the panels' hot
+part, and reports slots against nonzeros (gauge ``layout.padding_ratio``).
 """
 
 from __future__ import annotations
@@ -162,21 +164,39 @@ def _stack16(rows):
     return jnp.concatenate(blocks, axis=0).astype(jnp.bfloat16)
 
 
+def _gather_slots(tab, hit, lot):
+    """table[hi_s, lo_s] of every slot as a [1, S] row. ``tab`` is a
+    :func:`_table2` grid [2*N, 128], ``hit`` [N, S] the boolean one-hot of
+    the slots' table rows and ``lot`` [128, S] of their lanes:
+    ``[t_hi; t_lo] @ lot`` puts t[., lo_s] of every table row in slot s's
+    lane, ``where(hit, ., 0)`` keeps the slot's own row, and the sublane
+    sum has ONE nonzero, so it is exact."""
+    N = hit.shape[0]
+    g = _dot(tab, lot, _NN)                            # [2*N, S]
+    g = jnp.where(hit, g[:N] + g[N:], 0.0)
+    return jnp.sum(g, axis=0, keepdims=True)
+
+
+def _place_slots(p, hit, lot):
+    """sum_s p_s * onehot(hi_s, lo_s) as an [N, 128] grid. The per-slot
+    row ``p`` [1, S] is split ONCE, each half is placed in the slot's grid
+    row by a select (0/1 mask times a split value IS a select), and one NT
+    contraction over the lane axis against ``lot`` lands both halves."""
+    N = hit.shape[0]
+    halves = [jnp.where(hit, h.astype(jnp.float32), 0.0).astype(jnp.bfloat16)
+              for h in _split_bf16(p)]
+    d = _dot(jnp.concatenate(halves, axis=0), lot, _NT)   # [2*N, 128]
+    return d[:N] + d[N:]
+
+
 def _row_sums(tabs, vals, hit, lot, rt):
     """Per-row sums of vals_s * table[col_s], one row of the [8, R] result
     per table in ``tabs`` (stacked :func:`_table2` grids).
 
-    Gather: ``[w_hi; w_lo] @ lot`` puts w[., lo_s] of every column block in
-    slot s's lane; ``where(hit, ., 0)`` keeps the slot's own block and the
-    sublane sum has ONE nonzero, so it is exact. Times ``vals`` as a [1, S]
-    row. Row sum: an NT contraction over the lane axis of the per-slot rows
-    and ``rt``, all tables in one :func:`_stack16` LHS."""
-    B8 = hit.shape[0]
-    per_slot = []
-    for tab in tabs:
-        g = _dot(tab, lot, _NN)                        # [2*B8, S]
-        g = jnp.where(hit, g[:B8] + g[B8:], 0.0)
-        per_slot.append(jnp.sum(g, axis=0, keepdims=True) * vals)
+    Gather (:func:`_gather_slots`) times ``vals`` as a [1, S] row. Row sum:
+    an NT contraction over the lane axis of the per-slot rows and ``rt``,
+    all tables in one :func:`_stack16` LHS."""
+    per_slot = [_gather_slots(tab, hit, lot) * vals for tab in tabs]
     z = _dot(_stack16(per_slot), rt, _NT)              # [16, R]
     return z[:8] + z[8:]
 
@@ -185,17 +205,11 @@ def _scatter_accum(out_ref, per_row, vals, hit, lot, rt):
     """out[B8, 128] += sum_s per_row[row_s] * vals_s * onehot(col_s).
 
     ``per_row`` [1, R] reaches the slots through ``rt`` on the MXU (exact:
-    bf16x2); the per-slot product is split ONCE as a [1, S] row, each half
-    is placed in the slot's column block by a select (0/1 mask times a
-    split value IS a select), and one NT contraction over the lane axis
-    against ``lot`` lands both halves in the accumulator."""
-    B8 = hit.shape[0]
+    bf16x2); :func:`_place_slots` lands the per-slot product in the
+    accumulator."""
     s = _dot(_stack16([per_row]), rt, _NN)             # [16, S]
     p = (s[:8] + s[8:])[0:1] * vals                    # [1, S]
-    halves = [jnp.where(hit, h.astype(jnp.float32), 0.0).astype(jnp.bfloat16)
-              for h in _split_bf16(p)]
-    d = _dot(jnp.concatenate(halves, axis=0), lot, _NT)   # [2*B8, 128]
-    out_ref[:] = out_ref[:] + (d[:B8] + d[B8:])
+    out_ref[:] = out_ref[:] + _place_slots(p, hit, lot)
 
 
 # ---------------------------------------------------------------------------
@@ -440,6 +454,33 @@ def _value_grad_call(T, S, B, loss_name, use_offsets, interpret):
     )
 
 
+def run_tiles(shard, num_tiles, make_call, tile_args, rep_args, reduce: bool):
+    """Run ``make_call(T)`` -- a pallas_call over T tiles -- on a tiled
+    design. ``tile_args`` lead with the tile dim, ``rep_args`` (the
+    coefficient grids and shifts) are whole on every device.
+
+    Under ``shard`` (mesh, batch axis) each device runs the kernel on its
+    own T/n tiles inside ``jax.shard_map``; with ``reduce`` the outputs are
+    feature-space accumulators and are ``psum``med over the batch axis,
+    otherwise they are per-row and stay sharded like the tiles."""
+    if shard is None:
+        return make_call(num_tiles)(*tile_args, *rep_args)
+    mesh, axis = shard
+    call = make_call(num_tiles // mesh.shape[axis])
+
+    def local(*args):
+        out = call(*args)
+        return jax.lax.psum(out, axis) if reduce else out
+
+    return jax.shard_map(
+        local,
+        mesh=mesh,
+        in_specs=(P(axis),) * len(tile_args) + (P(),) * len(rep_args),
+        out_specs=P() if reduce else P(axis),
+        check_vma=False,  # pallas_call carries no vma rule
+    )(*tile_args, *rep_args)
+
+
 # ---------------------------------------------------------------------------
 # TiledBatch
 # ---------------------------------------------------------------------------
@@ -523,14 +564,16 @@ class TiledBatch:
         weights: Optional[np.ndarray] = None,
     ) -> "TiledBatch":
         """Host-side layout build: group nnz by row tile, pad to max. The
-        leaves stay HOST numpy arrays; :meth:`device` places them."""
+        leaves stay HOST numpy arrays; :meth:`device` places them. S is the
+        fullest tile's count: whoever packs reports slots against nonzeros
+        (``ops/panels.py::pack_design``: gauge ``layout.padding_ratio``)."""
         n = int(len(labels))
         R = ROWS_PER_TILE
         T = max(-(-n // R), 1)
         B = -(-int(num_features) // LANE)
-        rows = np.asarray(rows, np.int64)
-        cols = np.asarray(cols, np.int64)
-        values = np.asarray(values, np.float64)
+        # the input's own dtypes throughout: at 1e8 nonzeros an int64 /
+        # float64 copy of each array is most of the build
+        rows, cols, values = np.asarray(rows), np.asarray(cols), np.asarray(values)
         validate_coo_indices(rows, cols, n, num_features)
 
         tile = rows // R
@@ -545,17 +588,19 @@ class TiledBatch:
         starts = np.searchsorted(tile_s, np.arange(T))
         counts = np.diff(np.append(starts, len(tile_s)))
         S = int(max(LANE, -(-int(counts.max(initial=0)) // LANE) * LANE))
-        offs = np.arange(len(tile_s)) - starts[tile_s]
-        dest = tile_s * S + offs
+        idx = np.int32 if max(T * S, len(tile_s)) < 2 ** 31 else np.int64
+        dest = np.arange(len(tile_s), dtype=idx)
+        dest -= starts.astype(idx)[tile_s]
+        dest += tile_s.astype(idx) * idx(S)
 
         vals2 = np.zeros((T * S,), np.float32)
         hi2 = np.full((T * S,), B, np.int32)   # sentinel: one-hot all-zero
         lo2 = np.zeros((T * S,), np.int32)
         rlo2 = np.zeros((T * S,), np.int32)
         vals2[dest] = values
-        hi2[dest] = (cols // LANE).astype(np.int32)
-        lo2[dest] = (cols % LANE).astype(np.int32)
-        rlo2[dest] = (rows % R).astype(np.int32)
+        hi2[dest] = cols // LANE
+        lo2[dest] = cols % LANE
+        rlo2[dest] = rows % R
 
         npad = T * R
         lab = np.zeros(npad, np.float32)
@@ -649,30 +694,8 @@ class TiledBatch:
         return (self.vals, self.hi, self.lo, self.rlo)
 
     def _run(self, make_call, tile_args, rep_args, reduce: bool):
-        """Run ``make_call(T)`` — a pallas_call over T tiles — on this
-        batch. ``tile_args`` lead with the tile dim, ``rep_args`` (the
-        coefficient grids and shifts) are whole on every device.
-
-        Under ``shard`` each device runs the kernel on its own T/n tiles
-        inside ``jax.shard_map``; with ``reduce`` the outputs are feature-
-        space accumulators and are ``psum``med over the batch axis,
-        otherwise they are per-row and stay sharded like the tiles."""
-        if self.shard is None:
-            return make_call(self.num_tiles)(*tile_args, *rep_args)
-        mesh, axis = self.shard
-        call = make_call(self.num_tiles // mesh.shape[axis])
-
-        def local(*args):
-            out = call(*args)
-            return jax.lax.psum(out, axis) if reduce else out
-
-        return jax.shard_map(
-            local,
-            mesh=mesh,
-            in_specs=(P(axis),) * len(tile_args) + (P(),) * len(rep_args),
-            out_specs=P() if reduce else P(axis),
-            check_vma=False,  # pallas_call carries no vma rule
-        )(*tile_args, *rep_args)
+        return run_tiles(self.shard, self.num_tiles, make_call, tile_args,
+                         rep_args, reduce)
 
     def margins(self, w: Array, shift: Array | float = 0.0) -> Array:
         """Per-row margins z_i = x_i . w + shift + offset_i."""
@@ -791,8 +814,7 @@ class TiledBatch:
         return s1, s2, cnt
 
     def with_offsets(self, offsets: Array) -> "TiledBatch":
-        return dataclasses.replace(
-            self,
-            offsets3=offsets.astype(jnp.float32).reshape(
-                self.num_tiles, 1, ROWS_PER_TILE),
-        )
+        return dataclasses.replace(self, offsets3=self._rows3(offsets))
+
+    def with_weights(self, weights: Array) -> "TiledBatch":
+        return dataclasses.replace(self, weights3=self._rows3(weights))

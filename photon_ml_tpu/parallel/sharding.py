@@ -381,13 +381,18 @@ def place_batch(batch, mesh: Mesh, axis: Optional[str] = None):
     ``NamedSharding(mesh, P(axis))`` on its leading dim. The returned
     batch feeds :func:`photon_ml_tpu.parallel.distributed.gspmd_solve`
     directly — the whole optimizer while-loop then runs under one jit with
-    GSPMD-inserted psums (a TiledBatch's pallas kernels under
-    ``jax.shard_map`` over the same axis)."""
+    GSPMD-inserted psums (a TiledBatch's or PanelBatch's pallas kernels
+    under ``jax.shard_map`` over the same axis)."""
     import dataclasses
 
+    from photon_ml_tpu.ops.panels import PanelBatch
     from photon_ml_tpu.ops.tiled import TiledBatch
 
     axis = axis or data_axis(mesh)
+    if isinstance(batch, PanelBatch):
+        # packed for this many shards already (every shard the same tile
+        # counts, row windows local to it); its column order stays whole
+        return batch.place(mesh, axis)
     sharding = batch_sharding(mesh, axis)
     padded = pad_batch_rows(batch, axis_size(mesh, axis))
     placed = jax.tree.map(lambda x: jax.device_put(x, sharding), padded)

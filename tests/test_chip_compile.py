@@ -238,3 +238,119 @@ def test_unsharded_kernel_under_a_mesh_is_refused(topo, on_chip_kernels):
         jax.jit(
             lambda b, w: b.fused_value_grad(w, 0.0, "logistic")
         ).lower(batch, w).compile()
+
+
+# ---------------------------------------------------------------------------
+# column panels (ops/panels.py) at the click-log cell's shape: 7M rows x 1M
+# features, the plan the host packer made there (PERF.md, Findings PR 26)
+# ---------------------------------------------------------------------------
+
+PANEL_TILES = 54_784
+PANEL_HOT_S = 4352
+#: (window, first rank block, column windows, tiles) of the four tail classes
+PANEL_CLASSES = [(32, 32, 2, 15_308), (64, 96, 3, 11_110),
+                 (128, 288, 4, 8_083), (256, 800, 28, 13_603)]
+PANEL_FEATURES = 1_000_000
+
+
+def _panel_batch(sharding, whole, shard=None, shards=1):
+    from photon_ml_tpu.ops.panels import (
+        HOT_BLOCKS, PANEL_SLOTS, PanelBatch, PanelClass, PanelPart)
+
+    def leaf(shape, dtype, s=sharding):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=s)
+
+    tiles = -(-PANEL_TILES // (256 * shards)) * 256 * shards
+    hot = TiledBatch(
+        vals=leaf((tiles, 1, PANEL_HOT_S), jnp.float32),
+        **{k: leaf((tiles, 1, PANEL_HOT_S), jnp.int32)
+           for k in ("hi", "lo", "rlo")},
+        **{k: leaf((tiles, 1, ROWS_PER_TILE), jnp.float32)
+           for k in ("labels3", "offsets3", "weights3")},
+        num_features=HOT_BLOCKS * LANE, shard=shard)
+    parts = []
+    for W, first, windows, Tt in PANEL_CLASSES:
+        Tt = -(-Tt // shards) * shards
+        parts.append(PanelPart(
+            meta=leaf((Tt,), jnp.int32),
+            vals=leaf((Tt, 1, PANEL_SLOTS), jnp.float32),
+            **{k: leaf((Tt, 1, PANEL_SLOTS), jnp.int32)
+               for k in ("chi", "clo", "rhi", "rlo")},
+            cls=PanelClass(W, first, windows),
+            bits=max((windows - 1).bit_length(), 1)))
+    return PanelBatch(
+        hot=hot, parts=tuple(parts),
+        order=leaf((PANEL_FEATURES,), jnp.int32, whole),
+        rank=leaf((PANEL_FEATURES,), jnp.int32, whole),
+        num_features=PANEL_FEATURES, shards=shards, shard=shard)
+
+
+@pytest.mark.parametrize("W,first,windows,Tt", PANEL_CLASSES)
+@pytest.mark.parametrize("kernel", ["panel_margins", "panel_scatter"])
+def test_panel_kernel_compiles_for_v5e(kernel, W, first, windows, Tt,
+                                       one_chip):
+    """Each tail class's two kernels at the cell's shape: the scalar-
+    prefetched tile index, the whole coefficient grid of the class in VMEM,
+    the [2W, 1024] one-hot intermediates."""
+    from photon_ml_tpu.ops import panels
+
+    bits = max((windows - 1).bit_length(), 1)
+    blocks = W * windows
+    slot = ([((Tt,), jnp.int32), ((Tt, 1, 1024), jnp.float32)]
+            + [((Tt, 1, 1024), jnp.int32)] * 4)
+    if kernel == "panel_margins":
+        call = panels._panel_margins_call(
+            Tt, 1024, W, blocks, PANEL_TILES, bits, False)
+        shapes = slot + [((blocks, LANE), jnp.float32)]
+    else:
+        call = panels._panel_scatter_call(
+            Tt, 1024, W, blocks, bits, False, False)
+        shapes = slot + [((PANEL_TILES, LANE), jnp.float32)]
+    _compiles_as(kernel, call, shapes, one_chip)
+
+
+def test_panel_lbfgs_solve_compiles_for_v5e(one_chip, on_chip_kernels):
+    """The FE coordinate's solver over the whole panel design on one chip:
+    module ``jit_fe_solve``, and its Mosaic calls are the hot panel's tiled
+    kernels and the tail's panel kernels, all named."""
+    from photon_ml_tpu.game.coordinates import _fe_solver
+    from photon_ml_tpu.ops.objective import make_objective
+    from photon_ml_tpu.optim import OptimizerConfig, OptimizerType
+
+    cfg = OptimizerConfig(
+        optimizer_type=OptimizerType.LBFGS, max_iterations=25, tolerance=0.0)
+    obj = make_objective("logistic", l2_weight=10.0)
+    w0 = jax.ShapeDtypeStruct(
+        (PANEL_FEATURES,), jnp.float32, sharding=one_chip)
+    compiled = _fe_solver(cfg, "logistic").lower(
+        obj, _panel_batch(one_chip, one_chip), w0, jnp.float32(0.0), None
+    ).compile()
+    text = compiled.as_text()
+    assert re.match(r"HloModule jit_fe_solve\b", text)
+    calls = set(re.findall(
+        r"%([\w\-]+?)(?:\.\d+)? = [^\n]*custom_call_target=\"tpu_custom_call\"",
+        text))
+    assert calls == {"tiled_margins", "tiled_scatter", "panel_margins",
+                     "panel_scatter"}
+    mem = compiled.memory_analysis()
+    # the design is 4.9 GB; the solve's own state is a few [d] and [n]
+    # vectors: the whole program sits inside half the chip
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 8 << 30
+
+
+def test_sharded_panel_value_grad_compiles_for_v5e_2x2(topo, on_chip_kernels):
+    """Four chips: every part's tiles ``P("batch")``, the kernels per shard
+    under ``shard_map``, the feature-space sums all-reduced, the column
+    order whole on every chip."""
+    mesh = Mesh(np.array(topo.devices).reshape(4), ("batch",))
+    batch = _panel_batch(
+        NamedSharding(mesh, P("batch")), NamedSharding(mesh, P()),
+        shard=(mesh, "batch"), shards=4)
+    w = jax.ShapeDtypeStruct(
+        (PANEL_FEATURES,), jnp.float32, sharding=NamedSharding(mesh, P()))
+    compiled = jax.jit(
+        lambda b, w: b.fused_value_grad(w, 0.0, "logistic")
+    ).lower(batch, w).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "all-reduce" in text
+    assert "all-gather" not in text
