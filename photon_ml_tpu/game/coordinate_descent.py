@@ -24,12 +24,15 @@ incremental path, a benchmark — gets it, because it opens here)::
           update              to the end of the tracker's and guard's fetch
           score               coord.score + its 1-element fetch
           validate            validation scoring, evaluators, their fetches
+            validation_layout   first validation of a dataset only: its rows
+            validation_upload   laid out like a tiled coordinate's design
 """
 
 from __future__ import annotations
 
 import dataclasses
 import logging
+from functools import lru_cache
 from typing import Mapping, Optional, Sequence
 
 import jax
@@ -85,35 +88,79 @@ def padded_validation_arrays(
     """(labels, weights, offsets) as [n_pad] f32 device arrays with
     weight-0 padding rows — the evaluator input layout. Shared by the CD
     validation path below and the sweep selector (sweep/select.py), so
-    both score against identical padded arrays."""
+    both score against identical padded arrays. Built and uploaded once a
+    dataset and row count, and kept with the dataset (as
+    ``GameDataset.device_shard`` keeps its copy)."""
+    cache = data.__dict__.setdefault("_validation_arrays", {})
+    hit = cache.get(n_pad)
+    if hit is None:
 
-    def pad(a, fill=0.0):
-        out = np.full((n_pad,), fill)
-        out[: data.num_rows] = a
-        return jnp.asarray(out, jnp.float32)
+        def pad(a):
+            out = np.zeros((n_pad,), np.float32)
+            out[: data.num_rows] = a
+            return jnp.asarray(out)
 
-    return pad(data.response), pad(data.weight), pad(data.offset)
+        hit = cache[n_pad] = (
+            pad(data.response), pad(data.weight), pad(data.offset))
+    return hit
 
 
-def _evaluate(model: GameModel, spec: ValidationSpec) -> dict[str, float]:
+@lru_cache(maxsize=64)
+def _evaluator_program(kind: str, num_groups: int = 0, k: int = 0):
+    """One evaluator as a named executable ``evaluate_<kind>`` over the
+    sub-models' score vectors: their sum, the offsets and the metric in one
+    program (eagerly, ``auc`` alone was a dozen one-op programs a call)."""
+
+    def run(parts, offsets, labels, weights, group_ids=None):
+        full_scores = sum(parts[1:], start=parts[0]) + offsets
+        if kind == "sharded_auc":
+            return sharded_auc(
+                full_scores, labels, weights, group_ids, num_groups)
+        if kind == "sharded_precision_at_k":
+            return sharded_precision_at_k(
+                full_scores, labels, weights, group_ids, num_groups, k)
+        return EVALUATORS[kind](full_scores, labels, weights)
+
+    return telemetry.instrumented_jit(
+        run, name="evaluate_" + kind, multi_shape=True)
+
+
+def _evaluate(
+    model: GameModel,
+    spec: ValidationSpec,
+    coordinates: Optional[Mapping[str, object]] = None,
+) -> dict[str, float]:
     """Validation metrics of ``model``: one accounted fetch per evaluator
-    (the host's wait on validation scoring and the evaluator's program)."""
+    (the host's wait on validation scoring and the evaluator's program).
+    A sub-model whose coordinate (``coordinates``, by name) can score
+    another dataset's rows through its own layout does (``score_dataset``:
+    a tiled fixed effect); every other one is scored by ``model.score``.
+    Either way a score vector has the dataset's padded row count."""
 
     def fetch(value, spec_str: str) -> float:
         return float(telemetry.sync_fetch(value, label=f"evaluate:{spec_str}"))
 
-    scores = model.score(spec.data)
+    if not model.models:
+        raise ValueError("GAME model has no sub-models")
+    through_layout = {
+        name: coord.score_dataset
+        for name, coord in (coordinates or {}).items()
+        if hasattr(coord, "score_dataset")}
+    parts = tuple(
+        through_layout[name](sub, spec.data) if name in through_layout
+        else sub.score(spec.data)
+        for name, sub in model.models.items())
     n = spec.data.num_rows
-    n_pad = scores.shape[0]
+    n_pad = parts[0].shape[0]
     labels, weights, offsets = padded_validation_arrays(spec.data, n_pad)
-    full_scores = scores + offsets
 
     out = {}
     for spec_str in spec.evaluators:
         kind, group_col, k = parse_evaluator(spec_str)
         if kind in EVALUATORS:
             out[spec_str] = fetch(
-                EVALUATORS[kind](full_scores, labels, weights), spec_str
+                _evaluator_program(kind)(parts, offsets, labels, weights),
+                spec_str,
             )
             continue
         col = next(
@@ -128,18 +175,11 @@ def _evaluate(model: GameModel, spec: ValidationSpec) -> dict[str, float]:
         gids = jnp.asarray(
             np.pad(idc.codes, (0, n_pad - n)), jnp.int32
         )
-        if kind == "sharded_auc":
-            out[spec_str] = fetch(
-                sharded_auc(full_scores, labels, weights, gids, idc.num_entities),
-                spec_str,
-            )
-        else:
-            out[spec_str] = fetch(
-                sharded_precision_at_k(
-                    full_scores, labels, weights, gids, idc.num_entities, k
-                ),
-                spec_str,
-            )
+        out[spec_str] = fetch(
+            _evaluator_program(kind, idc.num_entities, k or 0)(
+                parts, offsets, labels, weights, gids),
+            spec_str,
+        )
     return out
 
 
@@ -381,7 +421,8 @@ def run_coordinate_descent(
                         if validation is not None:
                             game_model = GameModel(task=task, models=dict(models))
                             with telemetry.span("validate"):
-                                metrics = _evaluate(game_model, validation)
+                                metrics = _evaluate(
+                                    game_model, validation, coordinates)
                             entry["metrics"] = metrics
                             primary = validation.evaluators[0]
                             value = metrics[primary]

@@ -26,6 +26,8 @@ TPU realization:
 from __future__ import annotations
 
 import dataclasses
+import logging
+import weakref
 from functools import lru_cache
 from typing import Optional, Protocol
 
@@ -44,7 +46,12 @@ from photon_ml_tpu.data.normalization import NormalizationContext
 from photon_ml_tpu.game.random_effect_data import RandomEffectDataset
 from photon_ml_tpu.ops.objective import make_objective
 from photon_ml_tpu.ops.sparse import SparseBatch
-from photon_ml_tpu.ops.panels import PanelBatch, pack_design
+from photon_ml_tpu.ops.panels import (
+    PanelBatch,
+    pack_design,
+    pack_rows_like,
+    report_layout,
+)
 from photon_ml_tpu.ops.tiled import ROWS_PER_TILE
 from photon_ml_tpu.optim.adapter import glm_adapter
 from photon_ml_tpu.optim.common import BoxConstraints
@@ -53,11 +60,13 @@ from photon_ml_tpu.optim.guard import damped_objective, solve_health
 from photon_ml_tpu.parallel.distributed import gspmd_solve
 from photon_ml_tpu.parallel import sharding as psharding
 from photon_ml_tpu.telemetry.device import accounted_upload
-from photon_ml_tpu.telemetry.metrics import gauge
+from photon_ml_tpu.telemetry.metrics import counter, gauge
 from photon_ml_tpu.telemetry.trace import span
 from photon_ml_tpu.telemetry.xla import instrumented_jit, record_collective
 
 Array = jax.Array
+
+logger = logging.getLogger("photon_ml_tpu.game")
 
 
 class Coordinate(Protocol):
@@ -100,6 +109,14 @@ def _tiled_scorer():
         return batch.dot_rows(_to_design(batch, w.astype(jnp.float32)))
 
     return instrumented_jit(score, name="fe_score_tiled", multi_shape=True)
+
+
+def _fit_rows(z: Array, n_pad: int) -> Array:
+    """A design's per-row vector (its rows padded to whole tiles) cut or
+    padded to the dataset's own padded row count."""
+    if z.shape[0] >= n_pad:
+        return z[:n_pad]
+    return jnp.pad(z, (0, n_pad - z.shape[0]))
 
 
 def _to_design(design, vec):
@@ -263,6 +280,9 @@ class FixedEffectCoordinate:
         _record_placement(f"{self.name}.design", jax.tree.leaves(design)[0])
         # set where coefficients cross this class's edge in rank order
         self._panels = design if isinstance(design, PanelBatch) else None
+        # (weak reference to the dataset, its rows in this design's layout
+        # or None) of the last foreign dataset scored: score_dataset
+        self._foreign = None
 
     def _downsampled_weights(self, batch, update_index: int):
         rate = self.config.down_sampling_rate
@@ -402,11 +422,64 @@ class FixedEffectCoordinate:
             # the solve layout already holds the design in HBM — score
             # through it instead of uploading a second (COO) copy
             z = _tiled_scorer()(self._tiled, model.coefficients)
-            n_pad = self.data.shard(self.shard_name).num_rows
-            if z.shape[0] >= n_pad:
-                return z[:n_pad]
-            return jnp.pad(z, (0, n_pad - z.shape[0]))
+            return _fit_rows(z, self.data.shard(self.shard_name).num_rows)
         return model.score(self.data)
+
+    def score_dataset(self, model: FixedEffectModel, data: GameDataset) -> Array:
+        """``model.score(data)`` for rows that are not this coordinate's own
+        (validation). Where the training design is tiled, ``data``'s shard
+        is laid out the same way the first time it is scored, stays on the
+        device for as long as ``data`` is the dataset asked for, and is
+        scored by the kernels that score the training rows; XLA's gather
+        and segment-sum over the COO batch took 27 and 38 times as long on
+        a TPU at the benchmark's sizes (PERF.md, Findings PR 27). A COO
+        coordinate scores COO."""
+        if data is self.data:
+            return self.score(model)
+        if not self._use_tiled or model.shard_name != self.shard_name:
+            return model.score(data)
+        design = self._foreign_design(data)
+        if design is None:
+            counter("validate.coo_scores").inc()
+            return model.score(data)
+        z = _tiled_scorer()(design, model.coefficients)
+        return _fit_rows(z, data.shard(self.shard_name).num_rows)
+
+    def _foreign_design(self, data: GameDataset):
+        """``data``'s shard in the training design's layout, built on first
+        use (spans ``validation_layout`` / ``validation_upload``, counters
+        ``validate.layout.*``: the training design's ``layout`` / ``upload``
+        / ``layout.*`` stay its own); None where only COO can score it."""
+        if self._foreign is not None and self._foreign[0]() is data:
+            if self._foreign[1] is not None:
+                counter("validate.design_hits").inc()
+            return self._foreign[1]
+        self._foreign = None  # the last dataset's design goes first
+        batch = data.shard(self.shard_name)
+        design = None
+        if batch.num_features != self._tiled.num_features:
+            logger.warning(
+                "coordinate %s: shard '%s' of the dataset to score has %d "
+                "features, the training design %d; scoring it as COO",
+                self.name, self.shard_name, batch.num_features,
+                self._tiled.num_features,
+            )
+        else:
+            with span("validation_layout"):
+                host = pack_rows_like(
+                    self._tiled, batch,
+                    shards=1 if self.mesh is None else self._n_shards,
+                ).traced_as("validate")
+                report_layout(host, "validate.layout")
+            design = accounted_upload(
+                host.device if self.mesh is None
+                else lambda: psharding.place_batch(
+                    host, self.mesh, self._axis),
+                name="validation_upload",
+            )
+            counter("validate.design_builds").inc()
+        self._foreign = (weakref.ref(data), design)
+        return design
 
 
 # ---------------------------------------------------------------------------

@@ -274,7 +274,8 @@ def _whole_spec(rows):
 
 
 @functools.lru_cache(maxsize=None)
-def _panel_margins_call(Tt, S, W, blocks, tiles, bits, interpret):
+def _panel_margins_call(Tt, S, W, blocks, tiles, bits, interpret,
+                        name="panel_margins"):
     """``blocks``: rows of the class's coefficient grid; ``tiles``: row
     tiles of the design (rows of the [tiles, 128] per-row output)."""
     return pl.pallas_call(
@@ -284,7 +285,7 @@ def _panel_margins_call(Tt, S, W, blocks, tiles, bits, interpret):
             in_specs=_slot_specs(S) + [_whole_spec(blocks)],
             out_specs=_row_window_spec(W, bits)),
         out_shape=jax.ShapeDtypeStruct((tiles, LANE), jnp.float32),
-        interpret=interpret, name="panel_margins", **_PARAMS)
+        interpret=interpret, name=name, **_PARAMS)
 
 
 @functools.lru_cache(maxsize=None)
@@ -317,6 +318,9 @@ class PanelPart:
     rlo: Array    # i32[Tt, 1, S] row % 128
     cls: PanelClass = dataclasses.field(metadata=dict(static=True))
     bits: int = dataclasses.field(metadata=dict(static=True))
+    # the gather call's name in a device trace (TiledBatch.margins_name)
+    margins_name: str = dataclasses.field(
+        default="panel_margins", metadata=dict(static=True))
 
     def _slot_args(self):
         return (self.meta, self.vals, self.chi, self.clo, self.rhi, self.rlo)
@@ -330,7 +334,7 @@ class PanelPart:
             shard, self.vals.shape[0],
             lambda Tt: _panel_margins_call(
                 Tt, S, W, self.cls.num_blocks, tiles // n, self.bits,
-                tiled._interpret()),
+                tiled._interpret(), self.margins_name),
             self._slot_args(), (grid,), reduce=False)
 
     def scatter(self, rows2: Array, square: bool, shard) -> Array:
@@ -383,6 +387,17 @@ class PanelBatch:
     def padded_blocks(self) -> int:
         last = self.parts[-1].cls
         return last.first_block + last.num_blocks
+
+    def traced_as(self, prefix: str) -> "PanelBatch":
+        """This design with its gather calls named ``<prefix>_margins`` (the
+        hot panel) and ``<prefix>_panel_margins`` (the tail) in a device
+        trace."""
+        return dataclasses.replace(
+            self, hot=self.hot.traced_as(prefix),
+            parts=tuple(
+                dataclasses.replace(
+                    p, margins_name=prefix + "_panel_margins")
+                for p in self.parts))
 
     def with_offsets(self, offsets: Array) -> "PanelBatch":
         return dataclasses.replace(self, hot=self.hot.with_offsets(offsets))
@@ -610,7 +625,7 @@ def pack_panels(batch: SparseBatch, nonzeros, classes, order, rank,
             rows_padded(batch.labels), HOT_BLOCKS * LANE,
             offsets=rows_padded(batch.offsets),
             weights=rows_padded(batch.weights))
-        stored = [("hot", int(is_hot.sum()))]
+        stored = [int(is_hot.sum())]
         del is_hot
     parts = []
     with span("layout.tail"):
@@ -620,22 +635,27 @@ def pack_panels(batch: SparseBatch, nonzeros, classes, order, rank,
         for cls in classes:
             part, nnz = _pack_part(cls, vals, rows, ranks, tiles, shards)
             parts.append(part)
-            stored.append((f"w{cls.window}", nnz))
-    out = PanelBatch(hot=hot, parts=tuple(parts), order=order, rank=rank,
-                     num_features=batch.num_features,
-                     stored=tuple(nnz for _, nnz in stored), shards=shards)
-    for name, nnz in stored:
-        counter(f"layout.nnz.{name}").inc(nnz)
-    _report_slots(out.nnz_slots, sum(out.stored))
-    return out
+            stored.append(nnz)
+    return PanelBatch(hot=hot, parts=tuple(parts), order=order, rank=rank,
+                      num_features=batch.num_features,
+                      stored=tuple(stored), shards=shards)
 
 
-def _report_slots(slots: int, nnz: int) -> None:
-    """Counters ``layout.slots`` / ``layout.nnz`` and gauge
-    ``layout.padding_ratio`` = slots allocated / nonzeros stored."""
-    counter("layout.slots").inc(slots)
-    counter("layout.nnz").inc(nnz)
-    gauge("layout.padding_ratio").set(slots / max(nnz, 1))
+def report_layout(design, prefix: str = "layout") -> None:
+    """Counters ``<prefix>.slots`` / ``<prefix>.nnz`` and gauge
+    ``<prefix>.padding_ratio`` = slots allocated / nonzeros stored, of a
+    design still on the host; a :class:`PanelBatch` splits its nonzeros by
+    part besides (``<prefix>.nnz.hot``, ``<prefix>.nnz.w<W>``)."""
+    if isinstance(design, PanelBatch):
+        names = ["hot"] + [f"w{p.cls.window}" for p in design.parts]
+        for name, part_nnz in zip(names, design.stored):
+            counter(f"{prefix}.nnz.{name}").inc(part_nnz)
+        nnz = sum(design.stored)
+    else:
+        nnz = int(np.count_nonzero(design.vals))
+    counter(f"{prefix}.slots").inc(design.nnz_slots)
+    counter(f"{prefix}.nnz").inc(nnz)
+    gauge(f"{prefix}.padding_ratio").set(design.nnz_slots / max(nnz, 1))
 
 
 def pack_design(batch: SparseBatch, shards: int = 1):
@@ -653,7 +673,22 @@ def pack_design(batch: SparseBatch, shards: int = 1):
             classes = plan_panels(block_counts, batch.num_rows)
     if classes is None:
         with span("layout.plain"):
-            tiled = TiledBatch.pack_batch(batch)
-        _report_slots(tiled.nnz_slots, int(np.count_nonzero(tiled.vals)))
-        return tiled
-    return pack_panels(batch, nonzeros, classes, order, rank, shards)
+            design = TiledBatch.pack_batch(batch)
+    else:
+        design = pack_panels(batch, nonzeros, classes, order, rank, shards)
+    report_layout(design)
+    return design
+
+
+def pack_rows_like(design, batch: SparseBatch, shards: int = 1):
+    """Other rows of ``design``'s feature space (a validation batch) in
+    ``design``'s own layout, host numpy leaves. A :class:`PanelBatch`'s rows
+    go on ITS plan (order, rank and classes: no histogram is taken), so a
+    vector renumbered for ``design`` indexes both; a column ``design`` never
+    saw has a rank all the same, in the last class. Nothing is reported:
+    the caller says under which counters (:func:`report_layout`)."""
+    if not isinstance(design, PanelBatch):
+        return TiledBatch.pack_batch(batch)
+    return pack_panels(
+        batch, _nonzeros(batch), [p.cls for p in design.parts],
+        np.asarray(design.order), np.asarray(design.rank), shards)

@@ -376,7 +376,8 @@ def _shape_w(B):
 
 
 @functools.lru_cache(maxsize=None)
-def _margins_call(T, S, B, use_offsets, pair, interpret):
+def _margins_call(T, S, B, use_offsets, pair, interpret,
+                  name="tiled_margins"):
     kern = functools.partial(_margins_kernel, use_offsets, pair)
     n_tab = 2 if pair else 1
     out_shape = [jax.ShapeDtypeStruct((T, 1, ROWS_PER_TILE), jnp.float32)]
@@ -392,7 +393,7 @@ def _margins_call(T, S, B, use_offsets, pair, interpret):
         out_specs=out_specs if pair else out_specs[0],
         out_shape=out_shape if pair else out_shape[0],
         interpret=interpret,
-        name="tiled_margins",
+        name=name,
     )
 
 
@@ -516,6 +517,11 @@ class TiledBatch:
     # (mesh, batch axis name) the tile leaves are sharded over, else None
     shard: Optional[tuple[Mesh, str]] = dataclasses.field(
         default=None, metadata=dict(static=True))
+    # what this design's gather calls (margins, dot_rows, pair) are called
+    # in a device trace: a reduction that reads the training passes by name
+    # must not find a validation design's calls among them
+    margins_name: str = dataclasses.field(
+        default="tiled_margins", metadata=dict(static=True))
 
     # -- shape views --------------------------------------------------------
 
@@ -702,7 +708,8 @@ class TiledBatch:
         S, B = self.vals.shape[2], self.num_blocks
         sh = jnp.stack([jnp.asarray(shift, jnp.float32), jnp.float32(0)])
         z = self._run(
-            lambda T: _margins_call(T, S, B, True, False, _interpret()),
+            lambda T: _margins_call(
+                T, S, B, True, False, _interpret(), self.margins_name),
             (*self._slot_args(), self.offsets3),
             (self._w2(w), sh.reshape(1, 2)), reduce=False)
         return z.reshape(-1)
@@ -711,7 +718,8 @@ class TiledBatch:
         """Per-row raw dot products x_i . w (no offset/shift)."""
         S, B = self.vals.shape[2], self.num_blocks
         z = self._run(
-            lambda T: _margins_call(T, S, B, False, False, _interpret()),
+            lambda T: _margins_call(
+                T, S, B, False, False, _interpret(), self.margins_name),
             (*self._slot_args(), self.offsets3),
             (self._w2(w), jnp.zeros((1, 2), jnp.float32)), reduce=False)
         return z.reshape(-1)
@@ -725,7 +733,8 @@ class TiledBatch:
             jnp.asarray(shift, jnp.float32), jnp.asarray(p_shift, jnp.float32)
         ])
         z, u = self._run(
-            lambda T: _margins_call(T, S, B, True, True, _interpret()),
+            lambda T: _margins_call(
+                T, S, B, True, True, _interpret(), self.margins_name),
             (*self._slot_args(), self.offsets3),
             (self._w2(w), self._w2(p), sh.reshape(1, 2)), reduce=False)
         return z.reshape(-1), u.reshape(-1)
@@ -812,6 +821,11 @@ class TiledBatch:
             self, vals=(self.vals != 0).astype(jnp.float32))
         cnt = ones.scatter_features(valid)
         return s1, s2, cnt
+
+    def traced_as(self, prefix: str) -> "TiledBatch":
+        """This design with its gather calls named ``<prefix>_margins`` in
+        a device trace (:attr:`margins_name`)."""
+        return dataclasses.replace(self, margins_name=prefix + "_margins")
 
     def with_offsets(self, offsets: Array) -> "TiledBatch":
         return dataclasses.replace(self, offsets3=self._rows3(offsets))

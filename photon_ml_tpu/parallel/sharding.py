@@ -19,6 +19,7 @@ for mesh-spanning model state, so keep it free of training-only concerns.
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Optional
 
 import jax
@@ -341,7 +342,8 @@ def pad_batch_rows(batch, shards: int):
             pad = np.full((Tp - T,) + a.shape[1:], fill, a.dtype)
             return jnp.asarray(np.concatenate([a, pad], axis=0))
 
-        return TiledBatch(
+        return dataclasses.replace(
+            batch,
             vals=pad_tiles(batch.vals, 0.0),
             hi=pad_tiles(batch.hi, batch.num_blocks),
             lo=pad_tiles(batch.lo, 0),
@@ -349,7 +351,6 @@ def pad_batch_rows(batch, shards: int):
             labels3=pad_tiles(batch.labels3, 0.0),
             offsets3=pad_tiles(batch.offsets3, 0.0),
             weights3=pad_tiles(batch.weights3, 0.0),
-            num_features=batch.num_features,
         )
     if isinstance(batch, SparseBatch):
         n, nnz = batch.num_rows, batch.nnz
@@ -383,8 +384,6 @@ def place_batch(batch, mesh: Mesh, axis: Optional[str] = None):
     directly — the whole optimizer while-loop then runs under one jit with
     GSPMD-inserted psums (a TiledBatch's or PanelBatch's pallas kernels
     under ``jax.shard_map`` over the same axis)."""
-    import dataclasses
-
     from photon_ml_tpu.ops.panels import PanelBatch
     from photon_ml_tpu.ops.tiled import TiledBatch
 

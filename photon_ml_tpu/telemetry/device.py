@@ -21,7 +21,8 @@ Two costs a wall clock cannot attribute become metrics here:
   also counts into ``jit_compiles_eager`` / ``jit_compile_seconds_eager``.
 - Host->device placement of a design is the other crossing:
   :func:`accounted_upload` runs it under an ``upload`` span that ends on a
-  one-element fetch of the last array placed, and counts ``upload.bytes``.
+  one-element fetch of the last array placed, and counts ``upload.bytes``
+  (a validation design's: ``validation_upload``, on its first scoring).
 
 Metric names emitted:
 
@@ -31,7 +32,7 @@ Metric names emitted:
   ``jit_compile_seconds`` (histogram)
 - ``jit_compiles_eager`` / ``jit_compile_seconds_eager`` (counters)
 - ``jit_cache_hits`` / ``jit_cache_writes`` (counters)
-- ``upload.bytes`` (counter)
+- ``upload.bytes`` / ``validation_upload.bytes`` (counters)
 """
 
 from __future__ import annotations
@@ -113,26 +114,27 @@ def sync_fetch(x: Any, label: Optional[str] = None) -> np.ndarray:
     return out
 
 
-def accounted_upload(place: Callable[[], T]) -> T:
+def accounted_upload(place: Callable[[], T], name: str = "upload") -> T:
     """Run ``place()`` — a host->device placement returning a pytree of
-    device arrays — under an ``upload`` span. The span closes on a
-    one-element :func:`sync_fetch` of the last non-empty array placed
-    (transfers queue in order), and counter ``upload.bytes`` rises by the
-    bytes placed. Host code only: never call it inside a traced function."""
+    device arrays — under a span ``name`` (a training design's is
+    ``upload``). The span closes on a one-element :func:`sync_fetch` of the
+    last non-empty array placed (transfers queue in order), and counter
+    ``<name>.bytes`` rises by the bytes placed. Host code only: never call
+    it inside a traced function."""
     import jax
 
-    with trace.span("upload") as sp:
+    with trace.span(name) as sp:
         out = place()
         leaves = [
             x for x in jax.tree.leaves(out)
             if isinstance(x, jax.Array) and x.size
         ]
         nbytes = sum(int(x.nbytes) for x in leaves)
-        metrics.counter("upload.bytes").inc(nbytes)
+        metrics.counter(f"{name}.bytes").inc(nbytes)
         sp.set_attr(bytes=nbytes)
         if leaves:
             last = leaves[-1]
-            sync_fetch(last[(0,) * last.ndim], label="upload")
+            sync_fetch(last[(0,) * last.ndim], label=name)
     return out
 
 

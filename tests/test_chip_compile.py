@@ -354,3 +354,28 @@ def test_sharded_panel_value_grad_compiles_for_v5e_2x2(topo, on_chip_kernels):
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "all-reduce" in text
     assert "all-gather" not in text
+
+
+@pytest.mark.parametrize("design", ["plain", "panels"])
+def test_validation_scorer_calls_have_their_own_names_for_v5e(
+        design, one_chip, on_chip_kernels):
+    """A validation design (``traced_as("validate")``) through the FE
+    coordinate's scorer: module ``jit_fe_score_tiled``, and no Mosaic call in
+    it starts with ``tiled_`` or ``panel_``, the names by which a trace's
+    reduction finds the TRAINING passes."""
+    from photon_ml_tpu.game.coordinates import _tiled_scorer
+
+    if design == "plain":
+        batch, d = _batch(T, one_chip), NUM_FEATURES
+        want = {"validate_margins"}
+    else:
+        batch, d = _panel_batch(one_chip, one_chip), PANEL_FEATURES
+        want = {"validate_margins", "validate_panel_margins"}
+    w = jax.ShapeDtypeStruct((d,), jnp.float32, sharding=one_chip)
+    text = _tiled_scorer().lower(
+        batch.traced_as("validate"), w).compile().as_text()
+    assert re.match(r"HloModule jit_fe_score_tiled\b", text)
+    calls = set(re.findall(
+        r"%([\w\-]+?)(?:\.\d+)? = [^\n]*custom_call_target=\"tpu_custom_call\"",
+        text))
+    assert calls == want
