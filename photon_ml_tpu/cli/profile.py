@@ -10,8 +10,8 @@ TensorBoard's profile plugin or xprof). Every telemetry span mirrors
 itself as a ``jax.profiler.TraceAnnotation`` named ``photon:<span>``
 (``telemetry/trace.py``, always on), so the capture holds our span tree
 (``fit > coordinate_descent > cd_iteration > coordinate:<name> > update``)
-on the clock of the XLA executable timeline — the "which executable ran
-inside which phase" question BENCH_r05 could not answer.
+on the clock of the XLA executable timeline: which executable ran
+inside which phase.
 
 Degrades gracefully: a backend that cannot start the profiler logs a
 warning and runs the wrapped command unprofiled (exit code is the wrapped

@@ -5,8 +5,8 @@
 the record schema into a compact i32 program (opcodes mirrored in
 native/avro_decode.cpp), hands the container blocks to the C++
 interpreter, and gets back columnar numpy arrays — labels/offsets/weights,
-per-shard COO triples, and interned id columns. ~60x the pure-Python
-schema-walking decoder (PERF_NOTES.md).
+per-shard COO triples, and interned id columns, far faster than the
+pure-Python schema-walking decoder.
 
 Returns None whenever anything is unsupported (exotic schema shapes,
 missing native toolchain, non-deflate codec) — callers always keep the

@@ -286,8 +286,8 @@ def bootstrap_random_effect(
     composed with the per-entity vmap (sweep.runner.re_bootstrap_solver)
     solve B*E problems in ONE executable, every lane warm-started from
     the point estimate ``w0`` [E, K]. The bucket design broadcasts
-    across the B axis, so wall time stays well under 2x a single fit
-    even at B=64 (bench_diagnostics gates the ratio).
+    across the B axis (what B lanes cost over a single fit is not
+    measured on the chip).
 
     ``lane_weights`` [B, E, R] overrides the drawn multipliers — the
     masked-lane path passes a gathered slice of the full-bucket draw.

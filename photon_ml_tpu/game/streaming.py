@@ -27,9 +27,8 @@ cluster. The TPU-native answer:
     collective is the one-scalar convergence test per iteration
     (RandomEffectCoordinate.scala:101-130 semantics).
 
-``bench_scale.py`` drives this at ~1e9 coefficients on one chip;
 ``__graft_entry__.dryrun_multichip`` runs the sharded-table path on the
-virtual CPU mesh.
+virtual CPU mesh; not measured on the chip (ROADMAP R7).
 """
 
 from __future__ import annotations
@@ -259,9 +258,8 @@ class StreamingRandomEffectTrainer:
     ``chunks`` yields ``(start, batch_source)`` where ``batch_source`` is
     either a DenseBatch of HOST (numpy) arrays — uploaded with
     ``device_put`` one chunk ahead of the solve — or a zero-arg callable
-    returning a device DenseBatch (an on-device generator; used by the 1B
-    bench, whose chunks would otherwise be 2 GB of host upload each, and
-    by any caller whose features are computed rather than stored).
+    returning a device DenseBatch (an on-device generator, for a caller
+    whose features are computed rather than stored).
     """
 
     def __init__(
@@ -298,7 +296,7 @@ class StreamingRandomEffectTrainer:
         # chunks ahead of the solve behind a bounded queue — host-side feed
         # work AND the H2D transfer overlap the solve. False = fully
         # synchronous, the control arm for measuring the overlap win
-        # (bench_overlap.py)
+        # (not measured on the chip; ROADMAP S7)
         self.prefetch = prefetch
         if prefetch_depth < 1:
             raise ValueError("prefetch_depth must be >= 1")

@@ -43,9 +43,8 @@ Stages:
   ``/healthz``.
 
 Surfaces: ``cli train --warm-start <dir> [--delta <paths>]``, the
-``cli refresh`` subcommand, ``GameEstimator.fit_incremental``, the
-RunReport "Freshness" section, and ``bench_freshness.py``
-(time-to-fresh-model vs full retrain at a 5% delta).
+``cli refresh`` subcommand, ``GameEstimator.fit_incremental`` and the
+RunReport "Freshness" section.
 """
 
 from photon_ml_tpu.incremental.warmstart import (  # noqa: F401

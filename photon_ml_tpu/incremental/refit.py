@@ -15,9 +15,9 @@ fixed-effect coordinate refreshes normally over the combined stream.
 
 Telemetry: ``incremental.lanes_solved`` / ``incremental.lanes_skipped``
 (real entities re-solved vs kept), ``incremental.bucket_solves`` /
-``incremental.buckets_skipped`` — the structural evidence
-``bench_freshness.py`` asserts the ≥10× time-to-fresh claim on, and the
-RunReport "Freshness" section renders.
+``incremental.buckets_skipped`` — what the RunReport "Freshness"
+section renders (time-to-fresh against a full retrain is not measured
+on the chip; ROADMAP W9).
 
 Transplanting (:func:`transplant_random_effect`): the combined run's
 bucket geometry is rebuilt from scratch, so the base model's per-entity

@@ -2,9 +2,9 @@
 directory of Avro shards into a backpressured stream of device-ready
 chunks.
 
-The solvers eat 8M+ rows/s/chip while the one-shot Avro reader delivers
-~66-128K rows/s (BENCH_r04/r05 ``avro_ingest_*``) — any real end-to-end
-fit was ~60x ingest-bound (ROADMAP item 2). This package is the subsystem
+The solvers take millions of rows a second a chip (PERF.md section 5)
+and a one-shot Avro read does not feed them (ingest rows/s: not measured
+on the chip, ROADMAP W5). This package is the subsystem
 between the block-parallel decoder (``data/avro_native.py``) and the
 device:
 

@@ -12,8 +12,8 @@ features, fairly dense. At the reference's headline scale ("hundreds of
 billions of coefficients", /root/reference/README.md:73; projection
 envelope ~1e8 entities x ~1e3 features, projector/README.md:8-12) the solve
 throughput is set by how the per-entity sweeps map to hardware: COO
-gather/segment ops are random-access bound on TPU (~1e8 elem/s,
-PERF_NOTES.md), while dense [E, R, K] batched matmuls ride the MXU at
+gather/segment ops are random-access bound on TPU (under 1e8 elem/s,
+PERF.md section 3), while dense [E, R, K] batched matmuls ride the MXU at
 full bandwidth with ZERO random access. A vmapped solve over a [E, R, K]
 stack is one ``jnp.einsum`` per sweep.
 

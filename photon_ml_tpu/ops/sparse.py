@@ -285,9 +285,9 @@ class SparseBatch:
     def scatter_features(self, per_row: Array) -> Array:
         """Compute sum_i per_row[i] * x_i as a dense feature-space vector.
 
-        A scatter-add over the feature dimension. (A column-sorted CSC
-        mirror using sorted segment_sum was measured NOT faster on TPU —
-        segment_sum lowers to scatter there; see PERF_NOTES.md.)
+        A scatter-add over the feature dimension. (No column-sorted CSC
+        mirror is kept: a sorted segment_sum lowers to the same scatter
+        on TPU.)
         """
         contrib = self.values * jnp.take(per_row, self.rows, fill_value=0)
         return jnp.zeros((self.num_features,), dtype=contrib.dtype).at[self.cols].add(

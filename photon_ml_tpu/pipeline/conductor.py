@@ -28,7 +28,7 @@ Each cycle:
    hot-swap the live :class:`ModelRegistry` so the next score serves it;
 6. observe per-delta-file event→served staleness and publish the p99 as
    the gauge ``pipeline.event_to_served_staleness_p99_s`` (the tier's
-   headline SLO, gated in ``bench_suite --freshness``).
+   headline SLO; not measured on the chip, ROADMAP W9).
 
 Crash safety is inherited, not reimplemented: every publish goes through
 the registry's assemble-then-``os.rename`` protocol and the base
@@ -115,7 +115,7 @@ class PipelineSpec:
     registry_dir: str
     workdir: str
     interval_s: float = 5.0
-    # 0 = run until stopped (SIGTERM); tests and the bench pin a count
+    # 0 = run until stopped (SIGTERM); tests pin a count
     max_cycles: int = 0
     delta_glob: str = "*.avro"
     # escalation trips on EITHER threshold; escalate_after_cycles=0

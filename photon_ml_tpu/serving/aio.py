@@ -3,8 +3,8 @@ per connection.
 
 The stdlib :class:`~http.server.ThreadingHTTPServer` front end spends a
 thread (stack, GIL wakeups, scheduler churn) per in-flight connection —
-fine for tens of callers, the wrong shape for the sustained-load regime
-the SLO bench drives (thousands of open keep-alive connections feeding a
+fine for tens of callers, the wrong shape for sustained load
+(thousands of open keep-alive connections feeding a
 device that scores them 64 rows at a time). :class:`AsyncScoringServer`
 serves the same endpoints from ONE event loop:
 

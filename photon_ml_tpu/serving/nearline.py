@@ -56,8 +56,7 @@ explicit :meth:`flush`):
 Telemetry: ``serving.nearline.events`` / ``.dropped_events`` /
 ``.unknown_entities`` / ``.oov_features`` / ``.applies`` / ``.publishes``
 counters; ``serving.nearline.solve_ms`` and ``.update_lag_ms`` (event
-enqueue -> applied on the serving tables: the time-to-applied-update the
-SLO bench reports) histograms.
+enqueue -> applied on the serving tables) histograms.
 
 Fault seams: ``serving.nearline_event`` (event admission) and
 ``serving.nearline_apply`` (fires at BOTH commit points — the in-memory

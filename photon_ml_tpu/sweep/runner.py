@@ -151,8 +151,8 @@ def re_bootstrap_solver(config: OptimizerConfig):
     lane, ``w0`` [E, K] (the point estimate) broadcasts across B so
     every lane warm-starts from the fitted coefficients. One executable
     solves B*E independent small problems with the bucket design
-    broadcast across resamples, which is why B=64 costs well under 2x a
-    single fit (bench_diagnostics)."""
+    broadcast across resamples (what B lanes cost over a single fit is
+    not measured on the chip)."""
 
     def run(obj, ebatch, lane_weights, w0, l1):
         def one_sample(wts_b):

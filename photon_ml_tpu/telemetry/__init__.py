@@ -9,7 +9,8 @@ Five layers (ISSUE 1 gave emission; ISSUE 3 the interpretation):
   wrapper over it, so every driver phase is already a span.
 - :mod:`photon_ml_tpu.telemetry.metrics` — process-global counters /
   gauges / histograms with a ``snapshot()`` dict and a JSONL flush;
-  attached to the final ``TrainingFinishEvent`` and the bench JSON.
+  attached to the final ``TrainingFinishEvent``; the benchmark's
+  counter readers (``benchmark/readers``) read the same snapshot.
 - :mod:`photon_ml_tpu.telemetry.device` — ``sync_fetch()``, the one
   sanctioned device->host fetch point (fetches / bytes / blocking
   seconds), plus per-compile counters via ``jax.monitoring``.

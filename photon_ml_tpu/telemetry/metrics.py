@@ -5,7 +5,8 @@ listeners and per-phase Timed logs; nothing aggregates across a run. This
 registry is the aggregation point: any layer increments a named counter
 (``metrics.counter("device_fetches").inc()``), sets a gauge, or feeds a
 histogram, and ``snapshot()`` returns one JSON-safe dict for the finish
-event, the bench JSON, and the ``--telemetry-out`` flush.
+event, the benchmark's counter readers, and the ``--telemetry-out``
+flush.
 
 Thread-safe (one registry lock; metric mutation is a few ns under it) and
 allocation-light so hot paths can afford it. Histograms keep a bounded

@@ -34,8 +34,7 @@ So this module hooks every ``instrumented_jit`` dispatch (the
   :func:`bound_class`);
 - cross-checks the timing honesty itself: a measured rate above the
   resolved device peak is physically impossible, so it flags
-  ``timing_suspect`` instead of reporting a fake number (the PERF_NOTES
-  trap, machine-detected);
+  ``timing_suspect`` instead of reporting a fake number;
 - samples per-device HBM high-watermarks (``memory.
   record_device_watermarks``) on the same cadence, attributed to the
   open span's phase;
@@ -455,8 +454,8 @@ def publish_metrics(names: Optional[Any] = None) -> None:
                 logger.warning(
                     "timing suspect: executable '%s' measures above the "
                     "resolved device peak — the clock is not seeing the "
-                    "device (PERF_NOTES: only a device->host fetch truly "
-                    "syncs); treat its rates as fake until the "
+                    "device (only a device->host fetch truly syncs); "
+                    "treat its rates as fake until the "
                     "measurement path is fixed",
                     name,
                 )

@@ -1,9 +1,9 @@
-"""Live progress heartbeat: long fits and benches are never silent.
+"""Live progress heartbeat: long fits are never silent.
 
-BENCH_r05's north-star run timed out (rc=124) with NOTHING on stdout — an
-hours-long GAME fit gives no liveness signal between its start and its
-finish line. The :class:`Heartbeat` is a daemon thread that every
-``interval`` seconds emits ONE structured line to the
+An hours-long GAME fit gives no liveness signal between its start and
+its finish line, and one cut by a time limit leaves nothing. The
+:class:`Heartbeat` is a daemon thread that every ``interval`` seconds
+emits ONE structured line to the
 ``photon_ml_tpu.telemetry.progress`` logger and (optionally) a JSONL sink:
 
     {"type": "heartbeat", "seq": 3, "uptime_s": 90.1,

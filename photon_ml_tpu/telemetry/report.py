@@ -142,7 +142,7 @@ def compare_metrics(
     against its goodness direction by more than ``threshold`` (fractional,
     default 20%). Metrics missing from either side, or with a zero
     baseline (no ratio exists), are skipped. Shared by the run-report
-    compare and the bench_suite ``--gate``."""
+    compare and the fleet report."""
     directions = KEY_METRIC_DIRECTIONS if directions is None else directions
     out: list[MetricDelta] = []
     for name in sorted(set(current) & set(baseline)):
@@ -1049,8 +1049,8 @@ class RunReport:
                 + ", ".join(f"`{n}`" for n in suspects)
                 + " measured ABOVE the resolved device peak, which is "
                 "physically impossible — the clock is not seeing the "
-                "device (PERF_NOTES: only a device->host fetch truly "
-                "syncs). Treat these rates as fake.",
+                "device (only a device->host fetch truly syncs). "
+                "Treat these rates as fake.",
             ]
         out.append("")
         return out
@@ -1239,10 +1239,6 @@ class RunReport:
                         f" — p99 event->applied "
                         f"{srv['nearline_lag_p99_ms']:.1f} ms"
                     )
-            line += (
-                " — p99 across each disturbance is the SLO bench's "
-                "flatness gate (`serving_slo_p99_swap_ratio`)"
-            )
             out.append(line)
         unseen = srv.get("unseen_entities", 0)
         if unseen:

@@ -244,9 +244,8 @@ class RequestTracer:
         enabled: Optional[bool] = None,
         slow_threshold_ms: Any = _UNSET,
     ) -> None:
-        """Adjust the ring cap, enable/disable recording entirely (the
-        bench's untraced arm), or pin the slow threshold (``None``
-        restores the rolling p99)."""
+        """Adjust the ring cap, enable/disable recording entirely, or pin
+        the slow threshold (``None`` restores the rolling p99)."""
         with self._lock:
             if ring_limit is not None:
                 self._ring_limit = int(ring_limit)

@@ -10,7 +10,7 @@ convert to the Chrome trace-event format, so a full GAME fit opens as a
 flame chart in Perfetto (https://ui.perfetto.dev).
 
 Durations use ``time.monotonic()`` exclusively — wall-clock steps (NTP,
-DST) corrupt phase timings (PERF_NOTES.md "fake timing" gotcha). The one
+DST) corrupt phase timings. The one
 wall-clock anchor, recorded at configure time for human correlation, comes
 from ``datetime`` so the ``time.time()`` lint stays meaningful.
 

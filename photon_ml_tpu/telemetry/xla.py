@@ -74,8 +74,8 @@ __all__ = [
 logger = logging.getLogger("photon_ml_tpu.telemetry.xla")
 
 #: Distinct signatures of ONE executable name at which the recompile
-#: counter escalates to a structured warning (the recompile-storm signal
-#: that explained nothing in BENCH_r05).
+#: counter escalates to a structured warning (the recompile-storm
+#: signal).
 RECOMPILE_WARN_THRESHOLD = 3
 
 # Peak per-chip dense-matmul FLOP/s (bf16) and HBM bandwidth (bytes/s) by

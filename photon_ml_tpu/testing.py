@@ -5,7 +5,7 @@ methods for datasets/problems/coordinates used across integration tests,
 shipped in MAIN source) and photon-test-utils SparkTestUtils' balanced
 binary / Poisson / linear draws with controlled sparsity. Everything here
 returns plain numpy + framework types so the generators work identically
-under CPU test meshes and real TPU benches.
+under CPU test meshes and on a real TPU.
 """
 
 from __future__ import annotations

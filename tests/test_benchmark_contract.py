@@ -1,0 +1,378 @@
+"""What ``benchmark/`` reads out of the program BY NAME, held in tier-1.
+
+The benchmark's drivers reach into the built coordinates (``_tiled``,
+``.parts``, ``.stored``, ``last_tracker``, the identity of the estimator's
+cached coordinates) and every file under ``benchmark/metrics/`` hands a
+reader the name of a span, a counter, a Mosaic call or a module of the
+program. ``benchmark/tests`` is not collected by tier-1 and most readers
+return nothing, silently, for a name the program no longer has; so a rename
+on the hot path would otherwise surface only in a chip run, as a missing
+per-layer metric. Here each cell's own driver (``Driver(config, traffic,
+seed, rows=<small>, force_tiled=True)``, the rehearsal of
+``benchmark/tests/test_run_cpu.py``) runs its set-up and one more fit on
+the CPU in Pallas interpret mode, and every metric file that names
+something in the program gets one case per cell that reports it: the name
+is looked up in what that run left behind. Nothing under ``benchmark/`` is
+edited; no time is asserted."""
+
+from __future__ import annotations
+
+import dataclasses
+import glob
+import importlib
+import json
+import math
+import os
+import re
+
+import jax
+import pytest
+
+from benchmark import program_trace, run
+from benchmark.readers import (
+    counter_delta,
+    counter_ratio,
+    history_seconds,
+    program_span_seconds,
+)
+from photon_ml_tpu import telemetry
+from photon_ml_tpu.telemetry.xla import InstrumentedFunction
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
+    BENCH = json.load(_f)
+CELLS = [w["name"] for w in BENCH["workloads"]]
+METRICS = {}
+for _path in sorted(glob.glob(
+        os.path.join(ROOT, "benchmark", "metrics", "*.json"))):
+    with open(_path) as _f:
+        METRICS[os.path.basename(_path)] = json.load(_f)
+ROWS = 1024
+SEED = 2147483653
+
+
+#: readers whose parameters name a span, a counter, a module or a history
+#: entry of the program; and those that do where ``pattern`` names a Mosaic
+#: call (``^%<name>``) and not XLA's own custom-call target
+NAMING_READERS = {
+    "program_span_seconds", "counter_delta", "counter_ratio", "trace_module",
+    "history_seconds",
+}
+KERNEL_READERS = {"trace_kernel_roofline", "trace_kernel_seconds"}
+
+
+def names_the_program(spec) -> bool:
+    return spec["reader"] in NAMING_READERS or (
+        spec["reader"] in KERNEL_READERS
+        and spec["params"]["pattern"].startswith("^%"))
+
+
+#: the files that hand their reader no name of the program's: the driver's
+#: own clocks and spans, the allocator, XLA's custom-call target, whole
+#: device planes
+NAMES_NOTHING = {
+    "build_coordinates_s.json", "device_idle_pct.json",
+    "fe_kernels_roofline.json", "fit_roofline_mfu.json",
+    "idle_unattributed_s_per_fit.json", "peak_hbm_gb.json", "setup_s.json",
+    "train_rows_per_s.json",
+}
+
+
+def cases(*readers):
+    """(cell, metric file) for every file of ``readers`` that names
+    something in the program, once per cell that reports the metric."""
+    out = []
+    for cell in CELLS:
+        reported = set(run.cell_metrics(BENCH, cell, "per_layer"))
+        for name, spec in METRICS.items():
+            if (spec["reader"] in readers and names_the_program(spec)
+                    and name[:-len(".json")] in reported):
+                out.append(pytest.param(cell, name, id=f"{cell}-{name}"))
+    return out
+
+
+def _alternatives(pattern: str) -> list:
+    """Every way through ``pattern``'s ``|`` s, each a pattern without one:
+    ``a\\.(b|c)|d`` -> ``a\\.(?:b)``, ``a\\.(?:c)``, ``d``. A reader sums
+    whatever matches, so a span lost from ONE branch would go unseen."""
+    depth, cuts, groups = 0, [], []
+    i = 0
+    while i < len(pattern):
+        ch = pattern[i]
+        if ch == "\\":
+            i += 1
+        elif ch == "(":
+            depth += 1
+            if depth == 1:
+                groups.append([i, None])
+        elif ch == ")":
+            depth -= 1
+            if depth == 0:
+                groups[-1][1] = i
+        elif ch == "|" and depth == 0:
+            cuts.append(i)
+        i += 1
+    if cuts:
+        edges = [-1, *cuts, len(pattern)]
+        return [alt for a, b in zip(edges, edges[1:])
+                for alt in _alternatives(pattern[a + 1:b])]
+    for start, end in groups:
+        inner = _alternatives(pattern[start + 1:end])
+        if len(inner) > 1:
+            return [alt for one in inner for alt in _alternatives(
+                f"{pattern[:start]}(?:{one}){pattern[end + 1:]}")]
+    return [pattern]
+
+
+def _pallas_names(jaxpr) -> set:
+    """The ``name`` of every ``pallas_call`` equation in ``jaxpr`` and the
+    jaxprs nested in it (what a Mosaic call's events are named after)."""
+    names = set()
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            names.add(eqn.params["name"])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names |= _pallas_names(sub)
+    return names
+
+
+@dataclasses.dataclass
+class CellRun:
+    """What one cell's rehearsal left behind, taken before the per-test
+    telemetry reset: the readers' ``ctx`` (counter marks, the window's
+    fits, the driver's shapes), the program's span trees by root, the
+    registry's executables, and the programs the window's fit dispatched."""
+
+    driver: object
+    ctx: dict
+    roots: dict
+    registry: set
+    dispatched: dict  # executable name -> (InstrumentedFunction, args, kwargs)
+    kernels: set  # Mosaic call names in the dispatched programs
+
+
+def _rehearse(cell: str) -> CellRun:
+    workload = run.load_json("workloads", cell + ".json")
+    config = run.load_json("configs", workload["config"] + ".json")
+    traffic = run.load_json("traffic", workload["traffic"] + ".json")
+    module = importlib.import_module("benchmark.drivers." + config["driver"])
+
+    def counters():
+        return dict(telemetry.snapshot()["counters"])
+
+    telemetry.reset()
+    driver = module.Driver(config, traffic, SEED, rows=ROWS, force_tiled=True)
+    driver.setup()
+    marks = {"setup_end": counters(), "window_start": counters()}
+    dispatched = {}
+    real = InstrumentedFunction.__call__
+
+    def spy(self, *args, **kwargs):
+        # shapes, not arrays: a donated buffer is gone after the call
+        dispatched.setdefault(self.name, (self, *jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)
+            if isinstance(x, jax.Array) else x, (args, kwargs))))
+        return real(self, *args, **kwargs)
+
+    first = len(driver.fits)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(InstrumentedFunction, "__call__", spy)
+        record = driver.fit()
+    assert record["ok"], record
+    marks["window_end"] = counters()
+    kernels = set()
+    for fn, args, kwargs in dispatched.values():
+        kernels |= _pallas_names(
+            jax.make_jaxpr(fn.__wrapped__)(*args, **kwargs).jaxpr)
+    return CellRun(
+        driver=driver,
+        ctx={"counters": marks, "fits": driver.fits[first:],
+             "shapes": driver.shapes()},
+        roots={root: program_span_seconds.process_roots(root)
+               for root in ("build_coordinates", "coordinate_descent")},
+        registry={r.name for r in telemetry.XLA_REGISTRY.executables()},
+        dispatched=dispatched,
+        kernels=kernels,
+    )
+
+
+@pytest.fixture(scope="module")
+def rehearsal():
+    """cell -> its :class:`CellRun`, made on first use, once."""
+    made = {}
+
+    def get(cell: str) -> CellRun:
+        if cell not in made:
+            made[cell] = _rehearse(cell)
+        return made[cell]
+
+    return get
+
+
+# -- one case per metric file and cell ----------------------------------------
+
+
+def test_every_metric_file_is_guarded_or_names_nothing():
+    """A metric file added later lands in one list or the other."""
+    guarded = {name for name, spec in METRICS.items()
+               if names_the_program(spec)}
+    assert guarded.isdisjoint(NAMES_NOTHING)
+    assert guarded | NAMES_NOTHING == set(METRICS)
+
+
+@pytest.mark.parametrize("cell, metric", cases("program_span_seconds"))
+def test_span_named_by_a_metric_is_opened_by_the_program(
+        rehearsal, cell, metric):
+    params = METRICS[metric]["params"]
+    roots = rehearsal(cell).roots[params["root"]]
+    assert roots, f"the program opened no root span {params['root']!r}"
+    # set-up metrics read the process's FIRST root; per-fit ones every
+    # traced fit, which the window's (last) fit stands for here
+    roots = roots[:1] if params.get("source") == "process" else roots[-1:]
+    for span in _alternatives(params["span"]):
+        found = program_trace.select(roots, span, params.get("parent"))
+        assert found, (
+            f"no span {span!r} under {params.get('parent')!r} of "
+            f"{params['root']!r}; it holds "
+            f"{sorted({s.name for r in roots for s in r.walk()})}")
+        assert all(s.dur >= 0 and math.isfinite(s.dur) for s in found)
+
+
+@pytest.mark.parametrize("cell, metric", cases("counter_delta", "counter_ratio"))
+def test_counter_named_by_a_metric_is_kept_by_the_program(
+        rehearsal, cell, metric):
+    spec = METRICS[metric]
+    params = spec["params"]
+    ctx = rehearsal(cell).ctx
+    if spec["reader"] == "counter_ratio":
+        named = [params["numerator"], params["denominator"]]
+        mark = ctx["counters"][params["at"]]
+        value = counter_ratio.read(ctx, **params)
+        # never fewer slots than nonzeros
+        assert value is not None and value >= 1.0
+    else:
+        named = params["counters"]
+        mark = ctx["counters"][params["until"]]
+        value = counter_delta.read(ctx, **params)
+        if params["since"] == "window_start" and all(
+                name.startswith("jit_compile") for name in named):
+            assert value == 0  # a repeated fit compiles nothing
+        else:
+            assert value > 0
+    for name in named:
+        assert mark.get(name, 0) > 0, (name, sorted(mark))
+
+
+@pytest.mark.parametrize(
+    "cell, metric", cases("trace_kernel_roofline", "trace_kernel_seconds"))
+def test_kernel_named_by_a_metric_is_called_by_the_fit(
+        rehearsal, cell, metric):
+    params = METRICS[metric]["params"]
+    cell_run = rehearsal(cell)
+    pattern = re.compile(params["pattern"])
+    # an `XLA Ops` event of a Mosaic call is named %<name>[.<n>] = ...
+    assert any(pattern.search("%" + name) for name in cell_run.kernels), (
+        params["pattern"], sorted(cell_run.kernels))
+    if "coordinate" in params:
+        shape = cell_run.ctx["shapes"]["coordinates"].get(params["coordinate"])
+        assert shape is not None and shape["T"] > 0, (
+            params["coordinate"], cell_run.ctx["shapes"])
+        counts = importlib.import_module(
+            "benchmark.counts." + params["counts"])
+        flops, nbytes = counts.per_call(shape)
+        assert flops > 0 and nbytes > 0
+
+
+@pytest.mark.parametrize("cell, metric", cases("trace_module"))
+def test_module_named_by_a_metric_is_a_registered_executable(
+        rehearsal, cell, metric):
+    params = METRICS[metric]["params"]
+    cell_run = rehearsal(cell)
+    assert cell_run.dispatched
+    if params.get("unnamed"):
+        # what the reader subtracts: every program the fit dispatched by
+        # name is in the registry, and its module is called jit_<name>
+        checked = cell_run.dispatched
+    else:
+        pattern = re.compile(params["pattern"])
+        checked = {name: call for name, call in cell_run.dispatched.items()
+                   if pattern.search(f"jit_{name}(0)")}
+        assert checked, (params["pattern"], sorted(cell_run.dispatched))
+    for name, (fn, args, kwargs) in checked.items():
+        assert name in cell_run.registry, (name, sorted(cell_run.registry))
+        text = fn.lower(*args, **kwargs).as_text()
+        assert re.match(rf"module @jit_{re.escape(name)}\b", text), text[:80]
+
+
+@pytest.mark.parametrize("cell, metric", cases("history_seconds"))
+def test_history_read_by_a_metric_has_the_coordinate_and_its_seconds(
+        rehearsal, cell, metric):
+    params = METRICS[metric]["params"]
+    ctx = rehearsal(cell).ctx
+    steps = [s for f in ctx["fits"] for s in f["steps"]]
+    if "coordinate" in params:
+        steps = [s for s in steps if s["coordinate"] == params["coordinate"]]
+    assert steps and all(
+        math.isfinite(s["seconds"]) and s["seconds"] > 0 for s in steps)
+    value = history_seconds.read(ctx, **params)
+    assert value is not None and math.isfinite(value) and value > 0
+
+
+# -- what the drivers read off the program's objects --------------------------
+
+
+def test_plain_design_gives_the_driver_its_tile_shape(rehearsal):
+    """``game_fit.Driver.shapes()``: ``_tiled.num_tiles``, ``.vals.shape[2]``,
+    ``.num_blocks``, ``.num_features``."""
+    cell_run = rehearsal("glm_fe.lbfgs_fit")
+    fixed = cell_run.ctx["shapes"]["coordinates"]["fixed"]
+    assert fixed["kind"] == "fixed_effect"  # not "fixed_effect_coo"
+    design = cell_run.driver.coordinates["fixed"]._tiled
+    assert not hasattr(design, "parts")
+    assert (fixed["T"], fixed["S"], fixed["B"], fixed["features"]) == (
+        design.num_tiles, design.vals.shape[2], design.num_blocks,
+        cell_run.driver.shape["fe_features"])
+    assert fixed["T"] * 128 >= ROWS and fixed["S"] % 128 == 0
+
+
+def test_panel_design_gives_the_driver_its_parts(rehearsal):
+    """``game_fit_panels.Driver.shapes()``: ``.parts`` with ``cls.window``
+    and ``vals.shape[0]``, ``.stored`` (hot first, then one a part),
+    ``.hot``, ``.nnz_slots``, ``.num_tiles``."""
+    cell_run = rehearsal("criteo_fe.lbfgs_fit")
+    shapes = cell_run.ctx["shapes"]["coordinates"]
+    design = cell_run.driver.coordinates["fixed"]._tiled
+    assert len(design.stored) == 1 + len(design.parts) and design.parts
+    whole, hot, tail = (
+        shapes["fixed"], shapes["fixed.hot"], shapes["fixed.tail"])
+    assert "T" not in whole  # no reader takes the whole design for a pass
+    rows = cell_run.driver.shape["rows"]
+    assert whole["nnz"] == rows * cell_run.driver.shape["fe_nnz_per_row"]
+    assert whole["nnz"] == hot["nnz"] + tail["nnz"] <= whole["slots"]
+    assert whole["features"] == hot["features"] + tail["features"]
+    assert hot["T"] * 128 >= rows and hot["B"] * 128 >= hot["features"]
+    assert tail["classes"] == len(tail["windows"]) == len(tail["tiles"])
+    assert all(w > 0 for w in tail["windows"])
+    assert all(t > 0 for t in tail["tiles"]) and tail["T"] > 0
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_update_leaves_a_tracker_with_value_and_iterations(rehearsal, cell):
+    """``game_fit.step_facts`` reads ``last_tracker.final_value`` and
+    ``.iterations`` of the coordinate the step updated."""
+    cell_run = rehearsal(cell)
+    tracker = cell_run.driver.coordinates["fixed"].last_tracker
+    step = cell_run.ctx["fits"][-1]["steps"][-1]
+    assert step["loss"] == float(tracker.final_value)
+    assert step["solver_iterations"] == float(tracker.iterations) >= 1
+    assert math.isfinite(step["loss"])
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_estimator_hands_back_the_coordinates_it_built(rehearsal, cell):
+    """Every fit of the driver asks ``_build_coordinates(data, mesh=None)``
+    again and counts on the cached objects."""
+    driver = rehearsal(cell).driver
+    again = driver.estimator._build_coordinates(driver.train, mesh=None)
+    assert list(again) == list(driver.coordinates)
+    assert all(again[k] is v for k, v in driver.coordinates.items())

@@ -120,7 +120,7 @@ class TestLocalRules:
 
     def test_l006_not_in_benches(self):
         src = "import time\n\ndef f():\n    return time.time()\n"
-        assert lint(src, rel="bench_x.py", library=False) == []
+        assert lint(src, rel="chip_smoke.py", library=False) == []
 
     def test_l007_bare_block_until_ready(self):
         src = "def f(x):\n    x.block_until_ready()\n"

@@ -479,33 +479,3 @@ def test_raise_injection_at_chunk_boundary_leaves_resumable_state(
     trainer.train(table2, chunks, checkpointer=mgr,
                   start_chunk=state.next_chunk)
     np.testing.assert_array_equal(table2.to_numpy(), expected)
-
-
-def test_bench_suite_gate_refuses_while_armed(tmp_path):
-    """An armed plan under a GATED bench run is refused outright (exit
-    2): numbers produced under injection are not comparable to any
-    baseline, and a silent pass would corrupt the CI perf contract.
-    (bench.py / bench_suite.py also warn loudly on any armed run, same
-    as cli train/serve.)"""
-    import os
-    import subprocess
-    import sys
-
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    env["PHOTON_FAULT_PLAN"] = json.dumps(
-        {"rules": [{"point": "cd.step.boundary", "action": "raise"}]}
-    )
-    baseline = tmp_path / "baseline.json"
-    baseline.write_text("{}")
-    proc = subprocess.run(
-        [sys.executable, "bench_suite.py", "--gate", str(baseline)],
-        env=env,
-        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        capture_output=True,
-        text=True,
-        timeout=120,
-    )
-    assert proc.returncode == 2
-    assert "refusing --gate" in proc.stderr
-    assert "FAULT INJECTION ARMED" in proc.stderr

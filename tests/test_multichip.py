@@ -8,14 +8,8 @@ Acceptance pinned here:
   - ``comms.*`` collective estimates are recorded for every multi-device
     solve;
   - repeated solves with refreshed per-row arrays do NOT grow the
-    compiled-signature set (no recompile storms);
-  - the game_10B capacity config computes its per-device table bytes and
-    REFUSES to run unsharded with a clear headroom message;
-  - ``bench_suite --gate`` skips (with a note) multichip metrics missing
-    from an older baseline instead of erroring.
+    compiled-signature set (no recompile storms).
 """
-
-import json
 
 import jax
 import jax.numpy as jnp
@@ -257,111 +251,7 @@ def test_estimator_rejects_mesh_with_unknown_axes(rng, multichip):
 
 
 # ---------------------------------------------------------------------------
-# game_10B capacity config
-# ---------------------------------------------------------------------------
-
-
-def test_game_10b_refuses_unsharded(monkeypatch):
-    import bench_multichip as mc
-
-    monkeypatch.setenv("PHOTON_CHIP_HBM_GB", "16")
-    plan = mc.game_10b_plan(8)
-    assert plan["total_coefficients"] == 10_240_000_000
-    assert not plan["fits_unsharded"]
-    assert plan["per_device_gb"] < 16
-    with pytest.raises(RuntimeError, match="refuses to run on 1 device"):
-        mc.check_game_10b_headroom(1)
-    # the message carries the memory math and the fix
-    try:
-        mc.check_game_10b_headroom(1)
-    except RuntimeError as e:
-        msg = str(e)
-        assert "GB per device" in msg and "shard the entity axis" in msg
-    # sharded over >= min_devices it passes the headroom check
-    mc.check_game_10b_headroom(plan["min_devices"])
-    mc.check_game_10b_headroom(8)
-
-
-def test_game_10b_bench_line_shape(monkeypatch):
-    import bench_multichip as mc
-
-    monkeypatch.setenv("PHOTON_CHIP_HBM_GB", "16")
-    line = mc.bench_game_10b(8, simulated=True)
-    assert line["metric"] == "multichip_game10B_per_device_gb"
-    detail = line["detail"]
-    assert detail["unsharded_refused"] is True
-    assert "refuses to run" in detail["refusal"]
-    assert detail["sharded_plan_fits"] is True
-    assert detail["simulated"] is True
-    json.dumps(line)  # bench contract: every line is valid JSON
-
-
-# ---------------------------------------------------------------------------
-# gate tolerance for baselines predating the multichip metrics
-# ---------------------------------------------------------------------------
-
-
-def test_gate_skips_multichip_metrics_missing_from_baseline(capsys):
-    import bench_suite
-
-    results = {
-        "linreg_tron_1Mx10K_rows_per_sec_per_chip": 100.0,
-        "multichip_glm_rows_per_sec": 500.0,
-        "multichip_glmix_cd_coeffs_per_sec": None,  # budget-truncated
-    }
-    baseline = {"linreg_tron_1Mx10K_rows_per_sec_per_chip": 90.0}
-    rc = bench_suite.run_gate(results, baseline, threshold=0.2)
-    err = capsys.readouterr().err
-    assert rc == 0
-    assert "multichip_glm_rows_per_sec: new metric" in err
-    assert "skipped" in err
-    assert "truncated, not gated" in err
-
-
-def test_gate_fleet_observability_metrics_lower_is_better(capsys):
-    """The fleet_* observability metrics regress UPWARD (more waiting,
-    wider MFU spread = worse) and skip-with-note against baselines that
-    predate them — the established new-metric gate path."""
-    import bench_multichip
-    import bench_suite
-
-    assert "fleet_collective_wait_fraction" in bench_multichip.MULTICHIP_METRICS
-    assert "fleet_mfu_spread" in bench_multichip.MULTICHIP_METRICS
-    baseline = {
-        "fleet_collective_wait_fraction": 0.1,
-        "fleet_mfu_spread": 0.05,
-    }
-    # a RISE is the regression
-    rc = bench_suite.run_gate(
-        {"fleet_collective_wait_fraction": 0.5, "fleet_mfu_spread": 0.05},
-        baseline, threshold=0.2,
-    )
-    assert rc == bench_suite.GATE_EXIT_CODE
-    capsys.readouterr()
-    # a drop (less waiting) passes
-    rc = bench_suite.run_gate(
-        {"fleet_collective_wait_fraction": 0.05, "fleet_mfu_spread": 0.01},
-        baseline, threshold=0.2,
-    )
-    assert rc == 0
-    capsys.readouterr()
-    # baselines predating the fleet metrics: skipped with a note
-    rc = bench_suite.run_gate(
-        {
-            "fleet_collective_wait_fraction": 0.5,
-            "linreg_tron_1Mx10K_rows_per_sec_per_chip": 100.0,
-        },
-        {"linreg_tron_1Mx10K_rows_per_sec_per_chip": 100.0},
-        threshold=0.2,
-    )
-    err = capsys.readouterr().err
-    assert rc == 0
-    assert "fleet_collective_wait_fraction: new metric" in err
-
-
-# ---------------------------------------------------------------------------
-# ISSUE 16: per-device HBM high-watermarks across a multichip fleet, and
-# the gated per-kernel utilization metrics
+# ISSUE 16: per-device HBM high-watermarks across a multichip fleet
 # ---------------------------------------------------------------------------
 
 
@@ -399,38 +289,3 @@ def test_watermark_spread_across_eight_devices():
     assert g["memory.phase.score.device.0.peak_bytes"] == 2**20
     # the live spread names the imbalance: 12 MiB vs 1 MiB
     assert tmem.device_spread_bytes() == 11 * 2**20
-
-
-def test_gate_kernel_utilization_metrics(capsys):
-    """The per-kernel utilization metrics ride bench_suite's gate:
-    an MFU drop regresses (higher is better), and baselines predating
-    the profiler skip-with-note."""
-    import bench_suite
-
-    assert "glm_value_grad_mfu" in bench_suite.SUITE_METRICS
-    assert "hot_dispatch_fraction" in bench_suite.SUITE_METRICS
-    baseline = {"glm_value_grad_mfu": 0.5, "hot_dispatch_fraction": 0.8}
-    rc = bench_suite.run_gate(
-        {"glm_value_grad_mfu": 0.1, "hot_dispatch_fraction": 0.8},
-        baseline, threshold=0.2,
-    )
-    assert rc == bench_suite.GATE_EXIT_CODE  # MFU collapsed: regression
-    capsys.readouterr()
-    rc = bench_suite.run_gate(
-        {"glm_value_grad_mfu": 0.55, "hot_dispatch_fraction": 0.9},
-        baseline, threshold=0.2,
-    )
-    assert rc == 0  # better utilization passes
-    capsys.readouterr()
-    # an old baseline without the profiler metrics: skipped with a note
-    rc = bench_suite.run_gate(
-        {
-            "glm_value_grad_mfu": 0.1,
-            "linreg_tron_1Mx10K_rows_per_sec_per_chip": 100.0,
-        },
-        {"linreg_tron_1Mx10K_rows_per_sec_per_chip": 100.0},
-        threshold=0.2,
-    )
-    err = capsys.readouterr().err
-    assert rc == 0
-    assert "glm_value_grad_mfu: new metric" in err and "skipped" in err

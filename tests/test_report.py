@@ -220,21 +220,48 @@ def test_build_phase_tree_orphan_parent_roots_at_survivor():
     assert root.children["leaked"].count == 1  # rooted, not lost
 
 
-def test_compare_metrics_directions_and_threshold():
+_LOWER = {"ratio": -1, "p99_ms": -1, "rate": +1}
+
+
+@pytest.mark.parametrize(
+    "current, baseline, directions, expected",
+    [
+        # -20% rows/s is AT the threshold, not beyond: ok
+        ({"rows_per_sec": 80.0}, {"rows_per_sec": 100.0}, None,
+         {"rows_per_sec": False}),
+        # +50% compiles (lower-is-better): regression
+        ({"jit_compiles": 30.0}, {"jit_compiles": 20.0}, None,
+         {"jit_compiles": True}),
+        # 5% faster = improvement
+        ({"fit_seconds": 95.0}, {"fit_seconds": 100.0}, None,
+         {"fit_seconds": False}),
+        # utilization collapsed (higher-is-better): regression
+        ({"mfu": 0.1}, {"mfu": 0.5}, None, {"mfu": True}),
+        # zero baselines and unknown metrics are skipped
+        ({"x": 1.0}, {"x": 0.0}, None, {}),
+        ({"mystery": 1.0}, {"mystery": 2.0}, None, {}),
+        # the caller's own directions: a lower-is-better ratio regresses
+        # when it RISES, passes when it drops
+        ({"ratio": 3.0}, {"ratio": 2.0}, _LOWER, {"ratio": True}),
+        ({"ratio": 1.5}, {"ratio": 2.0}, _LOWER, {"ratio": False}),
+        ({"p99_ms": 20.0}, {"p99_ms": 10.0}, _LOWER, {"p99_ms": True}),
+        # ... and KEY_METRIC_DIRECTIONS no longer applies
+        ({"jit_compiles": 30.0}, {"jit_compiles": 20.0}, _LOWER, {}),
+        # a metric the baseline predates (or a side lacks) is skipped;
+        # the rest is still compared
+        ({"ratio": 9.0, "rate": 100.0}, {"rate": 95.0}, _LOWER,
+         {"rate": False}),
+        ({"rate": 100.0}, {"ratio": 2.0, "rate": 200.0}, _LOWER,
+         {"rate": True}),
+    ],
+)
+def test_compare_metrics_directions_and_threshold(
+    current, baseline, directions, expected
+):
     deltas = compare_metrics(
-        {"rows_per_sec": 80.0, "jit_compiles": 30.0, "fit_seconds": 95.0},
-        {"rows_per_sec": 100.0, "jit_compiles": 20.0, "fit_seconds": 100.0},
-        threshold=0.2,
+        current, baseline, threshold=0.2, directions=directions
     )
-    by = {d.metric: d for d in deltas}
-    # -20% rows/s is AT the threshold, not beyond: ok
-    assert not by["rows_per_sec"].regressed
-    # +50% compiles (lower-is-better): regression
-    assert by["jit_compiles"].regressed
-    assert not by["fit_seconds"].regressed  # 5% faster = improvement
-    # zero baselines and unknown metrics are skipped
-    assert compare_metrics({"x": 1.0}, {"x": 0.0}) == []
-    assert compare_metrics({"mystery": 1.0}, {"mystery": 2.0}) == []
+    assert {d.metric: d.regressed for d in deltas} == expected
 
 
 def test_run_report_load_merge_and_markdown(tmp_path):
@@ -343,104 +370,6 @@ def test_report_path_sibling():
 def test_metric_delta_is_json_safe():
     d = MetricDelta("m", 1.0, 2.0, -0.5, True)
     json.dumps(d.to_dict())
-
-
-# -- bench budget / gate ------------------------------------------------------
-
-
-def test_bench_suite_budget_emits_truncated_lines(capsys, monkeypatch):
-    import bench_suite
-
-    monkeypatch.setenv("PHOTON_BENCH_BUDGET_S", "0")
-    deadline = bench_suite.budget_deadline()
-    assert deadline is not None
-    # budget already spent: EVERY metric line still appears, truncated
-    results = bench_suite.run_suite(deadline=time.monotonic() - 1.0)
-    lines = [
-        json.loads(x)
-        for x in capsys.readouterr().out.splitlines()
-        if x.startswith("{")
-    ]
-    assert [x["metric"] for x in lines] == list(bench_suite.SUITE_METRICS)
-    assert all(x["truncated"] is True and x["value"] is None for x in lines)
-    assert all(v is None for v in results.values())
-    monkeypatch.delenv("PHOTON_BENCH_BUDGET_S")
-    assert bench_suite.budget_deadline() is None
-
-
-def test_bench_suite_gate(tmp_path, capsys):
-    import bench_suite
-
-    results = {
-        "linreg_tron_1Mx10K_rows_per_sec_per_chip": 50_000.0,
-        "poisson_offsets_box_1Mx10K_rows_per_sec_per_chip": None,  # truncated
-    }
-    baseline = tmp_path / "baseline.json"
-    baseline.write_text(json.dumps(
-        {"linreg_tron_1Mx10K_rows_per_sec_per_chip": 100_000.0}
-    ))
-    rc = bench_suite.run_gate(
-        results, bench_suite.load_gate_baseline(str(baseline)), 0.2
-    )
-    assert rc == bench_suite.GATE_EXIT_CODE
-    err = capsys.readouterr().err
-    assert "REGRESSED" in err and "truncated, not gated" in err
-    # within threshold: passes
-    rc = bench_suite.run_gate(
-        results, {"linreg_tron_1Mx10K_rows_per_sec_per_chip": 55_000.0}, 0.2
-    )
-    assert rc == 0
-    # a baseline sharing NO metric names (e.g. a run-report key_metrics
-    # doc) must ERROR, not silently pass the gate
-    rc = bench_suite.run_gate(
-        results, {"rows_per_sec": 1.0, "fit_seconds": 2.0}, 0.2
-    )
-    assert rc == 2
-    assert "no comparable metrics" in capsys.readouterr().err
-    # an all-truncated run compared NOTHING: the gate must not pass —
-    # a starved budget would otherwise keep a real regression green
-    rc = bench_suite.run_gate(
-        {"linreg_tron_1Mx10K_rows_per_sec_per_chip": None},
-        {"linreg_tron_1Mx10K_rows_per_sec_per_chip": 100.0},
-        0.2,
-    )
-    assert rc == 2
-    assert "budget-truncated" in capsys.readouterr().err
-
-
-def test_bench_suite_gate_baseline_formats(tmp_path):
-    import bench_suite
-
-    # JSONL of bench output lines
-    p = tmp_path / "lines.jsonl"
-    p.write_text(
-        json.dumps({"metric": "a", "value": 2.0, "unit": "rows/s"}) + "\n"
-        + json.dumps({"metric": "bad", "value": None, "truncated": True})
-        + "\nnot json\n"
-    )
-    assert bench_suite.load_gate_baseline(str(p)) == {"a": 2.0}
-    # run-report JSON with key_metrics
-    p2 = tmp_path / "report.json"
-    p2.write_text(json.dumps({"key_metrics": {"b": 3.0, "note": "x"}}))
-    assert bench_suite.load_gate_baseline(str(p2)) == {"b": 3.0}
-
-
-def test_bench_budget_skips_all_sub_benchmarks(capsys):
-    import bench
-
-    # deadline in the past: every sub-benchmark is skipped WITHOUT
-    # launching a subprocess, yet every expected metric line appears
-    bench.run_sub_benchmarks(deadline=time.monotonic() - 1.0)
-    lines = [
-        json.loads(x)
-        for x in capsys.readouterr().out.splitlines()
-        if x.startswith("{")
-    ]
-    expected = [
-        m for ms in bench._SCRIPT_METRICS.values() for m in ms
-    ]
-    assert [x["metric"] for x in lines] == expected
-    assert all(x["truncated"] is True for x in lines)
 
 
 # -- train CLI wiring ---------------------------------------------------------
@@ -682,49 +611,6 @@ def test_report_without_sweep_has_no_section():
     report = RunReport.from_live()
     assert report.sweep_summary() is None
     assert "Hyperparameter sweep" not in report.to_markdown()
-
-
-def test_gate_sweep_ratio_is_lower_is_better(capsys):
-    """sweep_over_single_ratio regresses when it RISES (wall-time ratio),
-    unlike the rows/s metrics; and old baselines skip it with a note."""
-    import bench_suite
-
-    # ratio rose 2.0 -> 3.0: regression
-    rc = bench_suite.run_gate(
-        {"sweep_over_single_ratio": 3.0},
-        {"sweep_over_single_ratio": 2.0},
-        0.2,
-    )
-    assert rc == bench_suite.GATE_EXIT_CODE
-    assert "REGRESSED" in capsys.readouterr().err
-    # ratio dropped (sweep got faster): fine
-    rc = bench_suite.run_gate(
-        {"sweep_over_single_ratio": 1.5},
-        {"sweep_over_single_ratio": 2.0},
-        0.2,
-    )
-    assert rc == 0
-    # pre-sweep baseline: skip-with-note, gate still compares the rest
-    rc = bench_suite.run_gate(
-        {"sweep_over_single_ratio": 2.5,
-         "linreg_tron_1Mx10K_rows_per_sec_per_chip": 100.0},
-        {"linreg_tron_1Mx10K_rows_per_sec_per_chip": 95.0},
-        0.2,
-    )
-    err = capsys.readouterr().err
-    assert rc == 0
-    assert "sweep_over_single_ratio: new metric" in err
-
-    # overlap_factor likewise skips on baselines that predate it
-    rc = bench_suite.run_gate(
-        {"overlap_factor": 1.2,
-         "linreg_tron_1Mx10K_rows_per_sec_per_chip": 100.0},
-        {"linreg_tron_1Mx10K_rows_per_sec_per_chip": 95.0},
-        0.2,
-    )
-    err = capsys.readouterr().err
-    assert rc == 0
-    assert "overlap_factor: new metric" in err
 
 
 def test_report_ingestion_section_round_trip():

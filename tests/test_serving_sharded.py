@@ -1122,71 +1122,6 @@ def test_sharded_async_serving_survives_swap_and_nearline_mid_traffic(
         registry.stop()
 
 
-# ---------------------------------------------------------------------------
-# SLO bench smoke slice (tier-1: seconds, not minutes)
-# ---------------------------------------------------------------------------
-
-
-def test_serving_slo_smoke():
-    """The bench_serving SLO sweep runs end-to-end at a tiny offered
-    load: every metric lands (or is None only if truncated — not here),
-    the grid carries the shed budget accounting, and the CPU run is
-    marked simulated."""
-    from bench_serving import SLO_METRICS, run_serving_slo
-
-    detail = {}
-    results = run_serving_slo(
-        n_features=64, n_entities=64, local_dim=4, row_nnz=4,
-        max_batch=8, rates=(40,), queue_depths=(64,),
-        measure_s=0.6, n_clients=2, detail_out=detail,
-    )
-    assert set(results) == set(SLO_METRICS)
-    assert results["serving_slo_rows_per_sec"] > 0
-    assert results["serving_slo_p99_ms"] > 0
-    assert results["serving_slo_p99_swap_ratio"] > 0
-    assert results["serving_slo_p99_nearline_ratio"] > 0
-    assert results["serving_nearline_apply_ms"] > 0
-    assert detail["simulated_on_cpu"] is True
-    assert detail["grid"] and detail["grid"][0]["shed_fraction"] is not None
-    assert detail["shed_budget"] == 0.01
-    assert "window" in detail and "marks_s" in detail["window"]
-
-
-def test_gate_skips_serving_slo_metrics_missing_from_baseline(capsys):
-    """An old baseline that predates the serving_slo_* metrics skips
-    them with a note (never fails or crashes the gate); once baselined,
-    the latency/ratio metrics gate LOWER-is-better — a p99 RISE is the
-    regression."""
-    import bench_suite
-
-    results = {
-        "linreg_tron_1Mx10K_rows_per_sec_per_chip": 100.0,
-        "serving_slo_rows_per_sec": 500.0,
-        "serving_slo_p99_ms": 12.0,
-        "serving_slo_p99_swap_ratio": 1.02,
-        "serving_nearline_apply_ms": None,  # budget-truncated
-    }
-    baseline = {"linreg_tron_1Mx10K_rows_per_sec_per_chip": 90.0}
-    rc = bench_suite.run_gate(results, baseline, threshold=0.2)
-    err = capsys.readouterr().err
-    assert rc == 0
-    assert "serving_slo_p99_ms: new metric" in err
-    assert "skipped" in err
-    assert "truncated, not gated" in err
-    # once the baseline carries them, a p99 RISE regresses...
-    rc = bench_suite.run_gate(
-        {"serving_slo_p99_ms": 20.0}, {"serving_slo_p99_ms": 10.0},
-        threshold=0.2,
-    )
-    assert rc == bench_suite.GATE_EXIT_CODE
-    # ...and a p99 DROP passes (lower-is-better direction)
-    rc = bench_suite.run_gate(
-        {"serving_slo_p99_ms": 5.0}, {"serving_slo_p99_ms": 10.0},
-        threshold=0.2,
-    )
-    assert rc == 0
-
-
 def test_serving_report_section_roundtrip():
     """The RunReport Serving section renders from live serving counters
     (requests, swaps, nearline applies + lag) in both JSON and markdown."""
@@ -1223,12 +1158,3 @@ def test_serving_report_section_roundtrip():
     assert "3 nearline apply(ies) covering 96 entity row(s)" in md
     assert "p99 event->applied 11.4 ms" in md
     assert "3 request(s) shed" in md
-
-
-def test_serving_slo_budget_truncation():
-    """An exhausted budget yields all-None metrics (the truncated-line
-    contract) instead of partial work past the deadline."""
-    from bench_serving import SLO_METRICS, run_serving_slo
-
-    results = run_serving_slo(deadline=time.monotonic() - 1)
-    assert results == {m: None for m in SLO_METRICS}

@@ -582,9 +582,9 @@ def test_check_lint_rejects_fake_timing_in_library_code(tmp_path):
     lib = LocalLint("photon_ml_tpu/x.py", tree, library=True)
     codes = [f.code for f in lib.findings]
     assert "L006" in codes and "L007" in codes
-    # benches/tests keep their freedom
-    bench = LocalLint("bench.py", ast.parse(src), library=False)
-    assert not any(f.code in ("L006", "L007") for f in bench.findings)
+    # scripts/tests keep their freedom
+    script = LocalLint("chip_smoke.py", ast.parse(src), library=False)
+    assert not any(f.code in ("L006", "L007") for f in script.findings)
     # a USED result is not flagged (only bare statements are timing syncs)
     used = ast.parse("import jax\ndef g(x):\n    return jax.block_until_ready(x)\n")
     lib2 = LocalLint("photon_ml_tpu/y.py", used, library=True)
@@ -593,7 +593,7 @@ def test_check_lint_rejects_fake_timing_in_library_code(tmp_path):
 
 def test_check_lint_rejects_bare_print_in_library_code():
     """L009 satellite: bare print() is rejected in library code, allowed
-    in CLI modules (stdout is their interface) and in benches/tests."""
+    in CLI modules (stdout is their interface) and in scripts/tests."""
     import ast
 
     from tools.analysis.local import LocalLint
@@ -605,8 +605,8 @@ def test_check_lint_rejects_bare_print_in_library_code():
         "photon_ml_tpu/cli/train.py", ast.parse(src), library=True
     )
     assert not any(f.code == "L009" for f in cli.findings)
-    bench = LocalLint("bench.py", ast.parse(src), library=False)
-    assert not any(f.code == "L009" for f in bench.findings)
+    script = LocalLint("chip_smoke.py", ast.parse(src), library=False)
+    assert not any(f.code == "L009" for f in script.findings)
     # method calls named print (e.g. logger-ish objects) are not flagged
     method = LocalLint(
         "photon_ml_tpu/game/y.py",

@@ -1,7 +1,7 @@
 """ISSUE 5 (hardware-level observability): the instrumented-jit executable
 registry, recompile attribution, roofline peaks, collective estimates, the
-run report's Device utilization section, heartbeat MFU fields, the bench
-budget flush margin, and the `cli profile` capture path."""
+run report's Device utilization section, heartbeat MFU fields, and the
+`cli profile` capture path."""
 
 import json
 import logging
@@ -600,53 +600,3 @@ def test_cli_profile_requires_wrapped_command(tmp_path):
 
     with pytest.raises(SystemExit):
         profile_main(["--profile-dir", str(tmp_path / "p")])
-
-
-# -- bench budget margin ------------------------------------------------------
-
-
-def test_budget_deadline_reserves_flush_margin(monkeypatch):
-    import time
-
-    import bench_suite
-
-    monkeypatch.setenv("PHOTON_BENCH_BUDGET_S", "100")
-    now = time.monotonic()
-    deadline = bench_suite.budget_deadline(now=now)
-    # the flush-by deadline sits one margin BEFORE the budget wall, so
-    # truncated lines + the run report flush before the outer timeout -k
-    assert deadline == pytest.approx(
-        now + 100 - bench_suite.DEFAULT_BUDGET_MARGIN_S
-    )
-    monkeypatch.setenv("PHOTON_BENCH_MARGIN_S", "10")
-    assert bench_suite.budget_deadline(now=now) == pytest.approx(now + 90)
-    # a budget at or below the margin keeps HALF the budget for work
-    # (never a negative window, never an all-skipped run)
-    monkeypatch.setenv("PHOTON_BENCH_MARGIN_S", "30")
-    monkeypatch.setenv("PHOTON_BENCH_BUDGET_S", "5")
-    assert bench_suite.budget_deadline(now=now) == pytest.approx(now + 2.5)
-    # malformed env values degrade instead of killing the bench at start
-    monkeypatch.setenv("PHOTON_BENCH_BUDGET_S", "100")
-    monkeypatch.setenv("PHOTON_BENCH_MARGIN_S", "")
-    assert bench_suite.budget_margin() == bench_suite.DEFAULT_BUDGET_MARGIN_S
-    monkeypatch.setenv("PHOTON_BENCH_MARGIN_S", "30s")
-    assert bench_suite.budget_margin() == bench_suite.DEFAULT_BUDGET_MARGIN_S
-    monkeypatch.setenv("PHOTON_BENCH_BUDGET_S", "15 minutes")
-    assert bench_suite.budget_deadline(now=now) is None
-
-
-def test_bench_headline_truncates_when_budget_spent(capsys):
-    import time
-
-    import bench
-
-    # deadline in the past: the headline never launches a subprocess but
-    # still emits one valid truncated line per expected metric
-    bench.run_headline(deadline=time.monotonic() - 1.0)
-    lines = [
-        json.loads(x)
-        for x in capsys.readouterr().out.splitlines()
-        if x.startswith("{")
-    ]
-    assert [x["metric"] for x in lines] == list(bench.HEADLINE_METRICS)
-    assert all(x["truncated"] is True for x in lines)

@@ -57,48 +57,49 @@ L010_HOT_PATH = {
 # Hot-path library modules where every jit-compiled program must go
 # through telemetry.xla.instrumented_jit (L011): a bare jax.jit hides its
 # compile time, cost analysis, and recompile attribution from the
-# executable registry — exactly the blind spot that made BENCH_r05
-# unexplainable. Cold paths (one-off summaries, diagnostics) may stay on
-# bare jax.jit via the allowlist.
+# executable registry (the benchmark's `compile_s`, `eager_compile_s`
+# and `eager_programs_per_fit` split programs by that registry). Cold
+# paths (one-off summaries, diagnostics) may stay on bare jax.jit via
+# the allowlist.
 L011_HOT_DIRS = (
     os.path.join("photon_ml_tpu", "parallel") + os.sep,
     os.path.join("photon_ml_tpu", "game") + os.sep,
     os.path.join("photon_ml_tpu", "ops") + os.sep,
     # the sweep runner batches G solver configs into single executables;
     # a bare jax.jit there hides exactly the multi-config warmup the
-    # recompile-storm gate needs multi_shape attribution for
+    # recompile-storm detector (telemetry/xla.py) needs multi_shape
+    # attribution for
     os.path.join("photon_ml_tpu", "sweep") + os.sep,
     # the ingest pipeline's assembler writes every chunk through donated
     # device programs, and its uploader feeds every training batch — a
-    # bare jax.jit there (and any sync reachable from it, L013) would be
-    # invisible on exactly the path the overlap benches gate
+    # bare jax.jit there (and any sync reachable from it, L013) would
+    # stall the decode/upload overlap unseen
     os.path.join("photon_ml_tpu", "ingest") + os.sep,
     # incremental warm-start retrains: the masked-lane re-solves and the
     # vocabulary-growth row expansion run on the training hot path — a
-    # bare jax.jit there would hide exactly the solve-count structure
-    # bench_freshness gates the ≥10× time-to-fresh claim on
+    # bare jax.jit there would hide how many solves a refresh dispatched
     os.path.join("photon_ml_tpu", "incremental") + os.sep,
     # the freshness conductor re-runs masked solves (and escalated full
     # fits) every cycle of a long-lived daemon: a bare jax.jit there
     # would hide recompiles that accumulate directly into the
-    # event→served staleness p99 the pipeline tier gates on
+    # event→served staleness the daemon reports
     os.path.join("photon_ml_tpu", "pipeline") + os.sep,
     # the quality layer runs inside every gated publish (gate stats on
     # the candidate model) and inside every score_rows chunk (drift
     # sketches): a bare jax.jit or stray device sync there would tax
-    # exactly the serving and publish paths the quality benches gate
+    # every publish and every scored chunk unseen
     os.path.join("photon_ml_tpu", "quality") + os.sep,
 )
 L011_HOT_FILES = {
     os.path.join("photon_ml_tpu", "serving", "engine.py"),
     # the nearline updater re-solves entity rows on a live-serving
     # cadence: a bare jax.jit there would hide exactly the executables
-    # whose recompiles the SLO bench gates p99 flatness over
+    # whose recompiles land in a live request's latency
     os.path.join("photon_ml_tpu", "serving", "nearline.py"),
     # GLMix bootstrap: B resample lanes ride the sweep solver family on
     # the publish path (and the masked incremental variant); a bare
-    # jax.jit there would hide exactly the lane-composition executables
-    # bench_diagnostics gates the <=2x overhead claim on
+    # jax.jit there would hide the lane-composition executables that
+    # decide what a bootstrap costs over a plain fit
     os.path.join("photon_ml_tpu", "diagnostics", "bootstrap.py"),
     os.path.join("photon_ml_tpu", "training.py"),
     # the executable profiler wraps EVERY instrumented dispatch: a bare
@@ -153,7 +154,7 @@ class LocalLint(ast.NodeVisitor):
     def __init__(self, path: str, tree: ast.Module, library: bool = False):
         self.path = path
         # library code (photon_ml_tpu/) additionally gets the fake-timing
-        # rules L006/L007; benches and tests may time however they like
+        # rules L006/L007; scripts and tests may time however they like
         self.library = library
         self._l008_exempt = path in L008_BLESSED
         self._l010_hot = path in L010_HOT_PATH

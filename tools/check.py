@@ -8,7 +8,7 @@ every source file parses and passes lint before code lands.
 The analysis itself lives in the tools/analysis package (see its module
 docstrings for the pass-by-pass story):
 
-  1. single parse of every .py under photon_ml_tpu/ tests/ tools/ bench*.py
+  1. single parse of every .py under photon_ml_tpu/ tests/ tools/
      (syntax errors are findings of that one parse — no separate
      py_compile phase)
   2. per-file stdlib AST lint, rules L001-L012 (tools/analysis/local.py)

@@ -577,7 +577,7 @@ def make_serving_model(
     """Build and publish one small deterministic GAME model (FE
     ``global`` + per-``userId`` RE over ``n_entities`` entities) into
     ``registry_dir``; returns the published version directory. The
-    serving chaos matrix, bench, and the e2e fleet test all share this
+    serving chaos matrix and the e2e fleet test share this
     builder so their subprocess members score the same coefficients."""
     import jax.numpy as jnp
     import numpy as np
@@ -657,7 +657,7 @@ class ServingFleetSpec:
     traffic_hz: float = 20.0
     #: dense feature noise synthesized onto traffic rows as
     #: ``((shard_name, n_cols), ...)`` — each row gets ``[col, value]``
-    #: pairs for cols [0, n_cols) on that shard (the bench/test owns the
+    #: pairs for cols [0, n_cols) on that shard (the caller owns the
     #: model, so it knows the feature space; empty = ids-only rows)
     traffic_features: tuple = ()
     rng_seed: int = 20260807
@@ -1520,8 +1520,8 @@ def main(argv=None) -> int:
         return _worker_main(args)
     if not args.workdir:
         parser.error("--workdir is required (or --worker --dir)")
-    # the supervisor owns recovery.fleet_* — export them like bench.py
-    # does (PHOTON_TELEMETRY_OUT / PHOTON_TRACE_OUT opt-in) so a real
+    # the supervisor owns recovery.fleet_* — export them
+    # (PHOTON_TELEMETRY_OUT / PHOTON_TRACE_OUT opt-in) so a real
     # fleet run's member deaths/relaunches reach the RunReport Recovery
     # section, not just this process's memory
     from photon_ml_tpu import telemetry
