@@ -14,7 +14,11 @@ the stored slots, whatever d is:
     a contiguous range of ranks is a range of like densities.
   - THE HOT PANEL is a plain ``TiledBatch`` over ranks < 4,096 and every
     row, on the one-hot kernels as they are: 2*32 table rows a 128 slots
-    stream under the MXU's weight loads, the cheapest a pass gets.
+    stream under the MXU's weight loads, the cheapest a pass gets. Its row
+    assignment is ``TiledBatch.pack_coo``'s own: rows of near one length
+    (a click log's: 39 a row, 33 of them hot) go strided, 12 bytes a slot
+    and ONE pass a call, ragged ones stay sorted with their ``rlo`` and
+    the row one-hot's pass.
   - THE TAIL is binned in two dimensions. A class of window W cuts its rank
     range into column windows of W blocks and the rows into row windows of
     W tiles; a bin (row window x column window) is stored as tiles of
@@ -38,8 +42,13 @@ the stored slots, whatever d is:
 Whether a design takes this layout is decided from the design alone
 (:func:`plan_panels`): from B while the plain kernels' modelled cost is
 within twice the floor of any layout, else from the column histogram. The
-model is the one PERF.md's PR 25 timings fit: a pass of a one-hot through
-the MXU costs max(15 ns, 0.167 ns x rows streamed) per 128 slots.
+model is the one PERF.md's PR 25 timings fit (``ops/tiled.py::_pass_ns``):
+a pass of a one-hot through the MXU costs max(15 ns, 0.167 ns x rows
+streamed) per 128 slots. The plan prices a plain tile and the hot panel
+at TWO passes, the sorted row assignment's; whether a tile drops the
+second is ``ops/tiled.py::strided_is_cheaper``'s, from the row lengths,
+after the plan: it halves at most one term that is compared at a factor
+of two.
 
 Everything a :class:`PanelBatch` takes or returns in feature space is in
 RANK order. ``game/coordinates.py::FixedEffectCoordinate`` renumbers
@@ -64,10 +73,13 @@ from photon_ml_tpu.ops.sparse import SparseBatch, validate_coo_indices
 from photon_ml_tpu.ops import tiled
 from photon_ml_tpu.ops.tiled import (
     LANE,
+    PASS_FLOOR_NS,
     ROWS_PER_TILE,
     TiledBatch,
+    _bincount,
     _gather_slots,
     _onehot_t,
+    _pass_ns,
     _place_slots,
     _split_bf16,
     run_tiles,
@@ -85,13 +97,6 @@ MAX_WINDOW = 256
 # block and double-buffered: 16 MB of the kernels' 48; a wider stretch of one
 # window is cut into several classes
 MAX_CLASS_BLOCKS = 16384
-# one pass of a one-hot through the MXU, per 128 slots (PERF.md, PR 25)
-PASS_FLOOR_NS = 15.0
-PASS_ROW_NS = 0.167
-
-
-def _pass_ns(rows: int) -> float:
-    return max(PASS_FLOOR_NS, PASS_ROW_NS * rows)
 
 
 def _up(x: int, m: int) -> int:
@@ -129,7 +134,14 @@ def plain_is_near_floor(num_blocks: int) -> bool:
 def plan_panels(block_counts: np.ndarray, num_rows: int):
     """The tail classes for a design whose rank block b holds
     ``block_counts[b]`` slots (non-increasing), or None where the plain
-    tiled layout is modelled within twice the panels' cost."""
+    tiled layout is modelled within twice the panels' cost.
+
+    ``plain`` and the hot panel's ``cost`` carry ``+ _pass_ns(16)``, the row
+    one-hot's pass of the SORTED row assignment. A strided design runs
+    without it, but which assignment a ``TiledBatch`` gets is decided
+    later, from its row lengths, where it is packed
+    (``ops/tiled.py::strided_is_cheaper``); the plan sees columns only and
+    prices the dearer of the two on both sides of its comparison."""
     B = len(block_counts)
     if B <= HOT_BLOCKS:
         return None
@@ -176,15 +188,6 @@ def column_order(cols: np.ndarray, num_features: int):
     sorted_counts = np.zeros(_up(num_features, LANE), np.int64)
     sorted_counts[:num_features] = counts[order]
     return order, rank, sorted_counts.reshape(-1, LANE).sum(axis=1)
-
-
-def _bincount(x: np.ndarray, n: int) -> np.ndarray:
-    """``np.bincount`` in blocks (it widens its whole input to int64)."""
-    out = np.zeros(n, np.int64)
-    step = 1 << 24
-    for s in range(0, len(x), step):
-        out += np.bincount(x[s:s + step], minlength=n)
-    return out
 
 
 # ---------------------------------------------------------------------------
@@ -645,14 +648,21 @@ def report_layout(design, prefix: str = "layout") -> None:
     """Counters ``<prefix>.slots`` / ``<prefix>.nnz`` and gauge
     ``<prefix>.padding_ratio`` = slots allocated / nonzeros stored, of a
     design still on the host; a :class:`PanelBatch` splits its nonzeros by
-    part besides (``<prefix>.nnz.hot``, ``<prefix>.nnz.w<W>``)."""
+    part besides (``<prefix>.nnz.hot``, ``<prefix>.nnz.w<W>``). Counter
+    ``<prefix>.tiles.strided`` or ``<prefix>.tiles.sorted``: the tiles of
+    the plain design, or of a panel design's hot part, by the row assignment
+    :meth:`TiledBatch.pack_coo` gave them."""
     if isinstance(design, PanelBatch):
         names = ["hot"] + [f"w{p.cls.window}" for p in design.parts]
         for name, part_nnz in zip(names, design.stored):
             counter(f"{prefix}.nnz.{name}").inc(part_nnz)
         nnz = sum(design.stored)
+        tiles = design.hot
     else:
         nnz = int(np.count_nonzero(design.vals))
+        tiles = design
+    how = "strided" if tiles.strided else "sorted"
+    counter(f"{prefix}.tiles.{how}").inc(tiles.num_tiles)
     counter(f"{prefix}.slots").inc(design.nnz_slots)
     counter(f"{prefix}.nnz").inc(nnz)
     gauge(f"{prefix}.padding_ratio").set(design.nnz_slots / max(nnz, 1))

@@ -6,43 +6,57 @@ bound (4.4 s a gather pass over 273M nonzeros; PERF.md section 3). This
 module reaches HBM/MXU speed instead by removing ALL random access:
 
   - Rows are grouped into tiles of R=128 consecutive rows. Each tile's nnz
-    become a fixed-length slot list of (value, col_hi, col_lo, row_local)
-    where ``col = col_hi * 128 + col_lo`` and ``row_local = row % 128``.
+    become a fixed-length slot list of (value, col_hi, col_lo) where
+    ``col = col_hi * 128 + col_lo``: 12 bytes a slot.
+  - WHOSE A SLOT IS, the design decides at pack time from its own row
+    lengths (:func:`strided_is_cheaper` inside :meth:`TiledBatch.pack_coo`).
+    STRIDED: slot ``k*128 + r`` of a tile is the k-th nonzero of row r, so
+    S = 128 x the longest row, a per-slot row [1, S] is S/128 lane-aligned
+    chunks each already in row order, and the kernels need nothing more:
+    a row sum is the chunks added up, a per-row value reaches its slots by
+    a lane repeat. SORTED: a tile's nonzeros fill its slots in arrival
+    order, S is the fullest tile's count, and a fourth array ``row_local =
+    row % 128`` (16 bytes a slot) feeds a row one-hot ``rt`` and one more
+    MXU pass. Rows of one length (or near it) go strided; a few very long
+    rows among short ones would pad every row to the longest and stay
+    sorted.
   - The coefficient vector lives as a [B8, 128] grid (B8 = B rounded up to
     the bfloat16 sublane tile of 16; rows from B on are zero).
   - SLOTS STAY ON LANES. The slot arrays arrive lane-major ([1, S] a tile)
     and every per-slot quantity keeps that shape from the block's load to
-    the matmul that consumes it; the one-hot axes go on sublanes. The three
+    the matmul that consumes it; the one-hot axes go on sublanes. The
     masks are transposed one-hots built by comparing a [1, S] row with an
     iota over dim 0 (a sublane broadcast, no relayout): ``hit`` [B8, S] of
-    col_hi, ``lot`` [128, S] of col_lo, ``rt`` [R, S] of row_local. A
-    per-slot vector is S/128 vregs as a [1, S] row and S/8 as an [S, 1]
-    column; the column-shaped kernels this replaces spent 3.0 of their
-    4.9-6.7 us a tile turning rows into columns and building masks from
-    them (PERF.md, Findings PR 25: the stubbed-kernel table).
+    col_hi, ``lot`` [128, S] of col_lo and, sorted only, ``rt`` [R, S] of
+    row_local. A per-slot vector is S/128 vregs as a [1, S] row and S/8 as
+    an [S, 1] column; the column-shaped kernels this replaces spent 3.0 of
+    their 4.9-6.7 us a tile turning rows into columns and building masks
+    from them (PERF.md, Findings PR 25: the stubbed-kernel table).
   - Gathering w[col] per slot = ``[w_hi; w_lo] @ lot`` ([2*B8, S]: every
     column block's w[., lo_s] in slot s's lane), then ``where(hit, ., 0)``
     summed over sublanes: one nonzero a column, so the sum is exact. The
-    per-row sum is an NT contraction over the lane axis of the per-slot
-    rows and ``rt``.
+    per-row sum is the [1, S] row's chunks added in float32 (strided), or
+    an NT contraction over the lane axis of the per-slot rows and ``rt``
+    (sorted).
   - Scattering per-slot contributions into feature space = the per-slot
     product split once as a row, each half placed in its column block by
     ``where(hit, ., 0)`` ([2*B8, S]), contracted over the lane axis
     against ``lot`` into a [B8, 128] accumulator laid out like the grid.
   - ``hit`` only feeds selects and stays boolean; ``lot`` and ``rt`` only
     feed the MXU. Every pass of a one-hot through the MXU is S/128 weight
-    tiles, each streamed by the other operand's rows, and the kernels'
-    times are their passes: at the shape below ~26 ms a call for a pass
-    that streams the 2*B8 = 160 table rows (a gather, a scatter) and ~14 ms
-    for one that streams 16 (the row sum, the rows-to-slots broadcast).
-    Margins and scatter are one of each (40), pair two and one (67),
-    value+grad and hv_at two and two (80, 78), hv three and two (105).
-    Building ``lot`` and ``rt`` and margins' two passes ALONE take 38.9 of
-    its 40.4 ms: every select and sum hides under them. A grid step alone
-    is 0.20 us of a tile's 0.86. Lane chunks of S, the ``wT @ hit``
-    contraction order (256 rows streamed), a ``where`` + reduce row sum
-    and the accumulator the other way up were all timed and are slower
-    (PERF.md, Findings PR 25).
+    tiles, each streamed by the other operand's rows. At the shape below a
+    pass that streams the 2*B8 = 160 table rows (a gather, a scatter) is
+    25.6 ms a call (pair less margins, either assignment), the sorted
+    layout's 16-row pass against ``rt`` (the row sum, the rows-to-slots
+    broadcast) 4.5-6.4 ms (each kernel's sorted time less its strided),
+    and what a call pays whatever it computes ~10 ms = 0.21 us a tile: a
+    kernel that only loads the slot blocks and builds ``hit`` takes 16.2 ms,
+    and NO vector stage shows in a call's time (``hit`` left out, ``lot``
+    built from half as many compares, the two table halves summed inside
+    the MXU: 35.2 -> 35.2, 35.0, 35.0 ms; PERF.md, Findings PR 29). Lane
+    chunks of the matmuls, the ``wT @ hit`` contraction order (256 rows
+    streamed), a ``where`` + reduce row sum over ``rt`` and the accumulator
+    the other way up were all timed and are slower (Findings PR 25).
   - f32 exactness comes from bf16x2 splits (x = hi + lo in bfloat16,
     products against 0/1 masks are exact, MXU accumulates in f32). The
     split MUST happen inside the kernel: XLA's
@@ -53,18 +67,19 @@ module reaches HBM/MXU speed instead by removing ALL random access:
     compiles on the jax this tree runs (0.9.0).
 
 Measured alone on one TPU v5 lite at 6M rows x 10K features, 20 nnz/row
-(T = 46,875, S = 2,560, B = 79; PERF.md Findings PR 25, "my chip run"), new
-against the column-shaped kernels: margins 40.4 ms (232.3), scatter 40.3
-(315.7), margins_pair 67.0 (338.0), fused value+grad 79.5 (372.9), fused Hv
-105.4 (497.4), hv_at 77.4 (349.8). Relative L2 error against float64 at that
-shape: margins 3.4e-6 (3.4e-6), scatter 3.5e-6 (3.9e-6).
+(T = 46,875, S = 2,560, B = 79; PERF.md Findings PR 29, "my chip run"),
+strided (sorted): margins 35.5 ms (40.0), scatter 34.1 (39.9), margins_pair
+61.1 (65.8), fused value+grad 68.1 (79.5), fused Hv 92.3 (105.1), hv_at 65.4
+(76.7). Relative L2 error against float64 at that shape: margins 2.4e-6
+(3.4e-6), scatter 3.0e-6 (3.9e-6).
 
 This replaces the hot loop the reference distributes over a Spark cluster
 (ValueAndGradientAggregator.scala:132-153) with on-chip matmuls.
 
 Width and skew: a pass costs slots x B (the [2*B8, S] intermediates above),
-so this layout is for designs of up to ~128 column blocks; S is the fullest
-tile's count, so ragged row lengths pad. ``ops/panels.py::pack_design``
+so this layout is for designs of up to ~128 column blocks; S is the longest
+row's or the fullest tile's, so ragged row lengths pad (rows or tiles: the
+rule takes the cheaper). ``ops/panels.py::pack_design``
 chooses between this layout and the column panels from the design's own
 width and column histogram, uses these kernels unchanged for the panels' hot
 part, and reports slots against nonzeros (gauge ``layout.padding_ratio``).
@@ -90,6 +105,36 @@ Array = jax.Array
 
 LANE = 128
 ROWS_PER_TILE = 128
+
+# one pass of a one-hot through the MXU, per 128 slots (PERF.md, PR 25): the
+# weight loads' floor, or the rows streamed against them
+PASS_FLOOR_NS = 15.0
+PASS_ROW_NS = 0.167
+
+
+def _pass_ns(rows: int) -> float:
+    return max(PASS_FLOOR_NS, PASS_ROW_NS * rows)
+
+
+def strided_is_cheaper(s_strided: int, s_sorted: int, num_blocks: int) -> bool:
+    """Whether a design's tiles cost less with slot ``k*128 + r`` given to
+    row r (``s_strided`` = 128 x the longest row, one pass of the 2*B8 table
+    rows a call) than with slots in arrival order (``s_sorted`` = the
+    fullest tile's count, that pass and the 16-row pass against the row
+    one-hot ``rt``): modelled time a tile, from the design's own row
+    lengths. Constant-length rows go strided; a few very long rows among
+    short ones would pad every row to the longest and stay sorted."""
+    table = _pass_ns(2 * _table_rows(num_blocks))
+    return s_strided * table <= s_sorted * (table + _pass_ns(16))
+
+
+def _bincount(x: np.ndarray, n: int) -> np.ndarray:
+    """``np.bincount`` in blocks (it widens its whole input to int64)."""
+    out = np.zeros(n, np.int64)
+    step = 1 << 24
+    for s in range(0, len(x), step):
+        out += np.bincount(x[s:s + step], minlength=n)
+    return out
 
 
 def _interpret() -> bool:
@@ -126,17 +171,29 @@ def _onehot_t(idx_row, n: int):
     return idx_row == iota
 
 
+def _slot_refs(strided: bool, refs):
+    """(vals, hi, lo, rlo or None, the other refs) of a kernel's refs: a
+    strided design has no ``rlo`` block."""
+    if strided:
+        return (*refs[:3], None, refs[3:])
+    return (*refs[:4], refs[4:])
+
+
 def _tile_masks(hi_ref, lo_ref, rlo_ref, B8: int):
-    """The three transposed one-hots of one tile, slots on lanes.
+    """The transposed one-hots of one tile, slots on lanes.
 
     ``hit`` [B8, S] only ever feeds a ``where`` and stays boolean. A padding
     slot carries the sentinel ``hi == B``: where B8 > B that is row B of
     ``hit``, which is a zero row of every table (:meth:`TiledBatch._w2`)
     and an accumulator row :meth:`TiledBatch._features` drops; where
     B8 == B it matches no row. ``lot`` [128, S] and ``rt`` [R, S] only
-    ever feed the MXU and are converted once."""
+    ever feed the MXU and are converted once. ``rt`` is the sorted
+    layout's alone: a strided tile (``rlo_ref`` None) has none, its slot
+    ``k*128 + r`` IS row r's."""
     hit = _onehot_t(hi_ref[0], B8)
     lot = _onehot_t(lo_ref[0], LANE).astype(jnp.bfloat16)
+    if rlo_ref is None:
+        return hit, lot, None
     rt = _onehot_t(rlo_ref[0], ROWS_PER_TILE).astype(jnp.bfloat16)
     return hit, lot, rt
 
@@ -189,27 +246,46 @@ def _place_slots(p, hit, lot):
     return d[:N] + d[N:]
 
 
-def _row_sums(tabs, vals, hit, lot, rt):
-    """Per-row sums of vals_s * table[col_s], one row of the [8, R] result
-    per table in ``tabs`` (stacked :func:`_table2` grids).
+def _chunk_sum(row):
+    """[1, S] -> [1, 128]: the sum of the S/128 lane-aligned chunks, added
+    pairwise (float32 on the VPU)."""
+    chunks = [row[:, k:k + LANE] for k in range(0, row.shape[1], LANE)]
+    while len(chunks) > 1:
+        chunks = [a + b for a, b in zip(chunks[::2], chunks[1::2])] + (
+            chunks[-1:] if len(chunks) % 2 else [])
+    return chunks[0]
 
-    Gather (:func:`_gather_slots`) times ``vals`` as a [1, S] row. Row sum:
-    an NT contraction over the lane axis of the per-slot rows and ``rt``,
-    all tables in one :func:`_stack16` LHS."""
+
+def _row_sums(tabs, vals, hit, lot, rt):
+    """Per-row sums of vals_s * table[col_s]: one [1, R] row per table in
+    ``tabs`` (stacked :func:`_table2` grids).
+
+    Gather (:func:`_gather_slots`) times ``vals`` as a [1, S] row. Row sum,
+    strided (``rt`` None): chunk k of that row holds the k-th nonzero of
+    every row in row order, so the chunks are added up. Sorted: an NT
+    contraction over the lane axis of the per-slot rows and ``rt``, all
+    tables in one :func:`_stack16` LHS."""
     per_slot = [_gather_slots(tab, hit, lot) * vals for tab in tabs]
+    if rt is None:
+        return [_chunk_sum(p) for p in per_slot]
     z = _dot(_stack16(per_slot), rt, _NT)              # [16, R]
-    return z[:8] + z[8:]
+    z = z[:8] + z[8:]
+    return [z[j:j + 1] for j in range(len(tabs))]
 
 
 def _scatter_accum(out_ref, per_row, vals, hit, lot, rt):
     """out[B8, 128] += sum_s per_row[row_s] * vals_s * onehot(col_s).
 
-    ``per_row`` [1, R] reaches the slots through ``rt`` on the MXU (exact:
-    bf16x2); :func:`_place_slots` lands the per-slot product in the
-    accumulator."""
-    s = _dot(_stack16([per_row]), rt, _NN)             # [16, S]
-    p = (s[:8] + s[8:])[0:1] * vals                    # [1, S]
-    out_ref[:] = out_ref[:] + _place_slots(p, hit, lot)
+    ``per_row`` [1, R] reaches the slots by a lane repeat where the tile is
+    strided (``rt`` None) and through ``rt`` on the MXU (exact: bf16x2)
+    where it is sorted; :func:`_place_slots` lands the per-slot product in
+    the accumulator."""
+    if rt is None:
+        s = jnp.tile(per_row, (1, vals.shape[1] // LANE))
+    else:
+        s = _dot(_stack16([per_row]), rt, _NN)         # [16, S]
+        s = (s[:8] + s[8:])[0:1]
+    out_ref[:] = out_ref[:] + _place_slots(s * vals, hit, lot)
 
 
 # ---------------------------------------------------------------------------
@@ -217,8 +293,7 @@ def _scatter_accum(out_ref, per_row, vals, hit, lot, rt):
 # ---------------------------------------------------------------------------
 
 
-def _margins_kernel(use_offsets: bool, pair: bool,
-                    *refs):
+def _margins_kernel(use_offsets: bool, pair: bool, strided: bool, *refs):
     """z = per-row sum of vals * w[col] (+offsets +shift).
 
     With ``pair`` a second table v is gathered in the same sweep (shares all
@@ -226,28 +301,28 @@ def _margins_kernel(use_offsets: bool, pair: bool,
     pass per LBFGS line search, and for (margins(w), dot_rows(v)) in
     Hessian-vector products.
     """
+    vals_ref, hi_ref, lo_ref, rlo_ref, refs = _slot_refs(strided, refs)
     if pair:
-        (vals_ref, hi_ref, lo_ref, rlo_ref, off_ref, w_ref, v_ref,
-         shift_ref, out_z_ref, out_u_ref) = refs
+        (off_ref, w_ref, v_ref, shift_ref, out_z_ref, out_u_ref) = refs
         tabs = [_table2(w_ref), _table2(v_ref)]
     else:
-        (vals_ref, hi_ref, lo_ref, rlo_ref, off_ref, w_ref,
-         shift_ref, out_z_ref) = refs
+        (off_ref, w_ref, shift_ref, out_z_ref) = refs
         tabs = [_table2(w_ref)]
     hit, lot, rt = _tile_masks(hi_ref, lo_ref, rlo_ref, w_ref.shape[0])
     sums = _row_sums(tabs, vals_ref[0], hit, lot, rt)
 
-    z = sums[0:1] + shift_ref[0, 0]
+    z = sums[0] + shift_ref[0, 0]
     if use_offsets:
         z = z + off_ref[0, :, :]
     out_z_ref[0, :, :] = z
     if pair:
-        out_u_ref[0, :, :] = sums[1:2] + shift_ref[0, 1]
+        out_u_ref[0, :, :] = sums[1] + shift_ref[0, 1]
 
 
-def _scatter_kernel(square: bool, *refs):
+def _scatter_kernel(square: bool, strided: bool, *refs):
     """g = sum_i per_row[i] * x_i (or x_i^2): transposed one-hot matmul."""
-    (vals_ref, hi_ref, lo_ref, rlo_ref, pr_ref, out_g_ref) = refs
+    vals_ref, hi_ref, lo_ref, rlo_ref, refs = _slot_refs(strided, refs)
+    (pr_ref, out_g_ref) = refs
     i = pl.program_id(0)
 
     @pl.when(i == 0)
@@ -261,10 +336,12 @@ def _scatter_kernel(square: bool, *refs):
     _scatter_accum(out_g_ref, pr_ref[0], vals, hit, lot, rt)
 
 
-def _value_grad_kernel(loss_name: str, use_offsets: bool, *refs):
+def _value_grad_kernel(loss_name: str, use_offsets: bool, strided: bool,
+                       *refs):
     """Fused weighted loss value + raw gradient scatter + sum(weights*dz)."""
-    (vals_ref, hi_ref, lo_ref, rlo_ref, lab_ref, wgt_ref, off_ref,
-     w_ref, shift_ref, out_s_ref, out_g_ref) = refs
+    vals_ref, hi_ref, lo_ref, rlo_ref, refs = _slot_refs(strided, refs)
+    (lab_ref, wgt_ref, off_ref, w_ref, shift_ref, out_s_ref,
+     out_g_ref) = refs
     i = pl.program_id(0)
 
     @pl.when(i == 0)
@@ -275,7 +352,7 @@ def _value_grad_kernel(loss_name: str, use_offsets: bool, *refs):
     hit, lot, rt = _tile_masks(hi_ref, lo_ref, rlo_ref, w_ref.shape[0])
     vals = vals_ref[0]
 
-    z = _row_sums([_table2(w_ref)], vals, hit, lot, rt)[0:1] + shift_ref[0, 0]
+    z = _row_sums([_table2(w_ref)], vals, hit, lot, rt)[0] + shift_ref[0, 0]
     if use_offsets:
         z = z + off_ref[0, :, :]
 
@@ -290,13 +367,14 @@ def _value_grad_kernel(loss_name: str, use_offsets: bool, *refs):
     _scatter_accum(out_g_ref, g_row, vals, hit, lot, rt)
 
 
-def _hv_kernel(loss_name: str, use_offsets: bool, *refs):
+def _hv_kernel(loss_name: str, use_offsets: bool, strided: bool, *refs):
     """Fused Hessian-vector sweep: gather z = margins(w) and u = dot(v) from
     the same masks, form q = weight * l''(z) * u, scatter q into feature
     space and accumulate sum(q) — TRON's CG step in ONE data pass (the
     composed margins_pair + scatter path costs two)."""
-    (vals_ref, hi_ref, lo_ref, rlo_ref, lab_ref, wgt_ref, off_ref,
-     w_ref, v_ref, shift_ref, out_s_ref, out_g_ref) = refs
+    vals_ref, hi_ref, lo_ref, rlo_ref, refs = _slot_refs(strided, refs)
+    (lab_ref, wgt_ref, off_ref, w_ref, v_ref, shift_ref, out_s_ref,
+     out_g_ref) = refs
     i = pl.program_id(0)
 
     @pl.when(i == 0)
@@ -308,10 +386,10 @@ def _hv_kernel(loss_name: str, use_offsets: bool, *refs):
     vals = vals_ref[0]
 
     sums = _row_sums([_table2(w_ref), _table2(v_ref)], vals, hit, lot, rt)
-    z = sums[0:1] + shift_ref[0, 0]
+    z = sums[0] + shift_ref[0, 0]
     if use_offsets:
         z = z + off_ref[0, :, :]
-    u = sums[1:2] + shift_ref[0, 1]
+    u = sums[1] + shift_ref[0, 1]
 
     loss = get_loss(loss_name)
     q_row = wgt_ref[0, :, :] * loss.d2z(z, lab_ref[0, :, :]) * u   # [1, R]
@@ -321,14 +399,14 @@ def _hv_kernel(loss_name: str, use_offsets: bool, *refs):
     _scatter_accum(out_g_ref, q_row, vals, hit, lot, rt)
 
 
-def _hv_at_kernel(*refs):
+def _hv_at_kernel(strided: bool, *refs):
     """Hessian-vector sweep with the margin-derived row curvature d2 =
     weight * l''(z) PRECOMPUTED: gather u = dot(v), form q = d2 * u,
     scatter q and accumulate sum(q) — one pass, one gather + one scatter
     matmul (vs _hv_kernel's two gathers + scatter; TRON CG holds z fixed
     for its whole inner loop)."""
-    (vals_ref, hi_ref, lo_ref, rlo_ref, d2_ref, v_ref, shift_ref,
-     out_s_ref, out_g_ref) = refs
+    vals_ref, hi_ref, lo_ref, rlo_ref, refs = _slot_refs(strided, refs)
+    (d2_ref, v_ref, shift_ref, out_s_ref, out_g_ref) = refs
     i = pl.program_id(0)
 
     @pl.when(i == 0)
@@ -339,7 +417,7 @@ def _hv_at_kernel(*refs):
     hit, lot, rt = _tile_masks(hi_ref, lo_ref, rlo_ref, v_ref.shape[0])
     vals = vals_ref[0]
 
-    u = _row_sums([_table2(v_ref)], vals, hit, lot, rt)[0:1] + shift_ref[0, 0]
+    u = _row_sums([_table2(v_ref)], vals, hit, lot, rt)[0] + shift_ref[0, 0]
     q_row = d2_ref[0, :, :] * u  # [1, R]
     out_s_ref[:] = out_s_ref[:] + jnp.stack(
         [jnp.sum(q_row), jnp.float32(0.0)]).reshape(1, 2)
@@ -355,6 +433,11 @@ def _hv_at_kernel(*refs):
 
 def _spec_s(S):
     return pl.BlockSpec((1, 1, S), lambda i: (i, 0, 0), memory_space=pltpu.VMEM)
+
+
+def _slot_specs(S, strided: bool):
+    """The slot blocks of one tile: vals, hi, lo and, sorted only, rlo."""
+    return [_spec_s(S)] * (3 if strided else 4)
 
 
 def _spec_r():
@@ -376,9 +459,9 @@ def _shape_w(B):
 
 
 @functools.lru_cache(maxsize=None)
-def _margins_call(T, S, B, use_offsets, pair, interpret,
+def _margins_call(T, S, B, strided, use_offsets, pair, interpret,
                   name="tiled_margins"):
-    kern = functools.partial(_margins_kernel, use_offsets, pair)
+    kern = functools.partial(_margins_kernel, use_offsets, pair, strided)
     n_tab = 2 if pair else 1
     out_shape = [jax.ShapeDtypeStruct((T, 1, ROWS_PER_TILE), jnp.float32)]
     out_specs = [_spec_r()]
@@ -388,7 +471,7 @@ def _margins_call(T, S, B, use_offsets, pair, interpret,
     return pl.pallas_call(
         kern,
         grid=(T,),
-        in_specs=[_spec_s(S)] * 4 + [_spec_r()] + [_spec_w(B)] * n_tab
+        in_specs=_slot_specs(S, strided) + [_spec_r()] + [_spec_w(B)] * n_tab
         + [pl.BlockSpec((1, 2), lambda i: (0, 0), memory_space=pltpu.SMEM)],
         out_specs=out_specs if pair else out_specs[0],
         out_shape=out_shape if pair else out_shape[0],
@@ -398,12 +481,12 @@ def _margins_call(T, S, B, use_offsets, pair, interpret,
 
 
 @functools.lru_cache(maxsize=None)
-def _scatter_call(T, S, B, square, interpret):
-    kern = functools.partial(_scatter_kernel, square)
+def _scatter_call(T, S, B, strided, square, interpret):
+    kern = functools.partial(_scatter_kernel, square, strided)
     return pl.pallas_call(
         kern,
         grid=(T,),
-        in_specs=[_spec_s(S)] * 4 + [_spec_r()],
+        in_specs=_slot_specs(S, strided) + [_spec_r()],
         out_specs=_spec_w(B),
         out_shape=_shape_w(B),
         interpret=interpret,
@@ -412,12 +495,12 @@ def _scatter_call(T, S, B, square, interpret):
 
 
 @functools.lru_cache(maxsize=None)
-def _hv_call(T, S, B, loss_name, use_offsets, interpret):
-    kern = functools.partial(_hv_kernel, loss_name, use_offsets)
+def _hv_call(T, S, B, strided, loss_name, use_offsets, interpret):
+    kern = functools.partial(_hv_kernel, loss_name, use_offsets, strided)
     return pl.pallas_call(
         kern,
         grid=(T,),
-        in_specs=[_spec_s(S)] * 4 + [_spec_r()] * 3 + [_spec_w(B)] * 2
+        in_specs=_slot_specs(S, strided) + [_spec_r()] * 3 + [_spec_w(B)] * 2
         + [pl.BlockSpec((1, 2), lambda i: (0, 0), memory_space=pltpu.SMEM)],
         out_specs=[_spec_whole((1, 2)), _spec_w(B)],
         out_shape=[jax.ShapeDtypeStruct((1, 2), jnp.float32), _shape_w(B)],
@@ -427,11 +510,11 @@ def _hv_call(T, S, B, loss_name, use_offsets, interpret):
 
 
 @functools.lru_cache(maxsize=None)
-def _hv_at_call(T, S, B, interpret):
+def _hv_at_call(T, S, B, strided, interpret):
     return pl.pallas_call(
-        _hv_at_kernel,
+        functools.partial(_hv_at_kernel, strided),
         grid=(T,),
-        in_specs=[_spec_s(S)] * 4 + [_spec_r()] + [_spec_w(B)]
+        in_specs=_slot_specs(S, strided) + [_spec_r()] + [_spec_w(B)]
         + [pl.BlockSpec((1, 2), lambda i: (0, 0), memory_space=pltpu.SMEM)],
         out_specs=[_spec_whole((1, 2)), _spec_w(B)],
         out_shape=[jax.ShapeDtypeStruct((1, 2), jnp.float32), _shape_w(B)],
@@ -441,12 +524,13 @@ def _hv_at_call(T, S, B, interpret):
 
 
 @functools.lru_cache(maxsize=None)
-def _value_grad_call(T, S, B, loss_name, use_offsets, interpret):
-    kern = functools.partial(_value_grad_kernel, loss_name, use_offsets)
+def _value_grad_call(T, S, B, strided, loss_name, use_offsets, interpret):
+    kern = functools.partial(
+        _value_grad_kernel, loss_name, use_offsets, strided)
     return pl.pallas_call(
         kern,
         grid=(T,),
-        in_specs=[_spec_s(S)] * 4 + [_spec_r()] * 3 + [_spec_w(B)]
+        in_specs=_slot_specs(S, strided) + [_spec_r()] * 3 + [_spec_w(B)]
         + [pl.BlockSpec((1, 2), lambda i: (0, 0), memory_space=pltpu.SMEM)],
         out_specs=[_spec_whole((1, 2)), _spec_w(B)],
         out_shape=[jax.ShapeDtypeStruct((1, 2), jnp.float32), _shape_w(B)],
@@ -509,7 +593,7 @@ class TiledBatch:
     vals: Array      # f32[T, 1, S] slot values (0 in padding)
     hi: Array        # i32[T, 1, S] col // 128 (== B sentinel in padding)
     lo: Array        # i32[T, 1, S] col % 128
-    rlo: Array       # i32[T, 1, S] row % 128
+    rlo: Optional[Array]  # i32[T, 1, S] row % 128; None: slot % 128 (strided)
     labels3: Array   # f32[T, 1, 128]
     offsets3: Array  # f32[T, 1, 128]
     weights3: Array  # f32[T, 1, 128]; 0 for padded rows
@@ -569,10 +653,16 @@ class TiledBatch:
         offsets: Optional[np.ndarray] = None,
         weights: Optional[np.ndarray] = None,
     ) -> "TiledBatch":
-        """Host-side layout build: group nnz by row tile, pad to max. The
-        leaves stay HOST numpy arrays; :meth:`device` places them. S is the
-        fullest tile's count: whoever packs reports slots against nonzeros
-        (``ops/panels.py::pack_design``: gauge ``layout.padding_ratio``)."""
+        """Host-side layout build: group nnz by row tile, pad. The leaves
+        stay HOST numpy arrays; :meth:`device` places them.
+
+        Two row assignments, chosen by :func:`strided_is_cheaper` from the
+        design's own row lengths. STRIDED: the k-th nonzero of row r goes to
+        slot ``k*128 + r`` of its tile, S = 128 x the longest row, and the
+        design has no ``rlo``. SORTED: a tile's nonzeros fill its slots in
+        arrival order, S is the fullest tile's count and ``rlo`` says whose
+        each slot is. Whoever packs reports slots against nonzeros and the
+        tiles packed each way (``ops/panels.py::report_layout``)."""
         n = int(len(labels))
         R = ROWS_PER_TILE
         T = max(-(-n // R), 1)
@@ -582,31 +672,37 @@ class TiledBatch:
         rows, cols, values = np.asarray(rows), np.asarray(cols), np.asarray(values)
         validate_coo_indices(rows, cols, n, num_features)
 
-        tile = rows // R
-        if len(tile) and not np.all(tile[1:] >= tile[:-1]):
-            order = np.argsort(tile, kind="stable")
-            tile_s = tile[order]
-            rows = rows[order]
-            cols = cols[order]
-            values = values[order]
-        else:  # ingest emits row-sorted COO — skip the nnz sort
-            tile_s = tile
-        starts = np.searchsorted(tile_s, np.arange(T))
-        counts = np.diff(np.append(starts, len(tile_s)))
-        S = int(max(LANE, -(-int(counts.max(initial=0)) // LANE) * LANE))
-        idx = np.int32 if max(T * S, len(tile_s)) < 2 ** 31 else np.int64
-        dest = np.arange(len(tile_s), dtype=idx)
-        dest -= starts.astype(idx)[tile_s]
-        dest += tile_s.astype(idx) * idx(S)
+        row_counts = _bincount(rows, T * R)
+        tile_counts = row_counts.reshape(T, R).sum(axis=1)
+        s_sorted = int(max(LANE, -(-int(tile_counts.max()) // LANE) * LANE))
+        s_strided = LANE * max(int(row_counts.max()), 1)
+        strided = strided_is_cheaper(s_strided, s_sorted, B)
+        S = s_strided if strided else s_sorted
+        # strided needs a row's nonzeros together, sorted only a tile's;
+        # ingest emits row-sorted COO, so neither sorts the nnz then
+        key = rows if strided else rows // R
+        if len(key) and not np.all(key[1:] >= key[:-1]):
+            order = np.argsort(key, kind="stable")
+            key, rows, cols, values = (
+                a[order] for a in (key, rows, cols, values))
+        counts = row_counts if strided else tile_counts
+        idx = np.int32 if max(T * S, len(rows)) < 2 ** 31 else np.int64
+        key = key.astype(idx, copy=False)
+        # a nonzero's rank among those of its row (strided) or tile (sorted)
+        dest = np.arange(len(rows), dtype=idx)
+        dest -= (np.cumsum(counts) - counts).astype(idx)[key]
+        if strided:
+            dest *= idx(R)
+            dest += key % idx(R)
+            dest += (key // idx(R)) * idx(S)
+        else:
+            dest += key * idx(S)
+        del key
 
-        vals2 = np.zeros((T * S,), np.float32)
-        hi2 = np.full((T * S,), B, np.int32)   # sentinel: one-hot all-zero
-        lo2 = np.zeros((T * S,), np.int32)
-        rlo2 = np.zeros((T * S,), np.int32)
-        vals2[dest] = values
-        hi2[dest] = cols // LANE
-        lo2[dest] = cols % LANE
-        rlo2[dest] = rows % R
+        def slots(fill, dtype, per_nnz):
+            out = np.full((T * S,), fill, dtype)
+            out[dest] = per_nnz
+            return out.reshape(T, 1, S)
 
         npad = T * R
         lab = np.zeros(npad, np.float32)
@@ -617,12 +713,11 @@ class TiledBatch:
         wgt = np.zeros(npad, np.float32)
         wgt[:n] = 1.0 if weights is None else np.asarray(weights, np.float64)
 
-        shp = (T, 1, S)
         return TiledBatch(
-            vals=vals2.reshape(shp),
-            hi=hi2.reshape(shp),
-            lo=lo2.reshape(shp),
-            rlo=rlo2.reshape(shp),
+            vals=slots(0.0, np.float32, values),
+            hi=slots(B, np.int32, cols // LANE),  # sentinel: one-hot all-zero
+            lo=slots(0, np.int32, cols % LANE),
+            rlo=None if strided else slots(0, np.int32, rows % R),
             labels3=lab.reshape(T, 1, R),
             offsets3=off.reshape(T, 1, R),
             weights3=wgt.reshape(T, 1, R),
@@ -678,7 +773,10 @@ class TiledBatch:
         vals = np.asarray(self.vals).reshape(-1)
         hi = np.asarray(self.hi).reshape(-1)
         lo = np.asarray(self.lo).reshape(-1)
-        rlo = np.asarray(self.rlo).reshape(-1)
+        if self.strided:
+            rlo = np.tile(np.arange(S) % ROWS_PER_TILE, T)
+        else:
+            rlo = np.asarray(self.rlo).reshape(-1)
         tiles = np.repeat(np.arange(T), S)
         keep = hi < self.num_blocks
         col = hi[keep] * LANE + lo[keep]
@@ -696,7 +794,18 @@ class TiledBatch:
         pad = B8 * LANE - self.num_features
         return jnp.pad(w.astype(jnp.float32), (0, pad)).reshape(B8, LANE)
 
+    @property
+    def strided(self) -> bool:
+        """Whether slot ``k*128 + r`` of a tile is row r's (no ``rlo``)."""
+        return self.rlo is None
+
+    def _statics(self):
+        """(S, B, strided): what the kernels are built for."""
+        return self.vals.shape[2], self.num_blocks, self.strided
+
     def _slot_args(self):
+        if self.strided:
+            return (self.vals, self.hi, self.lo)
         return (self.vals, self.hi, self.lo, self.rlo)
 
     def _run(self, make_call, tile_args, rep_args, reduce: bool):
@@ -705,21 +814,21 @@ class TiledBatch:
 
     def margins(self, w: Array, shift: Array | float = 0.0) -> Array:
         """Per-row margins z_i = x_i . w + shift + offset_i."""
-        S, B = self.vals.shape[2], self.num_blocks
+        st = self._statics()
         sh = jnp.stack([jnp.asarray(shift, jnp.float32), jnp.float32(0)])
         z = self._run(
             lambda T: _margins_call(
-                T, S, B, True, False, _interpret(), self.margins_name),
+                T, *st, True, False, _interpret(), self.margins_name),
             (*self._slot_args(), self.offsets3),
             (self._w2(w), sh.reshape(1, 2)), reduce=False)
         return z.reshape(-1)
 
     def dot_rows(self, w: Array) -> Array:
         """Per-row raw dot products x_i . w (no offset/shift)."""
-        S, B = self.vals.shape[2], self.num_blocks
+        st = self._statics()
         z = self._run(
             lambda T: _margins_call(
-                T, S, B, False, False, _interpret(), self.margins_name),
+                T, *st, False, False, _interpret(), self.margins_name),
             (*self._slot_args(), self.offsets3),
             (self._w2(w), jnp.zeros((1, 2), jnp.float32)), reduce=False)
         return z.reshape(-1)
@@ -728,13 +837,13 @@ class TiledBatch:
         self, w: Array, shift, p: Array, p_shift
     ) -> tuple[Array, Array]:
         """(margins(w, shift), dot_rows(p) + p_shift) in one fused sweep."""
-        S, B = self.vals.shape[2], self.num_blocks
+        st = self._statics()
         sh = jnp.stack([
             jnp.asarray(shift, jnp.float32), jnp.asarray(p_shift, jnp.float32)
         ])
         z, u = self._run(
             lambda T: _margins_call(
-                T, S, B, True, True, _interpret(), self.margins_name),
+                T, *st, True, True, _interpret(), self.margins_name),
             (*self._slot_args(), self.offsets3),
             (self._w2(w), self._w2(p), sh.reshape(1, 2)), reduce=False)
         return z.reshape(-1), u.reshape(-1)
@@ -750,9 +859,9 @@ class TiledBatch:
             self.num_tiles, 1, ROWS_PER_TILE)
 
     def _scatter(self, per_row: Array, square: bool) -> Array:
-        S, B = self.vals.shape[2], self.num_blocks
+        st = self._statics()
         g = self._run(
-            lambda T: _scatter_call(T, S, B, square, _interpret()),
+            lambda T: _scatter_call(T, *st, square, _interpret()),
             (*self._slot_args(), self._rows3(per_row)), (), reduce=True)
         return self._features(g)
 
@@ -773,11 +882,11 @@ class TiledBatch:
         the caller applies normalization back-transform and regularization
         (GLMObjective.value_and_grad fast path).
         """
-        S, B = self.vals.shape[2], self.num_blocks
+        st = self._statics()
         sh = jnp.stack([jnp.asarray(shift, jnp.float32), jnp.float32(0)])
         sums, g = self._run(
             lambda T: _value_grad_call(
-                T, S, B, loss_name, True, _interpret()),
+                T, *st, loss_name, True, _interpret()),
             (*self._slot_args(), self.labels3, self.weights3, self.offsets3),
             (self._w2(w), sh.reshape(1, 2)), reduce=True)
         return sums[0, 0], self._features(g), sums[0, 1]
@@ -788,12 +897,12 @@ class TiledBatch:
         """(raw Hv scatter sum_i wgt_i*l''(z_i)*(x_i.v)*x_i, sum of the
         per-row q = wgt*l''*u terms) in ONE fused sweep (TRON CG fast path).
         Caller applies normalization back-transform and the L2 term."""
-        S, B = self.vals.shape[2], self.num_blocks
+        st = self._statics()
         sh = jnp.stack([
             jnp.asarray(shift, jnp.float32), jnp.asarray(v_shift, jnp.float32)
         ])
         sums, g = self._run(
-            lambda T: _hv_call(T, S, B, loss_name, True, _interpret()),
+            lambda T: _hv_call(T, *st, loss_name, True, _interpret()),
             (*self._slot_args(), self.labels3, self.weights3, self.offsets3),
             (self._w2(w), self._w2(v), sh.reshape(1, 2)), reduce=True)
         return self._features(g), sums[0, 0]
@@ -804,10 +913,10 @@ class TiledBatch:
         """(raw Hv scatter, sum q) with the row curvature d2 = wgt*l''(z)
         precomputed: ONE pass doing gather u + scatter q (TRON CG holds z
         fixed across its inner loop)."""
-        S, B = self.vals.shape[2], self.num_blocks
+        st = self._statics()
         sh = jnp.stack([jnp.asarray(v_shift, jnp.float32), jnp.float32(0)])
         sums, g = self._run(
-            lambda T: _hv_at_call(T, S, B, _interpret()),
+            lambda T: _hv_at_call(T, *st, _interpret()),
             (*self._slot_args(), self._rows3(d2_row)),
             (self._w2(v_eff), sh.reshape(1, 2)), reduce=True)
         return self._features(g), sums[0, 0]

@@ -324,7 +324,8 @@ def pad_batch_rows(batch, shards: int):
 
     SparseBatch: pads rows (weight 0 -> inert) and nnz slots (value 0,
     row = last row -> inert). TiledBatch: pads whole tiles (weights 0,
-    ``hi`` = num_blocks sentinel so gathers contribute nothing).
+    ``hi`` = num_blocks sentinel so gathers contribute nothing; a tile's
+    slots are never cut, so a strided design stays strided).
     """
     import jax.numpy as jnp
 
@@ -347,7 +348,7 @@ def pad_batch_rows(batch, shards: int):
             vals=pad_tiles(batch.vals, 0.0),
             hi=pad_tiles(batch.hi, batch.num_blocks),
             lo=pad_tiles(batch.lo, 0),
-            rlo=pad_tiles(batch.rlo, 0),
+            rlo=None if batch.strided else pad_tiles(batch.rlo, 0),
             labels3=pad_tiles(batch.labels3, 0.0),
             offsets3=pad_tiles(batch.offsets3, 0.0),
             weights3=pad_tiles(batch.weights3, 0.0),

@@ -5,8 +5,9 @@ Interpret mode (every other tiled test) cannot see what the chip's compiler
 refuses: a misaligned slice, too much VMEM, a Mosaic kernel left for GSPMD
 to partition. These tests hand the installed TPU compiler the shapes
 ``chip_smoke.py`` trains at (1M rows x 10K features, 20 nnz/row: T=7813
-tiles, S=2560 slots, B=79 column blocks). Nothing runs, so they say nothing
-about results or speed.
+tiles, S=2560 slots, B=79 column blocks), strided (no ``rlo`` block: what
+both benchmark cells' constant-length rows pack to) and, one case a kernel
+family, sorted. Nothing runs, so they say nothing about results or speed.
 
 The topology is described inside a fixture of THIS file only: one process
 at a time may load libtpu, xdist hands a file to one worker, and a call at
@@ -64,14 +65,14 @@ def on_chip_kernels(monkeypatch):
     monkeypatch.setattr(tiled, "_interpret", lambda: False)
 
 
-def _batch(num_tiles, sharding, shard=None):
+def _batch(num_tiles, sharding, shard=None, strided=True):
     def leaf(width, dtype):
         return jax.ShapeDtypeStruct(
             (num_tiles, 1, width), dtype, sharding=sharding)
 
     return TiledBatch(
         vals=leaf(S, jnp.float32), hi=leaf(S, jnp.int32),
-        lo=leaf(S, jnp.int32), rlo=leaf(S, jnp.int32),
+        lo=leaf(S, jnp.int32), rlo=None if strided else leaf(S, jnp.int32),
         labels3=leaf(ROWS_PER_TILE, jnp.float32),
         offsets3=leaf(ROWS_PER_TILE, jnp.float32),
         weights3=leaf(ROWS_PER_TILE, jnp.float32),
@@ -84,34 +85,38 @@ def _batch(num_tiles, sharding, shard=None):
 S_SMALL, B_SMALL = 384, 3
 
 
-def _kernel_cases(S=S, B=B):
-    """name -> (pallas_call built with interpret=False, argument shapes)."""
-    slot = [((T, 1, S), jnp.float32)] + [((T, 1, S), jnp.int32)] * 3
+def _kernel_cases(S=S, B=B, T=T, strided=True):
+    """name -> (pallas_call built with interpret=False, argument shapes).
+    A strided design hands the kernels three slot arrays, a sorted one four
+    (``rlo``)."""
+    slot = [((T, 1, S), jnp.float32)] + [((T, 1, S), jnp.int32)] * (
+        2 if strided else 3)
     row = ((T, 1, ROWS_PER_TILE), jnp.float32)
     w2 = ((tiled._table_rows(B), LANE), jnp.float32)
     sh = ((1, 2), jnp.float32)
+    st = (T, S, B, strided)
     return {
         "margins": (
-            tiled._margins_call(T, S, B, True, False, False),
+            tiled._margins_call(*st, True, False, False),
             slot + [row, w2, sh]),
         "dot_rows": (
-            tiled._margins_call(T, S, B, False, False, False),
+            tiled._margins_call(*st, False, False, False),
             slot + [row, w2, sh]),
         "margins_pair": (
-            tiled._margins_call(T, S, B, True, True, False),
+            tiled._margins_call(*st, True, True, False),
             slot + [row, w2, w2, sh]),
         "scatter": (
-            tiled._scatter_call(T, S, B, False, False), slot + [row]),
+            tiled._scatter_call(*st, False, False), slot + [row]),
         "scatter_sq": (
-            tiled._scatter_call(T, S, B, True, False), slot + [row]),
+            tiled._scatter_call(*st, True, False), slot + [row]),
         "value_grad": (
-            tiled._value_grad_call(T, S, B, "logistic", True, False),
+            tiled._value_grad_call(*st, "logistic", True, False),
             slot + [row] * 3 + [w2, sh]),
         "hv": (
-            tiled._hv_call(T, S, B, "logistic", True, False),
+            tiled._hv_call(*st, "logistic", True, False),
             slot + [row] * 3 + [w2, w2, sh]),
         "hv_at": (
-            tiled._hv_at_call(T, S, B, False), slot + [row, w2, sh]),
+            tiled._hv_at_call(*st, False), slot + [row, w2, sh]),
     }
 
 
@@ -147,6 +152,36 @@ def test_kernel_compiles_for_v5e(name, kernel, one_chip):
 def test_kernel_family_compiles_at_a_narrow_shape_for_v5e(name, kernel,
                                                          one_chip):
     _compiles_as(kernel, *_kernel_cases(S_SMALL, B_SMALL)[name], one_chip)
+
+
+@pytest.mark.parametrize("name,kernel", [
+    ("margins_pair", "tiled_margins"), ("scatter_sq", "tiled_scatter"),
+    ("value_grad", "tiled_value_grad"), ("hv", "tiled_hv"),
+    ("hv_at", "tiled_hv_at"),
+])
+def test_kernel_family_compiles_sorted_for_v5e(name, kernel, one_chip):
+    """The arrival-order layout (four slot arrays, the row one-hot ``rt``
+    and its MXU pass): what a design of ragged rows still runs."""
+    _compiles_as(
+        kernel, *_kernel_cases(strided=False)[name], one_chip)
+
+
+# the benchmark's two cells, strided: glm_fe.lbfgs_fit's plain design and
+# criteo_fe.lbfgs_fit's hot panel at 39 slots a row
+CELL_SHAPES = {"glm_fe": (46_875, 2_560, 79), "criteo_hot": (54_784, 4_992, 32)}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_SHAPES))
+@pytest.mark.parametrize("name,kernel", [
+    ("margins", "tiled_margins"), ("margins_pair", "tiled_margins"),
+    ("scatter", "tiled_scatter"),
+])
+def test_strided_kernel_compiles_at_the_cells_shapes_for_v5e(
+        name, kernel, cell, one_chip):
+    tiles, slots, blocks = CELL_SHAPES[cell]
+    call, shapes = _kernel_cases(slots, blocks, tiles)[name]
+    assert sum(shape == (tiles, 1, slots) for shape, _ in shapes) == 3
+    _compiles_as(kernel, call, shapes, one_chip)
 
 
 def test_fe_solver_module_and_kernels_are_named_for_v5e(one_chip,
@@ -224,7 +259,8 @@ def test_sharded_fe_value_grad_compiles_for_v5e_2x2(axes, topo,
     # the tile arrays stay where they were put
     assert "all-gather" not in text
     per_device = compiled.memory_analysis().argument_size_in_bytes
-    assert per_device < 1.1 * (4 * S + 3 * ROWS_PER_TILE) * 4 * T / n + (1 << 20)
+    # three slot arrays a tile: the strided design carries no rlo
+    assert per_device < 1.1 * (3 * S + 3 * ROWS_PER_TILE) * 4 * T / n + (1 << 20)
 
 
 def test_unsharded_kernel_under_a_mesh_is_refused(topo, on_chip_kernels):
@@ -246,7 +282,7 @@ def test_unsharded_kernel_under_a_mesh_is_refused(topo, on_chip_kernels):
 # ---------------------------------------------------------------------------
 
 PANEL_TILES = 54_784
-PANEL_HOT_S = 4352
+PANEL_HOT_S = 4992  # strided: 128 x the 39 nonzeros of a click-log row
 #: (window, first rank block, column windows, tiles) of the four tail classes
 PANEL_CLASSES = [(32, 32, 2, 15_308), (64, 96, 3, 11_110),
                  (128, 288, 4, 8_083), (256, 800, 28, 13_603)]
@@ -263,8 +299,8 @@ def _panel_batch(sharding, whole, shard=None, shards=1):
     tiles = -(-PANEL_TILES // (256 * shards)) * 256 * shards
     hot = TiledBatch(
         vals=leaf((tiles, 1, PANEL_HOT_S), jnp.float32),
-        **{k: leaf((tiles, 1, PANEL_HOT_S), jnp.int32)
-           for k in ("hi", "lo", "rlo")},
+        **{k: leaf((tiles, 1, PANEL_HOT_S), jnp.int32) for k in ("hi", "lo")},
+        rlo=None,
         **{k: leaf((tiles, 1, ROWS_PER_TILE), jnp.float32)
            for k in ("labels3", "offsets3", "weights3")},
         num_features=HOT_BLOCKS * LANE, shard=shard)

@@ -138,12 +138,17 @@ def test_place_batch_pads_tiles(rng, multichip):
 # ---------------------------------------------------------------------------
 
 
-@pytest.fixture
-def tiled_pair(rng, multichip):
+@pytest.fixture(params=["strided", "sorted"])
+def tiled_pair(request, rng, multichip, monkeypatch):
     """(one-device TiledBatch, the same design placed over batch=4) with
-    313 rows: 3 tiles pad to 4, so one shard is all padding."""
+    313 rows: 3 tiles pad to 4, so one shard is all padding. Under both row
+    assignments: the mesh cuts tiles, never a tile's slots, so slot
+    ``k*128 + r`` stays row r's on every shard."""
+    from photon_ml_tpu.ops import tiled
     from photon_ml_tpu.ops.tiled import TiledBatch
 
+    strided = request.param == "strided"
+    monkeypatch.setattr(tiled, "strided_is_cheaper", lambda *_: strided)
     n, d = 313, 150
     X = rng.normal(size=(n, d)) * (rng.random((n, d)) < 0.2)
     y = (rng.random(n) > 0.5).astype(float)
@@ -154,6 +159,8 @@ def tiled_pair(rng, multichip):
     placed = psharding.place_batch(tb, mesh)
     assert placed.shard == (mesh, "batch")
     assert placed.vals.sharding.spec == P("batch")
+    assert tb.strided == placed.strided == strided
+    assert (placed.rlo is None) == strided
     return tb, placed
 
 

@@ -15,7 +15,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from photon_ml_tpu.ops import panels
+from photon_ml_tpu.ops import panels, tiled
 from photon_ml_tpu.ops.panels import PanelBatch, pack_design
 from photon_ml_tpu.ops.sparse import SparseBatch
 from photon_ml_tpu.ops.tiled import TiledBatch
@@ -48,12 +48,22 @@ def _coo(kind: str, seed: int = 7):
             (rng.random(n) + 0.5).astype(np.float32), d)
 
 
-@pytest.fixture(scope="module", params=["skewed", "uniform"])
+@pytest.fixture(scope="module", params=[
+    "skewed", "uniform", "skewed-sorted", "uniform-sorted"])
 def design(request):
-    rows, cols, vals, y, off, wgt, d = _coo(request.param)
+    """Both column histograms with the hot part as ``pack_coo``'s rule
+    gives it (strided: the rows are of one length or near it), and again
+    held to the sorted assignment."""
+    kind, _, how = request.param.partition("-")
+    rows, cols, vals, y, off, wgt, d = _coo(kind)
     sb = SparseBatch.from_coo(vals, rows, cols, y, d, offsets=off, weights=wgt)
-    packed = pack_design(sb)
+    with pytest.MonkeyPatch.context() as mp:
+        if how == "sorted":
+            mp.setattr(tiled, "strided_is_cheaper", lambda *_: False)
+        packed = pack_design(sb)
     assert isinstance(packed, PanelBatch)
+    assert packed.hot.strided == (how != "sorted")
+    assert (packed.hot.rlo is None) == packed.hot.strided
     return (rows, cols, vals.astype(np.float64), y, off, wgt, d), packed.device()
 
 
@@ -240,6 +250,10 @@ def test_plan_at_the_criteo_cell_histogram():
         assert prev is None or c.window > prev.window
         b += c.num_blocks
     assert b >= 7813 and classes[-1].window == panels.MAX_WINDOW
+    # the plan prices the SORTED hot panel (two passes); the row assignment
+    # is pack_coo's own and moves no class: these are PR 26's
+    assert [(c.window, c.first_block, c.num_windows) for c in classes] == [
+        (32, 32, 4), (64, 160, 6), (128, 544, 12), (256, 2080, 23)]
 
 
 def test_plan_cuts_a_class_to_the_vmem_budget():
@@ -355,7 +369,10 @@ def test_estimator_fit_on_panels_matches_plain_reference():
 @pytest.mark.parametrize("optimizer", [
     {"type": "tron", "max_iterations": 8, "tolerance": 1e-6,
      "regularization": "l2", "regularization_weight": 1.0},
-    {"type": "lbfgs", "max_iterations": 25, "tolerance": 1e-6,  # L1 -> OWLQN
+    # L1 -> OWLQN, run to its float32 end: a relative tolerance of 1e-6 stops
+    # two layouts at different points of a flat objective (8e-3 apart in the
+    # coefficients with the strided hot part, at the LOWER objective)
+    {"type": "lbfgs", "max_iterations": 100, "tolerance": 0.0,
      "regularization": "l1", "regularization_weight": 0.5},
 ], ids=["tron", "owlqn"])
 def test_tron_and_owlqn_run_on_panels(optimizer):
@@ -405,6 +422,7 @@ def panel_pair(multichip):
     mesh = make_mesh({"batch": 4, "model": 2})
     placed = psharding.place_batch(pack_design(sb, shards=4), mesh)
     assert placed.shard == (mesh, "batch") and placed.hot.shard == placed.shard
+    assert one.hot.strided and placed.hot.strided and placed.hot.rlo is None
     assert placed.parts[0].vals.sharding.spec == P("batch")
     assert placed.order.sharding.is_fully_replicated
     return one, placed
@@ -478,6 +496,9 @@ def test_layout_counters_and_spans():
     assert c["layout.nnz"] == len(vals) == sum(packed.stored)
     assert c["layout.nnz.hot"] == packed.stored[0]
     assert c["layout.slots"] == packed.nnz_slots
+    assert packed.hot.strided
+    assert c["layout.tiles.strided"] == packed.hot.num_tiles
+    assert "layout.tiles.sorted" not in c
     assert g["layout.padding_ratio"] == pytest.approx(
         packed.nnz_slots / len(vals))
     names = {s.name for s in telemetry.finished_spans()}

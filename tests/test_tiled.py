@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from photon_ml_tpu.ops import tiled
 from photon_ml_tpu.ops.objective import make_objective
 from photon_ml_tpu.ops.sparse import SparseBatch
 from photon_ml_tpu.ops.tiled import TiledBatch
@@ -21,6 +22,21 @@ from photon_ml_tpu.optim import (
     lbfgs_solve,
     tron_solve,
 )
+
+
+ASSIGNMENTS = ["strided", "sorted"]
+
+
+def _assigned(mp, how):
+    """Hold ``pack_coo``'s rule to one row assignment: the rule is the
+    design's own (no option says it), so a test steers it here."""
+    mp.setattr(tiled, "strided_is_cheaper", lambda *_: how == "strided")
+
+
+@pytest.fixture(params=ASSIGNMENTS)
+def assignment(request, monkeypatch):
+    _assigned(monkeypatch, request.param)
+    return request.param
 
 
 def _problem(rng, n=300, f=37, density=0.3, weights=True):
@@ -144,9 +160,11 @@ def test_tron_solve_matches_sparse_path(rng):
                                rtol=1e-4)
 
 
-def test_from_batch_roundtrip(rng):
+def test_from_batch_roundtrip(rng, assignment):
     sb, _ = _problem(rng, n=100, f=16)
     tb = TiledBatch.from_batch(sb)
+    assert tb.strided == (assignment == "strided")
+    assert (tb.rlo is None) == tb.strided
     dense_sb = sb.to_dense()
     dense_tb = tb.to_dense()[: sb.num_rows]
     np.testing.assert_allclose(dense_tb, dense_sb, rtol=1e-6)
@@ -195,10 +213,18 @@ _EDGE_SHAPES = {
 }
 
 
-@pytest.fixture(scope="module", params=list(_EDGE_SHAPES))
+@pytest.fixture(scope="module", params=[
+    f"{shape}-{how}" for shape in _EDGE_SHAPES for how in ASSIGNMENTS])
 def edge(request):
-    n, f, counts = _EDGE_SHAPES[request.param]
-    rng = np.random.default_rng(sorted(_EDGE_SHAPES).index(request.param))
+    shape, how = request.param.rsplit("-", 1)
+    with pytest.MonkeyPatch.context() as mp:
+        _assigned(mp, how)
+        return _edge_design(shape, how)
+
+
+def _edge_design(shape, how):
+    n, f, counts = _EDGE_SHAPES[shape]
+    rng = np.random.default_rng(sorted(_EDGE_SHAPES).index(shape))
     rows, cols = [], []
     for t, c in enumerate(counts):
         hi_row = min(n, (t + 1) * 128)
@@ -210,7 +236,12 @@ def edge(request):
         values=values, rows=rows, cols=cols, num_features=f,
         labels=(rng.random(n) > 0.5).astype(np.float64),
         offsets=rng.normal(size=n) * 0.1, weights=rng.random(n) + 0.5)
-    assert tb.vals.shape == (len(counts), 1, -(-max(counts) // 128) * 128)
+    if how == "sorted":
+        slots = -(-max(counts) // 128) * 128
+    else:
+        slots = 128 * int(np.bincount(rows).max())
+        assert tb.rlo is None
+    assert tb.vals.shape == (len(counts), 1, slots)
 
     def f32(x):
         return jnp.asarray(x, jnp.float32)
@@ -306,3 +337,125 @@ def test_exactness_limit_rejects_a_single_bfloat16_pass(edge):
     exact = X @ _f64(w)
     once = X @ _f64(w.astype(jnp.bfloat16))
     assert np.linalg.norm(once - exact) / np.linalg.norm(exact) > 10 * EXACT_REL
+
+
+# -- which row assignment a design gets, and that both are the same design --
+
+
+def _ragged(rng, n=300, f=500, long_row=77, long_nnz=500, short_nnz=5):
+    """Rows of ``short_nnz`` nonzeros and one of ``long_nnz``, row-sorted."""
+    lengths = np.full(n, short_nnz)
+    lengths[long_row] = long_nnz
+    rows = np.repeat(np.arange(n), lengths)
+    cols = np.concatenate([rng.permutation(f)[:k] for k in lengths])
+    return rng.normal(size=len(rows)), rows, cols
+
+
+def _arrival_order_leaves(values, rows, cols, num_tiles, num_features):
+    """The sorted layout as its definition reads, slot by slot."""
+    S = -(-int(np.bincount(rows // 128, minlength=num_tiles).max()) // 128) * 128
+    vals = np.zeros((num_tiles, 1, S), np.float32)
+    hi = np.full((num_tiles, 1, S), -(-num_features // 128), np.int32)
+    lo = np.zeros((num_tiles, 1, S), np.int32)
+    rlo = np.zeros((num_tiles, 1, S), np.int32)
+    filled = np.zeros(num_tiles, int)
+    for v, r, c in zip(values, rows, cols):
+        t, k = r // 128, filled[r // 128]
+        vals[t, 0, k], hi[t, 0, k], lo[t, 0, k] = v, c // 128, c % 128
+        rlo[t, 0, k] = r % 128
+        filled[t] += 1
+    return vals, hi, lo, rlo
+
+
+def test_a_long_row_among_short_ones_stays_sorted(rng):
+    """One row of 500 nonzeros among rows of 5: strided would store 128 x
+    500 slots a tile for ~1,100 nonzeros, so the rule keeps the arrival-order
+    layout, leaf for leaf what the packer made before it had a choice."""
+    values, rows, cols = _ragged(rng)
+    tb = TiledBatch.pack_coo(values, rows, cols, np.zeros(300), 500)
+    assert not tb.strided
+    want = _arrival_order_leaves(values, rows, cols, 3, 500)
+    for got, leaf in zip((tb.vals, tb.hi, tb.lo, tb.rlo), want):
+        np.testing.assert_array_equal(got, leaf)
+    # and the same design read back
+    X = np.zeros((384, 500))
+    X[rows, cols] = values.astype(np.float32)
+    np.testing.assert_array_equal(tb.to_dense(), X)
+
+
+@pytest.mark.parametrize("nnz_per_row,num_features,strided", [
+    (20, 10_000, True),    # constant rows: S stays 2,560, one pass fewer
+    (39, 4_096, True),     # the hot panel's table at the weight-load floor
+    (1, 100, True),
+])
+def test_constant_length_rows_go_strided(rng, nnz_per_row, num_features,
+                                         strided):
+    n = 300
+    rows = np.repeat(np.arange(n), nnz_per_row)
+    cols = rng.integers(0, num_features, size=len(rows))
+    tb = TiledBatch.pack_coo(
+        np.ones(len(rows)), rows, cols, np.zeros(n), num_features)
+    assert tb.strided == strided and tb.rlo is None
+    assert tb.vals.shape == (3, 1, 128 * nnz_per_row)
+
+
+def test_the_rule_weighs_slots_against_the_pass_saved():
+    # B = 79: a 160-row pass is 26.7 ns a 128 slots, the rt pass 15
+    assert tiled.strided_is_cheaper(2560, 2560, 79)
+    assert tiled.strided_is_cheaper(3968, 2560, 79)       # 1.55x the slots
+    assert not tiled.strided_is_cheaper(4096, 2560, 79)   # 1.6x
+    # B = 32: both passes on the floor, so up to twice the slots
+    assert tiled.strided_is_cheaper(4992, 4352, 32)
+    assert tiled.strided_is_cheaper(8704, 4352, 32)
+    assert not tiled.strided_is_cheaper(8832, 4352, 32)
+
+
+def test_unsorted_coo_packs_strided(rng, monkeypatch):
+    """Nonzeros in no order at all: slot k*128 + r still holds the k-th
+    nonzero of row r in INPUT order, and the design is the same design."""
+    _assigned(monkeypatch, "strided")
+    values, rows, cols = _ragged(rng, long_nnz=9)
+    shuffle = rng.permutation(len(rows))
+    v, r, c = values[shuffle], rows[shuffle], cols[shuffle]
+    tb = TiledBatch.pack_coo(v, r, c, np.zeros(300), 500)
+    assert tb.strided and tb.vals.shape == (3, 1, 128 * 9)
+    X = np.zeros((384, 500))
+    X[r, c] = v.astype(np.float32)
+    np.testing.assert_array_equal(tb.to_dense(), X)
+    slots = np.asarray(tb.vals).reshape(3, 9, 128)
+    for row in (0, 77, 299):
+        mine = v[r == row].astype(np.float32)
+        got = slots[row // 128, :, row % 128]
+        np.testing.assert_array_equal(got[:len(mine)], mine)
+        assert not got[len(mine):].any()
+    w = jnp.asarray(rng.normal(size=500), jnp.float32)
+    ordered = TiledBatch.pack_coo(values, rows, cols, np.zeros(300), 500)
+    np.testing.assert_allclose(
+        np.asarray(tb.device().dot_rows(w)),
+        np.asarray(ordered.device().dot_rows(w)), rtol=1e-5, atol=1e-5)
+
+
+def test_both_assignments_are_one_design(rng, monkeypatch):
+    """The same nonzeros packed both ways: one dense matrix, and every pass
+    agrees to float32 rounding."""
+    values, rows, cols = _ragged(rng, long_nnz=12)
+    labels = (rng.random(300) > 0.5).astype(float)
+    designs = {}
+    for how in ASSIGNMENTS:
+        _assigned(monkeypatch, how)
+        designs[how] = TiledBatch.from_coo(values, rows, cols, labels, 500)
+    a, b = designs["strided"], designs["sorted"]
+    assert a.strided and not b.strided
+    assert a.nnz_slots == 3 * 128 * 12 and b.nnz_slots < a.nnz_slots
+    np.testing.assert_array_equal(a.to_dense(), b.to_dense())
+    w = jnp.asarray(rng.normal(size=500), jnp.float32)
+    per_row = jnp.asarray(rng.normal(size=384), jnp.float32)
+    for got, want in [
+        (a.margins(w, 0.3), b.margins(w, 0.3)),
+        (a.scatter_features(per_row), b.scatter_features(per_row)),
+        (a.fused_value_grad(w * 0.05, 0.1, "logistic")[1],
+         b.fused_value_grad(w * 0.05, 0.1, "logistic")[1]),
+        (a.feature_moment_sums()[2], b.feature_moment_sums()[2]),
+    ]:
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-4)
