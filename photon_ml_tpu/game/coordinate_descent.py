@@ -22,6 +22,8 @@ incremental path, a benchmark — gets it, because it opens here)::
         coordinate:<name>     history ``seconds`` = its start -> score fetch
           residual            only with more than one coordinate
           update              to the end of the tracker's and guard's fetch
+            re_bucket:<R>x<K>   a random effect only: one bucket's dispatch
+            re_tracker          and the wait on every bucket's solve
           score               coord.score + its 1-element fetch
           validate            validation scoring, evaluators, their fetches
             validation_layout   first validation of a dataset only: its rows

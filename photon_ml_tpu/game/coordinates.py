@@ -616,23 +616,48 @@ def _pad_constraints(cons: Optional[BoxConstraints], total: int):
     )
 
 
-@lru_cache(maxsize=64)
+def _add_bucket_scores(scores: Array, row_index: Array, margins: Array):
+    """``scores`` [n] plus a bucket's per-entity margins [E, R], each at its
+    example row (``row_index`` [E, R], -1 on padding)."""
+    idx = row_index.reshape(-1)
+    return scores.at[jnp.maximum(idx, 0)].add(
+        jnp.where(idx >= 0, margins.reshape(-1), 0.0)
+    )
+
+
+@lru_cache(maxsize=1)
 def _re_scorer():
-    def score_bucket(coeffs, bucket_batch):
-        # per-entity margins x.w (no offsets) -> [E, R]
-        return jax.vmap(lambda w, b: b.dot_rows(w))(coeffs, bucket_batch)
+    def score_bucket(coeffs, bucket_batch, row_index, scores):
+        # per-entity margins x.w (no offsets) [E, R], added at their rows
+        margins = jax.vmap(lambda w, b: b.dot_rows(w))(coeffs, bucket_batch)
+        return _add_bucket_scores(scores, row_index, margins)
 
     return instrumented_jit(score_bucket, name="re_score", multi_shape=True)
 
 
-@lru_cache(maxsize=8)
+@lru_cache(maxsize=1)
 def _re_dense_scorer():
-    def score(coeffs, x_flat):
+    def score(coeffs, x_flat, row_index, scores):
         E, K = coeffs.shape
         x = x_flat.reshape(E, -1, K)
-        return jnp.einsum("erk,ek->er", x, coeffs)
+        margins = jnp.einsum(
+            "erk,ek->er", x, coeffs, precision=jax.lax.Precision.HIGHEST
+        )
+        return _add_bucket_scores(scores, row_index, margins)
 
     return instrumented_jit(score, name="re_score_dense", multi_shape=True)
+
+
+@lru_cache(maxsize=1)
+def _re_offsets():
+    """A bucket's offsets [E, R] plus the other coordinates' scores at its
+    rows (``EntityBucket.with_extra_offsets``, as one named program: eagerly
+    it was four one-op programs a bucket and update)."""
+
+    def offsets(bucket, per_row):
+        return bucket.with_extra_offsets(per_row).offsets
+
+    return instrumented_jit(offsets, name="re_offsets", multi_shape=True)
 
 
 def _packed_dense_batch(packed, w0):
@@ -671,17 +696,17 @@ def _bucket_dense_design(b: EntityBucket) -> Optional[np.ndarray]:
     if dense_bytes > max(64 << 20, _DENSE_BYTES_FACTOR * coo_bytes):
         return None
     vals = np.asarray(b.values)
-    rows = np.asarray(b.rows, np.int64)
-    cols = np.asarray(b.cols, np.int64)
-    e_idx = np.broadcast_to(
-        np.arange(E, dtype=np.int64)[:, None] * (R * K), rows.shape
+    e, slot = np.nonzero(vals)  # padded nnz carry value 0: nothing to add
+    flat = (
+        e * (R * K)
+        + np.asarray(b.rows)[e, slot].astype(np.int64) * K
+        + np.asarray(b.cols)[e, slot]
     )
-    flat = (e_idx + rows * K + cols).ravel()
-    # padded nnz carry value 0 -> accumulate harmlessly (bincount is the
-    # fast vectorized scatter-add; np.add.at is unbuffered/slow)
-    x = np.bincount(
-        flat, weights=vals.ravel(), minlength=E * R * K
-    ).astype(np.float32)
+    # scatter-ADD (a row may hold a column twice) straight into float32:
+    # numpy's indexed add is several times a float64 bincount over every
+    # cell + its cast, which was a third of a coordinate's layout seconds
+    x = np.zeros(E * R * K, np.float32)
+    np.add.at(x, flat, vals[e, slot].astype(np.float32))
     return x.reshape(E, R * K)
 
 
@@ -720,6 +745,15 @@ class RandomEffectCoordinate:
         # device bucket copies skip the COO arrays where dense is active
         self._dense_x = self.re_data.dense_designs()
         self._buckets = self.re_data.device_buckets_for_dense()
+        # per bucket and entity, its own rows x its own local features: the
+        # design cells one pass of its solve has to read (_report_stragglers)
+        self._entity_cells = [
+            np.count_nonzero(hb.row_index >= 0, axis=1)
+            * np.count_nonzero(
+                hb.projection < hb.num_global_features, axis=1)
+            for hb in self.re_data.buckets
+        ]
+        self._report_layout()
         # Box constraints are declared against GLOBAL feature ids
         # (OptimizerConfig constraintMap); each entity's local space is an
         # index-map renumbering (local k <-> global projection[e, k]), so the
@@ -768,6 +802,29 @@ class RandomEffectCoordinate:
         self.health_check = False
         self.last_health = None
 
+    def _report_layout(self) -> None:
+        """Counters ``re.<name>.*`` (and their sums over the coordinates,
+        ``re.*``): what the geometry buckets hold and what their rounding
+        costs. ``rows`` / ``nnz`` are the entities' own, ``rows_padded`` the
+        [E, R] slots, ``nnz_padded`` the design values as stored (E*R*K of
+        a dense-routed bucket, E*NZ of a COO one)."""
+        totals = dict.fromkeys(
+            ("entities", "rows", "rows_padded", "nnz", "nnz_padded",
+             "buckets"), 0)
+        for hb, x in zip(self.re_data.buckets, self._dense_x):
+            E, R = hb.num_entities, hb.rows_per_entity
+            totals["entities"] += E
+            totals["rows"] += int(np.count_nonzero(hb.row_index >= 0))
+            totals["rows_padded"] += E * R
+            totals["nnz"] += int(np.count_nonzero(hb.values))
+            totals["nnz_padded"] += (
+                E * hb.values.shape[1] if x is None
+                else E * R * hb.num_local_features)
+            totals["buckets"] += 1
+        for key, value in totals.items():
+            counter(f"re.{self.name}.{key}").inc(value)
+            counter(f"re.{key}").inc(value)
+
     def initialize_model(self) -> RandomEffectModel:
         # dtype from the HOST buckets: dense-routed device buckets carry
         # f32 placeholder stubs in `values`, not the dataset's dtype
@@ -790,6 +847,67 @@ class RandomEffectCoordinate:
             vocab=self.data.id_columns[self.re_data.id_name].vocab,
         )
 
+    def _solve_bucket(self, i: int, b, w0: Array, obj, residual_scores):
+        """Dispatch bucket ``i``'s vmapped solve from ``w0``. Returns
+        ``(SolveResult, coefficients [E, K], variances or None)``."""
+        bucket = b
+        if residual_scores is not None:
+            bucket = dataclasses.replace(
+                b, offsets=_re_offsets()(b, residual_scores)
+            )
+        dense = self._dense_x[i] is not None
+        if dense:
+            # packed flat design + per-row arrays; reshaped to
+            # [E, R, K] INSIDE the solver jit (_packed_dense_batch)
+            bb = (
+                self._dense_x[i],
+                bucket.labels,
+                bucket.offsets,
+                bucket.weights,
+            )
+        else:
+            bb = bucket.entity_batch()
+        cons = self._bucket_constraints[i]
+        solver = self._dense_solver if dense else self._solver
+        if self.mesh is None:
+            res, var = solver(obj, bb, w0, self._l1, cons)
+            return res, res.w, var
+        n_dev = psharding.axis_size(self.mesh, self._axis)
+        num_e = w0.shape[0]
+        total = -(-num_e // n_dev) * n_dev
+        bb_p, w0_p = _pad_entities(bb, w0, total)
+        cons_p = _pad_constraints(cons, total)
+        bb_p, w0_p, cons_p = place_entity_solve(
+            self.mesh, self._axis, bb_p, w0_p, cons_p
+        )
+        record_entity_solve_comms(
+            "re_solve", self.mesh, self._axis, self.config.max_iterations
+        )
+        res, var = solver(obj, bb_p, w0_p, self._l1, cons_p)
+        return res, res.w[:num_e], None if var is None else var[:num_e]
+
+    def _report_stragglers(self, iterations: np.ndarray) -> None:
+        """Counters ``re.<name>.lane_iterations`` (what every entity's own
+        solve needed, summed) and ``.lane_iterations_run`` (a vmapped
+        ``while_loop`` runs every lane of a bucket to its slowest entity:
+        entities x the bucket's longest solve, summed), with their sums
+        over the coordinates ``re.lane_iterations*``; ``.pass_cells`` is
+        each entity's iterations x its own rows x its own local features,
+        the design cells its passes had to read. From the tracker's
+        per-entity iterations, which lie bucket after bucket."""
+        needed = run = cells = lo = 0
+        for own in self._entity_cells:
+            its = iterations[lo:lo + len(own)]
+            lo += len(own)
+            if len(its):
+                needed += int(its.sum())
+                run += int(its.max()) * len(its)
+                cells += int(np.dot(its.astype(np.int64), own))
+        for scope in (f"re.{self.name}", "re"):
+            counter(f"{scope}.lane_iterations").inc(needed)
+            counter(f"{scope}.lane_iterations_run").inc(run)
+            counter(f"{scope}.pass_cells").inc(cells)
+
     def update_model(
         self, model: RandomEffectModel, residual_scores: Optional[Array]
     ) -> RandomEffectModel:
@@ -801,53 +919,18 @@ class RandomEffectCoordinate:
         tracker_vals = []
         healths = []
         obj = damped_objective(self._obj, self.extra_l2)
-        n_dev = (
-            0 if self.mesh is None
-            else psharding.axis_size(self.mesh, self._axis)
-        )
         for i, (b, bm) in enumerate(zip(self._buckets, model.buckets)):
-            bucket = (
-                b if residual_scores is None else b.with_extra_offsets(residual_scores)
-            )
-            dense = self._dense_x[i] is not None
-            if dense:
-                # packed flat design + per-row arrays; reshaped to
-                # [E, R, K] INSIDE the solver jit (_packed_dense_batch)
-                bb = (
-                    self._dense_x[i],
-                    bucket.labels,
-                    bucket.offsets,
-                    bucket.weights,
+            # the class's dispatch (the solve runs on after it returns): in
+            # a trace, which bucket the device programs that follow belong to
+            with span(f"re_bucket:{b.rows_per_entity}x{b.num_local_features}"):
+                res, w, var = self._solve_bucket(
+                    i, b, bm.coefficients, obj, residual_scores
                 )
-            else:
-                bb = bucket.entity_batch()
-            w0 = bm.coefficients
-            cons = self._bucket_constraints[i]
-            solver = self._dense_solver if dense else self._solver
-            if self.mesh is None:
-                res, var = solver(obj, bb, w0, self._l1, cons)
-                w = res.w
-            else:
-                num_e = w0.shape[0]
-                total = -(-num_e // n_dev) * n_dev
-                bb_p, w0_p = _pad_entities(bb, w0, total)
-                cons_p = _pad_constraints(cons, total)
-                bb_p, w0_p, cons_p = place_entity_solve(
-                    self.mesh, self._axis, bb_p, w0_p, cons_p
-                )
-                record_entity_solve_comms(
-                    "re_solve", self.mesh, self._axis,
-                    self.config.max_iterations,
-                )
-                res, var = solver(obj, bb_p, w0_p, self._l1, cons_p)
-                w = res.w[:num_e]
-                if var is not None:
-                    var = var[:num_e]
             # keep only the tiny telemetry vectors (the full SolveResult
             # frees per bucket); stay ON DEVICE — every host fetch is a
             # wait on this bucket's solve, so both arrays cross in ONE
             # np.asarray each after a device-side concat
-            n_real = int(w0.shape[0])
+            n_real = int(w.shape[0])
             tracker_its.append(res.iterations[:n_real])
             tracker_reasons.append(res.reason[:n_real])
             tracker_vals.append(res.value[:n_real])
@@ -863,9 +946,13 @@ class RandomEffectCoordinate:
             if self.health_check
             else None
         )
-        self.last_tracker = RandomEffectOptimizationTracker.from_device_parts(
-            tracker_its, tracker_reasons, tracker_vals
-        )
+        with span("re_tracker"):  # the host's wait on every bucket's solve
+            self.last_tracker = (
+                RandomEffectOptimizationTracker.from_device_parts(
+                    tracker_its, tracker_reasons, tracker_vals
+                )
+            )
+        self._report_stragglers(self.last_tracker.iterations)
         if new_buckets:
             _record_placement(
                 f"{self.name}.coefficients", new_buckets[0].coefficients
@@ -879,14 +966,13 @@ class RandomEffectCoordinate:
         scores = jnp.zeros((n_pad,), jnp.float32)
         for i, (b, bm) in enumerate(zip(self._buckets, model.buckets)):
             if self._dense_x[i] is not None:
-                margins = _re_dense_scorer()(bm.coefficients, self._dense_x[i])
+                scores = _re_dense_scorer()(
+                    bm.coefficients, self._dense_x[i], b.row_index, scores
+                )
             else:
-                margins = self._scorer(bm.coefficients, b.entity_batch())  # [E, R]
-            idx = b.row_index.reshape(-1)
-            vals = margins.reshape(-1)
-            scores = scores.at[jnp.maximum(idx, 0)].add(
-                jnp.where(idx >= 0, vals, 0.0)
-            )
+                scores = self._scorer(
+                    bm.coefficients, b.entity_batch(), b.row_index, scores
+                )
         if len(self.re_data.passive_rows):
             passive_scores = model.score(self.data)
             mask = np.zeros(n_pad, bool)

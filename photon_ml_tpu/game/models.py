@@ -11,6 +11,7 @@ offsets enter only through training objectives and evaluator inputs.
 from __future__ import annotations
 
 import dataclasses
+from functools import lru_cache
 from typing import Mapping, Optional
 
 import jax
@@ -101,8 +102,15 @@ class RandomEffectModel:
     def _grouping_for(
         self, data: GameDataset
     ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
-        """(codes, row_bucket, row_pos) host arrays for ``data`` — the
-        O(n log V) vocabulary join and bucket/position placement.
+        """(codes, row_bucket, row_pos) host arrays for ``data``."""
+        entry = self._grouping_entry(data)
+        return entry["codes"], entry["row_bucket"], entry["row_pos"]
+
+    def _grouping_entry(self, data: GameDataset) -> dict:
+        """The cached grouping of ``data`` for this model's tables:
+        ``codes``, ``row_bucket``, ``row_pos`` host arrays — the
+        O(n log V) vocabulary join and bucket/position placement — and
+        whatever :meth:`score` keeps beside them.
 
         Memoized per (model, dataset): repeated scoring of the same
         dataset (validation every CD iteration, the serving registry's
@@ -125,7 +133,7 @@ class RandomEffectModel:
             and entry["entity_pos"] is self.entity_pos
         ):
             telemetry.counter("scoring.code_cache.hits").inc()
-            return entry["codes"], entry["row_bucket"], entry["row_pos"]
+            return entry
         telemetry.counter("scoring.code_cache.misses").inc()
         idc = data.id_columns[self.id_name]
         codes = map_vocab_codes(self.vocab, idc.vocab[idc.codes])
@@ -141,7 +149,7 @@ class RandomEffectModel:
             "row_bucket": row_bucket,
             "row_pos": row_pos,
         }
-        return codes, row_bucket, row_pos
+        return cache[key]
 
     def to_summary_string(self) -> str:
         n_models = int(np.sum(self.entity_bucket >= 0))
@@ -152,76 +160,103 @@ class RandomEffectModel:
             f"buckets={len(self.buckets)}, local_dims={dims})"
         )
 
-    def score(self, data: GameDataset) -> Array:
-        """Scores for every example row; entities without a model score 0.
-
-        Device kernel per bucket: rows are grouped by entity bucket on host,
-        then each nnz looks up its coefficient by binary search over the
-        entity's sorted projection (searchsorted), multiplies and
-        segment-sums. Entities unseen in training contribute nothing —
-        matching the reference's behavior of scoring only entities with
-        models (RandomEffectModel joins by entity id).
-        """
-        if data.id_columns.get(self.id_name) is None:
-            raise KeyError(f"scoring data lacks id column '{self.id_name}'")
+    def _score_chunks(self, data: GameDataset) -> list:
+        """``data``'s nonzeros grouped by this model's buckets, in chunks
+        of at most ``SCORE_CHUNK``, on the device: [(bucket, values, rows,
+        global columns, entity positions)]. Built and uploaded once a
+        dataset and table placement and kept with the grouping (validation
+        scores the same rows after every coordinate update; the per-bucket
+        masks over every nonzero and their upload were most of a call)."""
+        entry = self._grouping_entry(data)
+        chunks = entry.setdefault("chunks", {}).get(SCORE_CHUNK)
+        if chunks is not None:
+            return chunks
         batch = data.shard(self.shard_name)
         n = data.num_rows
-        # host [n] arrays, -1 for unseen entities; memoized per dataset
-        _codes, row_bucket, row_pos = self._grouping_for(data)
-
+        row_bucket, row_pos = entry["row_bucket"], entry["row_pos"]
         vals = np.asarray(batch.values)
         rows = np.asarray(batch.rows)
         cols = np.asarray(batch.cols)
         live = (vals != 0) & (rows < n)
-
-        # nnz are processed in bounded chunks: the per-nnz [*, K] / [K, *]
-        # gathers otherwise materialize O(total_nnz x 128)-padded fusion
-        # outputs (a 20M-row shard measured a 51 GB allocation attempt)
-        scores = jnp.zeros((batch.num_rows,), dtype=batch.dtype)
-        for b_idx, bm in enumerate(self.buckets):
-            sel = live & (row_bucket[np.minimum(rows, n - 1)] == b_idx)
-            if not np.any(sel):
-                continue
-            sel_idx = np.nonzero(sel)[0]
-            K = bm.projection.shape[1]
+        bucket_of_nnz = np.where(live, row_bucket[np.minimum(rows, n - 1)], -1)
+        chunks = []
+        for b_idx in range(len(self.buckets)):
+            sel_idx = np.nonzero(bucket_of_nnz == b_idx)[0]
+            # nnz are processed in bounded chunks: the per-nnz [*, K] /
+            # [K, *] gathers otherwise materialize O(total_nnz x 128)-padded
+            # fusion outputs (a 20M-row shard measured a 51 GB allocation
+            # attempt)
             for lo in range(0, len(sel_idx), SCORE_CHUNK):
                 part = sel_idx[lo:lo + SCORE_CHUNK]
-                v = jnp.asarray(vals[part], batch.dtype)
-                r = jnp.asarray(rows[part], jnp.int32)
-                g = jnp.asarray(cols[part], jnp.int32)
-                pos = jnp.asarray(row_pos[rows[part]], jnp.int32)
+                chunks.append((
+                    b_idx,
+                    jnp.asarray(vals[part], batch.dtype),
+                    jnp.asarray(rows[part], jnp.int32),
+                    jnp.asarray(cols[part], jnp.int32),
+                    jnp.asarray(row_pos[rows[part]], jnp.int32),
+                ))
+        entry["chunks"][SCORE_CHUNK] = chunks
+        return chunks
 
-                if K <= 64:
-                    # TRANSPOSED compare-scan: [K, m] keeps the long nnz
-                    # dim in lanes (a [m, K] gather pads lanes 128/K-fold
-                    # — at K=4 that is 32x pure padding); each column
-                    # matches at most one projection slot, so the masked
-                    # sum IS the lookup
-                    proj_t = jnp.asarray(bm.projection).T[:, pos]  # [K, m]
-                    coef_t = bm.coefficients.T[:, pos]  # [K, m]
-                    w = jnp.sum(
-                        jnp.where(proj_t == g[None, :], coef_t, 0.0),
-                        axis=0,
-                    )
-                else:
-                    proj_rows = bm.projection[pos]  # [m, K]
-                    k = jax.vmap(jnp.searchsorted)(proj_rows, g)  # [m]
-                    k = jnp.minimum(k, K - 1)
-                    hit = (
-                        jnp.take_along_axis(
-                            proj_rows, k[:, None], axis=1
-                        )[:, 0]
-                        == g
-                    )
-                    w = jnp.where(
-                        hit,
-                        jnp.take_along_axis(
-                            bm.coefficients[pos], k[:, None], axis=1
-                        )[:, 0],
-                        0.0,
-                    )
-                scores = scores.at[r].add(v * w)
+    def score(self, data: GameDataset) -> Array:
+        """Scores for every example row; entities without a model score 0.
+
+        Device program per bucket chunk (``re_score_rows``): rows are
+        grouped by entity bucket on host, once a dataset, then each nnz
+        looks up its coefficient in the entity's sorted projection,
+        multiplies and adds at its row. Entities unseen in training
+        contribute nothing — matching the reference's behavior of scoring
+        only entities with models (RandomEffectModel joins by entity id).
+        """
+        if data.id_columns.get(self.id_name) is None:
+            raise KeyError(f"scoring data lacks id column '{self.id_name}'")
+        batch = data.shard(self.shard_name)
+        scores = jnp.zeros((batch.num_rows,), dtype=batch.dtype)
+        scorer = _row_scorer()
+        for b_idx, v, r, g, pos in self._score_chunks(data):
+            bm = self.buckets[b_idx]
+            scores = scorer(
+                scores, bm.coefficients, bm.projection, v, r, g, pos
+            )
         return scores
+
+
+@lru_cache(maxsize=1)
+def _row_scorer():
+    """``scores`` plus one chunk of nonzeros' value x coefficient, each at
+    its row: one named program a chunk shape (eagerly it was a dozen one-op
+    programs a bucket, each a compile of its own). Elementwise float32: no
+    product here goes through the MXU."""
+
+    def score_rows(scores, coefficients, projection, v, r, g, pos):
+        K = projection.shape[1]
+        if K <= 64:
+            # TRANSPOSED compare-scan: [K, m] keeps the long nnz dim in
+            # lanes (a [m, K] gather pads lanes 128/K-fold — at K=4 that
+            # is 32x pure padding); each column matches at most one
+            # projection slot, so the masked sum IS the lookup
+            proj_t = projection.T[:, pos]  # [K, m]
+            coef_t = coefficients.T[:, pos]  # [K, m]
+            w = jnp.sum(
+                jnp.where(proj_t == g[None, :], coef_t, 0.0), axis=0
+            )
+        else:
+            proj_rows = projection[pos]  # [m, K]
+            k = jax.vmap(jnp.searchsorted)(proj_rows, g)  # [m]
+            k = jnp.minimum(k, K - 1)
+            hit = jnp.take_along_axis(proj_rows, k[:, None], axis=1)[:, 0] == g
+            w = jnp.where(
+                hit,
+                jnp.take_along_axis(coefficients[pos], k[:, None], axis=1)[
+                    :, 0
+                ],
+                0.0,
+            )
+        return scores.at[r].add(v * w)
+
+    return telemetry.instrumented_jit(
+        score_rows, name="re_score_rows", multi_shape=True
+    )
 
 
 @dataclasses.dataclass(frozen=True)

@@ -7,10 +7,15 @@ projector/IndexMapProjectorRDD.scala:27-77).
 
 Where Spark bin-packs entities into JVM partitions and runs heterogeneous
 per-entity solves, XLA needs fixed shapes: entities are grouped into
-geometry buckets keyed by (rows, nnz, local-feature-count) rounded up to
-powers of two. Each bucket is a stack of same-shaped per-entity sparse
-problems solved by ONE vmapped optimizer call; bucket count is
-O(log^3 of the size spread), bounding recompilation.
+geometry buckets keyed by (rows, local-feature-count) rounded up to powers
+of two; a bucket's nnz width is its own fullest entity's, rounded up the
+same way. Each bucket is a stack of same-shaped per-entity sparse problems
+solved by ONE vmapped optimizer call. A heavy-tailed id column (rows per
+entity from 1 to 1e5) spreads over dozens of such classes, each a compile
+of the solver and of the scorer, so the classes of one coordinate are
+MERGED, cheapest pair first, until at most ``MAX_GEOMETRY_CLASSES`` are
+left (:func:`merge_geometry_classes`): that is the bound on recompilation.
+No row and no entity is dropped to get there: a merge only pads.
 
 Per-entity index-map projection (the reference's key scaling trick —
 projector/README.md says it reaches ~1e8 entities x ~1e3 features): each
@@ -42,6 +47,63 @@ Array = jax.Array
 
 def _next_pow2(x: int) -> int:
     return 1 if x <= 1 else 1 << (int(x - 1).bit_length())
+
+
+#: the most geometry buckets one random-effect coordinate may have: each
+#: is one compile of ``re_solve*`` and one of ``re_score*``
+MAX_GEOMETRY_CLASSES = 8
+#: what one entity's K x K factorisation costs a Newton iteration, in units
+#: of one design cell read three times: XLA's batched Cholesky took 1.6 us
+#: a 16 x 16 matrix on a v5e (PERF.md section 7, PR 23's reading) against
+#: ~24 ps a cell for the passes over the design, so K**3 cells x 16
+_FACTOR_CELLS = 16.0
+
+
+def _class_cost(E, R, K):
+    """Work of one Newton iteration over a class of E entities padded to
+    R rows and K local features, in design cells: the passes over the
+    [R, K] design and the K x K factorisation."""
+    return E * (R * K + _FACTOR_CELLS * K ** 3)
+
+
+def merge_geometry_classes(
+    classes: np.ndarray, counts: np.ndarray, max_classes: int
+) -> np.ndarray:
+    """Merge (R, K) geometry classes until at most ``max_classes`` stand.
+
+    ``classes`` is [C, 2] (rows, local features per entity, both already
+    rounded up), ``counts`` [C] the entities of each. Two classes merge
+    into (max R, max K); each round merges the pair whose merge adds the
+    least work (:func:`_class_cost`: thin classes and classes that differ
+    in R alone go first, a merge across K pays the factorisation at the
+    wider K and goes last). Returns [C]: for each input class, the index
+    (into ``classes``) of the class it ended in. C is tens, so the search
+    over pairs is nothing.
+    """
+    live = {
+        i: (int(r), int(k), int(e))
+        for i, ((r, k), e) in enumerate(zip(classes, counts))
+    }
+    target = np.arange(len(classes))
+    while len(live) > max(int(max_classes), 1):
+        best = None
+        keys = sorted(live)
+        for x, a in enumerate(keys):
+            ra, ka, ea = live[a]
+            for b in keys[x + 1:]:
+                rb, kb, eb = live[b]
+                r, k = max(ra, rb), max(ka, kb)
+                extra = (
+                    _class_cost(ea + eb, r, k)
+                    - _class_cost(ea, ra, ka) - _class_cost(eb, rb, kb)
+                )
+                if best is None or extra < best[0]:
+                    best = (extra, a, b, (r, k, ea + eb))
+        _, a, b, merged = best
+        live[a] = merged
+        del live[b]
+        target[target == b] = a
+    return target
 
 
 @jax.tree_util.register_dataclass
@@ -158,7 +220,7 @@ class RandomEffectDataset:
         if cached is None:
             designs = []
             for b in self.buckets:
-                with span("layout"):
+                with span("layout"), span("re_layout.densify"):
                     x = _bucket_dense_design(b)
                 designs.append(
                     None if x is None
@@ -316,196 +378,225 @@ def build_random_effect_dataset(
     groupByKey (RandomEffectDataSetPartitioner.scala:96-148). Builds 100K
     entities / 1M rows in seconds (tests/test_re_build.py measures).
     """
-    if id_name not in data.id_columns:
-        raise KeyError(f"unknown id column '{id_name}'; have {sorted(data.id_columns)}")
-    idc = data.id_columns[id_name]
-    batch = data.shard(shard_name)
-    n = data.num_rows
-    num_global = batch.num_features
-    rng = np.random.default_rng(seed)
+    with span("re_layout.group"):  # sort, cap, regroup, project
+        if id_name not in data.id_columns:
+            raise KeyError(f"unknown id column '{id_name}'; have {sorted(data.id_columns)}")
+        idc = data.id_columns[id_name]
+        batch = data.shard(shard_name)
+        n = data.num_rows
+        num_global = batch.num_features
+        rng = np.random.default_rng(seed)
 
-    np_dtype = np.dtype(dtype)
-    vals = np.asarray(batch.values)
-    rows = np.asarray(batch.rows)
-    cols = np.asarray(batch.cols)
-    # valid nnz only (value != 0 excludes padding); drop padded-row nnz
-    live = (vals != 0) & (rows < n)
-    vals, rows, cols = vals[live], rows[live], cols[live]
+        np_dtype = np.dtype(dtype)
+        vals = np.asarray(batch.values)
+        rows = np.asarray(batch.rows)
+        cols = np.asarray(batch.cols)
+        # valid nnz only (value != 0 excludes padding); drop padded-row nnz
+        live = (vals != 0) & (rows < n)
+        vals, rows, cols = vals[live], rows[live], cols[live]
 
-    codes = np.asarray(idc.codes)  # [n]
+        codes = np.asarray(idc.codes)  # [n]
 
-    # --- active/passive row selection (vectorized reservoir cap) ---
-    # group rows by entity with a random within-group order: rank < cap keeps
-    # a uniform sample per entity (the reservoir-with-rescale semantics of
-    # RandomEffectDataSet.scala:294-357)
-    rand_key = rng.random(n)
-    grp_order = np.lexsort((rand_key, codes))  # entity-grouped, random within
-    g_codes = codes[grp_order]
-    uniq_codes, grp_starts, grp_counts = np.unique(
-        g_codes, return_index=True, return_counts=True
-    )
-    ent_of_pos = np.searchsorted(uniq_codes, g_codes)
-    rank_in_ent = np.arange(n) - grp_starts[ent_of_pos]
-
-    counts_of_pos = grp_counts[ent_of_pos]
-    active_pos = counts_of_pos >= min_rows_per_entity
-    weights = data.weight.copy()
-    cap = active_rows_per_entity
-    if cap is not None:
-        capped = counts_of_pos > cap
-        active_pos &= ~capped | (rank_in_ent < cap)
-        # weight rescale so the capped sample represents the full count
-        resc = capped & (rank_in_ent < cap)
-        weights[grp_order[resc]] *= counts_of_pos[resc] / cap
-    act_rows_unsorted = grp_order[active_pos]
-    passive_rows = np.sort(grp_order[~active_pos])
-
-    # --- regroup active rows sorted by (entity, row id) ---
-    act_codes_u = codes[act_rows_unsorted]
-    o = np.lexsort((act_rows_unsorted, act_codes_u))
-    act_rows = act_rows_unsorted[o]  # member rows, entity-major, row-sorted
-    act_codes = act_codes_u[o]
-    act_uniq, act_starts, act_counts = np.unique(
-        act_codes, return_index=True, return_counts=True
-    )
-    n_act = len(act_rows)
-    n_ent = len(act_uniq)
-    ent_of_row = np.searchsorted(act_uniq, act_codes)  # [n_act]
-    local_row = np.arange(n_act) - act_starts[ent_of_row]
-
-    # per global row: its local row id and entity index (-1 if inactive)
-    row_local = np.full(n, -1, np.int64)
-    row_local[act_rows] = local_row
-    row_ent = np.full(n, -1, np.int64)
-    row_ent[act_rows] = ent_of_row
-
-    # --- nnz of active rows, sorted by (entity, local row) ---
-    keep_nnz = row_ent[rows] >= 0
-    nv, nr, nc = vals[keep_nnz], rows[keep_nnz], cols[keep_nnz]
-    ne = row_ent[nr]
-    nlr = row_local[nr]
-    o2 = np.lexsort((nlr, ne))  # segment_sum contract: rows sorted per entity
-    nv, nc, ne, nlr, ngr = nv[o2], nc[o2], ne[o2], nlr[o2], nr[o2]
-
-    if features_to_samples_ratio is not None:
-        # per-entity Pearson feature selection for low-data entities
-        # (RandomEffectDataSet.scala:420-434)
-        keep = _pearson_keep_mask(
-            nv,
-            nc,
-            ne,
-            y_of_nnz=np.asarray(data.response)[ngr],
-            y_act=np.asarray(data.response)[act_rows],
-            ent_of_row=ent_of_row,
-            act_counts=act_counts,
-            num_global=num_global,
-            ratio=float(features_to_samples_ratio),
+        # --- active/passive row selection (vectorized reservoir cap) ---
+        # group rows by entity with a random within-group order: rank < cap keeps
+        # a uniform sample per entity (the reservoir-with-rescale semantics of
+        # RandomEffectDataSet.scala:294-357)
+        cap = active_rows_per_entity
+        if cap is None:
+            # no cap, no sample: the order within an entity decides nothing,
+            # and a stable sort leaves each entity's rows ascending
+            grp_order = np.argsort(codes, kind="stable")
+        else:
+            rand_key = rng.random(n)
+            # entity-grouped, random within
+            grp_order = np.lexsort((rand_key, codes))
+        g_codes = codes[grp_order]
+        uniq_codes, grp_starts, grp_counts = np.unique(
+            g_codes, return_index=True, return_counts=True
         )
-        nv, nc, ne, nlr = nv[keep], nc[keep], ne[keep], nlr[keep]
+        ent_of_pos = np.searchsorted(uniq_codes, g_codes)
+        rank_in_ent = np.arange(n) - grp_starts[ent_of_pos]
 
-    nnz_counts = np.bincount(ne, minlength=n_ent).astype(np.int64)
-    nnz_starts = np.concatenate([[0], np.cumsum(nnz_counts)[:-1]])
-    slot = np.arange(len(nv)) - nnz_starts[ne]
+        counts_of_pos = grp_counts[ent_of_pos]
+        active_pos = counts_of_pos >= min_rows_per_entity
+        weights = data.weight.copy()
+        if cap is not None:
+            capped = counts_of_pos > cap
+            active_pos &= ~capped | (rank_in_ent < cap)
+            # weight rescale so the capped sample represents the full count
+            resc = capped & (rank_in_ent < cap)
+            weights[grp_order[resc]] *= counts_of_pos[resc] / cap
+        act_rows_unsorted = grp_order[active_pos]
+        passive_rows = np.sort(grp_order[~active_pos])
 
-    # --- per-entity projection: unique observed global cols ---
-    pair_key = ne * np.int64(num_global) + nc
-    uniq_pairs = np.unique(pair_key)
-    proj_ent = uniq_pairs // num_global
-    proj_col = (uniq_pairs % num_global).astype(np.int64)
-    proj_counts = np.bincount(proj_ent, minlength=n_ent).astype(np.int64)
-    proj_starts = np.concatenate([[0], np.cumsum(proj_counts)[:-1]])
-    proj_slot = np.arange(len(uniq_pairs)) - proj_starts[proj_ent]
-    # local col id of each nnz = rank of its col in its entity's projection
-    local_col = np.searchsorted(uniq_pairs, pair_key) - nnz_starts_like(
-        proj_starts, ne
-    )
+        # --- regroup active rows sorted by (entity, row id) ---
+        act_codes_u = codes[act_rows_unsorted]
+        if cap is None:  # already entity-major with ascending rows
+            act_rows, act_codes = act_rows_unsorted, act_codes_u
+        else:
+            o = np.lexsort((act_rows_unsorted, act_codes_u))
+            act_rows = act_rows_unsorted[o]  # entity-major, row-sorted
+            act_codes = act_codes_u[o]
+        act_uniq, act_starts, act_counts = np.unique(
+            act_codes, return_index=True, return_counts=True
+        )
+        n_act = len(act_rows)
+        n_ent = len(act_uniq)
+        ent_of_row = np.searchsorted(act_uniq, act_codes)  # [n_act]
+        local_row = np.arange(n_act) - act_starts[ent_of_row]
 
-    # --- geometry classes ---
-    Rs = _next_pow2_arr(act_counts)
-    Ks = _next_pow2_arr(np.maximum(proj_counts, 1))
-    NZs = _next_pow2_arr(np.maximum(nnz_counts, 1))
-    geom = np.stack([Rs, Ks, NZs], axis=1)
-    classes, class_of_ent = np.unique(geom, axis=0, return_inverse=True)
-    # sort classes lexicographically by (R, K, NZ) to keep bucket order
-    class_order = np.lexsort((classes[:, 2], classes[:, 1], classes[:, 0]))
-    class_rank = np.empty(len(classes), np.int64)
-    class_rank[class_order] = np.arange(len(classes))
-    class_of_ent = class_rank[class_of_ent]
-    classes = classes[class_order]
+        # per global row: its local row id and entity index (-1 if inactive)
+        row_local = np.full(n, -1, np.int64)
+        row_local[act_rows] = local_row
+        row_ent = np.full(n, -1, np.int64)
+        row_ent[act_rows] = ent_of_row
 
-    # position of each entity within its bucket (order of appearance =
-    # ascending entity code, since act_uniq is sorted)
-    ent_pos = np.zeros(n_ent, np.int64)
-    for b_idx in range(len(classes)):
-        sel = class_of_ent == b_idx
-        ent_pos[sel] = np.arange(int(sel.sum()))
+        # --- nnz of active rows, sorted by (entity, local row) ---
+        keep_nnz = row_ent[rows] >= 0
+        nv, nr, nc = vals[keep_nnz], rows[keep_nnz], cols[keep_nnz]
+        # segment_sum contract: rows sorted per entity. (entity, local row)
+        # is the row's place among the active rows: one key, one stable sort
+        row_place = np.full(n, -1, np.int64)
+        row_place[act_rows] = np.arange(n_act)
+        o2 = np.argsort(row_place[nr], kind="stable")
+        nv, nc, ngr = nv[o2], nc[o2], nr[o2]
+        ne, nlr = row_ent[ngr], row_local[ngr]
 
-    num_entities = idc.num_entities
-    entity_bucket = np.full(num_entities, -1, np.int32)
-    entity_pos = np.full(num_entities, -1, np.int32)
-    entity_bucket[act_uniq] = class_of_ent
-    entity_pos[act_uniq] = ent_pos
-
-    response = data.response
-    offset = data.offset
-
-    buckets = []
-    for b_idx, (R, K, NZ) in enumerate(classes):
-        R, K, NZ = int(R), int(K), int(NZ)
-        esel = class_of_ent == b_idx
-        E = int(esel.sum())
-        bcode = act_uniq[esel].astype(np.int32)
-
-        bv = np.zeros((E, NZ))
-        br = np.full((E, NZ), R - 1, np.int32)
-        bc = np.zeros((E, NZ), np.int32)
-        bl = np.zeros((E, R))
-        bo = np.zeros((E, R))
-        bw = np.zeros((E, R))
-        bp = np.full((E, K), num_global, np.int32)
-        brix = np.full((E, R), -1, np.int32)
-
-        # rows of this class's entities
-        rsel = esel[ent_of_row]
-        d_e = ent_pos[ent_of_row[rsel]]
-        d_r = local_row[rsel]
-        src = act_rows[rsel]
-        bl[d_e, d_r] = response[src]
-        bo[d_e, d_r] = offset[src]
-        bw[d_e, d_r] = weights[src]
-        brix[d_e, d_r] = src
-
-        # nnz of this class's entities
-        zsel = esel[ne]
-        z_e = ent_pos[ne[zsel]]
-        z_s = slot[zsel]
-        bv[z_e, z_s] = nv[zsel]
-        br[z_e, z_s] = nlr[zsel]
-        bc[z_e, z_s] = local_col[zsel]
-
-        # projections of this class's entities
-        psel = esel[proj_ent]
-        p_e = ent_pos[proj_ent[psel]]
-        p_s = proj_slot[psel]
-        bp[p_e, p_s] = proj_col[psel]
-
-        # leaves stay HOST numpy (transfer-free build; coordinates upload
-        # once via RandomEffectDataset.device_buckets)
-        buckets.append(
-            EntityBucket(
-                values=bv.astype(np_dtype),
-                rows=br,
-                cols=bc,
-                labels=bl.astype(np_dtype),
-                offsets=bo.astype(np_dtype),
-                weights=bw.astype(np_dtype),
-                projection=bp,
-                entity_codes=bcode,
-                row_index=brix,
-                num_local_features=K,
-                num_global_features=num_global,
+        if features_to_samples_ratio is not None:
+            # per-entity Pearson feature selection for low-data entities
+            # (RandomEffectDataSet.scala:420-434)
+            keep = _pearson_keep_mask(
+                nv,
+                nc,
+                ne,
+                y_of_nnz=np.asarray(data.response)[ngr],
+                y_act=np.asarray(data.response)[act_rows],
+                ent_of_row=ent_of_row,
+                act_counts=act_counts,
+                num_global=num_global,
+                ratio=float(features_to_samples_ratio),
             )
+            nv, nc, ne, nlr = nv[keep], nc[keep], ne[keep], nlr[keep]
+
+        nnz_counts = np.bincount(ne, minlength=n_ent).astype(np.int64)
+        nnz_starts = np.concatenate([[0], np.cumsum(nnz_counts)[:-1]])
+        slot = np.arange(len(nv)) - nnz_starts[ne]
+
+        # --- per-entity projection: unique observed global cols ---
+        pair_key = ne * np.int64(num_global) + nc
+        uniq_pairs = np.unique(pair_key)
+        proj_ent = uniq_pairs // num_global
+        proj_col = (uniq_pairs % num_global).astype(np.int64)
+        proj_counts = np.bincount(proj_ent, minlength=n_ent).astype(np.int64)
+        proj_starts = np.concatenate([[0], np.cumsum(proj_counts)[:-1]])
+        proj_slot = np.arange(len(uniq_pairs)) - proj_starts[proj_ent]
+        # local col id of each nnz = rank of its col in its entity's projection
+        local_col = np.searchsorted(uniq_pairs, pair_key) - nnz_starts_like(
+            proj_starts, ne
         )
+
+    with span("re_layout.bucket"):  # classes, then one fill a class
+        # --- geometry classes: pow2 (R, K), merged down to a bounded number;
+        # a class's nnz width is its own fullest entity's ---
+        Rs = _next_pow2_arr(act_counts)
+        Ks = _next_pow2_arr(np.maximum(proj_counts, 1))
+        geom = np.stack([Rs, Ks], axis=1)
+        fine, fine_of_ent, fine_counts = np.unique(
+            geom, axis=0, return_inverse=True, return_counts=True
+        )
+        fine_of_ent = fine_of_ent.reshape(-1)
+        target = merge_geometry_classes(fine, fine_counts, MAX_GEOMETRY_CLASSES)
+        kept, class_of_fine = np.unique(target, return_inverse=True)
+        rk = np.array(
+            [[fine[target == t, 0].max(), fine[target == t, 1].max()]
+             for t in kept], np.int64,
+        ).reshape(len(kept), 2)
+        # np.unique sorted `fine` by (R, K); a merged class sits where its
+        # first member sat, so order the classes by their own (R, K) again
+        class_order = np.lexsort((rk[:, 1], rk[:, 0]))
+        class_rank = np.empty(len(kept), np.int64)
+        class_rank[class_order] = np.arange(len(kept))
+        class_of_ent = class_rank[class_of_fine[fine_of_ent]]
+        rk = rk[class_order]
+        nz_max = np.zeros(len(kept), np.int64)
+        np.maximum.at(nz_max, class_of_ent, nnz_counts)
+        classes = np.concatenate(
+            [rk, _next_pow2_arr(np.maximum(nz_max, 1))[:, None]], axis=1
+        )
+
+        # position of each entity within its bucket (order of appearance =
+        # ascending entity code, since act_uniq is sorted)
+        ent_pos = np.zeros(n_ent, np.int64)
+        for b_idx in range(len(classes)):
+            sel = class_of_ent == b_idx
+            ent_pos[sel] = np.arange(int(sel.sum()))
+
+        num_entities = idc.num_entities
+        entity_bucket = np.full(num_entities, -1, np.int32)
+        entity_pos = np.full(num_entities, -1, np.int32)
+        entity_bucket[act_uniq] = class_of_ent
+        entity_pos[act_uniq] = ent_pos
+
+        response = data.response
+        offset = data.offset
+
+        buckets = []
+        for b_idx, (R, K, NZ) in enumerate(classes):
+            R, K, NZ = int(R), int(K), int(NZ)
+            esel = class_of_ent == b_idx
+            E = int(esel.sum())
+            bcode = act_uniq[esel].astype(np.int32)
+
+            bv = np.zeros((E, NZ))
+            br = np.full((E, NZ), R - 1, np.int32)
+            bc = np.zeros((E, NZ), np.int32)
+            bl = np.zeros((E, R))
+            bo = np.zeros((E, R))
+            bw = np.zeros((E, R))
+            bp = np.full((E, K), num_global, np.int32)
+            brix = np.full((E, R), -1, np.int32)
+
+            # rows of this class's entities
+            rsel = esel[ent_of_row]
+            d_e = ent_pos[ent_of_row[rsel]]
+            d_r = local_row[rsel]
+            src = act_rows[rsel]
+            bl[d_e, d_r] = response[src]
+            bo[d_e, d_r] = offset[src]
+            bw[d_e, d_r] = weights[src]
+            brix[d_e, d_r] = src
+
+            # nnz of this class's entities
+            zsel = esel[ne]
+            z_e = ent_pos[ne[zsel]]
+            z_s = slot[zsel]
+            bv[z_e, z_s] = nv[zsel]
+            br[z_e, z_s] = nlr[zsel]
+            bc[z_e, z_s] = local_col[zsel]
+
+            # projections of this class's entities
+            psel = esel[proj_ent]
+            p_e = ent_pos[proj_ent[psel]]
+            p_s = proj_slot[psel]
+            bp[p_e, p_s] = proj_col[psel]
+
+            # leaves stay HOST numpy (transfer-free build; coordinates upload
+            # once via RandomEffectDataset.device_buckets)
+            buckets.append(
+                EntityBucket(
+                    values=bv.astype(np_dtype),
+                    rows=br,
+                    cols=bc,
+                    labels=bl.astype(np_dtype),
+                    offsets=bo.astype(np_dtype),
+                    weights=bw.astype(np_dtype),
+                    projection=bp,
+                    entity_codes=bcode,
+                    row_index=brix,
+                    num_local_features=K,
+                    num_global_features=num_global,
+                )
+            )
 
     return RandomEffectDataset(
         id_name=id_name,

@@ -34,6 +34,17 @@ from photon_ml_tpu.ops.losses import get_loss
 
 Array = jax.Array
 
+#: Every product of this layout is float32-grade where it is written: at
+#: jax's default precision a float32 matmul is ONE bfloat16 pass on a TPU,
+#: and per-entity coefficients then sit 3e-3 from the float32 solve's
+#: (PERF.md, Findings PR 30). No global setting can say so for them: Mosaic
+#: refuses the tiled kernels' bf16 dots under ``jax_default_matmul_precision``.
+_HI = jax.lax.Precision.HIGHEST
+
+
+def _mm(a: Array, b: Array) -> Array:
+    return jnp.matmul(a, b, precision=_HI)
+
 
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
@@ -86,13 +97,13 @@ class DenseBatch:
     # -- sweeps (SparseBatch duck-type) --------------------------------------
 
     def margins(self, w: Array, shift: Array | float = 0.0) -> Array:
-        return self.x @ w + shift + self.offsets
+        return _mm(self.x, w) + shift + self.offsets
 
     def dot_rows(self, w: Array) -> Array:
-        return self.x @ w
+        return _mm(self.x, w)
 
     def margins_pair(self, w, shift, p, p_shift):
-        zu = self.x @ jnp.stack([w, p], axis=1)        # [R, 2]
+        zu = _mm(self.x, jnp.stack([w, p], axis=1))    # [R, 2]
         return zu[:, 0] + shift + self.offsets, zu[:, 1] + p_shift
 
     def fused_value_grad(self, w, shift, loss_name: str):
@@ -100,25 +111,25 @@ class DenseBatch:
         z = self.margins(w, shift)
         l, dz = loss.loss_and_dz(z, self.labels)
         wdz = self.weights * dz
-        return jnp.sum(self.weights * l), wdz @ self.x, jnp.sum(wdz)
+        return jnp.sum(self.weights * l), _mm(wdz, self.x), jnp.sum(wdz)
 
     def fused_hessian_vector(self, w, shift, v, v_shift, loss_name: str):
         loss = get_loss(loss_name)
-        zu = self.x @ jnp.stack([w, v], axis=1)
+        zu = _mm(self.x, jnp.stack([w, v], axis=1))
         z = zu[:, 0] + shift + self.offsets
         u = zu[:, 1] + v_shift
         q = self.weights * loss.d2z(z, self.labels) * u
-        return q @ self.x, jnp.sum(q)
+        return _mm(q, self.x), jnp.sum(q)
 
     def fused_hv_at(self, d2_row, v, v_shift):
-        q = d2_row * (self.x @ v + v_shift)
-        return q @ self.x, jnp.sum(q)
+        q = d2_row * (_mm(self.x, v) + v_shift)
+        return _mm(q, self.x), jnp.sum(q)
 
     def scatter_features(self, per_row: Array) -> Array:
-        return per_row @ self.x
+        return _mm(per_row, self.x)
 
     def scatter_features_sq(self, per_row: Array) -> Array:
-        return per_row @ (self.x * self.x)
+        return _mm(per_row, self.x * self.x)
 
     def with_offsets(self, offsets: Array) -> "DenseBatch":
         return dataclasses.replace(

@@ -226,7 +226,11 @@ class GLMObjective:
             X = X - self.shifts[None, :]
         if self.factors is not None:
             X = X * self.factors[None, :]
-        H = (X * d2[:, None]).T @ X
+        # float32-grade where it is written (one bfloat16 pass by default on
+        # a TPU; ops/dense.py says why no global setting can do this)
+        H = jnp.matmul(
+            (X * d2[:, None]).T, X, precision=jax.lax.Precision.HIGHEST
+        )
         H = self._psum(H, axis_name)
         d = batch.num_features
         return H + self.l2_weight.astype(w.dtype) * jnp.eye(d, dtype=w.dtype)

@@ -168,4 +168,5 @@ def glm_adapter(
         curvature=curvature,
         hvp_at=hvp_at,
         hessian=hessian,
+        value_scale=lambda: psum(jnp.sum(batch.weights)),
     )

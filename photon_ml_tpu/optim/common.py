@@ -77,6 +77,12 @@ class Objective(NamedTuple):
     # Full dense Hessian (small-d only): the batched-Newton fast path for
     # per-entity solves. None when the layout can't densify (TiledBatch).
     hessian: Optional[Callable[[Array], Array]] = None  # w -> H [d, d]
+    # The scale the value is SUMMED at, where that is more than the value:
+    # for a GLM the sum of the row weights (a row's loss is computed from
+    # numbers of order one, and is no better than eps of THAT however small
+    # it comes out: softplus(9) - 9 is 1.2e-4 +- 1e-6). Newton's stop reads
+    # it; nothing else does.
+    value_scale: Optional[Callable[[], Array]] = None
 
 
 def from_value_and_grad(

@@ -230,6 +230,7 @@ def dispatch_solve(
             init_grad_norm=init_grad_norm,
             ls_prepare=adapter.ls_prepare,
             ls_eval=adapter.ls_eval,
+            value_scale=adapter.value_scale,
         )
 
     lcfg = LBFGSConfig(

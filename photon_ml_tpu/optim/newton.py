@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from photon_ml_tpu.optim.common import (
+    FUNCTION_VALUES_CONVERGED,
     NOT_CONVERGED,
     BoxConstraints,
     SolveResult,
@@ -63,6 +64,7 @@ def newton_solve(
     init_grad_norm: Optional[Array] = None,
     ls_prepare=None,
     ls_eval=None,
+    value_scale=None,
 ) -> SolveResult:
     """Minimize a convex twice-differentiable objective.
 
@@ -71,6 +73,8 @@ def newton_solve(
     lanes frozen (the RE bucket pattern). With the optional directional
     oracle (``ls_prepare``/``ls_eval``, unconstrained only) the damping
     candidates cost O(n) elementwise each instead of full objective sweeps.
+    ``value_scale() -> scalar`` (``Objective.value_scale``) says at what
+    scale the value is summed where that is more than the value itself.
     """
     dtype = w0.dtype
     d = w0.shape[0]
@@ -96,6 +100,15 @@ def newton_solve(
     )
 
     eye = jnp.eye(d, dtype=dtype)
+    unconstrained = constraints is None
+    # what the objective must move by to count as moving: the tolerance, or
+    # the dtype's own resolution where the tolerance asks for less
+    scale = jnp.abs(anchor_f)
+    if value_scale is not None:
+        scale = jnp.maximum(scale, jnp.asarray(value_scale(), dtype))
+    floor = scale * jnp.maximum(
+        jnp.asarray(config.tolerance, dtype), jnp.finfo(dtype).eps
+    )
     use_oracle = (
         constraints is None and ls_prepare is not None and ls_eval is not None
     )
@@ -114,6 +127,17 @@ def newton_solve(
             -s.grad,
         )
 
+        # the step's own forecast of what it can gain (half the squared
+        # Newton decrement). Under ``floor`` the objective cannot show the
+        # gain: the comparisons below would be between roundings, and which
+        # of a bucket's lanes then stops would be chance (a vmapped bucket
+        # runs to its slowest lane). Such a step is taken whole and is the
+        # last: the forecast comes from the gradient and the Hessian, whose
+        # relative error is small where the objective's differences have none
+        # left.
+        gain = -0.5 * jnp.dot(s.grad, step)
+        last = unconstrained & (gain <= floor)
+
         # damping: evaluate ALL candidate alphas 1, 1/2, 1/4, ... in ONE
         # vectorized sweep (no sequential halving loop — latency is the
         # enemy for vmapped per-entity solves) and take the first decrease
@@ -131,8 +155,10 @@ def newton_solve(
             )
             f_tries = jax.vmap(lambda wt: value_and_grad(wt)[0])(w_tries)
         good = f_tries < s.value
-        found = jnp.any(good)
-        best_alpha = jnp.where(found, alphas[jnp.argmax(good)], 0.0)
+        found = jnp.any(good) | last
+        best_alpha = jnp.where(
+            last, 1.0, jnp.where(found, alphas[jnp.argmax(good)], 0.0)
+        )
 
         w_new = project_or_identity(constraints, s.w + best_alpha * step)
         f_new, g_new = value_and_grad(w_new)
@@ -148,6 +174,11 @@ def newton_solve(
             config.tolerance,
             ~found,  # no decreasing step found = objective not improving
         )
+        reason = jnp.where(
+            last & (it < config.max_iterations),
+            FUNCTION_VALUES_CONVERGED,
+            reason,
+        ).astype(jnp.int32)
         nxt = _NewtonState(
             w=w_new,
             value=f_new,

@@ -26,6 +26,7 @@ import os
 import re
 
 import jax
+import numpy as np
 import pytest
 
 from benchmark import program_trace, run
@@ -56,7 +57,7 @@ SEED = 2147483653
 #: call (``^%<name>``) and not XLA's own custom-call target
 NAMING_READERS = {
     "program_span_seconds", "counter_delta", "counter_ratio", "trace_module",
-    "history_seconds",
+    "trace_module_roofline", "history_seconds",
 }
 KERNEL_READERS = {"trace_kernel_roofline", "trace_kernel_seconds"}
 
@@ -75,6 +76,10 @@ NAMES_NOTHING = {
     "fe_kernels_roofline.json", "fit_roofline_mfu.json",
     "idle_unattributed_s_per_fit.json", "peak_hbm_gb.json", "setup_s.json",
     "train_rows_per_s.json",
+    # XLA's own custom-call targets on a TPU (Cholesky and the triangular
+    # solve's block inverse): tests/test_chip_compile.py finds them in the
+    # described-v5e compile of the per-entity solver
+    "re_factor_device_s_per_fit.json",
 }
 
 
@@ -283,7 +288,8 @@ def test_kernel_named_by_a_metric_is_called_by_the_fit(
         assert flops > 0 and nbytes > 0
 
 
-@pytest.mark.parametrize("cell, metric", cases("trace_module"))
+@pytest.mark.parametrize(
+    "cell, metric", cases("trace_module", "trace_module_roofline"))
 def test_module_named_by_a_metric_is_a_registered_executable(
         rehearsal, cell, metric):
     params = METRICS[metric]["params"]
@@ -362,10 +368,100 @@ def test_update_leaves_a_tracker_with_value_and_iterations(rehearsal, cell):
     ``.iterations`` of the coordinate the step updated."""
     cell_run = rehearsal(cell)
     tracker = cell_run.driver.coordinates["fixed"].last_tracker
-    step = cell_run.ctx["fits"][-1]["steps"][-1]
+    step = [s for s in cell_run.ctx["fits"][-1]["steps"]
+            if s["coordinate"] == "fixed"][-1]
     assert step["loss"] == float(tracker.final_value)
     assert step["solver_iterations"] == float(tracker.iterations) >= 1
     assert math.isfinite(step["loss"])
+
+
+MIXED = "ml20m_glmix.cd_fit"
+RANDOM_EFFECTS = ["per-user", "per-movie"]
+
+
+@pytest.mark.parametrize("name", RANDOM_EFFECTS)
+def test_random_effect_update_leaves_per_entity_values_and_iterations(
+        rehearsal, name):
+    """``game_fit.step_facts`` sums ``last_tracker.final_values`` and takes
+    the mean of ``.iterations`` (one entry an entity) of a random effect."""
+    cell_run = rehearsal(MIXED)
+    coordinate = cell_run.driver.coordinates[name]
+    tracker = coordinate.last_tracker
+    step = [s for s in cell_run.ctx["fits"][-1]["steps"]
+            if s["coordinate"] == name][-1]
+    entities = sum(b.num_entities for b in coordinate.re_data.buckets)
+    assert len(tracker.iterations) == len(tracker.final_values) == entities
+    assert step["loss"] == float(
+        np.sum(tracker.final_values, dtype=np.float64))
+    assert step["solver_iterations"] == float(np.mean(tracker.iterations))
+    assert 1 <= tracker.iterations.max() <= 4  # the traffic's cap
+
+
+@pytest.mark.parametrize("name", RANDOM_EFFECTS)
+def test_random_effect_layout_gives_the_driver_its_buckets(rehearsal, name):
+    """``game_fit_mixed.Driver.shapes()``: ``re_data.buckets`` (entities,
+    rows and local features a bucket, ``values``, ``row_index``,
+    ``projection``, ``num_global_features``), ``_dense_x`` beside them, and
+    ``random_effect_data.MAX_GEOMETRY_CLASSES`` as the bound."""
+    cell_run = rehearsal(MIXED)
+    shape = cell_run.ctx["shapes"]["coordinates"][name]
+    coordinate = cell_run.driver.coordinates[name]
+    assert shape["kind"] == "random_effect"
+    assert 1 <= len(shape["buckets"]) <= shape["max_buckets"]
+    assert len(shape["buckets"]) == len(coordinate._dense_x)
+    rows = cell_run.driver.shape["rows"]
+    padded = sum(e * r for e, r, *_ in shape["buckets"])
+    assert shape["pass"]["r_sum"] == rows <= padded
+    for (e, r, k, nz, dense), b in zip(
+            shape["buckets"], coordinate.re_data.buckets):
+        assert (e, r, k) == (
+            b.num_entities, b.rows_per_entity, b.num_local_features)
+        assert e > 0 and r & (r - 1) == 0 and k & (k - 1) == 0
+    assert 1 <= shape["pass"]["k_min"] <= shape["features"] <= shape[
+        "global_features"]
+    assert shape["pass"]["rk"] <= shape["pass"]["rkk"]
+    counts = importlib.import_module("benchmark.counts.re_newton_pass")
+    flops, nbytes = counts.per_fit(shape, 100.0, 1000.0)
+    assert flops > 0 and nbytes == 12000.0
+
+
+@pytest.mark.parametrize("name", RANDOM_EFFECTS)
+def test_random_effect_counters_hold_what_the_layout_and_a_fit_cost(
+        rehearsal, name):
+    """``re.<coordinate>.*``: the layout's at the end of set-up, the
+    stragglers' after every update; ``re.*`` are their sums."""
+    ctx = rehearsal(MIXED).ctx
+    shape = ctx["shapes"]["coordinates"][name]
+    at_setup, at_end = ctx["counters"]["setup_end"], ctx["counters"][
+        "window_end"]
+    get = lambda mark, key: mark[f"re.{name}.{key}"]  # noqa: E731
+    assert get(at_setup, "buckets") == len(shape["buckets"])
+    assert get(at_setup, "entities") == shape["entities"]
+    assert get(at_setup, "rows") == shape["pass"]["r_sum"]
+    assert get(at_setup, "rows_padded") == sum(
+        e * r for e, r, *_ in shape["buckets"])
+    assert get(at_setup, "nnz") <= get(at_setup, "nnz_padded")
+    assert get(at_end, "lane_iterations") <= get(
+        at_end, "lane_iterations_run")
+    assert get(at_end, "lane_iterations") <= get(at_end, "pass_cells")
+    for key in ("rows", "rows_padded", "lane_iterations", "pass_cells"):
+        mark = at_end if "iterations" in key or "cells" in key else at_setup
+        assert mark[f"re.{key}"] == sum(
+            mark[f"re.{other}.{key}"] for other in RANDOM_EFFECTS)
+
+
+def test_fixed_effect_of_the_mixed_cell_reports_its_own_nonzeros(rehearsal):
+    """Ragged rows: ``nnz`` is the shard's own count, and the tile shape
+    comes with what the packer chose (``rlo is None``: strided)."""
+    cell_run = rehearsal(MIXED)
+    fixed = cell_run.ctx["shapes"]["coordinates"]["fixed"]
+    design = cell_run.driver.coordinates["fixed"]._tiled
+    vals = cell_run.driver.raw["train"]["global_vals"]
+    assert fixed["kind"] == "fixed_effect" and fixed["features"] == 32
+    assert 2 * len(vals) <= fixed["nnz"] == np.count_nonzero(vals)
+    assert (fixed["T"], fixed["S"], fixed["B"]) == (
+        design.num_tiles, design.vals.shape[2], design.num_blocks)
+    assert fixed["strided"] == (design.rlo is None)
 
 
 @pytest.mark.parametrize("cell", CELLS)

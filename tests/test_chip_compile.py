@@ -415,3 +415,100 @@ def test_validation_scorer_calls_have_their_own_names_for_v5e(
         r"%([\w\-]+?)(?:\.\d+)? = [^\n]*custom_call_target=\"tpu_custom_call\"",
         text))
     assert calls == want
+
+
+# -- the random-effect path: float32-grade products where they are written ---
+
+
+def _single_pass_products(text: str) -> list:
+    """``dot`` / ``convolution`` instructions of a compiled module that
+    multiply float32 operands without ``operand_precision={highest,highest}``:
+    on a TPU such a product is ONE bfloat16 pass."""
+    found = []
+    for line in text.splitlines():
+        if re.search(r" (dot|convolution)\(", line) and "f32[" in line:
+            if "operand_precision={highest,highest}" not in line:
+                found.append(line.strip()[:200])
+    return found
+
+
+def _entity_solver(packed: bool):
+    import dataclasses
+
+    from photon_ml_tpu.config import parse_optimizer_config
+    from photon_ml_tpu.game.coordinates import _re_solver
+
+    config = parse_optimizer_config({
+        "type": "newton", "max_iterations": 20, "tolerance": 1e-7,
+        "regularization": "l2", "regularization_weight": 1.0})
+    return _re_solver(
+        dataclasses.replace(config, regularization_weight=0.0), "logistic",
+        False, False, packed=packed)
+
+
+def _objective_shapes(one_chip):
+    from photon_ml_tpu.ops.objective import make_objective
+
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        make_objective("logistic", l2_weight=1.0))
+
+
+# (entities, padded rows, padded local features) of buckets as
+# ``ml20m_glmix.cd_fit`` has them: per-user at K = 32 and 16, per-movie K = 1
+@pytest.mark.parametrize("E, R, K", [
+    (4096, 256, 32), (2048, 64, 16), (8192, 32, 1), (2, 65536, 1)])
+def test_dense_entity_solve_is_float32_grade_for_v5e(E, R, K, one_chip):
+    """``re_solve_dense`` (``optim/newton.py`` over ``ops/dense.py`` and
+    ``ops/objective.py::dense_hessian``): margins, gradient, Hessian and the
+    line search's directional margins name their precision; the
+    factorisation is XLA's own (its custom calls are what
+    ``re_factor_device_s_per_fit`` sums)."""
+    def s(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    packed = (s((E, R * K)), s((E, R)), s((E, R)), s((E, R)))
+    text = _entity_solver(True).lower(
+        _objective_shapes(one_chip), packed, s((E, K)), s(()), None
+    ).compile().as_text()
+    assert re.match(r"HloModule jit_re_solve_dense\b", text)
+    assert not _single_pass_products(text)
+    if K > 1:
+        assert "operand_precision={highest,highest}" in text
+        assert 'custom_call_target="Cholesky"' in text
+        assert 'custom_call_target="InvertDiagBlocksLowerTriangular"' in text
+
+
+def test_coo_entity_solve_is_float32_grade_for_v5e(one_chip):
+    """``re_solve`` (the COO route): its sweeps are gathers and segment
+    sums, and the Hessian's product over the densified rows is pinned."""
+    from photon_ml_tpu.ops.sparse import SparseBatch
+
+    E, R, K, NZ = 512, 64, 32, 256
+
+    def s(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    batch = SparseBatch(
+        values=s((E, NZ)), rows=s((E, NZ), jnp.int32),
+        cols=s((E, NZ), jnp.int32), labels=s((E, R)), offsets=s((E, R)),
+        weights=s((E, R)), num_features=K)
+    text = _entity_solver(False).lower(
+        _objective_shapes(one_chip), batch, s((E, K)), s(()), None
+    ).compile().as_text()
+    assert re.match(r"HloModule jit_re_solve\b", text)
+    assert not _single_pass_products(text)
+
+
+@pytest.mark.parametrize("E, R, K", [(4096, 256, 32), (8192, 32, 1)])
+def test_dense_entity_scores_are_float32_grade_for_v5e(E, R, K, one_chip):
+    from photon_ml_tpu.game.coordinates import _re_dense_scorer
+
+    def s(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = _re_dense_scorer().lower(
+        s((E, K)), s((E, R * K)), s((E, R), jnp.int32), s((E * R,))
+    ).compile().as_text()
+    assert re.match(r"HloModule jit_re_score_dense\b", text)
+    assert not _single_pass_products(text)

@@ -2,6 +2,8 @@
 per-entity references, coordinate descent on synthetic GLMix data."""
 
 import jax.numpy as jnp
+import re
+
 import numpy as np
 import pytest
 
@@ -577,7 +579,16 @@ def test_fit_span_tree_two_coordinates(rng):
         parts = kids[step.span_id]
         assert [p.name for p in parts] == [
             "residual", "update", "score", "validate"]
-        assert all(not kids.get(p.span_id) for p in parts)  # leaves
+        for p in parts:  # leaves, but for a random effect's update
+            inner = [k.name for k in kids.get(p.span_id, [])]
+            if p.name == "update" and step.name == "coordinate:per-user":
+                # one dispatch span a geometry bucket, then the wait
+                assert inner[-1] == "re_tracker" and len(inner) > 1
+                assert all(re.fullmatch(r"re_bucket:\d+x\d+", n)
+                           for n in inner[:-1])
+                _contained_in_order(p, kids[p.span_id])
+            else:
+                assert not inner
         _contained_in_order(step, parts)
         score = parts[2]
         assert entry["seconds"] == pytest.approx(

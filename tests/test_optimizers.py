@@ -289,6 +289,60 @@ def test_newton_vmapped_batch(rng):
         np.testing.assert_allclose(res.w[e], w_star, rtol=3e-3, atol=3e-3)
 
 
+def _newton_logistic(X, y, offsets, cap=20):
+    batch = SparseBatch.from_dense(X, y, offsets=offsets)
+    cfg = OptimizerConfig(
+        optimizer_type=OptimizerType.NEWTON,
+        regularization=RegularizationContext(RegularizationType.L2),
+        regularization_weight=1.0,
+        tolerance=1e-7,
+        max_iterations=cap,
+    )
+    return solve("logistic", batch, cfg, jnp.zeros(X.shape[1], jnp.float32))
+
+
+@pytest.mark.parametrize("offset", [9.0, 12.0, -14.0])
+def test_newton_one_row_entity_ends_under_the_ceiling(offset):
+    """A one-row intercept far out on the sigmoid: softplus(z) - y z moves
+    by its own rounding (1e-6) where a Newton step gains 1e-9, so a stop
+    that waits for the objective to hold still runs to the ceiling by
+    chance. The step's own forecast ends it: taken whole, then the last."""
+    y = np.asarray([1.0 if offset > 0 else 0.0])
+    res = _newton_logistic(np.ones((1, 1)), y, np.asarray([offset]))
+    assert int(res.iterations) <= 3
+    assert res.reason == FUNCTION_VALUES_CONVERGED
+    # the L2 optimum: w = -(sigmoid(z) - y) to first order, and float32's
+    # sigmoid next to 1 is an ulp of 1 (6e-8) off
+    want = -(1.0 / (1.0 + np.exp(-offset)) - y[0])
+    np.testing.assert_allclose(float(res.w[0]), want, rtol=1e-3, atol=1e-7)
+
+
+def test_newton_takes_its_last_step(rng):
+    """Where the forecast gain falls under float32's resolution of the
+    objective the step is still taken: the end sits at the float64
+    optimum to float32's grade, not one step short of it, and permuting
+    the rows (another rounding of every sum) ends at the same count."""
+    n, d = 300, 6
+    X = (rng.random((n, d)) < 0.4).astype(np.float64)
+    X[:, 0] = 1.0
+    w_true = rng.normal(size=d)
+    y = (rng.random(n) < 1 / (1 + np.exp(-X @ w_true))).astype(np.float64)
+    res = _newton_logistic(X, y, np.zeros(n))
+    w = np.zeros(d)
+    for _ in range(30):  # float64 Newton
+        p = 1 / (1 + np.exp(-X @ w))
+        H = (X * (p * (1 - p))[:, None]).T @ X + np.eye(d)
+        w = w - np.linalg.solve(H, X.T @ (p - y) + w)
+    assert res.reason == FUNCTION_VALUES_CONVERGED
+    np.testing.assert_allclose(res.w, w, rtol=0, atol=2e-5)
+    counts = set()
+    for _ in range(5):
+        order = rng.permutation(n)
+        counts.add(int(_newton_logistic(X[order], y[order],
+                                        np.zeros(n)).iterations))
+    assert counts == {int(res.iterations)}
+
+
 def test_newton_rejects_l1_and_hinge():
     cfg = OptimizerConfig(
         optimizer_type=OptimizerType.NEWTON,
