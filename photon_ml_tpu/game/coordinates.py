@@ -55,8 +55,13 @@ from photon_ml_tpu.ops.panels import (
 from photon_ml_tpu.ops.tiled import ROWS_PER_TILE
 from photon_ml_tpu.optim.adapter import glm_adapter
 from photon_ml_tpu.optim.common import BoxConstraints
-from photon_ml_tpu.optim.factory import OptimizerConfig, dispatch_solve
+from photon_ml_tpu.optim.factory import (
+    OptimizerConfig,
+    OptimizerType,
+    dispatch_solve,
+)
 from photon_ml_tpu.optim.guard import damped_objective, solve_health
+from photon_ml_tpu.optim.spd_solve import takes_hand_solve
 from photon_ml_tpu.parallel.distributed import gspmd_solve
 from photon_ml_tpu.parallel import sharding as psharding
 from photon_ml_tpu.telemetry.device import accounted_upload
@@ -908,6 +913,21 @@ class RandomEffectCoordinate:
             counter(f"{scope}.lane_iterations_run").inc(run)
             counter(f"{scope}.pass_cells").inc(cells)
 
+    def _report_solve_route(self, b) -> None:
+        """Counters ``re.<name>.hand_solve_lanes`` / ``.xla_solve_lanes``
+        (and their sums over the coordinates, ``re.*``): the entities a
+        Newton update dispatched through the hand SPD solve over entity
+        lanes and through XLA's batched factorisation
+        (``optim/spd_solve.py::takes_hand_solve``: by the bucket's K)."""
+        if self.config.optimizer_type != OptimizerType.NEWTON:
+            return
+        hand = takes_hand_solve(b.num_local_features)
+        for scope in (f"re.{self.name}", "re"):
+            counter(f"{scope}.hand_solve_lanes").inc(
+                b.num_entities if hand else 0)
+            counter(f"{scope}.xla_solve_lanes").inc(
+                0 if hand else b.num_entities)
+
     def update_model(
         self, model: RandomEffectModel, residual_scores: Optional[Array]
     ) -> RandomEffectModel:
@@ -926,6 +946,7 @@ class RandomEffectCoordinate:
                 res, w, var = self._solve_bucket(
                     i, b, bm.coefficients, obj, residual_scores
                 )
+            self._report_solve_route(b)
             # keep only the tiny telemetry vectors (the full SolveResult
             # frees per bucket); stay ON DEVICE — every host fetch is a
             # wait on this bucket's solve, so both arrays cross in ONE

@@ -6,9 +6,13 @@ same serial LBFGS/TRON it uses globally, RandomEffectCoordinate.scala:
 are TINY (projected local dims K ~ 16-1000): under ``vmap`` the deep
 LBFGS/line-search ``while_loop`` nest is LATENCY-bound — hundreds of
 sequential micro-steps — while an explicit-Hessian Newton iteration is a
-few big batched ops on the MXU: build H [E, K, K] via one data sweep,
-Cholesky-solve, damp by fixed step-halving. 5-10x shallower loops for the
-same optimum on convex GLMs.
+few big batched ops: build H [E, K, K] via one data sweep on the MXU
+(``precision=HIGHEST``), solve ``(H + ridge I) p = -g`` for the step, damp
+by fixed step-halving. 5-10x shallower loops for the same optimum on convex
+GLMs. The solve is float32 vector arithmetic over ENTITY lanes for K <= 32
+(``optim/spd_solve.py``: a hand Cholesky and both substitutions on
+``[.., E]`` slabs, no custom call) and XLA's blocked ``cholesky`` +
+``cho_solve`` above that; which one is a static shape, not a setting.
 
 Guard rails: requires a twice-differentiable loss (no smoothed hinge), no
 L1 (factory rejects), and is intended for small K — H is dense [K, K].
@@ -31,6 +35,7 @@ from photon_ml_tpu.optim.common import (
     convergence_reason,
     project_or_identity,
 )
+from photon_ml_tpu.optim.spd_solve import spd_step, takes_hand_solve
 
 Array = jax.Array
 
@@ -40,7 +45,23 @@ class NewtonConfig:
     max_iterations: int = 20
     tolerance: float = 1e-7
     max_halvings: int = 10  # damping: halve the step until f decreases
-    ridge: float = 1e-8  # Cholesky jitter
+    ridge: float = 1e-8  # added to H's diagonal before either factorisation
+
+
+def _newton_step(H: Array, grad: Array) -> Array:
+    """``-(H^-1 grad)``, or steepest descent ``-grad`` where ``H`` is not
+    SPD. Up to ``HAND_SOLVE_MAX_DIM`` coefficients (a static shape) by the
+    hand solve over entity lanes, above it by XLA's blocked routine."""
+    if takes_hand_solve(grad.shape[0]):
+        return spd_step(H, grad)
+    L = jnp.linalg.cholesky(H)
+    ok = jnp.all(jnp.isfinite(L))
+    eye = jnp.eye(grad.shape[0], dtype=H.dtype)
+    return jnp.where(
+        ok,
+        -jax.scipy.linalg.cho_solve((jnp.where(ok, L, eye), True), grad),
+        -grad,
+    )
 
 
 class _NewtonState(NamedTuple):
@@ -117,15 +138,7 @@ def newton_solve(
         return s.reason == NOT_CONVERGED
 
     def body(s: _NewtonState) -> _NewtonState:
-        H = hessian(s.w) + config.ridge * eye
-        # Cholesky solve; fall back to steepest descent if H is not SPD
-        L = jnp.linalg.cholesky(H)
-        ok = jnp.all(jnp.isfinite(L))
-        step = jnp.where(
-            ok,
-            -jax.scipy.linalg.cho_solve((jnp.where(ok, L, eye), True), s.grad),
-            -s.grad,
-        )
+        step = _newton_step(hessian(s.w) + config.ridge * eye, s.grad)
 
         # the step's own forecast of what it can gain (half the squared
         # Newton decrement). Under ``floor`` the objective cannot show the

@@ -454,16 +454,23 @@ def _objective_shapes(one_chip):
         make_objective("logistic", l2_weight=1.0))
 
 
+_XLA_FACTOR_TARGETS = ("Cholesky", "InvertDiagBlocksLowerTriangular",
+                       "TriangularSolve")
+
+
 # (entities, padded rows, padded local features) of buckets as
-# ``ml20m_glmix.cd_fit`` has them: per-user at K = 32 and 16, per-movie K = 1
+# ``ml20m_glmix.cd_fit`` has them: per-user at K = 32 and 16, per-movie
+# K = 1; and one wider than the hand solve takes
 @pytest.mark.parametrize("E, R, K", [
-    (4096, 256, 32), (2048, 64, 16), (8192, 32, 1), (2, 65536, 1)])
+    (4096, 256, 32), (2048, 64, 16), (8192, 32, 1), (2, 65536, 1),
+    (512, 128, 64)])
 def test_dense_entity_solve_is_float32_grade_for_v5e(E, R, K, one_chip):
     """``re_solve_dense`` (``optim/newton.py`` over ``ops/dense.py`` and
     ``ops/objective.py::dense_hessian``): margins, gradient, Hessian and the
-    line search's directional margins name their precision; the
-    factorisation is XLA's own (its custom calls are what
-    ``re_factor_device_s_per_fit`` sums)."""
+    line search's directional margins name their precision. Up to K = 32
+    the step is ``optim/spd_solve.py``'s float32 vector arithmetic and the
+    module holds none of XLA's factorisation custom calls; above it the
+    factorisation is XLA's own (what ``re_factor_device_s_per_fit`` sums)."""
     def s(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
@@ -475,8 +482,37 @@ def test_dense_entity_solve_is_float32_grade_for_v5e(E, R, K, one_chip):
     assert not _single_pass_products(text)
     if K > 1:
         assert "operand_precision={highest,highest}" in text
-        assert 'custom_call_target="Cholesky"' in text
-        assert 'custom_call_target="InvertDiagBlocksLowerTriangular"' in text
+    found = {t for t in _XLA_FACTOR_TARGETS
+             if f'custom_call_target="{t}"' in text}
+    if K <= 32:
+        assert not found
+    else:
+        assert {"Cholesky", "InvertDiagBlocksLowerTriangular"} <= found
+
+
+def test_entity_sharded_dense_solve_compiles_for_v5e_2x2(topo):
+    """A bucket's entity axis sharded over the four chips
+    (``place_entity_solve``): the hand solve's ``[.., E]`` slabs partition
+    with the rest of the vmapped solve, no chip gathers the entity axis."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.array(topo.devices).reshape(4), ("model",))
+    split = NamedSharding(mesh, P("model"))
+    whole = NamedSharding(mesh, P())
+    E, R, K = 4096, 256, 32
+
+    def s(shape, sharding=split):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding)
+
+    packed = (s((E, R * K)), s((E, R)), s((E, R)), s((E, R)))
+    text = _entity_solver(True).lower(
+        _objective_shapes(whole), packed, s((E, K)), s((), whole), None
+    ).compile().as_text()
+    assert re.match(r"HloModule jit_re_solve_dense\b", text)
+    assert not re.search(r"all-gather|all-to-all|collective-permute", text)
+    assert "f32[1024,32,32]" in text and "f32[4096,32,32]" not in text
+    assert not any(
+        f'custom_call_target="{t}"' in text for t in _XLA_FACTOR_TARGETS)
 
 
 def test_coo_entity_solve_is_float32_grade_for_v5e(one_chip):
@@ -498,6 +534,8 @@ def test_coo_entity_solve_is_float32_grade_for_v5e(one_chip):
     ).compile().as_text()
     assert re.match(r"HloModule jit_re_solve\b", text)
     assert not _single_pass_products(text)
+    assert not any(
+        f'custom_call_target="{t}"' in text for t in _XLA_FACTOR_TARGETS)
 
 
 @pytest.mark.parametrize("E, R, K", [(4096, 256, 32), (8192, 32, 1)])
