@@ -154,6 +154,7 @@ def parse_coordinate_config(obj: Mapping):
             active_rows_per_entity=obj.pop("active_rows_per_entity", None),
             min_rows_per_entity=int(obj.pop("min_rows_per_entity", 1)),
             seed=int(obj.pop("seed", 0)),
+            layout=obj.pop("layout", "auto"),
         )
     else:
         raise ValueError(f"unknown coordinate type '{ctype}'")

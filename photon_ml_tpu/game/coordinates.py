@@ -513,16 +513,19 @@ def _make_solve_one(config: OptimizerConfig, compute_variances: bool):
     return solve_one
 
 
-def _adapt_solve_one(config, compute_variances: bool, packed: bool):
+def _adapt_solve_one(config, compute_variances: bool, packed: bool,
+                     kmajor: bool = False):
     """Per-entity solve body; ``packed`` reassembles a DenseBatch from the
-    flat packed design inside jit (_packed_dense_batch)."""
+    flat packed design inside jit (_packed_dense_batch): row-major [R*K],
+    or with ``kmajor`` feature-major [K*R] (what the factored coordinate's
+    projection pass writes)."""
     base_one = _make_solve_one(config, compute_variances)
     if not packed:
         return base_one
 
     def solve_one(obj, batch, w0, l1, constraints):
-        return base_one(obj, _packed_dense_batch(batch, w0), w0, l1,
-                        constraints)
+        return base_one(
+            obj, _packed_dense_batch(batch, w0, kmajor), w0, l1, constraints)
 
     return solve_one
 
@@ -534,8 +537,9 @@ def _re_solver(
     constrained: bool | str = False,
     compute_variances: bool = False,
     packed: bool = False,
+    kmajor: bool = False,
 ):
-    solve_one = _adapt_solve_one(config, compute_variances, packed)
+    solve_one = _adapt_solve_one(config, compute_variances, packed, kmajor)
     # obj, l1 broadcast; batch leaves, w0 (and per-entity constraint boxes,
     # when present) map over the entity axis. constrained="shared" keeps one
     # [K] box broadcast to every entity (the streaming table's dense local
@@ -665,16 +669,19 @@ def _re_offsets():
     return instrumented_jit(offsets, name="re_offsets", multi_shape=True)
 
 
-def _packed_dense_batch(packed, w0):
+def _packed_dense_batch(packed, w0, kmajor: bool = False):
     """Reassemble a DenseBatch from the PACKED per-entity design INSIDE
     jit: the design is stored flat [R*K] per entity (TPU pads a resident
     [E, R, K] array's K lanes to 128 — 128/K-fold HBM bloat; the flat
-    layout is padding-free and the in-jit reshape is a transient)."""
+    layout is padding-free and the in-jit reshape is a transient). With
+    ``kmajor`` the flat design is [K*R], a feature's rows together: the
+    transpose folds into the products' dimension numbers."""
     from photon_ml_tpu.ops.dense import DenseBatch
 
     x_flat, labels, offsets, weights = packed
+    k = w0.shape[0]
     return DenseBatch(
-        x=x_flat.reshape(-1, w0.shape[0]),
+        x=x_flat.reshape(k, -1).T if kmajor else x_flat.reshape(-1, k),
         labels=labels,
         offsets=offsets,
         weights=weights,

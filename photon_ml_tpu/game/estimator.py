@@ -113,6 +113,8 @@ class FactoredRandomEffectConfig:
     active_rows_per_entity: Optional[int] = None
     min_rows_per_entity: int = 1
     seed: int = 0
+    # the refit's base design: "auto" tiles it on a TPU, as a fixed effect's
+    layout: str = "auto"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -212,9 +214,12 @@ class GameEstimator:
         (prepareTrainingDataSet is outside the config loop in the
         reference, GameEstimator.scala:135-187 vs :279-398)."""
         ratio = getattr(c, "features_to_samples_ratio", None)
+        # a factored coordinate solves every entity at latent_dim: its
+        # buckets are classed by rows alone
+        by_features = not isinstance(c, FactoredRandomEffectConfig)
         key = (
             id(data), c.id_name, c.shard_name, c.active_rows_per_entity,
-            c.min_rows_per_entity, ratio,
+            c.min_rows_per_entity, ratio, by_features,
         )
         hit = self._re_datasets.get(key)
         # the cached entry pins a strong reference to its dataset, so the
@@ -232,6 +237,7 @@ class GameEstimator:
                 active_rows_per_entity=c.active_rows_per_entity,
                 min_rows_per_entity=c.min_rows_per_entity,
                 features_to_samples_ratio=ratio,
+                class_by_features=by_features,
             )
         self._re_datasets[key] = (data, red)
         return red
@@ -386,6 +392,7 @@ class GameEstimator:
                         mf_iterations=c.mf_iterations,
                         seed=c.seed,
                         mesh=entity_mesh,
+                        layout=c.layout,
                     )
                 else:
                     raise TypeError(
@@ -833,6 +840,7 @@ def _config_metadata(config: GameConfig) -> dict:
             out["latent_dim"] = c.latent_dim
             out["mf_iterations"] = c.mf_iterations
             out["seed"] = c.seed
+            out["layout"] = c.layout
             out["optimizer"] = describe_opt(c.re_optimizer)
             out["latent_optimizer"] = describe_opt(c.latent_optimizer)
         else:

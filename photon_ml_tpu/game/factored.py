@@ -9,19 +9,34 @@ a SHARED latent projection matrix A [K, d]; a row of entity e scores
 
   1. latent-space RE solve: project each entity's data through A and run the
      per-entity GLM solves in R^K (reusing RandomEffectCoordinate.updateModel
-     in the reference, :111-130; here the vmapped bucket solver),
-  2. latent matrix refit: fix the c_e and refit vec(A) as ONE distributed
-     GLM over kronecker(x, c_e) features (updateLatentProjectionMatrix
-     :226-255, kroneckerProductFeaturesAndCoefficients :269-287).
+     in the reference, :111-130; here the vmapped DENSE bucket solver, so a
+     K <= 32 Newton step is ``optim/spd_solve.py``'s hand solve),
+  2. latent matrix refit: fix the c_e and refit vec(A) as ONE GLM over
+     kronecker(x, c_e) features (updateLatentProjectionMatrix :226-255,
+     kroneckerProductFeaturesAndCoefficients :269-287).
 
-TPU-first shape trick: the kronecker-expanded design has STATIC structure —
-for nnz (row i, col j, value v) of entity e, the expanded entries are
-(i, j*K + l, v * c_e[l]) for l < K. The (rows, cols) index arrays are built
-once at coordinate construction; each refit only recomputes the VALUES by a
-[m, K] gather of the current latent table — no data movement, no reshuffle,
-one jit-compiled solve per refit (vs the reference's regenerated + reshuffled
-RDD per iteration). The reference's sparsityToleranceThreshold (drop tiny
-products) does not apply: XLA needs static shapes, and zero values are inert.
+The Kronecker design is NEVER built. With X the shard's design and
+C[row] = c_entity(row), the refit's margins are sum_l C[:, l] * (X A[l, :])
+and its gradient w.r.t. A[l, :] is X^T (g * C[:, l]): K right-hand sides
+over ONE design of nnz(X) nonzeros (:class:`LatentRefitBatch`, a
+``SparseBatch`` duck type over vec(A), so every optimizer of
+``dispatch_solve`` runs on it unchanged). That design holds the shard's
+rows in the COORDINATE'S OWN ORDER: bucket after bucket, entity after
+entity, each entity padded to its bucket's R rows. In that order
+
+  - C is a broadcast of the latent table (no gather),
+  - P = X A^T [K, rows], the projection pass, IS every bucket's latent
+    design [E, K, R] after a reshape: the per-entity solves read it
+    feature-major (``_re_solver(packed=True, kmajor=True)``), and no
+    [.., K]-trailing array (whose lanes a TPU pads 128/K-fold) is ever
+    formed,
+  - the per-row arrays are the buckets' own, concatenated.
+
+The design is a ``TiledBatch`` on a TPU (``layout``; one sweep of its tiles
+serves all K tables: ``%mf_margins_k`` / ``%mf_scatter_k``) and a COO
+``SparseBatch`` elsewhere (K passes of ``dot_rows`` / ``scatter_features``
+under one loop). The reference's sparsityToleranceThreshold (drop tiny
+products) does not apply: nothing is multiplied out.
 """
 
 from __future__ import annotations
@@ -42,11 +57,26 @@ from photon_ml_tpu.data.projection import (
 from photon_ml_tpu.game.dataset import GameDataset
 from photon_ml_tpu.game.models import map_vocab_codes
 from photon_ml_tpu.game.random_effect_data import RandomEffectDataset
+from photon_ml_tpu.ops.losses import get_loss
 from photon_ml_tpu.ops.objective import make_objective
 from photon_ml_tpu.ops.sparse import SparseBatch
 from photon_ml_tpu.optim.adapter import glm_adapter
-from photon_ml_tpu.optim.factory import OptimizerConfig, dispatch_solve
-from photon_ml_tpu.parallel.distributed import distributed_solve
+from photon_ml_tpu.optim.factory import (
+    OptimizerConfig,
+    OptimizerType,
+    dispatch_solve,
+)
+from photon_ml_tpu.optim.spd_solve import takes_hand_solve
+from photon_ml_tpu.optim.trackers import (
+    FactoredRandomEffectOptimizationTracker,
+    FixedEffectOptimizationTracker,
+    RandomEffectOptimizationTracker,
+)
+from photon_ml_tpu.ops.panels import report_layout
+from photon_ml_tpu.ops.tiled import ROWS_PER_TILE, TILES_A_STEP, TiledBatch
+from photon_ml_tpu.telemetry.device import accounted_upload
+from photon_ml_tpu.telemetry.metrics import counter
+from photon_ml_tpu.telemetry.trace import span
 from photon_ml_tpu.telemetry.xla import instrumented_jit
 
 Array = jax.Array
@@ -177,58 +207,199 @@ class MatrixFactorizationModel:
 
 
 # ---------------------------------------------------------------------------
+# the refit's design: vec(A) as a GLM without the Kronecker product
+# ---------------------------------------------------------------------------
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class LatentRefitBatch:
+    """The latent-matrix refit's examples: feature ``j*K + l`` of a row is
+    ``x[j] * c[l]`` (kronecker(x, c_entity)), held as the base design and
+    the rows' latent vectors and never multiplied out.
+
+    Duck-type compatible with :class:`SparseBatch` for everything
+    ``GLMObjective`` and ``glm_adapter`` use; ``w`` is vec(A) with A[l, j]
+    at ``j*K + l``. Every pass is the base design's ``project_rows`` or
+    ``scatter_rows`` plus elementwise work on [K, rows] arrays.
+    """
+
+    design: object  # SparseBatch | TiledBatch, rows in the coordinate's order
+    c_rows: Array  # f[K, rows]: the latent vector of each row's entity
+    labels: Array  # f[rows]
+    offsets: Array  # f[rows]
+    weights: Array  # f[rows]; 0 on padding rows
+
+    @property
+    def latent_dim(self) -> int:
+        return self.c_rows.shape[0]
+
+    @property
+    def num_features(self) -> int:
+        return self.design.num_features * self.latent_dim
+
+    @property
+    def num_rows(self) -> int:
+        return self.labels.shape[0]
+
+    @property
+    def dtype(self):
+        return self.c_rows.dtype
+
+    def _matrix(self, w: Array) -> Array:
+        return w.reshape(-1, self.latent_dim).T  # [K, d]
+
+    # -- sweeps (SparseBatch duck-type) --------------------------------------
+
+    def dot_rows(self, w: Array) -> Array:
+        return jnp.sum(
+            self.c_rows * self.design.project_rows(self._matrix(w)), axis=0)
+
+    def margins(self, w: Array, shift: Array | float = 0.0) -> Array:
+        return self.dot_rows(w) + shift + self.offsets
+
+    def margins_pair(self, w, shift, p, p_shift):
+        return self.margins(w, shift), self.dot_rows(p) + p_shift
+
+    def scatter_features(self, per_row: Array) -> Array:
+        g = self.design.scatter_rows(self.c_rows * per_row[None, :])
+        return g.T.reshape(-1)
+
+    def scatter_features_sq(self, per_row: Array) -> Array:
+        g = jax.lax.map(self.design.scatter_features_sq,
+                        self.c_rows * self.c_rows * per_row[None, :])
+        return g.T.reshape(-1)
+
+    def fused_value_grad(self, w, shift, loss_name: str):
+        loss = get_loss(loss_name)
+        l, dz = loss.loss_and_dz(self.margins(w, shift), self.labels)
+        wdz = self.weights * dz
+        return (jnp.sum(self.weights * l), self.scatter_features(wdz),
+                jnp.sum(wdz))
+
+    def fused_hessian_vector(self, w, shift, v, v_shift, loss_name: str):
+        loss = get_loss(loss_name)
+        z, u = self.margins_pair(w, shift, v, v_shift)
+        q = self.weights * loss.d2z(z, self.labels) * u
+        return self.scatter_features(q), jnp.sum(q)
+
+    def fused_hv_at(self, d2_row, v, v_shift):
+        q = d2_row * (self.dot_rows(v) + v_shift)
+        return self.scatter_features(q), jnp.sum(q)
+
+    def with_offsets(self, offsets: Array) -> "LatentRefitBatch":
+        return dataclasses.replace(
+            self, offsets=jnp.asarray(offsets, self.offsets.dtype))
+
+
+def _rows_of_buckets(parts, shapes, total: int, lead: tuple = ()) -> Array:
+    """Per-bucket arrays [*lead, E, R] -> one [*lead, total] array in the
+    coordinate's row order (zeros past the last bucket)."""
+    flat = [p.reshape(*lead, e * r) for p, (_, e, r) in zip(parts, shapes)]
+    used = sum(e * r for _, e, r in shapes)
+    flat.append(jnp.zeros((*lead, total - used), jnp.float32))
+    return jnp.concatenate(flat, axis=-1)
+
+
+def _c_rows(latents, shapes, total: int) -> Array:
+    """[K, total]: each row's entity's latent vector, a broadcast of the
+    buckets' tables [E, K] along their R rows."""
+    k = latents[0].shape[1]
+    return _rows_of_buckets(
+        [jnp.broadcast_to(c.T[:, :, None], (k, e, r))
+         for c, (_, e, r) in zip(latents, shapes)],
+        shapes, total, lead=(k,))
+
+
+@lru_cache(maxsize=64)
+def _latent_design_fn(shapes: tuple):
+    """(design, A) -> every bucket's latent design, feature-major and flat
+    [E, K*R]: the projection pass P = X A^T [K, rows], cut at the buckets
+    and turned entity-major. No gather: the design's rows lie in bucket
+    order."""
+
+    def designs(design, a):
+        p = design.project_rows(a)
+        k = a.shape[0]
+        return tuple(
+            p[:, off:off + e * r].reshape(k, e, r).transpose(1, 0, 2)
+            .reshape(e, k * r)
+            for off, e, r in shapes)
+
+    return instrumented_jit(designs, name="factored_project", multi_shape=True)
+
+
+@lru_cache(maxsize=64)
+def _row_scores_fn(shapes: tuple):
+    """(design, A, latents, place) -> scores [len(place)]: the coordinate's
+    margins in its own row order, each read at its example row (``place``
+    [n]: a row's position in that order, -1 for none)."""
+
+    def scores(design, a, latents, place):
+        z = jnp.sum(
+            _c_rows(latents, shapes, design.num_rows) * design.project_rows(a),
+            axis=0)
+        return jnp.where(place >= 0, jnp.take(z, jnp.maximum(place, 0)), 0.0)
+
+    return instrumented_jit(scores, name="factored_score", multi_shape=True)
+
+
+@lru_cache(maxsize=1)
+def _foreign_scores_fn():
+    """(design, A, latent, flat) -> scores of another dataset's rows (its
+    own order): (A x) . c_entity, 0 where ``flat`` [rows] is -1. The latent
+    vectors come by ONE flat take with the rows on lanes ([K, rows])."""
+
+    def scores(design, a, latent, flat):
+        k = latent.shape[1]
+        idx = jnp.maximum(flat, 0)[None, :] * k + jnp.arange(
+            k, dtype=flat.dtype)[:, None]
+        c = jnp.take(latent.reshape(-1), idx)  # [K, rows]
+        return jnp.where(
+            flat >= 0, jnp.sum(c * design.project_rows(a), axis=0), 0.0)
+
+    return instrumented_jit(
+        scores, name="factored_score_rows", multi_shape=True)
+
+
+@lru_cache(maxsize=64)
+def _latent_fit_solver(config: OptimizerConfig, loss_name: str,
+                       shapes: tuple):
+    def run(obj, design, labels, weights, offsets, latents, w0, l1):
+        total = design.num_rows
+        batch = LatentRefitBatch(
+            design=design,
+            c_rows=_c_rows(latents, shapes, total),
+            labels=labels,
+            offsets=_rows_of_buckets(offsets, shapes, total),
+            weights=weights,
+        )
+        return dispatch_solve(glm_adapter(obj, batch), w0, config, l1)
+
+    return instrumented_jit(run, name="factored_latent_fit", multi_shape=True)
+
+
+# ---------------------------------------------------------------------------
 # coordinate
 # ---------------------------------------------------------------------------
 
 
-@lru_cache(maxsize=64)
-def _latent_design_T_fn(R: int):
-    """[E]-vmapped transposed latent design X~^T [K, R].
-
-    TPU layout note: the latent dim K is tiny (2-16) — any tensor with K
-    as the TRAILING dim pads its lanes 128/K-fold (measured 64x = 12.3 GB
-    of padding on a 197 MB gather at K=2). This variant keeps the long
-    dims (NZ, R) in lanes throughout: the per-row reduction is a
-    [K, NZ] @ [NZ, R] one-hot matmul instead of a segment_sum over
-    [NZ, K] rows."""
-
-    def one(values, rows, cols, projection, a_ext):
-        K, d1 = a_ext.shape
-        g = projection[cols]  # [NZ]
-        # FLAT 1-D take from the flattened table: the 2-D-table gather
-        # a_ext[:, g] materializes an [E*NZ, K] fusion output whose K
-        # lanes pad to 128 (an 18 GB allocation at 20M rows); a 1-D-table
-        # take with [K, NZ] indices keeps NZ in lanes throughout
-        idx2 = g[None, :] + (jnp.arange(K, dtype=g.dtype) * d1)[:, None]
-        a = jnp.take(a_ext.reshape(-1), idx2)  # [K, NZ]
-        contrib = values[None, :] * a  # [K, NZ]
-        onehot = (
-            rows[None, :] == jnp.arange(R, dtype=rows.dtype)[:, None]
-        ).astype(contrib.dtype)  # [R, NZ]
-        return jax.lax.dot_general(
-            contrib, onehot,
-            dimension_numbers=(((1,), (1,)), ((), ())),
-        )  # [K, R]
-
-    return instrumented_jit(
-        jax.vmap(one, in_axes=(0, 0, 0, 0, None)), name="factored_project"
-    )
+def _solver_key(config: OptimizerConfig) -> OptimizerConfig:
+    """The compiled solver's cache key: the weight is a traced leaf of the
+    objective, so a lambda sweep shares one program."""
+    return dataclasses.replace(config, regularization_weight=0.0)
 
 
-@lru_cache(maxsize=64)
-def _latent_fit_solver(config: OptimizerConfig, loss_name: str):
-    def run(obj, batch, w0, l1):
-        return dispatch_solve(glm_adapter(obj, batch), w0, config, l1)
+def _objective_and_l1(loss_name: str, config: OptimizerConfig):
+    """(objective carrying the config's L2 weight, its L1 weight)."""
+    reg, weight = config.regularization, config.regularization_weight
+    return (make_objective(loss_name, l2_weight=reg.l2_weight(weight)),
+            jnp.float32(reg.l1_weight(weight)))
 
-    return instrumented_jit(run, name="factored_latent_fit")
 
-
-@instrumented_jit(name="factored_kron_values")
-def _kron_values(vals_sorted, flat_idx, latent):
-    """Row-sorted kron values: pre-permuted base values times a FLAT 1-D
-    latent gather (see the construction comment — 2-D/tiny-trailing-dim
-    gathers pad their program temps to 128 lanes at scale)."""
-    return vals_sorted * jnp.take(latent.reshape(-1), flat_idx)
+#: what ``benchmark/drivers/game_fit_mf.py`` asks for before it generates a
+#: row: this coordinate refits vec(A) without a Kronecker design
+KRON_FREE_REFIT = True
 
 
 @dataclasses.dataclass
@@ -251,17 +422,18 @@ class FactoredRandomEffectCoordinate:
     mf_iterations: int = 1
     seed: int = 0
     mesh: Optional[Mesh] = None  # 1-D mesh: entity-shards the latent RE
-    # solves (shard_map, no collectives) and data-parallels the latent
-    # matrix refit (distributed_solve) over the same devices
+    # solves (GSPMD over the lanes) and row-shards the refit's design
     # refit_projection=False freezes A after random initialization: the
     # coordinate becomes RandomEffectCoordinateInProjectedSpace with a
     # Gaussian RandomProjection (ProjectorType.RANDOM analog) — per-entity
-    # solves in the fixed projected space, no kron refit.
+    # solves in the fixed projected space, no refit.
     refit_projection: bool = True
     # with refit_projection=False, optionally pass the intercept through the
     # projection untouched (buildGaussianRandomProjectionMatrix's
     # isKeepingInterceptTerm dummy row)
     projection_intercept_index: Optional[int] = None
+    # the base design's layout: "auto" tiles it on a TPU, COO elsewhere
+    layout: str = "auto"
 
     def __post_init__(self):
         if self.latent_dim < 1:
@@ -274,6 +446,8 @@ class FactoredRandomEffectCoordinate:
                 "(the MF refit would overwrite the passthrough row; the "
                 "reference's MF init uses isKeepingInterceptTerm=false)"
             )
+        if self.layout not in ("auto", "coo", "tiled"):
+            raise ValueError(f"unknown layout '{self.layout}'")
         self.re_config.validate(self.loss_name)
         self.latent_config.validate(self.loss_name)
         if self.re_config.box_constraints or self.latent_config.box_constraints:
@@ -281,10 +455,8 @@ class FactoredRandomEffectCoordinate:
                 "box constraints are not supported in latent/projected spaces"
             )
         k = self.latent_dim
-        d = self.re_data.num_global_features
         buckets = self.re_data.buckets
         self._batch = self.data.shard(self.re_data.shard_name)
-        n_pad = self._batch.num_rows
         # rows of A, including the optional intercept passthrough row
         self._proj_rows = k + (1 if self.projection_intercept_index is not None else 0)
 
@@ -297,140 +469,142 @@ class FactoredRandomEffectCoordinate:
             eb >= 0, self._flat_offsets[np.maximum(eb, 0)] + ep, -1
         ).astype(np.int64)
 
-        if not self.refit_projection:
-            # fixed projection: the kron structure is never needed
-            key_re = dataclasses.replace(self.re_config, regularization_weight=0.0)
-            from photon_ml_tpu.game.coordinates import _re_solver
-
-            self._re_solver = _re_solver(key_re, self.loss_name)
-            if self.mesh is not None:
-                self._resolve_mesh_axis()
-            self._re_obj = make_objective(
-                self.loss_name,
-                l2_weight=self.re_config.regularization.l2_weight(
-                    self.re_config.regularization_weight
-                ),
-            )
-            self._re_l1 = jnp.float32(
-                self.re_config.regularization.l1_weight(
-                    self.re_config.regularization_weight
-                )
-            )
-            return
-
-        # --- static kronecker structure (host, once) ---
-        g_rows, g_cols, g_vals, g_ent = [], [], [], []
-        for b_idx, b in enumerate(buckets):
-            rows_l = np.asarray(b.rows)  # [E, NZ] local rows
-            row_index = np.asarray(b.row_index)  # [E, R]
-            gr = np.take_along_axis(row_index, rows_l, axis=1)  # [E, NZ]
-            gc = np.take_along_axis(
-                np.asarray(b.projection), np.asarray(b.cols), axis=1
-            )
-            vals = np.asarray(b.values)
-            ent = np.broadcast_to(
-                (self._flat_offsets[b_idx] + np.arange(b.num_entities))[:, None],
-                gr.shape,
-            )
-            # padding nnz: value 0 -> contributions vanish; clamp indices
-            # into range so gathers stay valid
-            gr = np.where((gr < 0) | (vals == 0), n_pad - 1, gr)
-            gc = np.where(gc >= d, 0, gc)
-            g_rows.append(gr.reshape(-1))
-            g_cols.append(gc.reshape(-1))
-            g_vals.append(vals.reshape(-1))
-            g_ent.append(ent.reshape(-1))
-        g_rows = np.concatenate(g_rows) if g_rows else np.zeros(0, np.int64)
-        g_cols = np.concatenate(g_cols) if g_cols else np.zeros(0, np.int64)
-        g_vals = np.concatenate(g_vals) if g_vals else np.zeros(0)
-        g_ent = np.concatenate(g_ent) if g_ent else np.zeros(0, np.int64)
-        m = len(g_vals)
-
-        kron_rows = np.repeat(g_rows, k)
-        kron_cols = (g_cols[:, None] * k + np.arange(k)[None, :]).reshape(-1)
-
-        # active-row labels/weights/base-offsets scattered from the buckets
-        # (weights carry the active-data cap rescale; passive rows weight 0)
-        lab = np.zeros(n_pad)
-        wgt = np.zeros(n_pad)
-        off = np.zeros(n_pad)
-        for b in buckets:
-            ri = np.asarray(b.row_index)
-            valid = ri >= 0
-            lab[ri[valid]] = np.asarray(b.labels)[valid]
-            wgt[ri[valid]] = np.asarray(b.weights)[valid]
-            off[ri[valid]] = np.asarray(b.offsets)[valid]
-        self._base_offsets = off
-
-        # order nnz by row for segment-sum friendliness. The base values
-        # and flat latent-gather indices are PRE-PERMUTED on the host so
-        # each matrix step is one flat 1-D take (a runtime [m*k]
-        # permutation gather — or a [m, K] latent gather — lowers with
-        # tiny-trailing-dim index/output temps that pad to 128 lanes:
-        # measured 12+ GB of padding at north-star scale).
-        o = np.argsort(kron_rows, kind="stable")
-        bases = o // k
-        lcol = o % k
-        self._kron_vals_sorted = jnp.asarray(
-            g_vals[bases], self._batch.dtype
-        )
-        self._kron_flat_idx = jnp.asarray(
-            g_ent[bases] * k + lcol, jnp.int32
-        )
-        self._num_kron_features = d * k
-
-        key_re = dataclasses.replace(self.re_config, regularization_weight=0.0)
-        key_lat = dataclasses.replace(self.latent_config, regularization_weight=0.0)
-        # the per-entity bucket solver is shared with RandomEffectCoordinate
-        # (identical dispatch; one lru_cache entry for both coordinate types)
-        from photon_ml_tpu.game.coordinates import _re_solver
-
-        self._re_solver = _re_solver(key_re, self.loss_name)
-        self._lat_solver = _latent_fit_solver(key_lat, self.loss_name)
         if self.mesh is not None:
             self._resolve_mesh_axis()
-            # mesh mode never materializes the single-device kron template
-            self._latent_template = None
-            self._build_stacked_latent(kron_rows[o], kron_cols[o], lab, wgt)
-        else:
-            self._latent_template = SparseBatch(
-                values=jnp.zeros((m * k,), self._batch.dtype),
-                rows=jnp.asarray(kron_rows[o], jnp.int32),
-                cols=jnp.asarray(kron_cols[o], jnp.int32),
-                labels=jnp.asarray(lab, self._batch.dtype),
-                offsets=jnp.asarray(off, self._batch.dtype),
-                weights=jnp.asarray(wgt, self._batch.dtype),
-                num_features=d * k,
-            )
-        self._re_obj = make_objective(
-            self.loss_name,
-            l2_weight=self.re_config.regularization.l2_weight(
-                self.re_config.regularization_weight
-            ),
-        )
-        self._re_l1 = jnp.float32(
-            self.re_config.regularization.l1_weight(
-                self.re_config.regularization_weight
-            )
-        )
-        self._lat_obj = make_objective(
-            self.loss_name,
-            l2_weight=self.latent_config.regularization.l2_weight(
-                self.latent_config.regularization_weight
-            ),
-        )
-        self._lat_l1 = jnp.float32(
-            self.latent_config.regularization.l1_weight(
-                self.latent_config.regularization_weight
-            )
-        )
+        self._use_tiled = self.mesh is None and (
+            self.layout == "tiled"
+            or (self.layout == "auto" and jax.default_backend() == "tpu"))
+        self._build_design()
+
+        from photon_ml_tpu.game.coordinates import _re_solver
+
+        # the dense bucket route over the projection pass's feature-major
+        # designs: one lru_cache entry with every coordinate of this config
+        self._re_solver = _re_solver(
+            _solver_key(self.re_config), self.loss_name, packed=True,
+            kmajor=True)
+        self._re_obj, self._re_l1 = _objective_and_l1(
+            self.loss_name, self.re_config)
+        if self.refit_projection:
+            self._lat_solver = _latent_fit_solver(
+                _solver_key(self.latent_config), self.loss_name, self._shapes)
+            self._lat_obj, self._lat_l1 = _objective_and_l1(
+                self.loss_name, self.latent_config)
+        # the last foreign dataset scored through device-resident state
+        # (score_dataset): (weakref to it, (design, flat) or None)
+        self._foreign = None
+        self.last_tracker = None
+
+    # -- layout --------------------------------------------------------------
+
+    def _build_design(self) -> None:
+        """The shard's rows in the coordinate's own order (bucket, entity,
+        row of the entity; an entity padded to its bucket's R), as ONE base
+        design with the buckets' per-row arrays beside it. Host spans
+        ``mf_layout.group`` (the order) and ``mf_layout.design`` (the
+        packing) under ``layout`` / ``mf_layout``; the placement is span
+        ``mf_upload`` under ``upload``. Nothing of length nnz x K exists."""
+        buckets = self.re_data.buckets
+        n = self.data.num_rows
+        n_pad = self._batch.num_rows
+        d = self.re_data.num_global_features
+        with span("layout"), span("mf_layout"):
+            with span("mf_layout.group"):
+                shapes, off = [], 0
+                place = np.full(n_pad, -1, np.int32)
+                for b in buckets:
+                    e, r = b.num_entities, b.rows_per_entity
+                    shapes.append((off, e, r))
+                    ri = np.asarray(b.row_index).reshape(-1)
+                    at = np.flatnonzero(ri >= 0)
+                    place[ri[at]] = off + at
+                    off += e * r
+                self._shapes = tuple(shapes)
+                # whole grid steps of the K-table sweeps
+                step = ROWS_PER_TILE * max(TILES_A_STEP)
+                total = max(-(-off // step), 1) * step
+
+                def rows(field):
+                    out = np.zeros(total, np.float32)
+                    for b, (o, e, r) in zip(buckets, shapes):
+                        out[o:o + e * r] = np.asarray(
+                            getattr(b, field)).reshape(-1)
+                    return out
+
+                labels, weights = rows("labels"), rows("weights")
+                vals = np.asarray(self._batch.values)
+                src = np.asarray(self._batch.rows)
+                cols = np.asarray(self._batch.cols)
+                keep = np.flatnonzero((vals != 0) & (src < n))
+                keep = keep[place[src[keep]] >= 0]
+                vals, cols = vals[keep], cols[keep]
+                mf_rows = place[src[keep]]
+                del src, keep
+            with span("mf_layout.design"):
+                if self._use_tiled:
+                    host = TiledBatch.pack_coo(
+                        values=vals.astype(np.float32), rows=mf_rows,
+                        cols=cols, labels=labels, num_features=d,
+                        weights=weights,
+                    ).traced_as("mf")
+                    report_layout(host, f"mf.{self.name}.layout")
+                else:
+                    host = SparseBatch.from_coo(
+                        values=vals, rows=mf_rows, cols=cols, labels=labels,
+                        num_features=d, weights=weights,
+                    )
+        self._nnz = int(len(vals))
+        shard = None
+        if self.mesh is not None:
+            from jax.sharding import NamedSharding, PartitionSpec
+
+            # row- and nonzero-sharded over the coordinate's one axis where
+            # the lengths divide it; GSPMD partitions the refit's passes
+            def spec(x):
+                even = x.ndim and x.shape[0] % self._n_dev == 0
+                return NamedSharding(
+                    self.mesh, PartitionSpec(self._axis if even else None))
+
+            shard = spec
+        with span("upload"):
+            placed = accounted_upload(
+                lambda: jax.tree.map(
+                    jnp.asarray if shard is None
+                    else (lambda x: jax.device_put(x, shard(np.asarray(x)))),
+                    (host, labels, weights, place)),
+                name="mf_upload")
+        self._design, self._labels, self._weights, self._place = placed
+        self._report_layout()
+
+    def _report_layout(self) -> None:
+        """Counters ``re.<name>.*`` as a random-effect coordinate writes
+        them for its buckets (``nnz_padded`` the latent designs' E*R*K
+        cells), and ``mf.<name>.refit_nnz`` / ``.latent_dim`` /
+        ``.kron_nnz_materialised`` (0: the Kronecker design is not built)."""
+        k = self._proj_rows
+        totals = dict.fromkeys(
+            ("entities", "rows", "rows_padded", "nnz", "nnz_padded",
+             "buckets"), 0)
+        for hb in self.re_data.buckets:
+            e, r = hb.num_entities, hb.rows_per_entity
+            totals["entities"] += e
+            totals["rows"] += int(np.count_nonzero(hb.row_index >= 0))
+            totals["rows_padded"] += e * r
+            totals["nnz_padded"] += e * r * k
+            totals["buckets"] += 1
+        totals["nnz"] = self._nnz
+        for key, value in totals.items():
+            counter(f"re.{self.name}.{key}").inc(value)
+        counter(f"mf.{self.name}.refit_nnz").inc(self._nnz)
+        counter(f"mf.{self.name}.latent_dim").inc(k)
+        counter(f"mf.{self.name}.kron_nnz_materialised").inc(0)
 
     def _resolve_mesh_axis(self) -> None:
         """Pick the ONE mesh axis this coordinate parallelizes over: the
-        entity-sharded latent solves and the row-stacked kron refit both
-        use it, so their shard counts agree. A model/entity axis wins
-        (the latent table is per-entity state), then a batch/data axis,
-        then the mesh's first axis (legacy 1-D meshes)."""
+        entity-sharded latent solves and the row-sharded refit both use it.
+        A model/entity axis wins (the latent table is per-entity state),
+        then a batch/data axis, then the mesh's first axis (legacy 1-D
+        meshes)."""
         from photon_ml_tpu.parallel import sharding as psharding
 
         self._axis = (
@@ -439,49 +613,6 @@ class FactoredRandomEffectCoordinate:
             or self.mesh.axis_names[0]
         )
         self._n_dev = psharding.axis_size(self.mesh, self._axis)
-
-    def _build_stacked_latent(self, rows_np, cols_np, lab, wgt) -> None:
-        """Pre-shard the STATIC kronecker structure over the mesh: contiguous
-        row blocks per device with local row ids, plus an index map so each
-        refit only gathers the fresh values into place (the per-iteration
-        analog of FixedEffectCoordinate._restack)."""
-        n_dev = self._n_dev
-        n_pad = self._batch.num_rows
-        rows_per = -(-n_pad // n_dev)
-        shard_of = np.minimum(rows_np // rows_per, n_dev - 1)
-        counts = np.bincount(shard_of, minlength=n_dev)
-        nnz_max = max(int(counts.max()), 1)
-
-        idx_map = np.full((n_dev, nnz_max), -1, np.int64)
-        srows = np.full((n_dev, nnz_max), rows_per - 1, np.int32)
-        scols = np.zeros((n_dev, nnz_max), np.int32)
-        for s in range(n_dev):
-            sel = np.nonzero(shard_of == s)[0]
-            idx_map[s, : len(sel)] = sel
-            srows[s, : len(sel)] = rows_np[sel] - s * rows_per
-            scols[s, : len(sel)] = cols_np[sel]
-
-        def rowwise(a):
-            out = np.zeros((n_dev * rows_per,))
-            out[: len(a)] = a
-            return jnp.asarray(out.reshape(n_dev, rows_per), self._batch.dtype)
-
-        from photon_ml_tpu.parallel.mesh import put_sharded
-
-        self._stacked_rows_per = rows_per
-        self._stacked_idx = jnp.asarray(idx_map, jnp.int32)
-        # place each shard's static block on its device once (the
-        # FixedEffectCoordinate put_sharded pattern); refits only move values
-        stacked_host = SparseBatch(
-            values=jnp.zeros((n_dev, nnz_max), self._batch.dtype),
-            rows=jnp.asarray(srows),
-            cols=jnp.asarray(scols),
-            labels=rowwise(lab),
-            offsets=rowwise(self._base_offsets),
-            weights=rowwise(wgt),
-            num_features=self._num_kron_features,
-        )
-        self._stacked_template = put_sharded(stacked_host, self.mesh, self._axis)
 
     # -- model plumbing ------------------------------------------------------
 
@@ -509,180 +640,210 @@ class FactoredRandomEffectCoordinate:
         hi = int(self._flat_offsets[b_idx + 1])
         return latent[lo:hi]
 
-    def _latent_re_step(
-        self, latent: Array, a_ext: Array, residual: Optional[Array]
-    ):
-        """One pass of per-entity solves in latent space over all buckets.
-        Returns ``(latent', (its, reasons, values))`` — the telemetry stays
-        as DEVICE arrays so the MF alternation loop never blocks on a host
-        fetch; update_model packs it once after the loop."""
-        k = self._proj_rows
-        parts = []
-        t_its, t_reasons, t_vals = [], [], []
-        for b_idx, b in enumerate(self.re_data.device_buckets()):
-            bucket = b if residual is None else b.with_extra_offsets(residual)
-            E, R = b.num_entities, b.rows_per_entity
-            # transposed design (long dims in lanes) then one bounded
-            # [E, R, K] transpose: the direct [.., K]-trailing gather pads
-            # lanes 128/K-fold (12.3 GB of padding at K=2 on this bucket)
-            X = _latent_design_T_fn(R)(
-                b.values, b.rows, b.cols, b.projection, a_ext
-            ).transpose(0, 2, 1)  # [E, R, K]
-            dense = SparseBatch(
-                values=X.reshape(E, R * k),
-                rows=jnp.broadcast_to(
-                    jnp.repeat(jnp.arange(R, dtype=jnp.int32), k), (E, R * k)
-                ),
-                cols=jnp.broadcast_to(
-                    jnp.tile(jnp.arange(k, dtype=jnp.int32), R), (E, R * k)
-                ),
-                labels=bucket.labels,
-                offsets=bucket.offsets,
-                weights=bucket.weights,
-                num_features=k,
-            )
-            w0 = self._bucket_slice(latent, b_idx)
-            if self.mesh is None:
-                res, _ = self._re_solver(
-                    self._re_obj, dense, w0, self._re_l1, None
-                )
-                w = res.w
-            else:
-                total = -(-E // self._n_dev) * self._n_dev
-                from photon_ml_tpu.game.coordinates import (
-                    _pad_entities,
-                    place_entity_solve,
-                    record_entity_solve_comms,
-                )
+    def _latents(self, latent: Array) -> tuple:
+        return tuple(
+            self._bucket_slice(latent, i)
+            for i in range(len(self.re_data.buckets)))
 
-                dense_p, w0_p = _pad_entities(dense, w0, total)
-                dense_p, w0_p, _ = place_entity_solve(
-                    self.mesh, self._axis, dense_p, w0_p
-                )
-                record_entity_solve_comms(
-                    "latent_re_solve", self.mesh, self._axis,
-                    self.re_config.max_iterations,
-                )
-                res, _ = self._re_solver(
-                    self._re_obj, dense_p, w0_p, self._re_l1, None
-                )
-                w = res.w[:E]
-            parts.append(w)
-            t_its.append(res.iterations[:E])
-            t_reasons.append(res.reason[:E])
-            t_vals.append(res.value[:E])
-        new_latent = jnp.concatenate(parts, axis=0) if parts else latent
-        return new_latent, (t_its, t_reasons, t_vals)
+    def _bucket_offsets(self, residual: Optional[Array]) -> tuple:
+        """Every bucket's offsets [E, R], the other coordinates' scores
+        added at its rows (``re_offsets``, one program a bucket)."""
+        from photon_ml_tpu.game.coordinates import _re_offsets
 
-    def _latent_matrix_step(self, latent: Array, a: Array, residual: Optional[Array]):
-        """Refit vec(A) as one GLM over the static kronecker structure.
-        Returns ``(A', SolveResult)`` — tracker construction (4 scalar host
-        fetches) is deferred past the MF loop by update_model."""
-        vals = _kron_values(
-            self._kron_vals_sorted, self._kron_flat_idx, latent
+        buckets = self.re_data.device_buckets_stripped()
+        if residual is None:
+            return tuple(b.offsets for b in buckets)
+        return tuple(_re_offsets()(b, residual) for b in buckets)
+
+    def solve_bucket(self, obj, x_flat, labels, offsets, weights, w0):
+        """One bucket's (or any stack of lanes') vmapped latent solve on
+        the dense route from its feature-major design [E, K*R]. Under a
+        mesh the lanes are padded to the axis and entity-sharded. Returns
+        the solver's result over the lanes given."""
+        bb = (x_flat, labels, offsets, weights)
+        if self.mesh is None:
+            return self._re_solver(obj, bb, w0, self._re_l1, None)[0]
+        from photon_ml_tpu.game.coordinates import (
+            _pad_entities,
+            place_entity_solve,
+            record_entity_solve_comms,
         )
-        w0 = a.T.reshape(-1)  # vec layout matches cols j*K + l
+
+        lanes = w0.shape[0]
+        total = -(-lanes // self._n_dev) * self._n_dev
+        bb_p, w0_p = _pad_entities(bb, w0, total)
+        bb_p, w0_p, _ = place_entity_solve(self.mesh, self._axis, bb_p, w0_p)
+        record_entity_solve_comms(
+            "latent_re_solve", self.mesh, self._axis,
+            self.re_config.max_iterations,
+        )
+        res = self._re_solver(obj, bb_p, w0_p, self._re_l1, None)[0]
+        return jax.tree.map(
+            lambda x: x[:lanes] if getattr(x, "ndim", 0) else x, res)
+
+    def latent_designs(self, a: Array) -> tuple:
+        """Every bucket's latent design [E, K*R] (feature-major) under the
+        projection ``a`` [K, d]: one projection pass."""
+        return _latent_design_fn(self._shapes)(self._design, a)
+
+    def _latent_re_step(self, latent: Array, a: Array, offsets: tuple):
+        """One pass of per-entity solves in latent space over all buckets.
+        Returns ``(latent', RandomEffectOptimizationTracker)``."""
+        k = self._proj_rows
+        with span("latent_design"):
+            designs = self.latent_designs(a)
+        parts, t_its, t_reasons, t_vals = [], [], [], []
+        buckets = self.re_data.device_buckets_stripped()
+        for b_idx, b in enumerate(buckets):
+            e, r = b.num_entities, b.rows_per_entity
+            with span(f"latent_bucket:{r}x{k}"):
+                res = self.solve_bucket(
+                    self._re_obj, designs[b_idx], b.labels, offsets[b_idx],
+                    b.weights,
+                    self._bucket_slice(latent, b_idx))
+            if self.re_config.optimizer_type == OptimizerType.NEWTON:
+                hand = takes_hand_solve(k)
+                for scope in (f"mf.{self.name}", "mf"):
+                    counter(f"{scope}.hand_solve_lanes").inc(e if hand else 0)
+                    counter(f"{scope}.xla_solve_lanes").inc(0 if hand else e)
+            parts.append(res.w)
+            t_its.append(res.iterations)
+            t_reasons.append(res.reason)
+            t_vals.append(res.value)
+        new_latent = jnp.concatenate(parts, axis=0) if parts else latent
+        with span("latent_tracker"):  # the host's wait on every bucket
+            tracker = RandomEffectOptimizationTracker.from_device_parts(
+                t_its, t_reasons, t_vals)
+        self._report_stragglers(tracker.iterations)
+        return new_latent, tracker
+
+    def _report_stragglers(self, iterations: np.ndarray) -> None:
+        """Counters ``mf.<name>.lane_iterations`` / ``.lane_iterations_run``
+        (and their sums ``mf.*``): every entity's own Newton iterations, and
+        entities x its bucket's longest solve (a vmapped ``while_loop`` runs
+        every lane to the slowest)."""
+        needed = run = 0
+        for i in range(len(self.re_data.buckets)):
+            its = iterations[
+                int(self._flat_offsets[i]):int(self._flat_offsets[i + 1])]
+            if len(its):
+                needed += int(its.sum())
+                run += int(its.max()) * len(its)
+        for scope in (f"mf.{self.name}", "mf"):
+            counter(f"{scope}.lane_iterations").inc(needed)
+            counter(f"{scope}.lane_iterations_run").inc(run)
+
+    def _latent_matrix_step(self, latent: Array, a: Array, offsets: tuple):
+        """Refit vec(A) as one GLM over :class:`LatentRefitBatch`. Returns
+        ``(A', FixedEffectOptimizationTracker)``."""
         k = self.latent_dim
-        if self.mesh is not None:
-            # scatter the fresh values into the pre-sharded static layout;
-            # everything else about the stacked batch is fixed
-            sv = jnp.where(
-                self._stacked_idx >= 0,
-                vals[jnp.maximum(self._stacked_idx, 0)],
-                0.0,
-            )
-            stacked = dataclasses.replace(self._stacked_template, values=sv)
-            if residual is not None:
-                off = jnp.asarray(self._base_offsets, sv.dtype) + residual
-                total = self._n_dev * self._stacked_rows_per
-                off = jnp.pad(off, (0, total - off.shape[0]))
-                stacked = dataclasses.replace(
-                    stacked, offsets=off.reshape(self._n_dev, -1)
-                )
-            res = distributed_solve(
-                self.loss_name,
-                stacked,
-                self.latent_config,
-                w0,
-                self.mesh,
-                axis=self._axis,
-            )
-            return res.w.reshape(-1, k).T, res
-        batch = dataclasses.replace(self._latent_template, values=vals)
-        if residual is not None:
-            off = jnp.asarray(self._base_offsets, batch.dtype) + residual
-            batch = dataclasses.replace(batch, offsets=off)
-        res = self._lat_solver(self._lat_obj, batch, w0, self._lat_l1)
-        return res.w.reshape(-1, k).T, res  # [K, d]
+        res = self._lat_solver(
+            self._lat_obj, self._design, self._labels, self._weights,
+            offsets, self._latents(latent), a.T.reshape(-1), self._lat_l1)
+        tracker = FixedEffectOptimizationTracker.from_result(res)
+        for scope in (f"mf.{self.name}", "mf"):
+            counter(f"{scope}.refit_iterations").inc(tracker.iterations)
+            # a margin-carrying iteration is one projection and one scatter
+            # pass, and so is the start
+            counter(f"{scope}.refit_evaluations").inc(tracker.iterations + 1)
+        return res.w.reshape(-1, k).T, tracker  # [K, d]
 
     def update_model(
         self,
         model: FactoredRandomEffectModel,
         residual_scores: Optional[Array],
     ) -> FactoredRandomEffectModel:
-        from photon_ml_tpu.optim.trackers import (
-            FactoredRandomEffectOptimizationTracker,
-            FixedEffectOptimizationTracker,
-            RandomEffectOptimizationTracker,
-        )
-
         latent = model.latent
         a = model.projection.matrix
+        offsets = self._bucket_offsets(residual_scores)
         if not self.refit_projection:
             # fixed random projection: per-entity solves only
-            latent, re_parts = self._latent_re_step(
-                latent, model.projection.extended(), residual_scores
-            )
-            re_t = RandomEffectOptimizationTracker.from_device_parts(*re_parts)
+            latent, re_t = self._latent_re_step(latent, a, offsets)
             self.last_tracker = FactoredRandomEffectOptimizationTracker(
                 steps=((re_t, None),)
             )
             return dataclasses.replace(model, latent=latent)
-        raw_steps = []
-        for _ in range(self.mf_iterations):
-            a_ext = ProjectionMatrix(matrix=a).extended()
-            latent, re_parts = self._latent_re_step(latent, a_ext, residual_scores)
-            a, lat_res = self._latent_matrix_step(latent, a, residual_scores)
-            raw_steps.append((re_parts, lat_res))
-        # all host fetches happen HERE, after the alternation finished, so
-        # each iteration's dispatch overlaps the previous one's execution
+        steps = []
+        for i in range(self.mf_iterations):
+            with span(f"mf_iteration:{i}"):
+                latent, re_t = self._latent_re_step(latent, a, offsets)
+                with span("latent_refit"):
+                    a, lat_t = self._latent_matrix_step(latent, a, offsets)
+            steps.append((re_t, lat_t))
         self.last_tracker = FactoredRandomEffectOptimizationTracker(
-            steps=tuple(
-                (
-                    RandomEffectOptimizationTracker.from_device_parts(*rp),
-                    FixedEffectOptimizationTracker.from_result(lr),
-                )
-                for rp, lr in raw_steps
-            )
-        )
+            steps=tuple(steps))
         return dataclasses.replace(
             model, latent=latent, projection=ProjectionMatrix(matrix=a)
         )
 
     def score(self, model: FactoredRandomEffectModel) -> Array:
-        """Training-data scores: bucket fast path for active rows, generic
-        model path for passive rows."""
-        a_ext = model.projection.extended()
-        n_pad = self._batch.num_rows
-        scores = jnp.zeros((n_pad,), jnp.float32)
-        for b_idx, b in enumerate(self.re_data.device_buckets()):
-            R = b.rows_per_entity
-            # same transposed-design + transpose consumption as
-            # _latent_re_step: feeding the [E, K, R] design straight into
-            # an einsum made XLA materialize the inner gather as a
-            # lane-padded [m, K] fusion output (18 GB at 20M rows)
-            X = _latent_design_T_fn(R)(
-                b.values, b.rows, b.cols, b.projection, a_ext
-            ).transpose(0, 2, 1)  # [E, R, K]
-            c = self._bucket_slice(model.latent, b_idx)  # [E, K]
-            margins = jnp.einsum("erk,ek->er", X, c)
-            idx = b.row_index.reshape(-1)
-            scores = scores.at[jnp.maximum(idx, 0)].add(
-                jnp.where(idx >= 0, margins.reshape(-1), 0.0)
-            )
+        """Training-data scores: one projection pass in the coordinate's
+        row order for active rows, the generic model path for passive
+        rows."""
+        scores = _row_scores_fn(self._shapes)(
+            self._design, model.projection.matrix,
+            self._latents(model.latent), self._place)
         if len(self.re_data.passive_rows):
             passive = model.score(self.data)
-            mask = np.zeros(n_pad, bool)
+            mask = np.zeros(self._batch.num_rows, bool)
             mask[self.re_data.passive_rows] = True
             scores = jnp.where(jnp.asarray(mask), passive, scores)
         return scores
+
+    # -- another dataset's rows (validation) ---------------------------------
+
+    def score_dataset(
+        self, model: FactoredRandomEffectModel, data: GameDataset
+    ) -> Array:
+        """``model.score(data)`` for rows that are not this coordinate's own
+        (validation), from state kept on the device beside ``data``: its
+        shard laid out like the training design and each row's place in the
+        latent table, built and uploaded the first time ``data`` is scored
+        (spans ``mf_validation_layout`` / ``mf_validation_upload``) and kept
+        for as long as it is the dataset asked for. ``model.score`` walks
+        the shard's nonzeros on the host and uploads four arrays a call."""
+        from photon_ml_tpu.game.coordinates import _fit_rows
+
+        if data is self.data:
+            return self.score(model)
+        state = self._foreign_state(model, data)
+        if state is None:
+            return model.score(data)
+        design, flat = state
+        z = _foreign_scores_fn()(
+            design, model.projection.matrix, model.latent, flat)
+        return _fit_rows(z, data.shard(self.re_data.shard_name).num_rows)
+
+    def _foreign_state(self, model, data: GameDataset):
+        import weakref
+
+        if self._foreign is not None and self._foreign[0]() is data:
+            if self._foreign[1] is not None:
+                counter("validate.mf_design_hits").inc()
+            return self._foreign[1]
+        self._foreign = None  # the last dataset's state goes first
+        state = None
+        batch = data.shard(self.re_data.shard_name)
+        idc = data.id_columns.get(self.re_data.id_name)
+        if (idc is not None and self.mesh is None
+                and batch.num_features == self.re_data.num_global_features):
+            with span("mf_validation_layout"):
+                n = data.num_rows
+                codes = map_vocab_codes(model.vocab, idc.vocab[idc.codes])
+                flat = np.full(batch.num_rows, -1, np.int32)
+                flat[:n] = np.where(
+                    codes >= 0, self._entity_flat[np.maximum(codes, 0)], -1)
+                if self._use_tiled:
+                    host = TiledBatch.pack_batch(batch).traced_as(
+                        "mf_validate")
+                    flat = np.pad(
+                        flat, (0, host.num_rows - len(flat)),
+                        constant_values=-1)
+                else:
+                    host = batch
+            state = accounted_upload(
+                lambda: jax.tree.map(jnp.asarray, (host, flat)),
+                name="mf_validation_upload")
+            counter("validate.mf_design_builds").inc()
+        self._foreign = (weakref.ref(data), state)
+        return state

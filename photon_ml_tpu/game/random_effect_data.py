@@ -274,6 +274,25 @@ class RandomEffectDataset:
             object.__setattr__(self, "_device_buckets_dense", cached)
         return cached
 
+    def device_buckets_stripped(self) -> tuple[EntityBucket, ...]:
+        """Device buckets that hold the per-row leaves alone (labels,
+        offsets, weights, row_index, entity codes): the COO arrays and the
+        projections are (1, 1) stubs. For the factored coordinate, whose
+        design is one array over all buckets and whose solves are
+        ``latent_dim`` wide whatever an entity observed. Uploaded once."""
+        cached = self.__dict__.get("_device_buckets_stripped")
+        if cached is None:
+            stub = np.zeros((1, 1), np.float32)
+            stub_i = np.zeros((1, 1), np.int32)
+            cached = tuple(
+                accounted_upload(
+                    lambda b=b: jax.device_put(dataclasses.replace(
+                        b, values=stub, rows=stub_i, cols=stub_i,
+                        projection=stub_i)))
+                for b in self.buckets)
+            object.__setattr__(self, "_device_buckets_stripped", cached)
+        return cached
+
     def to_summary_string(self) -> str:
         """RandomEffectDataSet.toSummaryString analog (:174-197): per-bucket
         geometry + active/passive split."""
@@ -369,8 +388,14 @@ def build_random_effect_dataset(
     features_to_samples_ratio: Optional[float] = None,
     seed: int = 0,
     dtype=jnp.float32,
+    class_by_features: bool = True,
 ) -> RandomEffectDataset:
     """Group, cap, project, and bucket one random-effect coordinate's data.
+
+    ``class_by_features=False`` classes the entities by their rows alone
+    (the factored coordinate: every entity's solve is ``latent_dim`` wide,
+    whatever it observed); a bucket's local width is then its widest
+    entity's and costs the solve nothing.
 
     Fully vectorized host build: sorting/searchsorted/bincount over bulk
     arrays with one small Python loop over geometry CLASSES (tens), never
@@ -500,7 +525,8 @@ def build_random_effect_dataset(
         # a class's nnz width is its own fullest entity's ---
         Rs = _next_pow2_arr(act_counts)
         Ks = _next_pow2_arr(np.maximum(proj_counts, 1))
-        geom = np.stack([Rs, Ks], axis=1)
+        geom = np.stack(
+            [Rs, Ks if class_by_features else np.ones_like(Ks)], axis=1)
         fine, fine_of_ent, fine_counts = np.unique(
             geom, axis=0, return_inverse=True, return_counts=True
         )
@@ -518,6 +544,8 @@ def build_random_effect_dataset(
         class_rank[class_order] = np.arange(len(kept))
         class_of_ent = class_rank[class_of_fine[fine_of_ent]]
         rk = rk[class_order]
+        if not class_by_features:
+            np.maximum.at(rk[:, 1], class_of_ent, Ks)
         nz_max = np.zeros(len(kept), np.int64)
         np.maximum.at(nz_max, class_of_ent, nnz_counts)
         classes = np.concatenate(

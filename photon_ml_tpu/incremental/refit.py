@@ -534,12 +534,6 @@ class MaskedFactoredRandomEffectCoordinate:
         return self.inner.score(model)
 
     def update_model(self, model, residual_scores):
-        from photon_ml_tpu.game.coordinates import (
-            place_entity_solve,
-            record_entity_solve_comms,
-        )
-        from photon_ml_tpu.game.factored import _latent_design_T_fn
-        from photon_ml_tpu.ops.sparse import SparseBatch
         from photon_ml_tpu.optim.trackers import (
             FactoredRandomEffectOptimizationTracker,
             RandomEffectOptimizationTracker,
@@ -548,8 +542,6 @@ class MaskedFactoredRandomEffectCoordinate:
 
         inner = self.inner
         obj = damped_objective(inner._re_obj, self.extra_l2)
-        a_ext = model.projection.extended()
-        k = inner._proj_rows
         n_dev = (
             0 if inner.mesh is None
             else psharding.axis_size(inner.mesh, inner._axis)
@@ -557,7 +549,12 @@ class MaskedFactoredRandomEffectCoordinate:
         latent = model.latent
         tracker_its, tracker_reasons, tracker_vals = [], [], []
         healths = []
-        for b_idx, b in enumerate(inner.re_data.device_buckets()):
+        # one projection pass gives every bucket's latent design; the
+        # touched lanes are then taken out of it
+        designs = inner.latent_designs(model.projection.matrix)
+        offsets = inner._bucket_offsets(residual_scores)
+        buckets = inner.re_data.device_buckets_stripped()
+        for b_idx, b in enumerate(buckets):
             ti = self._positions[b_idx]
             n_real = int(b.num_entities)
             if not len(ti):
@@ -582,44 +579,11 @@ class MaskedFactoredRandomEffectCoordinate:
             def take(x):
                 return jnp.take(x, idx_dev, axis=0)
 
-            bucket = (
-                b if residual_scores is None
-                else b.with_extra_offsets(residual_scores)
-            )
-            R = b.rows_per_entity
-            # gather the touched entities' raw arrays FIRST, then build
-            # the transposed latent design only over them — the design
-            # cost scales with touched lanes, not bucket size
-            X = _latent_design_T_fn(R)(
-                take(b.values), take(b.rows), take(b.cols),
-                take(b.projection), a_ext,
-            ).transpose(0, 2, 1)  # [total, R, K]
-            dense = SparseBatch(
-                values=X.reshape(total, R * k),
-                rows=jnp.broadcast_to(
-                    jnp.repeat(jnp.arange(R, dtype=jnp.int32), k),
-                    (total, R * k),
-                ),
-                cols=jnp.broadcast_to(
-                    jnp.tile(jnp.arange(k, dtype=jnp.int32), R),
-                    (total, R * k),
-                ),
-                labels=take(bucket.labels),
-                offsets=take(bucket.offsets),
-                weights=take(bucket.weights),
-                num_features=k,
-            )
             flat = inner._flat_offsets[b_idx] + idx
             w0 = jnp.take(latent, jnp.asarray(flat, jnp.int32), axis=0)
-            if inner.mesh is not None:
-                dense, w0, _ = place_entity_solve(
-                    inner.mesh, inner._axis, dense, w0
-                )
-                record_entity_solve_comms(
-                    "latent_re_solve", inner.mesh, inner._axis,
-                    inner.re_config.max_iterations,
-                )
-            res, _ = inner._re_solver(obj, dense, w0, inner._re_l1, None)
+            res = inner.solve_bucket(
+                obj, take(designs[b_idx]), take(b.labels),
+                take(offsets[b_idx]), take(b.weights), w0)
             w = res.w[:T]
             flat_t = jnp.asarray(
                 inner._flat_offsets[b_idx] + ti, jnp.int32

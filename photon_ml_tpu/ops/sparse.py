@@ -301,6 +301,17 @@ class SparseBatch:
             contrib
         )
 
+    # -- K vectors at once (the factored coordinate's [K, d] projection);
+    # TiledBatch serves all K in one sweep of its tiles -----------------------
+
+    def project_rows(self, a: Array) -> Array:
+        """``a`` [K, F] -> [K, rows]: row l is ``dot_rows(a[l])``."""
+        return jax.lax.map(self.dot_rows, a)
+
+    def scatter_rows(self, g: Array) -> Array:
+        """``g`` [K, rows] -> [K, F]: row l is ``scatter_features(g[l])``."""
+        return jax.lax.map(self.scatter_features, g)
+
     def feature_moment_sums(self) -> tuple[Array, Array, Array]:
         """Per-feature (sum x, sum x^2, count nonzero) over valid rows."""
         valid = jnp.take(

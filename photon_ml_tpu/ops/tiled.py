@@ -76,6 +76,17 @@ strided (sorted): margins 35.5 ms (40.0), scatter 34.1 (39.9), margins_pair
 This replaces the hot loop the reference distributes over a Spark cluster
 (ValueAndGradientAggregator.scala:132-153) with on-chip matmuls.
 
+K tables at once (``project_rows`` / ``scatter_rows``, the factored
+coordinate's refit of a [K, d] projection): a strided design serves all K in
+ONE sweep of its tiles, the masks built once a tile, several tiles a grid
+step, each table one gather or placement pass (``%<prefix>_margins_k`` /
+``%<prefix>_scatter_k``; the tables' bf16x2 halves are made once a call by
+``%<prefix>_tables_k``). A pass streams 2*B8 table rows a table and 128
+slots, so at K = 16 and B = 214 a tile is MXU-bound at ~1.2 us: 264 / 273 ms
+a pass over 212,296 tiles of one-hot rows against 1,265 / 1,152 ms for 16
+calls of ``dot_rows`` / ``scatter_features`` (PERF.md, Findings PR 33). A
+sorted design makes the K calls under one loop.
+
 Width and skew: a pass costs slots x B (the [2*B8, S] intermediates above),
 so this layout is for designs of up to ~128 column blocks; S is the longest
 row's or the fullest tile's, so ragged row lengths pad (rows or tiles: the
@@ -425,6 +436,51 @@ def _hv_at_kernel(strided: bool, *refs):
     _scatter_accum(out_g_ref, q_row, vals, hit, lot, rt)
 
 
+def _split_tables_kernel(a_ref, out_ref):
+    """[K, B8, 128] float32 grids -> their bf16x2 halves stacked
+    [K, 2*B8, 128], once a call (inside a kernel: XLA would fold the low
+    half to zero, see the module's note on splits)."""
+    for l in range(a_ref.shape[0]):
+        out_ref[l] = jnp.concatenate(_split_bf16(a_ref[l]), axis=0)
+
+
+def _project_kernel(K: int, G: int, vals_ref, hi_ref, lo_ref, tab_ref,
+                    out_ref):
+    """P[l] = per-row sum of vals * A[l, col] for the K tables of ``tab``
+    ([K, 2*B8, 128]: :func:`_split_tables_kernel`'s halves) over the G
+    strided tiles of one grid step: a tile's masks are built once, each
+    table is one :func:`_gather_slots` pass. Out [G, K, R]."""
+    B8 = tab_ref.shape[1] // 2
+    for t in range(G):
+        hit = _onehot_t(hi_ref[t], B8)
+        lot = _onehot_t(lo_ref[t], LANE).astype(jnp.bfloat16)
+        vals = vals_ref[t]
+        for l in range(K):
+            out_ref[t, l:l + 1, :] = _chunk_sum(
+                _gather_slots(tab_ref[l], hit, lot) * vals)
+
+
+def _scatter_k_kernel(K: int, G: int, vals_ref, hi_ref, lo_ref, g_ref,
+                      out_ref):
+    """out[l] += sum_s g[l, row_s] * vals_s * onehot(col_s) for K per-row
+    vectors ``g`` [G, K, R] over the G strided tiles of one grid step."""
+    i = pl.program_id(0)
+
+    @pl.when(i == 0)
+    def _():
+        out_ref[:] = jnp.zeros_like(out_ref)
+
+    B8 = out_ref.shape[1]
+    for t in range(G):
+        hit = _onehot_t(hi_ref[t], B8)
+        lot = _onehot_t(lo_ref[t], LANE).astype(jnp.bfloat16)
+        vals = vals_ref[t]
+        reps = vals.shape[1] // LANE
+        for l in range(K):
+            s = jnp.tile(g_ref[t, l:l + 1, :], (1, reps))
+            out_ref[l] = out_ref[l] + _place_slots(s * vals, hit, lot)
+
+
 # Every pallas_call below carries a ``name``: it becomes the custom call's
 # HLO instruction name (``%tiled_margins.1 = ... custom-call(...)``), which
 # is what a device event's name starts with in a profiler trace — the one
@@ -481,7 +537,8 @@ def _margins_call(T, S, B, strided, use_offsets, pair, interpret,
 
 
 @functools.lru_cache(maxsize=None)
-def _scatter_call(T, S, B, strided, square, interpret):
+def _scatter_call(T, S, B, strided, square, interpret,
+                  name="tiled_scatter"):
     kern = functools.partial(_scatter_kernel, square, strided)
     return pl.pallas_call(
         kern,
@@ -490,7 +547,7 @@ def _scatter_call(T, S, B, strided, square, interpret):
         out_specs=_spec_w(B),
         out_shape=_shape_w(B),
         interpret=interpret,
-        name="tiled_scatter",
+        name=name,
     )
 
 
@@ -536,6 +593,69 @@ def _value_grad_call(T, S, B, strided, loss_name, use_offsets, interpret):
         out_shape=[jax.ShapeDtypeStruct((1, 2), jnp.float32), _shape_w(B)],
         interpret=interpret,
         name="tiled_value_grad",
+    )
+
+
+#: tiles a grid step of the K-table sweeps, where the count divides: a step
+#: costs ~0.2 us whatever it computes (PERF.md, Findings PR 29)
+TILES_A_STEP = (8, 4, 2, 1)
+
+
+def _tiles_a_step(T: int) -> int:
+    return next(g for g in TILES_A_STEP if T % g == 0)
+
+
+def _spec_g(G, *tail):
+    """G tiles of a [T, *tail] array a grid step."""
+    zeros = (0,) * len(tail)
+    return pl.BlockSpec((G, *tail), lambda i: (i, *zeros),
+                        memory_space=pltpu.VMEM)
+
+
+def _spec_table_k(K, rows):
+    return pl.BlockSpec((K, rows, LANE), lambda i: (0, 0, 0),
+                        memory_space=pltpu.VMEM)
+
+
+@functools.lru_cache(maxsize=None)
+def _split_tables_call(K, B, interpret, name):
+    B8 = _table_rows(B)
+    return pl.pallas_call(
+        _split_tables_kernel,
+        grid=(1,),
+        in_specs=[_spec_table_k(K, B8)],
+        out_specs=_spec_table_k(K, 2 * B8),
+        out_shape=jax.ShapeDtypeStruct((K, 2 * B8, LANE), jnp.bfloat16),
+        interpret=interpret,
+        name=name,
+    )
+
+
+@functools.lru_cache(maxsize=None)
+def _project_call(T, S, B, K, G, interpret, name):
+    return pl.pallas_call(
+        functools.partial(_project_kernel, K, G),
+        grid=(T // G,),
+        in_specs=[_spec_g(G, 1, S)] * 3
+        + [_spec_table_k(K, 2 * _table_rows(B))],
+        out_specs=_spec_g(G, K, ROWS_PER_TILE),
+        out_shape=jax.ShapeDtypeStruct((T, K, ROWS_PER_TILE), jnp.float32),
+        interpret=interpret,
+        name=name,
+    )
+
+
+@functools.lru_cache(maxsize=None)
+def _scatter_k_call(T, S, B, K, G, interpret, name):
+    return pl.pallas_call(
+        functools.partial(_scatter_k_kernel, K, G),
+        grid=(T // G,),
+        in_specs=[_spec_g(G, 1, S)] * 3 + [_spec_g(G, K, ROWS_PER_TILE)],
+        out_specs=_spec_table_k(K, _table_rows(B)),
+        out_shape=jax.ShapeDtypeStruct(
+            (K, _table_rows(B), LANE), jnp.float32),
+        interpret=interpret,
+        name=name,
     )
 
 
@@ -858,10 +978,16 @@ class TiledBatch:
         return per_row.astype(jnp.float32).reshape(
             self.num_tiles, 1, ROWS_PER_TILE)
 
+    @property
+    def _scatter_name(self) -> str:
+        """``<prefix>_scatter`` beside ``<prefix>_margins``."""
+        return self.margins_name.replace("_margins", "_scatter")
+
     def _scatter(self, per_row: Array, square: bool) -> Array:
         st = self._statics()
         g = self._run(
-            lambda T: _scatter_call(T, *st, square, _interpret()),
+            lambda T: _scatter_call(
+                T, *st, square, _interpret(), self._scatter_name),
             (*self._slot_args(), self._rows3(per_row)), (), reduce=True)
         return self._features(g)
 
@@ -920,6 +1046,47 @@ class TiledBatch:
             (*self._slot_args(), self._rows3(d2_row)),
             (self._w2(v_eff), sh.reshape(1, 2)), reduce=True)
         return self._features(g), sums[0, 0]
+
+
+    # -- K tables in one sweep (the factored coordinate's refit) ------------
+
+    def project_rows(self, a: Array) -> Array:
+        """``a`` [K, F] -> [K, n_pad]: row l is ``dot_rows(a[l])``. A strided
+        design gathers all K rows in ONE sweep of its tiles
+        (``%<prefix>_margins_k``, the masks built once a tile); a sorted one
+        makes K calls of :meth:`dot_rows` under one loop."""
+        K = a.shape[0]
+        if not self.strided or self.shard is not None:
+            return jax.lax.map(self.dot_rows, a)
+        S, B, _ = self._statics()
+        B8 = _table_rows(B)
+        grid = jnp.pad(
+            a.astype(jnp.float32), ((0, 0), (0, B8 * LANE - self.num_features))
+        ).reshape(K, B8, LANE)
+        G = _tiles_a_step(self.num_tiles)
+        tabs = _split_tables_call(
+            K, B, _interpret(),
+            self.margins_name.replace("_margins", "_tables") + "_k")(grid)
+        p = _project_call(
+            self.num_tiles, S, B, K, G, _interpret(),
+            self.margins_name + "_k",
+        )(*self._slot_args(), tabs)
+        return p.transpose(1, 0, 2).reshape(K, -1)
+
+    def scatter_rows(self, g: Array) -> Array:
+        """``g`` [K, n_pad] -> [K, F]: row l is ``scatter_features(g[l])``;
+        one sweep where the design is strided (``%<prefix>_scatter_k``)."""
+        K = g.shape[0]
+        if not self.strided or self.shard is not None:
+            return jax.lax.map(self.scatter_features, g)
+        S, B, _ = self._statics()
+        g3 = g.astype(jnp.float32).reshape(
+            K, self.num_tiles, ROWS_PER_TILE).transpose(1, 0, 2)
+        out = _scatter_k_call(
+            self.num_tiles, S, B, K, _tiles_a_step(self.num_tiles),
+            _interpret(), self._scatter_name + "_k",
+        )(*self._slot_args(), g3)
+        return out.reshape(K, -1)[:, : self.num_features]
 
     def feature_moment_sums(self) -> tuple[Array, Array, Array]:
         """Per-feature (sum x, sum x^2, count nonzero) over valid rows."""

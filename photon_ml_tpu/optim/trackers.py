@@ -213,6 +213,23 @@ class FactoredRandomEffectOptimizationTracker:
 
     steps: tuple  # of (RandomEffectOptimizationTracker, FE tracker | None)
 
+    @property
+    def final_value(self) -> float:
+        """The objective the update ended on: the last refit's final value
+        (data loss + the projection's L2 term), or in fixed-projection mode
+        the entities' final values summed."""
+        re_t, fe_t = self.steps[-1]
+        if fe_t is not None:
+            return float(fe_t.final_value)
+        return float(np.sum(re_t.final_values, dtype=np.float64))
+
+    @property
+    def iterations(self) -> float:
+        """The last alternation's latent solves: the entities' mean
+        iterations (a refit's own count is ``steps[i][1].iterations``)."""
+        its = self.steps[-1][0].iterations
+        return float(np.mean(its)) if len(its) else 0.0
+
     def to_summary_string(self) -> str:
         lines = []
         for i, (re_t, fe_t) in enumerate(self.steps):

@@ -463,7 +463,115 @@ def test_fixed_effect_of_the_mixed_cell_reports_its_own_nonzeros(rehearsal):
         design.num_tiles, design.vals.shape[2], design.num_blocks)
     assert fixed["strided"] == (design.rlo is None)
 
+MF = "ml20m_mf.cd_fit"
+FACTORED = "user-x-movie"
 
+
+def test_factored_update_leaves_the_refit_value_and_the_lanes_iterations(
+        rehearsal):
+    """``game_fit.step_facts`` reads ``last_tracker.final_value`` (the
+    refit's final objective) and ``.iterations`` (the latent solves' mean)
+    of a factored coordinate."""
+    cell_run = rehearsal(MF)
+    tracker = cell_run.driver.coordinates[FACTORED].last_tracker
+    step = [s for s in cell_run.ctx["fits"][-1]["steps"]
+            if s["coordinate"] == FACTORED][-1]
+    re_t, fe_t = tracker.steps[-1]
+    assert step["loss"] == float(fe_t.final_value) == tracker.final_value
+    assert step["solver_iterations"] == float(np.mean(re_t.iterations))
+    assert 1 <= fe_t.iterations <= 15  # the traffic's cap
+
+
+def test_factored_layout_gives_the_driver_its_shape(rehearsal):
+    """``game_fit_mf.Driver.shapes()``: ``re_data.buckets``,
+    ``latent_dim``, ``_nnz``, ``_design.num_tiles``; the coordinate passes
+    for a random effect of ``latent_dim`` features a row, and ``mf`` holds
+    what ``counts/mf_refit_pass.py`` prices a pass from."""
+    cell_run = rehearsal(MF)
+    shape = cell_run.ctx["shapes"]["coordinates"][FACTORED]
+    coordinate = cell_run.driver.coordinates[FACTORED]
+    rows = cell_run.driver.shape["rows"]
+    assert shape["kind"] == "random_effect" and shape["features"] == 16.0
+    assert 1 <= len(shape["buckets"]) <= shape["max_buckets"]
+    assert [b[:2] for b in shape["buckets"]] == [
+        [b.num_entities, b.rows_per_entity]
+        for b in coordinate.re_data.buckets]
+    # classed by rows alone: no two buckets of one R
+    assert len({b[1] for b in shape["buckets"]}) == len(shape["buckets"])
+    assert shape["mf"] == {
+        "nnz": rows, "rows": rows, "latent_dim": 16,
+        "features": cell_run.driver.shape["movies"]}
+    assert shape["T"] * 128 >= sum(e * r for e, r, _ in shape["buckets"])
+    counts = importlib.import_module("benchmark.counts.mf_refit_pass")
+    flops, nbytes = counts.per_fit(shape, 3.0)
+    assert flops == 3 * 4 * 16 * rows and nbytes > 3 * 8 * rows
+    fit = importlib.import_module("benchmark.counts.glmix_fit")
+    steps = cell_run.ctx["fits"][-1]["steps"]
+    assert len(list(fit.per_fit(cell_run.ctx["shapes"], steps))) == len(steps)
+
+
+def test_factored_counters_say_what_was_not_built_and_which_route(rehearsal):
+    """``mf.<coordinate>.*``: nothing of Kronecker length, every lane on
+    the hand solve, an evaluation more than the refit's iterations."""
+    ctx = rehearsal(MF).ctx
+    at_setup, at_end = ctx["counters"]["setup_end"], ctx["counters"][
+        "window_end"]
+    shape = ctx["shapes"]["coordinates"][FACTORED]
+    assert at_setup[f"mf.{FACTORED}.kron_nnz_materialised"] == 0
+    assert at_setup[f"mf.{FACTORED}.refit_nnz"] == shape["mf"]["nnz"]
+    assert at_setup[f"mf.{FACTORED}.latent_dim"] == 16
+    assert at_setup[f"re.{FACTORED}.buckets"] == len(shape["buckets"])
+    assert at_setup[f"re.{FACTORED}.rows_padded"] == sum(
+        e * r for e, r, _ in shape["buckets"])
+    assert at_end[f"mf.{FACTORED}.xla_solve_lanes"] == 0
+    assert at_end[f"mf.{FACTORED}.hand_solve_lanes"] > 0
+    assert at_end[f"mf.{FACTORED}.refit_evaluations"] > at_end[
+        f"mf.{FACTORED}.refit_iterations"] > 0
+    assert at_end[f"mf.{FACTORED}.lane_iterations"] <= at_end[
+        f"mf.{FACTORED}.lane_iterations_run"]
+    assert at_end.get("xla.fallback_calls", 0) == 0
+
+
+def test_factored_outputs_are_its_own_training_scores(rehearsal):
+    """``game_fit_mf.Driver.outputs()``: the coordinate's scores over the
+    training rows under its name (``coordinate.score``); the projection the
+    reference starts from under ``shape['latent_init']`` and the rows it
+    scores under ``shape['compared_rows']``."""
+    driver = rehearsal(MF).driver
+    out = driver.outputs()
+    n = driver.shape["rows"]
+    assert out["coefficients"][FACTORED].shape == (n,)
+    assert out["coefficients"][FACTORED].any()
+    assert np.all(np.isfinite(out["coefficients"][FACTORED]))
+    assert out["coefficients"]["fixed"].shape == (32,)
+    rows = driver.shape["compared_rows"][FACTORED]
+    np.testing.assert_array_equal(rows["ids"], driver.raw["train"]["userId"])
+    np.testing.assert_array_equal(
+        rows["cols"], driver.raw["train"]["movie_onehot_cols"][:, 0])
+    init = driver.shape["latent_init"][FACTORED]
+    assert init.shape == (16, driver.shape["movies"])
+    np.testing.assert_array_equal(
+        init, np.asarray(driver.coordinates[FACTORED].initialize_model()
+                         .projection.matrix))
+
+
+def test_mf_driver_refuses_a_program_that_builds_the_kronecker_design(
+        monkeypatch):
+    """Before it generates a row (the parent of PR 33 on the new cell)."""
+    from photon_ml_tpu.game import factored
+
+    workload = run.load_json("workloads", MF + ".json")
+    config = run.load_json("configs", workload["config"] + ".json")
+    traffic = run.load_json("traffic", workload["traffic"] + ".json")
+    module = importlib.import_module("benchmark.drivers." + config["driver"])
+    driver = module.Driver(config, traffic, SEED, rows=ROWS, force_tiled=True)
+    monkeypatch.delattr(factored, "KRON_FREE_REFIT")
+    with pytest.raises(RuntimeError, match="Kronecker"):
+        driver.setup()
+    assert driver.raw is None
+
+
+# last: building the coordinates again resets every `last_tracker`
 @pytest.mark.parametrize("cell", CELLS)
 def test_estimator_hands_back_the_coordinates_it_built(rehearsal, cell):
     """Every fit of the driver asks ``_build_coordinates(data, mesh=None)``

@@ -432,7 +432,7 @@ def _single_pass_products(text: str) -> list:
     return found
 
 
-def _entity_solver(packed: bool):
+def _entity_solver(packed: bool, kmajor: bool = False):
     import dataclasses
 
     from photon_ml_tpu.config import parse_optimizer_config
@@ -443,7 +443,7 @@ def _entity_solver(packed: bool):
         "regularization": "l2", "regularization_weight": 1.0})
     return _re_solver(
         dataclasses.replace(config, regularization_weight=0.0), "logistic",
-        False, False, packed=packed)
+        False, False, packed=packed, kmajor=kmajor)
 
 
 def _objective_shapes(one_chip):
@@ -550,3 +550,103 @@ def test_dense_entity_scores_are_float32_grade_for_v5e(E, R, K, one_chip):
     ).compile().as_text()
     assert re.match(r"HloModule jit_re_score_dense\b", text)
     assert not _single_pass_products(text)
+
+
+# -- the factored coordinate (ml20m_mf.cd_fit): latent solves, the refit -----
+
+MF_K, MF_D = 16, 27_278
+# (entities, padded rows) of the cell's buckets, the first as it stands
+MF_BUCKETS = ((41_659, 64), (512, 1024))
+
+
+def test_latent_solve_of_the_mf_cell_takes_the_hand_solve_for_v5e(one_chip):
+    """The latent per-user solve at 41,659 x 64 x 16 on the dense route,
+    from the feature-major flat design the projection pass writes
+    (``_re_solver(packed=True, kmajor=True)``): float32-grade products, the
+    module named ``jit_re_solve_dense``, and no ``Cholesky`` custom call: K = 16
+    is the hand SPD solve's."""
+    def s(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    E, R = MF_BUCKETS[0]
+    packed = (s((E, MF_K * R)), s((E, R)), s((E, R)), s((E, R)))
+    text = _entity_solver(True, kmajor=True).lower(
+        _objective_shapes(one_chip), packed, s((E, MF_K)), s(()), None
+    ).compile().as_text()
+    assert re.match(r"HloModule jit_re_solve_dense\b", text)
+    assert not _single_pass_products(text)
+    assert not any(
+        f'custom_call_target="{t}"' in text for t in _XLA_FACTOR_TARGETS)
+
+
+def _mf_design(one_chip, tiles):
+    def leaf(dtype):
+        return jax.ShapeDtypeStruct(
+            (tiles, 1, LANE), dtype, sharding=one_chip)
+
+    return TiledBatch(
+        vals=leaf(jnp.float32), hi=leaf(jnp.int32), lo=leaf(jnp.int32),
+        rlo=None, labels3=leaf(jnp.float32), offsets3=leaf(jnp.float32),
+        weights3=leaf(jnp.float32), num_features=MF_D,
+        margins_name="mf_margins")
+
+
+def test_mf_refit_holds_no_array_of_kronecker_length_for_v5e(
+        one_chip, on_chip_kernels):
+    """The refit of vec(A) over the one-hot design at d = 27,278, K = 16
+    (B = 214 column blocks): it compiles, its Mosaic calls are the K-wide
+    ``mf_margins_k`` / ``mf_scatter_k``, and no buffer of the program has
+    the parent's Kronecker length (nonzeros x K) or is wider than the
+    [K, rows] sides of a pass."""
+    import dataclasses
+
+    from photon_ml_tpu.config import parse_optimizer_config
+    from photon_ml_tpu.game import factored
+
+    def s(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    shapes, off = [], 0
+    for e, r in MF_BUCKETS:
+        shapes.append((off, e, r))
+        off += e * r
+    tiles = -(-off // ROWS_PER_TILE)
+    rows = tiles * ROWS_PER_TILE
+    nnz = int(off / 1.76)  # one nonzero a real row
+    config = parse_optimizer_config({
+        "type": "lbfgs", "max_iterations": 15, "tolerance": 0.0,
+        "regularization": "l2", "regularization_weight": 1.0})
+    solver = factored._latent_fit_solver(
+        dataclasses.replace(config, regularization_weight=0.0), "logistic",
+        tuple(shapes))
+    text = solver.lower(
+        _objective_shapes(one_chip), _mf_design(one_chip, tiles),
+        s((rows,)), s((rows,)), tuple(s((e, r)) for e, r in MF_BUCKETS),
+        tuple(s((e, MF_K)) for e, _ in MF_BUCKETS), s((MF_K * MF_D,)),
+        s(())).compile().as_text()
+    assert re.match(r"HloModule jit_factored_latent_fit\b", text)
+    assert "mf_margins_k" in text and "mf_scatter_k" in text
+    sizes = set()
+    for shape in re.findall(r"[fsu]\d+\[([\d,]+)\]", text):
+        sizes.add(int(np.prod([int(x) for x in shape.split(",")])))
+    assert nnz * MF_K not in sizes
+    assert max(sizes) <= max(MF_K * rows, 11 * MF_K * MF_D)
+
+
+def test_mf_projection_pass_compiles_at_the_cells_shape_for_v5e(
+        one_chip, on_chip_kernels):
+    """``jit_factored_project``: the K-wide gather over 231,905 tiles of
+    one-hot rows and the cut into the buckets' feature-major designs."""
+    from photon_ml_tpu.game import factored
+
+    shapes, off = [], 0
+    for e, r in MF_BUCKETS:
+        shapes.append((off, e, r))
+        off += e * r
+    tiles = -(-off // ROWS_PER_TILE)
+    out = factored._latent_design_fn(tuple(shapes)).lower(
+        _mf_design(one_chip, tiles),
+        jax.ShapeDtypeStruct((MF_K, MF_D), jnp.float32, sharding=one_chip))
+    text = out.compile().as_text()
+    assert re.match(r"HloModule jit_factored_project\b", text)
+    assert "mf_margins_k" in text
