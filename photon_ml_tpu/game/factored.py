@@ -32,6 +32,17 @@ entity, each entity padded to its bucket's R rows. In that order
     formed,
   - the per-row arrays are the buckets' own, concatenated.
 
+The REFIT alone may walk the rows in another order: the latent vectors are
+constant inside it and its state is vec(A), so its per-row arrays are its
+own. Where the base design has one nonzero a row (a one-hot shard: row id x
+column id, upstream's ``MatrixFactorizationModel``) and is tiled, the refit
+runs over a SECOND layout of it, sorted by column
+(:class:`SortedRefitRows`, ``ops/tiled.py::ColumnSortedTiles``): a tile's
+slots then touch a window of 16 table rows and not all of A, the gradient
+is a windowed segment sum, and twice a fit the latent vectors and the
+offsets are gathered into that order. Read off the data, never set: counter
+``mf.<name>.refit_column_sorted``.
+
 The design is a ``TiledBatch`` on a TPU (``layout``; one sweep of its tiles
 serves all K tables: ``%mf_margins_k`` / ``%mf_scatter_k``) and a COO
 ``SparseBatch`` elsewhere (K passes of ``dot_rows`` / ``scatter_features``
@@ -73,7 +84,14 @@ from photon_ml_tpu.optim.trackers import (
     RandomEffectOptimizationTracker,
 )
 from photon_ml_tpu.ops.panels import report_layout
-from photon_ml_tpu.ops.tiled import ROWS_PER_TILE, TILES_A_STEP, TiledBatch
+from photon_ml_tpu.ops.tiled import (
+    ROWS_PER_TILE,
+    TILES_A_STEP,
+    WINDOW,
+    ColumnSortedTiles,
+    TiledBatch,
+    sorted_slots,
+)
 from photon_ml_tpu.telemetry.device import accounted_upload
 from photon_ml_tpu.telemetry.metrics import counter
 from photon_ml_tpu.telemetry.trace import span
@@ -220,11 +238,15 @@ class LatentRefitBatch:
 
     Duck-type compatible with :class:`SparseBatch` for everything
     ``GLMObjective`` and ``glm_adapter`` use; ``w`` is vec(A) with A[l, j]
-    at ``j*K + l``. Every pass is the base design's ``project_rows`` or
-    ``scatter_rows`` plus elementwise work on [K, rows] arrays.
+    at ``j*K + l``. Every pass is one of the design's contracted forms
+    (``ops/sparse.py::ContractedRows``): ``project_rows`` / ``scatter_rows``
+    plus elementwise work on [K, rows] arrays where the rows lie in the
+    coordinate's order, one windowed sweep where the design is the
+    column-sorted second layout (:class:`SortedRefitRows`). The rows may lie
+    in ANY order: nothing outside the batch sees a per-row array of it.
     """
 
-    design: object  # SparseBatch | TiledBatch, rows in the coordinate's order
+    design: object  # SparseBatch | TiledBatch | ColumnSortedTiles
     c_rows: Array  # f[K, rows]: the latent vector of each row's entity
     labels: Array  # f[rows]
     offsets: Array  # f[rows]
@@ -252,8 +274,7 @@ class LatentRefitBatch:
     # -- sweeps (SparseBatch duck-type) --------------------------------------
 
     def dot_rows(self, w: Array) -> Array:
-        return jnp.sum(
-            self.c_rows * self.design.project_rows(self._matrix(w)), axis=0)
+        return self.design.contract_rows(self._matrix(w), self.c_rows)
 
     def margins(self, w: Array, shift: Array | float = 0.0) -> Array:
         return self.dot_rows(w) + shift + self.offsets
@@ -262,13 +283,12 @@ class LatentRefitBatch:
         return self.margins(w, shift), self.dot_rows(p) + p_shift
 
     def scatter_features(self, per_row: Array) -> Array:
-        g = self.design.scatter_rows(self.c_rows * per_row[None, :])
-        return g.T.reshape(-1)
+        return self.design.scatter_contracted(
+            per_row, self.c_rows).T.reshape(-1)
 
     def scatter_features_sq(self, per_row: Array) -> Array:
-        g = jax.lax.map(self.design.scatter_features_sq,
-                        self.c_rows * self.c_rows * per_row[None, :])
-        return g.T.reshape(-1)
+        return self.design.scatter_contracted(
+            per_row, self.c_rows, square=True).T.reshape(-1)
 
     def fused_value_grad(self, w, shift, loss_name: str):
         loss = get_loss(loss_name)
@@ -290,6 +310,45 @@ class LatentRefitBatch:
     def with_offsets(self, offsets: Array) -> "LatentRefitBatch":
         return dataclasses.replace(
             self, offsets=jnp.asarray(offsets, self.offsets.dtype))
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class SortedRefitRows:
+    """The refit's SECOND layout of a base design with one nonzero a row:
+    the rows sorted by column (``ops/tiled.py::ColumnSortedTiles``), and
+    what brings the coordinate's per-row arrays into that order. Inside a
+    refit the latent vectors are constant and the optimizer's state is
+    vec(A) alone, so the refit may walk the rows in any order; in this one a
+    tile touches a window of 16 table rows and not all of them. Lengths are
+    the layout's slots; a padding slot has weight 0 and points at row 0 /
+    entity 0."""
+
+    design: ColumnSortedTiles
+    labels: Array  # f[slots]
+    weights: Array  # f[slots]; 0 on padding slots
+    order: Array  # i32[slots]: the slot's row, as a position in the coordinate's order
+    entity: Array  # i32[slots]: its entity's row of the flat latent table
+
+
+#: rows a step of :func:`_latent_rows`: one gather's [rows, K] result is
+#: lane-padded 128/K-fold, so it has to stay small (PERF.md, Findings PR 33)
+_GATHER_ROWS = 1 << 17
+
+
+def _latent_rows(latent: Array, entity: Array) -> Array:
+    """[K, slots]: ``latent[entity].T`` with the slots on lanes. One row
+    take of K floats a slot, in chunks that are flattened before they leave
+    the loop (a 1-D array has one layout: stacked as [chunks, K, rows] XLA
+    keeps K minor and pads the lanes 8x at K = 16)."""
+    n, k = entity.shape[0], latent.shape[1]
+    chunks = -(-n // _GATHER_ROWS)
+    idx = jnp.pad(entity, (0, chunks * _GATHER_ROWS - n))
+    flat = jax.lax.map(
+        lambda i: jnp.take(latent, i, axis=0).T.reshape(-1),
+        idx.reshape(chunks, _GATHER_ROWS))
+    return flat.reshape(chunks, k, _GATHER_ROWS).transpose(1, 0, 2).reshape(
+        k, -1)[:, :n]
 
 
 def _rows_of_buckets(parts, shapes, total: int, lead: tuple = ()) -> Array:
@@ -365,15 +424,27 @@ def _foreign_scores_fn():
 @lru_cache(maxsize=64)
 def _latent_fit_solver(config: OptimizerConfig, loss_name: str,
                        shapes: tuple):
-    def run(obj, design, labels, weights, offsets, latents, w0, l1):
+    def run(obj, design, labels, weights, offsets, latents, w0, l1,
+            by_column=None):
         total = design.num_rows
-        batch = LatentRefitBatch(
-            design=design,
-            c_rows=_c_rows(latents, shapes, total),
-            labels=labels,
-            offsets=_rows_of_buckets(offsets, shapes, total),
-            weights=weights,
-        )
+        row_offsets = _rows_of_buckets(offsets, shapes, total)
+        if by_column is None:
+            batch = LatentRefitBatch(
+                design=design,
+                c_rows=_c_rows(latents, shapes, total),
+                labels=labels,
+                offsets=row_offsets,
+                weights=weights,
+            )
+        else:  # SortedRefitRows: the [K, total] broadcast is never formed
+            batch = LatentRefitBatch(
+                design=by_column.design,
+                c_rows=_latent_rows(
+                    jnp.concatenate(latents, axis=0), by_column.entity),
+                labels=by_column.labels,
+                offsets=jnp.take(row_offsets, by_column.order),
+                weights=by_column.weights,
+            )
         return dispatch_solve(glm_adapter(obj, batch), w0, config, l1)
 
     return instrumented_jit(run, name="factored_latent_fit", multi_shape=True)
@@ -540,6 +611,17 @@ class FactoredRandomEffectCoordinate:
                 vals, cols = vals[keep], cols[keep]
                 mf_rows = place[src[keep]]
                 del src, keep
+                # one nonzero in every placed row: the refit gets a second
+                # layout, sorted by column (a property of the data; no key)
+                by_column = order = None
+                if (self._use_tiled and len(vals)
+                        and len(vals) == np.count_nonzero(place >= 0)
+                        and np.bincount(mf_rows).max() == 1):
+                    with span("mf_refit_layout.sort"):
+                        # 16-bit keys take numpy's radix sort
+                        order = np.argsort(
+                            cols.astype(np.uint16) if d <= 1 << 16 else cols,
+                            kind="stable")
             with span("mf_layout.design"):
                 if self._use_tiled:
                     host = TiledBatch.pack_coo(
@@ -548,6 +630,10 @@ class FactoredRandomEffectCoordinate:
                         weights=weights,
                     ).traced_as("mf")
                     report_layout(host, f"mf.{self.name}.layout")
+                    if order is not None:
+                        with span("mf_refit_layout.pack"):
+                            by_column = self._pack_by_column(
+                                order, vals, cols, mf_rows, labels, weights)
                 else:
                     host = SparseBatch.from_coo(
                         values=vals, rows=mf_rows, cols=cols, labels=labels,
@@ -571,10 +657,38 @@ class FactoredRandomEffectCoordinate:
                 lambda: jax.tree.map(
                     jnp.asarray if shard is None
                     else (lambda x: jax.device_put(x, shard(np.asarray(x)))),
-                    (host, labels, weights, place)),
+                    (host, labels, weights, place, by_column)),
                 name="mf_upload")
-        self._design, self._labels, self._weights, self._place = placed
+        (self._design, self._labels, self._weights, self._place,
+         self._by_column) = placed
         self._report_layout()
+
+    def _pack_by_column(self, order, vals, cols, mf_rows, labels,
+                        weights) -> SortedRefitRows:
+        """The second layout from ``order`` (the stable sort of the
+        nonzeros by column): the design, and the per-row arrays the refit
+        needs in its slots' order."""
+        host, slot = ColumnSortedTiles.pack(
+            np.asarray(vals, np.float32)[order], cols[order],
+            self.re_data.num_global_features, sorted_slots(self._proj_rows))
+        at = mf_rows[order]  # the sorted rows' places in the coordinate's order
+        # the flat latent row of every place: a bucket's entities, R each
+        entity = np.zeros(len(labels), np.int32)
+        for (o, e, r), first in zip(self._shapes, self._flat_offsets):
+            entity[o:o + e * r] = np.repeat(
+                np.arange(first, first + e, dtype=np.int32), r)
+
+        def slots(dtype, per_row):
+            out = np.zeros(host.num_rows, dtype)
+            out[slot] = per_row
+            return out
+
+        return SortedRefitRows(
+            design=host.traced_as("mf"),
+            labels=slots(np.float32, labels[at]),
+            weights=slots(np.float32, weights[at]),
+            order=slots(np.int32, at),
+            entity=slots(np.int32, entity[at]))
 
     def _report_layout(self) -> None:
         """Counters ``re.<name>.*`` as a random-effect coordinate writes
@@ -598,6 +712,10 @@ class FactoredRandomEffectCoordinate:
         counter(f"mf.{self.name}.refit_nnz").inc(self._nnz)
         counter(f"mf.{self.name}.latent_dim").inc(k)
         counter(f"mf.{self.name}.kron_nnz_materialised").inc(0)
+        counter(f"mf.{self.name}.refit_column_sorted").inc(
+            self._by_column is not None)
+        counter(f"mf.{self.name}.refit_window_rows").inc(
+            WINDOW if self._by_column is not None else 0)
 
     def _resolve_mesh_axis(self) -> None:
         """Pick the ONE mesh axis this coordinate parallelizes over: the
@@ -739,7 +857,8 @@ class FactoredRandomEffectCoordinate:
         k = self.latent_dim
         res = self._lat_solver(
             self._lat_obj, self._design, self._labels, self._weights,
-            offsets, self._latents(latent), a.T.reshape(-1), self._lat_l1)
+            offsets, self._latents(latent), a.T.reshape(-1), self._lat_l1,
+            self._by_column)
         tracker = FixedEffectOptimizationTracker.from_result(res)
         for scope in (f"mf.{self.name}", "mf"):
             counter(f"{scope}.refit_iterations").inc(tracker.iterations)

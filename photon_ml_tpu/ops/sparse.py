@@ -59,9 +59,34 @@ def validate_coo_indices(
         )
 
 
+class ContractedRows:
+    """The contracted forms of a design's K-table passes, which the
+    factored coordinate's refit calls (``LatentRefitBatch``): the K
+    projections of a row summed against its latent vector, and K scatters of
+    one per-row vector scaled by it. A design that holds its rows in an
+    order of its own (``ops/tiled.py::ColumnSortedTiles``) answers them in
+    one pass each; here they are ``project_rows`` / ``scatter_rows`` and the
+    elementwise product."""
+
+    def contract_rows(self, a: Array, c_rows: Array) -> Array:
+        """``a`` [K, F], ``c_rows`` [K, rows] -> [rows]:
+        ``sum_l c_rows[l] * dot_rows(a[l])``."""
+        return jnp.sum(c_rows * self.project_rows(a), axis=0)
+
+    def scatter_contracted(self, q: Array, c_rows: Array,
+                           square: bool = False) -> Array:
+        """``q`` [rows], ``c_rows`` [K, rows] -> [K, F]: row l is
+        ``scatter_features(q * c_rows[l])``; with ``square`` the design's
+        values and ``c_rows`` are squared (a Hessian diagonal)."""
+        if square:
+            return jax.lax.map(
+                self.scatter_features_sq, c_rows * c_rows * q[None, :])
+        return self.scatter_rows(c_rows * q[None, :])
+
+
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
-class SparseBatch:
+class SparseBatch(ContractedRows):
     """A fixed-shape batch of sparse labeled examples.
 
     The TPU-native analog of the reference's ``RDD[LabeledPoint]`` /

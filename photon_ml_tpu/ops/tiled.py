@@ -87,6 +87,17 @@ a pass over 212,296 tiles of one-hot rows against 1,265 / 1,152 ms for 16
 calls of ``dot_rows`` / ``scatter_features`` (PERF.md, Findings PR 33). A
 sorted design makes the K calls under one loop.
 
+Those sweeps stream every row of every table past every slot and keep one
+in 224. Where a pass of the K tables is contracted with a per-row [K]
+vector on the way (``ContractedRows``: the refit's margins and gradient)
+and the design has ONE nonzero a row, :class:`ColumnSortedTiles` holds the
+rows a second time, sorted by column: a tile's slots then lie in one window
+of ``WINDOW`` = 16 table rows, the window of all K tables is ONE matmul's
+left operand (2K x 16 rows against the 2K x 224 above), and only margins
+[rows] or the [K, F] gradient leave the kernel
+(``%<prefix>_margins_k_sorted`` / ``%<prefix>_scatter_k_sorted``; PERF.md,
+Findings PR 34).
+
 Width and skew: a pass costs slots x B (the [2*B8, S] intermediates above),
 so this layout is for designs of up to ~128 column blocks; S is the longest
 row's or the fullest tile's, so ragged row lengths pad (rows or tiles: the
@@ -110,7 +121,11 @@ from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, PartitionSpec as P
 
 from photon_ml_tpu.ops.losses import get_loss
-from photon_ml_tpu.ops.sparse import SparseBatch, validate_coo_indices
+from photon_ml_tpu.ops.sparse import (
+    ContractedRows,
+    SparseBatch,
+    validate_coo_indices,
+)
 
 Array = jax.Array
 
@@ -481,6 +496,78 @@ def _scatter_k_kernel(K: int, G: int, vals_ref, hi_ref, lo_ref, g_ref,
             out_ref[l] = out_ref[l] + _place_slots(s * vals, hit, lot)
 
 
+#: table rows of a column-sorted tile's window: the bfloat16 sublane tile,
+#: so a window of the split tables is an aligned dynamic slice
+WINDOW = 16
+
+
+def sorted_slots(K: int) -> int:
+    """Slots of a column-sorted tile for K tables: 4,096, halved while the
+    float32 product of the tile's one matmul, [2K*WINDOW, S], is over 8 MiB
+    (K = 32: 2,048). A grid step costs ~0.35 us whatever it computes: a pass
+    over 18M rows at K = 16 takes 16.3 / 14.7 / 13.8 / 13.4 ms at 1,024 /
+    2,048 / 4,096 / 8,192 slots (PERF.md, Findings PR 34)."""
+    slots = 4096
+    while slots > LANE and 2 * K * WINDOW * slots * 4 > 8 << 20:
+        slots //= 2
+    return slots
+
+
+def _window_start(w_ref):
+    """First table row of this grid step's window (scalar-prefetched)."""
+    return pl.multiple_of(w_ref[pl.program_id(0)] * WINDOW, WINDOW)
+
+
+def _contract_window_kernel(K: int, w_ref, vals_ref, hi_ref, lo_ref, c_ref,
+                            tab_ref, out_ref):
+    """z_s = vals_s * sum_l c[l, s] * A[l, col_s] over one column-sorted
+    tile, whose S slots all lie in ONE window of ``WINDOW`` table rows.
+    ``tab`` [2K, B8, 128] holds the K tables' bf16x2 halves (row 2l the
+    high half of table l, 2l + 1 its low half) whole in VMEM; the window of
+    all of them is ONE matmul's left operand [2K*WINDOW, 128] against
+    ``lot``. The contraction with ``c`` [K, S] comes before the select, so
+    only a [WINDOW, S] slab meets ``hit`` and only margins [1, S] leave."""
+    win = tab_ref[:, pl.ds(_window_start(w_ref), WINDOW), :]
+    lot = _onehot_t(lo_ref[0], LANE).astype(jnp.bfloat16)
+    g = _dot(win.reshape(2 * K * WINDOW, LANE), lot, _NN)   # [2K*W, S]
+    c = c_ref[...]
+    acc = None
+    for l in range(K):
+        lo_half = (2 * l + 1) * WINDOW
+        gl = (g[lo_half - WINDOW:lo_half] + g[lo_half:lo_half + WINDOW]
+              ) * c[l:l + 1, :]
+        acc = gl if acc is None else acc + gl
+    hit = _onehot_t(hi_ref[0], WINDOW)
+    out_ref[0] = jnp.sum(
+        jnp.where(hit, acc, 0.0), axis=0, keepdims=True) * vals_ref[0]
+
+
+def _scatter_window_kernel(K: int, square: bool, w_ref, vals_ref, hi_ref,
+                           lo_ref, q_ref, c_ref, out_ref):
+    """out[l, col_s] += q_s * vals_s * c[l, s] over one column-sorted tile:
+    the K per-slot rows, split once, are placed in the window's rows by
+    selects and land by ONE NT matmul against ``lot``; the [K, B8, 128]
+    accumulator stays in VMEM for the whole call and a tile adds to its
+    window of it."""
+    @pl.when(pl.program_id(0) == 0)
+    def _():
+        out_ref[:] = jnp.zeros_like(out_ref)
+
+    vals = vals_ref[0]
+    if square:
+        vals = vals * vals
+    halves = _split_bf16(c_ref[...] * (q_ref[0] * vals))        # [K, S] x 2
+    hit = _onehot_t(hi_ref[0], WINDOW)
+    lot = _onehot_t(lo_ref[0], LANE).astype(jnp.bfloat16)
+    placed = [
+        jnp.where(hit, h[l:l + 1, :].astype(jnp.float32), 0.0)
+        .astype(jnp.bfloat16) for l in range(K) for h in halves]
+    d = _dot(jnp.concatenate(placed, axis=0), lot, _NT)         # [2K*W, 128]
+    d = d.reshape(K, 2, WINDOW, LANE)
+    at = pl.ds(_window_start(w_ref), WINDOW)
+    out_ref[:, at, :] = out_ref[:, at, :] + d[:, 0] + d[:, 1]
+
+
 # Every pallas_call below carries a ``name``: it becomes the custom call's
 # HLO instruction name (``%tiled_margins.1 = ... custom-call(...)``), which
 # is what a device event's name starts with in a profiler trace — the one
@@ -613,7 +700,9 @@ def _spec_g(G, *tail):
 
 
 def _spec_table_k(K, rows):
-    return pl.BlockSpec((K, rows, LANE), lambda i: (0, 0, 0),
+    """K whole [rows, 128] grids (``*_``: a scalar-prefetch grid hands the
+    index map its prefetched words too)."""
+    return pl.BlockSpec((K, rows, LANE), lambda i, *_: (0, 0, 0),
                         memory_space=pltpu.VMEM)
 
 
@@ -659,6 +748,54 @@ def _scatter_k_call(T, S, B, K, G, interpret, name):
     )
 
 
+def _spec_sorted(lead, S):
+    """One column-sorted tile of a [T, 1, S] slot array (``lead`` 1) or of
+    a [K, T*S] per-slot array (``lead`` K), under the scalar prefetch."""
+    if lead == 1:
+        return pl.BlockSpec((1, 1, S), lambda i, w: (i, 0, 0),
+                            memory_space=pltpu.VMEM)
+    return pl.BlockSpec((lead, S), lambda i, w: (0, i),
+                        memory_space=pltpu.VMEM)
+
+
+@functools.lru_cache(maxsize=None)
+def _contract_window_call(T, S, B, K, interpret, name):
+    return pl.pallas_call(
+        functools.partial(_contract_window_kernel, K),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(T,),
+            in_specs=[_spec_sorted(1, S)] * 3 + [_spec_sorted(K, S)]
+            + [_spec_table_k(2 * K, _table_rows(B))],
+            out_specs=_spec_sorted(1, S)),
+        out_shape=jax.ShapeDtypeStruct((T, 1, S), jnp.float32),
+        interpret=interpret, name=name)
+
+
+@functools.lru_cache(maxsize=None)
+def _scatter_window_call(T, S, B, K, square, interpret, name):
+    return pl.pallas_call(
+        functools.partial(_scatter_window_kernel, K, square),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(T,),
+            in_specs=[_spec_sorted(1, S)] * 4 + [_spec_sorted(K, S)],
+            out_specs=_spec_table_k(K, _table_rows(B))),
+        out_shape=jax.ShapeDtypeStruct(
+            (K, _table_rows(B), LANE), jnp.float32),
+        interpret=interpret, name=name)
+
+
+def _split_tables(a, num_features: int, name: str):
+    """``a`` [K, F] float32 -> the bf16x2 halves of its K coefficient grids,
+    [K, 2*B8, 128] (Mosaic call ``name``: :func:`_split_tables_kernel`)."""
+    K = a.shape[0]
+    B = -(-num_features // LANE)
+    B8 = _table_rows(B)
+    grid = jnp.pad(
+        a.astype(jnp.float32), ((0, 0), (0, B8 * LANE - num_features))
+    ).reshape(K, B8, LANE)
+    return _split_tables_call(K, B, _interpret(), name)(grid)
+
+
 def run_tiles(shard, num_tiles, make_call, tile_args, rep_args, reduce: bool):
     """Run ``make_call(T)`` -- a pallas_call over T tiles -- on a tiled
     design. ``tile_args`` lead with the tile dim, ``rep_args`` (the
@@ -693,7 +830,7 @@ def run_tiles(shard, num_tiles, make_call, tile_args, rep_args, reduce: bool):
 
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
-class TiledBatch:
+class TiledBatch(ContractedRows):
     """Sparse labeled examples in the tiled one-hot-matmul layout.
 
     Duck-type compatible with :class:`SparseBatch` for everything
@@ -1059,14 +1196,10 @@ class TiledBatch:
         if not self.strided or self.shard is not None:
             return jax.lax.map(self.dot_rows, a)
         S, B, _ = self._statics()
-        B8 = _table_rows(B)
-        grid = jnp.pad(
-            a.astype(jnp.float32), ((0, 0), (0, B8 * LANE - self.num_features))
-        ).reshape(K, B8, LANE)
         G = _tiles_a_step(self.num_tiles)
-        tabs = _split_tables_call(
-            K, B, _interpret(),
-            self.margins_name.replace("_margins", "_tables") + "_k")(grid)
+        tabs = _split_tables(
+            a, self.num_features,
+            self.margins_name.replace("_margins", "_tables") + "_k")
         p = _project_call(
             self.num_tiles, S, B, K, G, _interpret(),
             self.margins_name + "_k",
@@ -1108,3 +1241,132 @@ class TiledBatch:
 
     def with_weights(self, weights: Array) -> "TiledBatch":
         return dataclasses.replace(self, weights3=self._rows3(weights))
+
+
+# ---------------------------------------------------------------------------
+# one-hot rows sorted by column: the factored coordinate's refit
+# ---------------------------------------------------------------------------
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class ColumnSortedTiles:
+    """A design of ONE nonzero a row with its rows sorted by column, for
+    the passes of a [K, F] table that are contracted with a per-row [K]
+    vector (the two methods of ``ops/sparse.py::ContractedRows``, each ONE
+    sweep here; the refit of a factored coordinate's projection, whose
+    per-row arrays are its own and may lie in any order).
+
+    Sorted by column, S consecutive rows touch a handful of adjacent
+    columns, so a tile needs a WINDOW of the table, not all of it: the
+    sorted rows are cut where ``col // (128 * WINDOW)`` changes and then
+    into tiles of S slots, so that every tile lies in one aligned window of
+    ``WINDOW`` = 16 table rows (a window's last tile is padded; at most one
+    a window, 14 of 4,409 at d = 27,278 and K = 16). A tile's gather or
+    placement is then ONE matmul whose left operand is the window of all K
+    tables (2K x 16 rows), where :meth:`TiledBatch.project_rows` streams
+    all 2 x B8 rows of every table (PERF.md, Findings PR 34). ``num_rows`` is
+    the slots': the caller lays its per-row arrays out by the slot of each
+    row that :meth:`pack` returns; a padding slot carries value 0 and
+    ``hi == WINDOW``, which no window row matches.
+    """
+
+    window: Array    # i32[T] a tile's window: table rows from window*WINDOW
+    vals: Array      # f32[T, 1, S] slot values (0 in padding)
+    hi: Array        # i32[T, 1, S] col // 128 inside the window (padding: WINDOW)
+    lo: Array        # i32[T, 1, S] col % 128
+    num_features: int = dataclasses.field(metadata=dict(static=True))
+    # the Mosaic calls' prefix in a device trace: ``<prefix>_margins_k_sorted``
+    # / ``<prefix>_scatter_k_sorted`` / ``<prefix>_tables_k``
+    prefix: str = dataclasses.field(default="tiled", metadata=dict(static=True))
+
+    @property
+    def num_tiles(self) -> int:
+        return self.vals.shape[0]
+
+    @property
+    def num_rows(self) -> int:
+        return self.vals.shape[0] * self.vals.shape[2]
+
+    @property
+    def num_blocks(self) -> int:
+        return -(-self.num_features // LANE)
+
+    @staticmethod
+    def pack(values: np.ndarray, cols: np.ndarray, num_features: int,
+             slots: int) -> tuple["ColumnSortedTiles", np.ndarray]:
+        """Host-side layout of rows ALREADY sorted by column (``cols``
+        non-decreasing, one entry a row) into tiles of ``slots``
+        (:func:`sorted_slots` of the tables it will serve). Returns the
+        layout (host numpy leaves) and each row's slot in it."""
+        cols = np.asarray(cols)
+        if len(cols) and (cols[1:] < cols[:-1]).any():
+            raise ValueError("ColumnSortedTiles.pack: cols are not sorted")
+        validate_coo_indices(cols[:0], cols[[0, -1][:len(cols)]], 1,
+                             num_features)
+        windows = _table_rows(-(-int(num_features) // LANE)) // WINDOW
+        # a window's rows are consecutive: where each starts, and its tiles
+        edges = np.arange(windows + 1, dtype=np.int64) * (WINDOW * LANE)
+        first = np.searchsorted(  # needles of cols' own dtype: no copy of it
+            cols, np.minimum(edges, int(num_features)).astype(cols.dtype))
+        counts = np.diff(first)
+        tiles = -(-counts // slots)
+        tiles[0] += not tiles.any()  # an empty design is one tile of padding
+        T = int(tiles.sum())
+        slot = np.arange(len(cols), dtype=np.int64) + np.repeat(
+            (np.cumsum(tiles) - tiles) * slots - first[:-1], counts)
+
+        def placed(fill, dtype, per_row):
+            out = np.full(T * slots, fill, dtype)
+            out[slot] = per_row
+            return out.reshape(T, 1, slots)
+
+        return ColumnSortedTiles(
+            window=np.repeat(np.arange(windows, dtype=np.int32), tiles),
+            vals=placed(0.0, np.float32, values),
+            hi=placed(WINDOW, np.int32, cols // LANE % WINDOW),
+            lo=placed(0, np.int32, cols % LANE),
+            num_features=int(num_features)), slot
+
+    def traced_as(self, prefix: str) -> "ColumnSortedTiles":
+        return dataclasses.replace(self, prefix=prefix)
+
+    def to_dense(self) -> np.ndarray:
+        """Host-side densify, a row a slot (tests / diagnostics only)."""
+        T, _, S = self.vals.shape
+        hi = np.asarray(self.hi).reshape(-1)
+        col = ((np.repeat(np.asarray(self.window), S) * WINDOW + hi) * LANE
+               + np.asarray(self.lo).reshape(-1))
+        keep = np.flatnonzero(hi < WINDOW)
+        X = np.zeros((T * S, self.num_features), np.float64)
+        X[keep, col[keep]] = np.asarray(self.vals).reshape(-1)[keep]
+        return X
+
+    def _slot_args(self):
+        return (self.window, self.vals, self.hi, self.lo)
+
+    def contract_rows(self, a: Array, c_rows: Array) -> Array:
+        """``sum_l c_rows[l] * (X a[l])`` [rows] in one sweep: the [K, rows]
+        projection is never written."""
+        K = a.shape[0]
+        T, _, S = self.vals.shape
+        tabs = _split_tables(a, self.num_features, self.prefix + "_tables_k")
+        z = _contract_window_call(
+            T, S, self.num_blocks, K, _interpret(),
+            self.prefix + "_margins_k_sorted",
+        )(*self._slot_args(), c_rows.astype(jnp.float32),
+          tabs.reshape(2 * K, -1, LANE))
+        return z.reshape(-1)
+
+    def scatter_contracted(self, q: Array, c_rows: Array,
+                           square: bool = False) -> Array:
+        """``X^T (q * c_rows[l])`` for every l, [K, F], in one sweep."""
+        K = c_rows.shape[0]
+        T, _, S = self.vals.shape
+        c = c_rows.astype(jnp.float32)
+        out = _scatter_window_call(
+            T, S, self.num_blocks, K, square, _interpret(),
+            self.prefix + "_scatter_k_sorted",
+        )(*self._slot_args(), q.astype(jnp.float32).reshape(T, 1, S),
+          c * c if square else c)
+        return out.reshape(K, -1)[:, : self.num_features]

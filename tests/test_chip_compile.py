@@ -650,3 +650,100 @@ def test_mf_projection_pass_compiles_at_the_cells_shape_for_v5e(
     text = out.compile().as_text()
     assert re.match(r"HloModule jit_factored_project\b", text)
     assert "mf_margins_k" in text
+
+
+# -- the refit's column-sorted second layout (PR 34) -------------------------
+
+# ml20m_mf.cd_fit: 18M one-hot rows in tiles of 4,096 slots (K = 16; 140,625
+# tiles of 128 rows, 32 a grid step), one padded tile a window of 16 table
+# rows at most (B8 = 224: 14 windows)
+MF_SORTED_TILES = -(-18_000_000 // tiled.sorted_slots(16)) + 14
+MF_B = -(-MF_D // LANE)  # 214
+
+
+def _sorted_slot_shapes():
+    T, S = MF_SORTED_TILES, tiled.sorted_slots(16)
+    return [((T,), jnp.int32), ((T, 1, S), jnp.float32),
+            ((T, 1, S), jnp.int32), ((T, 1, S), jnp.int32)]
+
+
+@pytest.mark.parametrize("kernel", ["mf_margins_k_sorted", "mf_scatter_k_sorted"])
+def test_windowed_kernel_compiles_at_the_mf_cells_shape_for_v5e(
+        kernel, one_chip):
+    """The two kernels over the column-sorted layout at 4,409 tiles x 4,096
+    slots, B = 214, K = 16, a window of 16 table rows: the scalar-prefetched
+    window of each tile, the aligned dynamic slice of the bf16x2 tables (whole
+    in VMEM) and of the float32 accumulator, all inside the VMEM the call
+    asks for. Their names keep the prefixes ``%mf_margins_k`` /
+    ``%mf_scatter_k`` that ``mf_gather_roofline`` / ``mf_scatter_roofline``
+    read."""
+    T, S = MF_SORTED_TILES, tiled.sorted_slots(16)
+    assert tiled.WINDOW == 16 and T * S // ROWS_PER_TILE >= 140_625
+    B8 = tiled._table_rows(MF_B)
+    if kernel == "mf_margins_k_sorted":
+        call = tiled._contract_window_call(T, S, MF_B, MF_K, False, kernel)
+        shapes = _sorted_slot_shapes() + [
+            ((MF_K, T * S), jnp.float32),
+            ((2 * MF_K, B8, LANE), jnp.bfloat16)]
+    else:
+        call = tiled._scatter_window_call(
+            T, S, MF_B, MF_K, False, False, kernel)
+        shapes = _sorted_slot_shapes() + [
+            ((T, 1, S), jnp.float32), ((MF_K, T * S), jnp.float32)]
+    _compiles_as(kernel, call, shapes, one_chip)
+    assert kernel.startswith(("mf_margins_k", "mf_scatter_k"))
+
+
+def test_mf_refit_over_the_second_layout_compiles_for_v5e(
+        one_chip, on_chip_kernels):
+    """``jit_factored_latent_fit`` with the column-sorted rows at the cell's
+    size: its Mosaic calls are the windowed pair (and the split of the
+    tables), the standing K-wide sweeps are not in it, and the [K, total]
+    broadcast of the latent table (1.7 GB in the cell) is not formed."""
+    import dataclasses
+
+    from photon_ml_tpu.config import parse_optimizer_config
+    from photon_ml_tpu.game import factored
+
+    def s(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    shapes, off = [], 0
+    for e, r in MF_BUCKETS:
+        shapes.append((off, e, r))
+        off += e * r
+    tiles = -(-off // ROWS_PER_TILE)
+    rows = tiles * ROWS_PER_TILE
+    T, S = MF_SORTED_TILES, tiled.sorted_slots(16)
+    by_column = factored.SortedRefitRows(
+        design=tiled.ColumnSortedTiles(
+            window=s((T,), jnp.int32), vals=s((T, 1, S)),
+            hi=s((T, 1, S), jnp.int32), lo=s((T, 1, S), jnp.int32),
+            num_features=MF_D, prefix="mf"),
+        labels=s((T * S,)), weights=s((T * S,)),
+        order=s((T * S,), jnp.int32), entity=s((T * S,), jnp.int32))
+    config = parse_optimizer_config({
+        "type": "lbfgs", "max_iterations": 15, "tolerance": 0.0,
+        "regularization": "l2", "regularization_weight": 1.0})
+    solver = factored._latent_fit_solver(
+        dataclasses.replace(config, regularization_weight=0.0), "logistic",
+        tuple(shapes))
+    compiled = solver.lower(
+        _objective_shapes(one_chip), _mf_design(one_chip, tiles),
+        s((rows,)), s((rows,)), tuple(s((e, r)) for e, r in MF_BUCKETS),
+        tuple(s((e, MF_K)) for e, _ in MF_BUCKETS), s((MF_K * MF_D,)),
+        s(()), by_column).compile()
+    text = compiled.as_text()
+    assert re.match(r"HloModule jit_factored_latent_fit\b", text)
+    calls = set(re.findall(
+        r"%(\w+?)(?:\.\d+)? = [^\n]*custom_call_target=\"tpu_custom_call\"",
+        text))
+    assert calls == {
+        "mf_tables_k", "mf_margins_k_sorted", "mf_scatter_k_sorted"}, calls
+    sizes = set()
+    for shape in re.findall(r"f32\[([\d,]+)\]", text):
+        sizes.add(int(np.prod([int(x) for x in shape.split(",")])))
+    assert MF_K * rows not in sizes
+    # the latent rows [K, slots] (1.15 GB) and a copy of them in the loop
+    # that gathers them; the coordinate-order refit holds 8.0 GB
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 << 30

@@ -5,7 +5,9 @@ old path's arithmetic, kept as the oracle) and against ``jax.grad`` of the
 plain margins; the dense-route latent solve against the COO-route solve;
 device-resident validation scores against ``FactoredRandomEffectModel
 .score``; the K-table sweeps of a tiled design; buckets classed by rows
-alone; the mesh path on four virtual devices; what the telemetry says."""
+alone; the mesh path on four virtual devices; what the telemetry says; and
+since PR 34 the refit over the column-sorted second layout of a one-hot
+design against the refit in the coordinate's own row order."""
 
 import dataclasses
 
@@ -204,13 +206,14 @@ def _mf_data(rng, n_users=30, d=25, one_hot=True):
 
 
 def _coordinate(data, layout="coo", mesh=None, mf_iterations=1,
-                kind=OptimizerType.NEWTON, name="mf"):
+                kind=OptimizerType.NEWTON, name="mf", refit_cap=8):
     red = build_random_effect_dataset(
         data, "userId", "item", class_by_features=False)
     return FactoredRandomEffectCoordinate(
         name=name, data=data, re_data=red, loss_name="logistic",
         re_config=_opt(kind, lam=1.0, iters=20, tol=1e-7),
-        latent_config=_opt(OptimizerType.LBFGS, lam=1.0, iters=8, tol=0.0),
+        latent_config=_opt(
+            OptimizerType.LBFGS, lam=1.0, iters=refit_cap, tol=0.0),
         latent_dim=K, mf_iterations=mf_iterations, layout=layout, mesh=mesh)
 
 
@@ -385,6 +388,145 @@ def test_mesh_path_on_four_devices_matches_one_device(rng):
     np.testing.assert_allclose(
         sharded.score(m_shard), local.score(m_local), rtol=5e-3, atol=5e-4)
     assert factored.KRON_FREE_REFIT
+
+
+# -- the column-sorted second layout (PR 34) ---------------------------------
+
+
+def _refit(coord, latent, a, by_column):
+    """One refit of vec(A) from ``a`` through the coordinate's own compiled
+    solver, over the second layout or (``by_column`` None) in the
+    coordinate's row order."""
+    res = coord._lat_solver(
+        coord._lat_obj, coord._design, coord._labels, coord._weights,
+        coord._bucket_offsets(None), coord._latents(latent),
+        a.T.reshape(-1), coord._lat_l1, by_column)
+    return (np.asarray(res.w).reshape(-1, K).T, float(res.value),
+            int(res.iterations))
+
+
+def test_column_sorted_refit_is_the_coordinate_order_refit(rng):
+    """Same design, same optimizer, same start: the rows walked by column
+    give the coordinate-order refit's A, loss and iteration count."""
+    data, *_ = _mf_data(rng, n_users=40, d=300)  # three column blocks
+    # a cap under the iteration at which float32 ends the solve by itself
+    # (the 6th or 7th here, by the last bits of the sums' order)
+    coord = _coordinate(data, layout="tiled", refit_cap=5)
+    by_column = coord._by_column
+    assert by_column is not None and isinstance(coord._design, TiledBatch)
+    assert by_column.design.num_rows >= data.num_rows
+    # the slots hold every row once: labels, weights and entities permuted
+    order = np.asarray(by_column.order)
+    live = np.asarray(by_column.weights) > 0
+    assert live.sum() == data.num_rows
+    assert len(set(order[live])) == data.num_rows
+    np.testing.assert_array_equal(
+        np.asarray(coord._labels)[order[live]],
+        np.asarray(by_column.labels)[live])
+    model = coord.initialize_model()
+    a = model.projection.matrix
+    latent, _ = coord._latent_re_step(
+        model.latent, a, coord._bucket_offsets(None))
+    c_sorted = factored._latent_rows(latent, by_column.entity)
+    c_rows = factored._c_rows(
+        coord._latents(latent), coord._shapes, coord._design.num_rows)
+    np.testing.assert_array_equal(
+        np.asarray(c_sorted)[:, live], np.asarray(c_rows)[:, order[live]])
+    a_col, loss_col, its_col = _refit(coord, latent, a, by_column)
+    a_row, loss_row, its_row = _refit(coord, latent, a, None)
+    assert its_col == its_row == 5
+    np.testing.assert_allclose(loss_col, loss_row, rtol=1e-6)
+    assert np.linalg.norm(a_col - a_row) <= 1e-5 * np.linalg.norm(a_row)
+    # and the update itself takes the second layout
+    telemetry.reset()
+    stepped, _ = coord._latent_matrix_step(
+        latent, a, coord._bucket_offsets(None))
+    np.testing.assert_array_equal(stepped, a_col)
+    calls = {
+        e.params["name"]
+        for e in jax.make_jaxpr(
+            lambda w: LatentRefitBatch(
+                design=by_column.design, c_rows=c_sorted,
+                labels=by_column.labels, offsets=by_column.labels,
+                weights=by_column.weights).fused_value_grad(w, 0.0, "logistic")
+        )(a.T.reshape(-1)).jaxpr.eqns if e.primitive.name == "pallas_call"}
+    assert calls == {
+        "mf_tables_k", "mf_margins_k_sorted", "mf_scatter_k_sorted"}
+
+
+@pytest.mark.parametrize("case", [
+    "one_hot_tiled", "two_nonzeros_a_row", "one_hot_mesh", "one_hot_coo",
+    "a_row_without_a_nonzero"])
+def test_second_layout_is_chosen_by_the_design_alone(rng, case):
+    """One nonzero in every row AND the Mosaic design: nothing else engages
+    it, and counter ``mf.<name>.refit_column_sorted`` says which."""
+    from jax.sharding import Mesh
+
+    one_hot = case != "two_nonzeros_a_row"
+    data, *_ = _mf_data(rng, n_users=12, one_hot=one_hot)
+    if case == "a_row_without_a_nonzero":
+        shard = data.shard("item")
+        values = np.asarray(shard.values).copy()
+        values[3] = 0.0
+        data = dataclasses.replace(data, feature_shards={
+            "item": dataclasses.replace(shard, values=values)})
+    mesh = (Mesh(np.asarray(jax.devices()[:4]), ("entity",))
+            if case == "one_hot_mesh" else None)
+    telemetry.reset()
+    coord = _coordinate(
+        data, layout="coo" if case == "one_hot_coo" else "tiled", mesh=mesh,
+        name="uxm")
+    engaged = case == "one_hot_tiled"
+    counters = telemetry.snapshot()["counters"]
+    assert counters["mf.uxm.refit_column_sorted"] == int(engaged)
+    assert counters["mf.uxm.refit_window_rows"] == (16 if engaged else 0)
+    assert (coord._by_column is not None) == engaged
+    names = {s.name for s in telemetry.finished_spans()}
+    assert ({"mf_refit_layout.sort", "mf_refit_layout.pack"} <= names
+            ) == engaged
+    model = coord.update_model(coord.initialize_model(), None)
+    assert np.all(np.isfinite(np.asarray(model.projection.matrix)))
+    counters = telemetry.snapshot()["counters"]
+    assert counters["mf.uxm.refit_evaluations"] == counters[
+        "mf.uxm.refit_iterations"] + 1
+
+
+@pytest.mark.parametrize("one_hot", [True, False])
+def test_masked_factored_update_is_unchanged_by_the_second_layout(
+        rng, one_hot):
+    """``incremental/refit.py::MaskedFactoredRandomEffectCoordinate`` over a
+    coordinate with (one-hot) and without the second layout: the touched
+    entities' latent vectors are the full latent step's, the others stand
+    bit for bit, the projection is frozen."""
+    from photon_ml_tpu.incremental.refit import (
+        MaskedFactoredRandomEffectCoordinate,
+    )
+
+    data, *_ = _mf_data(rng, n_users=20, one_hot=one_hot)
+    inner = _coordinate(data, layout="tiled")
+    assert (inner._by_column is not None) == one_hot
+    model = inner.update_model(inner.initialize_model(), None)
+    touched = np.zeros(inner.re_data.num_entities, bool)
+    touched[[1, 5, 11]] = True
+    masked = MaskedFactoredRandomEffectCoordinate(inner, touched)
+    residual = jnp.asarray(
+        rng.normal(size=inner._batch.num_rows).astype(np.float32) * 0.3)
+    got = masked.update_model(model, residual)
+    full, _ = inner._latent_re_step(
+        model.latent, model.projection.matrix,
+        inner._bucket_offsets(residual))
+    flat = inner._entity_flat[touched]
+    others = np.setdiff1d(np.arange(inner._n_flat), flat)
+    np.testing.assert_array_equal(
+        np.asarray(got.latent)[others], np.asarray(model.latent)[others])
+    np.testing.assert_allclose(
+        np.asarray(got.latent)[flat], np.asarray(full)[flat],
+        rtol=1e-4, atol=1e-5)
+    assert not np.array_equal(
+        np.asarray(got.latent)[flat], np.asarray(model.latent)[flat])
+    np.testing.assert_array_equal(
+        got.projection.matrix, model.projection.matrix)
+    assert masked.lanes_solved == 3
 
 
 def test_program_agrees_with_the_plain_reference_through_the_mf_driver():

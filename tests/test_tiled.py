@@ -459,3 +459,103 @@ def test_both_assignments_are_one_design(rng, monkeypatch):
     ]:
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-4)
+
+
+# -- one-hot rows sorted by column: the windowed K-table kernels -------------
+
+# name -> (features, slots a tile, the sorted rows' columns)
+_SORTED_SHAPES = {
+    # every seventh column has no row; two windows, the last tile padded
+    "columns_with_no_row": (
+        5_000, 256, lambda rng: np.delete(
+            np.sort(rng.integers(0, 5_000, 2_600)),
+            np.s_[::7])),
+    # one tile whose 128 slots lie in two adjacent 128-column blocks
+    "tile_straddles_two_blocks": (
+        2_048, 128, lambda rng: np.sort(rng.integers(300, 460, 128))),
+    # rows either side of column 2,048 (table row 16): the first window's
+    # tile is closed at 37 slots, the next opens in the second window
+    "tile_closed_at_the_window": (
+        4_096, 128, lambda rng: np.concatenate(
+            [np.sort(rng.integers(1_990, 2_048, 37)),
+             np.sort(rng.integers(2_048, 2_100, 60))])),
+    # the tile K = 16 gets (4,096 slots), 1.3 of them: a padded last step
+    "padded_last_grid_step": (
+        1_500, tiled.sorted_slots(16),
+        lambda rng: np.sort(rng.integers(0, 1_500, 5_324))),
+    # no row at all: one tile of padding
+    "empty": (300, 128, lambda rng: np.zeros(0, np.int64)),
+}
+
+
+@pytest.mark.parametrize("K", [16, 3])
+@pytest.mark.parametrize("shape", _SORTED_SHAPES)
+def test_windowed_kernels_exact_against_float64(shape, K):
+    """``ColumnSortedTiles.contract_rows`` / ``.scatter_contracted`` (the
+    refit's two passes over the column-sorted layout) against a float64
+    dense computation, at the tolerance of the standing kernels."""
+    f, slots, columns = _SORTED_SHAPES[shape]
+    rng = np.random.default_rng(sorted(_SORTED_SHAPES).index(shape))
+    cols = columns(rng)
+    n = len(cols)
+    values = rng.normal(size=n) * np.exp(rng.normal(size=n))
+    host, slot = tiled.ColumnSortedTiles.pack(values, cols, f, slots=slots)
+    design = jax.tree.map(jnp.asarray, host)
+    windows = np.asarray(host.window)
+    assert np.all(np.diff(windows) >= 0)
+    X = design.to_dense()
+    rows = design.num_rows
+    # a row lands in its slot, in its window, and nothing else is there
+    want_x = np.zeros((n, f))
+    want_x[np.arange(n), cols] = values.astype(np.float32)
+    np.testing.assert_array_equal(X[slot], want_x)
+    assert np.count_nonzero(X) == np.count_nonzero(want_x)
+    assert np.array_equal(windows[slot // slots], cols // (128 * tiled.WINDOW))
+    if shape == "tile_straddles_two_blocks":
+        assert len(windows) == 1 and len(set(cols // 128)) == 2
+    if shape == "tile_closed_at_the_window":
+        assert list(windows) == [0, 1] and list(slot[[36, 37]]) == [36, 128]
+    if shape == "padded_last_grid_step":
+        assert rows == 2 * tiled.sorted_slots(16) > n
+    if shape == "empty":
+        assert rows == slots and not X.any()
+
+    a = rng.normal(size=(K, f)) * np.exp(rng.normal(size=f))
+    c = rng.normal(size=(K, rows))
+    q = rng.normal(size=rows) * np.exp(rng.normal(size=rows))
+    a32, c32, q32 = (jnp.asarray(x, jnp.float32) for x in (a, c, q))
+    a, c, q = _f64(a32), _f64(c32), _f64(q32)
+    for got, want in (
+            (design.contract_rows(a32, c32), np.sum(c * (a @ X.T), axis=0)),
+            (design.scatter_contracted(q32, c32), (c * q) @ X),
+            (design.scatter_contracted(q32, c32, square=True),
+             (c * c * q) @ (X * X))):
+        assert got.shape == want.shape
+        if want.any():
+            err = np.linalg.norm(_f64(got) - want) / np.linalg.norm(want)
+            assert err < EXACT_REL, err
+        else:
+            assert not np.asarray(got).any()
+    # the contracted forms of a design in any row order agree with it
+    coo = SparseBatch.from_coo(
+        values=values, rows=np.arange(n), cols=cols, labels=np.zeros(max(n, 1)),
+        num_features=f)
+    ours = _f64(design.contract_rows(a32, c32))[slot]
+    theirs = _f64(coo.contract_rows(a32, c32[:, slot]))[:n]
+    assert np.linalg.norm(ours - theirs) <= EXACT_REL * np.linalg.norm(theirs)
+
+
+def test_column_sorted_pack_refuses_unsorted_columns():
+    with pytest.raises(ValueError, match="not sorted"):
+        tiled.ColumnSortedTiles.pack(
+            np.ones(3), np.asarray([4, 2, 9]), 16, slots=128)
+    with pytest.raises(ValueError, match="feature indices"):
+        tiled.ColumnSortedTiles.pack(
+            np.ones(2), np.asarray([4, 16]), 16, slots=128)
+
+
+def test_a_column_sorted_tile_shrinks_as_the_tables_grow():
+    """The tile's one matmul writes [2K x 16, slots] float32: 8 MiB at
+    most, so that a wider latent space still compiles."""
+    assert [tiled.sorted_slots(k) for k in (1, 3, 16, 17, 32, 64, 1024)] == [
+        4096, 4096, 4096, 2048, 2048, 1024, 128]
