@@ -35,6 +35,7 @@ fit they are compared with. ``--rows`` shrinks everything for a rehearsal.
 from __future__ import annotations
 
 import argparse
+import glob
 import json
 import math
 import os
@@ -361,14 +362,17 @@ def _last_json(text: str) -> dict:
     raise SmokeFailure("phase printed no JSON line")
 
 
-def run_phase(name: str, argv: list[str], ok_codes=(0,)) -> tuple[dict, float]:
-    """Run one child to its end; return (its last JSON line, seconds)."""
+def run_phase(name: str, argv: list[str], ok_codes=(0,),
+              env: dict | None = None) -> tuple[dict, float]:
+    """Run one child to its end (``env``: variables added to its
+    environment); return (its last JSON line, seconds)."""
     log = os.path.join(WORKDIR, "logs", f"{name}.err")
     t0 = time.perf_counter()
     with open(log, "w") as err:
         proc = subprocess.run(
             argv, cwd=ROOT, stdout=subprocess.PIPE, stderr=err, text=True,
-            timeout=PHASE_TIMEOUT_S,
+            timeout=PHASE_TIMEOUT_S, env=None if env is None else {
+                **os.environ, **env},
         )
     seconds = time.perf_counter() - t0
     if proc.returncode not in ok_codes:
@@ -417,11 +421,6 @@ def telemetry_facts(snap: dict) -> dict:
         "python_rows": c.get("avro.python_rows", 0),
         "device_fetches": c.get("device_fetches", 0),
         "device_fetch_seconds": c.get("device_fetch_seconds", 0.0),
-        "mosaic_kernels": {
-            k[len("xla.exec."):-len(".mosaic_kernels")]: int(v)
-            for k, v in g.items()
-            if k.startswith("xla.exec.") and k.endswith(".mosaic_kernels")
-        },
         "placement_devices": {
             k[len("placement."):-len(".devices")]: int(v)
             for k, v in g.items()
@@ -449,11 +448,30 @@ def check_run_facts(name: str, facts: dict, expect_rows: int) -> None:
         )
 
 
-def check_tiled(name: str, facts: dict, executable: str) -> None:
+def ir_dir(name: str) -> str:
+    """Where a ``cli train`` child's jax dumps the module of every program
+    it compiles or loads from the cache (``JAX_DUMP_IR_TO``)."""
+    return os.path.join(WORKDIR, "ir", name)
+
+
+def mosaic_kernels(name: str, executable: str) -> int:
+    """Mosaic calls (``@tpu_custom_call``) in the module ``jit_<executable>``
+    the child ``name`` handed the compiler: the StableHLO jax dumps at
+    ``compile_or_get_cached``, on a persistent-cache hit too."""
+    paths = glob.glob(os.path.join(
+        ir_dir(name), f"jax_ir*_jit_{executable}_compile.mlir"))
+    count = 0
+    for path in paths:
+        with open(path) as f:
+            count += f.read().count("@tpu_custom_call(")
+    return count
+
+
+def check_tiled(name: str, executable: str) -> None:
     """The FE solve must hold Mosaic kernels: pallas lowered for the TPU."""
     if REQUIRED_PLATFORM != "tpu":
         return  # the rehearsal: pallas is interpreted, no Mosaic kernel exists
-    kernels = facts["mosaic_kernels"].get(executable, 0)
+    kernels = mosaic_kernels(name, executable)
     if kernels < 1:
         raise SmokeFailure(
             f"{name}: executable '{executable}' holds no tpu_custom_call — "
@@ -546,7 +564,8 @@ def train(name: str, config: dict, total_rows: int, *mesh: str):
     with open(cfg_path, "w") as f:
         json.dump(config, f)
     summary, seconds = run_phase(name, cli_argv(
-        "train", "--config", cfg_path, "--telemetry-out", tel_path, *mesh))
+        "train", "--config", cfg_path, "--telemetry-out", tel_path, *mesh),
+        env={"JAX_DUMP_IR_TO": ir_dir(name)})
     facts = telemetry_facts(read_telemetry(tel_path))
     check_run_facts(name, facts, total_rows)
     check_history(name, summary)
@@ -698,7 +717,7 @@ def one_chip(seed: int, size: dict) -> dict:
 
     model_dir = os.path.join(WORKDIR, "model")
     summary, facts, seconds = train("train", glmix_config(model_dir), total_rows)
-    check_tiled("train", facts, "fe_solve")
+    check_tiled("train", "fe_solve")
     auc = final_auc(summary)
     check_auc("train", auc, data["planted_auc"], size)
     emit(phase="train", seconds=seconds, validation_auc=auc,
@@ -749,7 +768,7 @@ def one_chip(seed: int, size: dict) -> dict:
     summary, facts, seconds = train(
         "solvers", solvers_config(os.path.join(WORKDIR, "solvers")),
         total_rows)
-    check_tiled("solvers", facts, "fe_solve")
+    check_tiled("solvers", "fe_solve")
     emit(phase="solvers", seconds=seconds,
          validation_auc=final_auc(summary),
          trackers=[[e["coordinate"], e.get("tracker")]
@@ -842,7 +861,7 @@ def four_chips(seed: int, size: dict) -> dict:
         summary, facts, seconds = train(
             name, glmix_config(out_dir), total_rows,
             *(("--mesh", mesh) if mesh else ()))
-        check_tiled(name, facts, "gspmd_solve" if mesh else "fe_solve")
+        check_tiled(name, "gspmd_solve" if mesh else "fe_solve")
         auc = final_auc(summary)
         check_auc(name, auc, data["planted_auc"], size)
         fits[name] = (auc, *load_coefficients(out_dir))

@@ -166,7 +166,8 @@ class FixedEffectCoordinate:
 
     def __post_init__(self):
         self.config.validate(self.loss_name)
-        self._base_batch = self.data.batch_for(self.shard_name)
+        with span("build.rows"):  # labels, offsets, weights beside the shard
+            self._base_batch = self.data.batch_for(self.shard_name)
         # "auto": the tiled one-hot-matmul layouts are the TPU fast path
         # (ops/tiled.py, and ops/panels.py for wide designs: PERF.md has
         # both against COO's gather / scatter-add on the chip); elsewhere
@@ -244,17 +245,17 @@ class FixedEffectCoordinate:
                 lower=_to_design(host_tiled, self._constraints.lower),
                 upper=_to_design(host_tiled, self._constraints.upper),
             )
-        self._obj = make_objective(
-            self.loss_name,
-            l2_weight=self.config.regularization.l2_weight(
-                self.config.regularization_weight
-            ),
-            factors=self._factors,
-            shifts=self._shifts,
-        )
-        self._l1 = jnp.float32(
-            self.config.regularization.l1_weight(self.config.regularization_weight)
-        )
+        with span("build.objective"):  # its eager one-op programs
+            self._obj = make_objective(
+                self.loss_name,
+                l2_weight=self.config.regularization.l2_weight(
+                    self.config.regularization_weight
+                ),
+                factors=self._factors,
+                shifts=self._shifts,
+            )
+            self._l1 = jnp.float32(self.config.regularization.l1_weight(
+                self.config.regularization_weight))
         if self.mesh is not None:
             # GSPMD path: the FLAT design (tiles or COO slots) is committed
             # with NamedSharding(mesh, P(batch)) ONCE; per-update offsets
@@ -759,13 +760,14 @@ class RandomEffectCoordinate:
         self._buckets = self.re_data.device_buckets_for_dense()
         # per bucket and entity, its own rows x its own local features: the
         # design cells one pass of its solve has to read (_report_stragglers)
-        self._entity_cells = [
-            np.count_nonzero(hb.row_index >= 0, axis=1)
-            * np.count_nonzero(
-                hb.projection < hb.num_global_features, axis=1)
-            for hb in self.re_data.buckets
-        ]
-        self._report_layout()
+        with span("build.layout_report"):  # host passes over the buckets
+            self._entity_cells = [
+                np.count_nonzero(hb.row_index >= 0, axis=1)
+                * np.count_nonzero(
+                    hb.projection < hb.num_global_features, axis=1)
+                for hb in self.re_data.buckets
+            ]
+            self._report_layout()
         # Box constraints are declared against GLOBAL feature ids
         # (OptimizerConfig constraintMap); each entity's local space is an
         # index-map renumbering (local k <-> global projection[e, k]), so the
@@ -799,15 +801,15 @@ class RandomEffectCoordinate:
             packed=True,
         )
         self._scorer = _re_scorer()
-        self._obj = make_objective(
-            self.loss_name,
-            l2_weight=self.config.regularization.l2_weight(
-                self.config.regularization_weight
-            ),
-        )
-        self._l1 = jnp.float32(
-            self.config.regularization.l1_weight(self.config.regularization_weight)
-        )
+        with span("build.objective"):  # its eager one-op programs
+            self._obj = make_objective(
+                self.loss_name,
+                l2_weight=self.config.regularization.l2_weight(
+                    self.config.regularization_weight
+                ),
+            )
+            self._l1 = jnp.float32(self.config.regularization.l1_weight(
+                self.config.regularization_weight))
         # guarded-solve hooks (optim.guard); health reduces only when the
         # guard flips health_check on
         self.extra_l2 = 0.0

@@ -19,6 +19,7 @@ from typing import Mapping, Optional, Sequence
 import numpy as np
 
 from photon_ml_tpu.ops.sparse import SparseBatch
+from photon_ml_tpu.telemetry.trace import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -34,8 +35,10 @@ class IdColumn:
 
     @staticmethod
     def from_values(values: Sequence) -> "IdColumn":
-        vocab, codes = np.unique(np.asarray(values), return_inverse=True)
-        return IdColumn(codes=codes.astype(np.int64), vocab=vocab)
+        # a sort of every row's id: seconds at 18M rows
+        with span("dataset.ids"):
+            vocab, codes = np.unique(np.asarray(values), return_inverse=True)
+            return IdColumn(codes=codes.astype(np.int64), vocab=vocab)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -104,26 +107,35 @@ def build_game_dataset(
     offset: Optional[np.ndarray] = None,
     weight: Optional[np.ndarray] = None,
 ) -> GameDataset:
-    n = len(response)
-    for name, b in feature_shards.items():
-        if b.num_rows < n:
-            raise ValueError(
-                f"feature shard '{name}' has {b.num_rows} rows < {n} examples"
-            )
-    # All score/residual paths combine per-shard [n_pad] vectors, so every
-    # shard must share one padded row count — normalize to the max.
-    n_pad = max(b.num_rows for b in feature_shards.values())
-    feature_shards = {
-        name: (b if b.num_rows == n_pad else b.pad_rows_to(n_pad, b.nnz))
-        for name, b in feature_shards.items()
-    }
-    return GameDataset(
-        response=np.asarray(response, np.float64),
-        offset=np.zeros(n) if offset is None else np.asarray(offset, np.float64),
-        weight=np.ones(n) if weight is None else np.asarray(weight, np.float64),
-        feature_shards=dict(feature_shards),
-        id_columns={
-            k: v if isinstance(v, IdColumn) else IdColumn.from_values(v)
-            for k, v in (id_columns or {}).items()
-        },
-    )
+    """The shards and id columns as one row-aligned dataset, under a
+    ``dataset.game`` span (children ``dataset.pad_rows`` where a shard is
+    padded to the others' rows, ``dataset.ids`` an id column's codes)."""
+    with span("dataset.game", rows=len(response)):
+        n = len(response)
+        for name, b in feature_shards.items():
+            if b.num_rows < n:
+                raise ValueError(
+                    f"feature shard '{name}' has {b.num_rows} rows < {n} "
+                    "examples"
+                )
+        # All score/residual paths combine per-shard [n_pad] vectors, so
+        # every shard must share one padded row count — normalize to the max.
+        n_pad = max(b.num_rows for b in feature_shards.values())
+        with span("dataset.pad_rows"):
+            feature_shards = {
+                name: (b if b.num_rows == n_pad
+                       else b.pad_rows_to(n_pad, b.nnz))
+                for name, b in feature_shards.items()
+            }
+        return GameDataset(
+            response=np.asarray(response, np.float64),
+            offset=(np.zeros(n) if offset is None
+                    else np.asarray(offset, np.float64)),
+            weight=(np.ones(n) if weight is None
+                    else np.asarray(weight, np.float64)),
+            feature_shards=dict(feature_shards),
+            id_columns={
+                k: v if isinstance(v, IdColumn) else IdColumn.from_values(v)
+                for k, v in (id_columns or {}).items()
+            },
+        )

@@ -253,7 +253,10 @@ class GameEstimator:
         ``build_coordinates`` span: every caller (``fit``, sweeps, the
         incremental path, a benchmark) gets it. A build runs under a
         ``build:<coordinate>`` span whose ``layout`` / ``upload`` children
-        the coordinate and its datasets open."""
+        the coordinate and its datasets open; ``build.*`` children name
+        the rest (``build.normalization``, ``build.table_estimate`` here,
+        ``build.rows``, ``build.objective``, ``build.layout_report``,
+        ``build.entity_map`` in the coordinates)."""
         with telemetry.span("build_coordinates") as sp:
             coords, built = self._build_or_reuse(
                 data, mesh, opt_overrides or {}, only
@@ -333,7 +336,8 @@ class GameEstimator:
                 continue
             with telemetry.span(f"build:{name}"):
                 if isinstance(c, FixedEffectConfig):
-                    norm = self._normalization_for(data, c)
+                    with telemetry.span("build.normalization"):
+                        norm = self._normalization_for(data, c)
                     coords[name] = FixedEffectCoordinate(
                         name=name,
                         data=data,
@@ -347,10 +351,11 @@ class GameEstimator:
                     )
                 elif isinstance(c, RandomEffectConfig):
                     red = self._re_dataset(data, c)
-                    _record_table_estimate(
-                        name, red, dim=c.projected_dim
-                        if c.projector == "random" else None,
-                    )
+                    with telemetry.span("build.table_estimate"):
+                        _record_table_estimate(
+                            name, red, dim=c.projected_dim
+                            if c.projector == "random" else None,
+                        )
                     if c.projector == "random":
                         # fixed Gaussian projection: per-entity solves in the
                         # shared projected space (RandomEffectCoordinateIn
@@ -380,7 +385,8 @@ class GameEstimator:
                         )
                 elif isinstance(c, FactoredRandomEffectConfig):
                     red = self._re_dataset(data, c)
-                    _record_table_estimate(name, red, dim=c.latent_dim)
+                    with telemetry.span("build.table_estimate"):
+                        _record_table_estimate(name, red, dim=c.latent_dim)
                     coords[name] = FactoredRandomEffectCoordinate(
                         name=name,
                         data=data,

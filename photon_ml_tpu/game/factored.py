@@ -532,13 +532,15 @@ class FactoredRandomEffectCoordinate:
         self._proj_rows = k + (1 if self.projection_intercept_index is not None else 0)
 
         # flat latent-table layout: bucket entities concatenated in order
-        sizes = [b.num_entities for b in buckets]
-        self._flat_offsets = np.concatenate([[0], np.cumsum(sizes)]).astype(np.int64)
-        self._n_flat = int(self._flat_offsets[-1])
-        eb, ep = self.re_data.entity_bucket, self.re_data.entity_pos
-        self._entity_flat = np.where(
-            eb >= 0, self._flat_offsets[np.maximum(eb, 0)] + ep, -1
-        ).astype(np.int64)
+        with span("build.entity_map"):
+            sizes = [b.num_entities for b in buckets]
+            self._flat_offsets = np.concatenate(
+                [[0], np.cumsum(sizes)]).astype(np.int64)
+            self._n_flat = int(self._flat_offsets[-1])
+            eb, ep = self.re_data.entity_bucket, self.re_data.entity_pos
+            self._entity_flat = np.where(
+                eb >= 0, self._flat_offsets[np.maximum(eb, 0)] + ep, -1
+            ).astype(np.int64)
 
         if self.mesh is not None:
             self._resolve_mesh_axis()
@@ -554,13 +556,15 @@ class FactoredRandomEffectCoordinate:
         self._re_solver = _re_solver(
             _solver_key(self.re_config), self.loss_name, packed=True,
             kmajor=True)
-        self._re_obj, self._re_l1 = _objective_and_l1(
-            self.loss_name, self.re_config)
-        if self.refit_projection:
-            self._lat_solver = _latent_fit_solver(
-                _solver_key(self.latent_config), self.loss_name, self._shapes)
-            self._lat_obj, self._lat_l1 = _objective_and_l1(
-                self.loss_name, self.latent_config)
+        with span("build.objective"):  # their eager one-op programs
+            self._re_obj, self._re_l1 = _objective_and_l1(
+                self.loss_name, self.re_config)
+            if self.refit_projection:
+                self._lat_solver = _latent_fit_solver(
+                    _solver_key(self.latent_config), self.loss_name,
+                    self._shapes)
+                self._lat_obj, self._lat_l1 = _objective_and_l1(
+                    self.loss_name, self.latent_config)
         # the last foreign dataset scored through device-resident state
         # (score_dataset): (weakref to it, (design, flat) or None)
         self._foreign = None

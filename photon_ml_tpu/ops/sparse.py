@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from photon_ml_tpu.telemetry.trace import span
+
 Array = jax.Array
 
 
@@ -141,46 +143,58 @@ class SparseBatch(ContractedRows):
         Raises on out-of-range row/col indices — a silent out-of-range col
         would be dropped by the clamped device gathers and corrupt the
         scatter adds (TiledBatch.from_coo validates identically).
+
+        Runs under a ``dataset.sparse_batch`` span (children
+        ``dataset.validate``, ``dataset.sort`` where the rows arrive out of
+        order, ``dataset.pad``): what ``cli train``'s input and a
+        benchmark's set-up assemble.
         """
-        n = int(len(labels))
-        rows = np.asarray(rows)
-        cols = np.asarray(cols)
-        validate_coo_indices(rows, cols, n, num_features)
-        values = np.asarray(values)
-        if len(rows) and not np.all(rows[1:] >= rows[:-1]):
-            # ingest paths emit row-sorted COO; only re-sort when needed
-            order = np.argsort(rows, kind="stable")
-            values = values[order]
-            rows = rows[order]
-            cols = cols[order]
+        with span("dataset.sparse_batch", nnz=int(len(values))):
+            n = int(len(labels))
+            rows = np.asarray(rows)
+            cols = np.asarray(cols)
+            values = np.asarray(values)
+            with span("dataset.validate"):
+                validate_coo_indices(rows, cols, n, num_features)
+                ordered = not len(rows) or bool(np.all(rows[1:] >= rows[:-1]))
+            if not ordered:
+                # ingest paths emit row-sorted COO; only re-sort when needed
+                with span("dataset.sort"):
+                    order = np.argsort(rows, kind="stable")
+                    values = values[order]
+                    rows = rows[order]
+                    cols = cols[order]
 
-        n_pad = _round_up(n, row_pad_multiple)
-        nnz = int(len(values))
-        nnz_pad = _round_up(nnz, nnz_pad_multiple)
+            with span("dataset.pad"):
+                n_pad = _round_up(n, row_pad_multiple)
+                nnz = int(len(values))
+                nnz_pad = _round_up(nnz, nnz_pad_multiple)
 
-        labels_p = _pad(np.asarray(labels, dtype=np.float64), n_pad)
-        offsets_p = _pad(
-            np.zeros(n) if offsets is None else np.asarray(offsets, np.float64), n_pad
-        )
-        weights_p = _pad(
-            np.ones(n) if weights is None else np.asarray(weights, np.float64), n_pad
-        )
+                labels_p = _pad(np.asarray(labels, dtype=np.float64), n_pad)
+                offsets_p = _pad(
+                    np.zeros(n) if offsets is None
+                    else np.asarray(offsets, np.float64), n_pad
+                )
+                weights_p = _pad(
+                    np.ones(n) if weights is None
+                    else np.asarray(weights, np.float64), n_pad
+                )
 
-        # leaves stay HOST numpy (dtype applied host-side): construction is
-        # transfer-free, and consumers upload exactly once where the batch
-        # is actually solved/scored (see .device()). This keeps the
-        # host-side data plane (RE grouping, tiling, stats, ingest) off the
-        # PCIe link entirely.
-        np_dtype = np.dtype(dtype)
-        return SparseBatch(
-            values=_pad(values, nnz_pad, dtype=np_dtype),
-            rows=_pad(rows, nnz_pad, fill=n_pad - 1, dtype=np.int32),
-            cols=_pad(cols, nnz_pad, dtype=np.int32),
-            labels=labels_p.astype(np_dtype),
-            offsets=offsets_p.astype(np_dtype),
-            weights=weights_p.astype(np_dtype),
-            num_features=int(num_features),
-        )
+                # leaves stay HOST numpy (dtype applied host-side):
+                # construction is transfer-free, and consumers upload exactly
+                # once where the batch is actually solved/scored (see
+                # .device()). This keeps the host-side data plane (RE
+                # grouping, tiling, stats, ingest) off the PCIe link entirely.
+                np_dtype = np.dtype(dtype)
+                return SparseBatch(
+                    values=_pad(values, nnz_pad, dtype=np_dtype),
+                    rows=_pad(rows, nnz_pad, fill=n_pad - 1, dtype=np.int32),
+                    cols=_pad(cols, nnz_pad, dtype=np.int32),
+                    labels=labels_p.astype(np_dtype),
+                    offsets=offsets_p.astype(np_dtype),
+                    weights=weights_p.astype(np_dtype),
+                    num_features=int(num_features),
+                )
 
     def device(self, sharding=None) -> "SparseBatch":
         """Upload every leaf (no-op for leaves already on device)."""

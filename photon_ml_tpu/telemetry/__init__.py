@@ -54,13 +54,17 @@ Typical use::
 
 Importing this package installs the jit compile hooks (idempotent, and a
 no-op without jax.monitoring), so recompiles are counted from the first
-traced program onward.
+traced program onward, and publishes the package's own import seconds as
+the counter ``import.seconds`` (``photon_ml_tpu/_import_clock.py``), which
+``reset()`` leaves alone.
 """
 
 from __future__ import annotations
 
 import os
 from typing import Optional
+
+from photon_ml_tpu import _import_clock
 
 from photon_ml_tpu.telemetry import (  # noqa: F401
     identity,
@@ -73,6 +77,7 @@ from photon_ml_tpu.telemetry import (  # noqa: F401
 from photon_ml_tpu.telemetry import requests  # noqa: F401  (needs trace)
 from photon_ml_tpu.telemetry.identity import member_artifact_path  # noqa: F401
 from photon_ml_tpu.telemetry.device import (  # noqa: F401
+    declare_phase_counters,
     install_compile_hooks,
     sync_fetch,
 )
@@ -193,6 +198,7 @@ def reset() -> None:
     ``configure_from_env`` atexit flush."""
     trace.reset()
     metrics.reset()
+    declare_phase_counters()
     memory.reset()
     xla.reset()
     profile.reset()
@@ -206,6 +212,9 @@ def reset() -> None:
 
 
 install_compile_hooks()
+# the package's own imports (photon_ml_tpu/_import_clock.py), kept outside
+# the registry so that reset() cannot erase them
+metrics.register_counter_provider("import.seconds", _import_clock.seconds)
 # arm the executable-level dispatch sampler (idempotent; profile.reset()
 # re-arms, so test isolation never leaves profiling dark)
 profile.install()

@@ -35,6 +35,7 @@ __all__ = [
     "histogram",
     "snapshot",
     "register_snapshot_provider",
+    "register_counter_provider",
     "flush_jsonl",
     "reset",
 ]
@@ -185,6 +186,7 @@ class MetricsRegistry:
         self._gauges: dict[str, Gauge] = {}
         self._histograms: dict[str, Histogram] = {}
         self._providers: dict[str, Any] = {}
+        self._counter_providers: dict[str, Any] = {}
 
     def register_snapshot_provider(self, name: str, fn) -> None:
         """Attach a named section to every :meth:`snapshot`: ``fn()``
@@ -201,6 +203,14 @@ class MetricsRegistry:
             raise ValueError(f"snapshot section name {name!r} is reserved")
         with self._lock:
             self._providers[name] = fn
+
+    def register_counter_provider(self, name: str, fn) -> None:
+        """A counter whose value is kept outside the registry: ``fn()``
+        (a float) is published under ``name`` in ``counters`` by every
+        :meth:`snapshot`. It survives :meth:`reset`, for a total that a
+        run's reset must not erase (the package's import seconds)."""
+        with self._lock:
+            self._counter_providers[name] = fn
 
     def counter(self, name: str) -> Counter:
         with self._lock:
@@ -246,8 +256,15 @@ class MetricsRegistry:
             gauges = dict(self._gauges)
             histograms = dict(self._histograms)
             providers = dict(self._providers)
+            kept = dict(self._counter_providers)
+        values = {n: c.value for n, c in counters.items()}
+        for name, fn in kept.items():
+            try:
+                values[name] = float(fn())
+            except Exception:  # noqa: BLE001 — observability, never control
+                continue
         out: dict[str, Any] = {
-            "counters": {n: c.value for n, c in sorted(counters.items())},
+            "counters": dict(sorted(values.items())),
             "gauges": {n: g.value for n, g in sorted(gauges.items())},
             "histograms": {
                 n: h.summary() for n, h in sorted(histograms.items())
@@ -303,5 +320,6 @@ peek_gauge = REGISTRY.peek_gauge
 histogram = REGISTRY.histogram
 snapshot = REGISTRY.snapshot
 register_snapshot_provider = REGISTRY.register_snapshot_provider
+register_counter_provider = REGISTRY.register_counter_provider
 flush_jsonl = REGISTRY.flush_jsonl
 reset = REGISTRY.reset

@@ -13,7 +13,11 @@ Three pieces:
   shape-signature goes through ``lowered.compile()`` with the compile wall
   time, ``cost_analysis()`` FLOPs / bytes-accessed, and
   ``memory_analysis()`` temp/arg/output bytes recorded in the process-
-  global :data:`XLA_REGISTRY`, keyed by ``(name, signature)``. Subsequent
+  global :data:`XLA_REGISTRY`, keyed by ``(name, signature)``, beside
+  the compile's phases as jax's monitoring events report them (tracing,
+  lowering, a persistent-cache load, the backend compile; counters
+  ``xla.exec.<name>.<phase>_seconds``); the two analyses' own seconds are
+  counter ``xla.analysis_seconds``. Subsequent
   same-signature calls dispatch to the cached compiled executable and
   accumulate per-call FLOPs/bytes onto the open telemetry span (so the
   run report can compute per-phase roofline numbers from span wall time).
@@ -216,21 +220,6 @@ def _analyze(compiled: Any) -> tuple[Optional[Mapping], Any]:
     return cost, mem
 
 
-def _mosaic_kernels(compiled: Any) -> Optional[int]:
-    """How many Mosaic kernels the compiled module calls — the proof that a
-    pallas path really lowered for the TPU and not through the
-    interpreter. None when the executable offers no text. Only a TPU
-    program can hold one: elsewhere the text is not even rendered."""
-    import jax
-
-    if jax.default_backend() != "tpu":
-        return 0
-    try:
-        return compiled.as_text().count('custom_call_target="tpu_custom_call"')
-    except Exception:  # noqa: BLE001 — accounting must never fail a compile
-        return None
-
-
 # ---------------------------------------------------------------------------
 # shape signatures
 # ---------------------------------------------------------------------------
@@ -350,21 +339,31 @@ class ExecutableRecord:
     signature: tuple[str, ...]
     structure: str = ""
     compile_seconds: float = 0.0
+    # what jax's monitoring events said of the compile, by phase (telemetry
+    # .device's hooks), each its own seconds: tracing, lowering to MLIR, a
+    # persistent-cache load, the backend-compile event less that load.
+    # compile_seconds less the four is what no phase names.
+    trace_seconds: float = 0.0
+    lower_seconds: float = 0.0
+    cache_load_seconds: float = 0.0
+    backend_seconds: float = 0.0
     flops: Optional[float] = None
     bytes_accessed: Optional[float] = None
     temp_bytes: Optional[int] = None
     argument_bytes: Optional[int] = None
     output_bytes: Optional[int] = None
     generated_code_bytes: Optional[int] = None
-    # Mosaic (pallas TPU) kernels in the compiled module: 0 on a backend
-    # where pallas runs in interpret mode, None when there is no text
-    mosaic_kernels: Optional[int] = None
     calls: int = 0
 
     def to_dict(self) -> dict[str, Any]:
         d = dataclasses.asdict(self)
         d["signature"] = list(self.signature)
         return d
+
+
+#: an executable's compile phases, as ExecutableRecord fields
+#: ``<phase>_seconds`` and counters ``xla.exec.<name>.<phase>_seconds``
+_PHASES = ("trace", "lower", "cache_load", "backend")
 
 
 class ExecutableRegistry:
@@ -388,7 +387,7 @@ class ExecutableRegistry:
         cost: Optional[Mapping],
         mem: Any,
         multi_shape: bool = False,
-        mosaic_kernels: Optional[int] = None,
+        phases: Optional[Mapping[str, float]] = None,
     ) -> ExecutableRecord:
         """Insert (or refresh) the record for a freshly compiled
         executable, publish its compile metrics, and attribute a
@@ -398,12 +397,20 @@ class ExecutableRegistry:
         design (the serving engine's padded batch buckets, per-bucket
         entity counts): new signatures still register and publish compile
         metrics, but are not counted as recompiles and never trip the
-        storm warning — the gate metric must not flag healthy warmups."""
+        storm warning — the gate metric must not flag healthy warmups.
+        ``phases``: the compile's seconds by phase (``trace``, ``lower``,
+        ``cache_load``, ``backend``; ``telemetry.device.accounted_compile``).
+        """
+        phases = phases or {}
         rec = ExecutableRecord(
             name=name,
             signature=signature,
             structure=structure,
             compile_seconds=float(compile_seconds),
+            trace_seconds=float(phases.get("trace", 0.0)),
+            lower_seconds=float(phases.get("lower", 0.0)),
+            cache_load_seconds=float(phases.get("cache_load", 0.0)),
+            backend_seconds=float(phases.get("backend", 0.0)),
             flops=None if cost is None else _maybe_float(cost.get("flops")),
             bytes_accessed=(
                 None if cost is None
@@ -415,7 +422,6 @@ class ExecutableRegistry:
             generated_code_bytes=_mem_field(
                 mem, "generated_code_size_in_bytes"
             ),
-            mosaic_kernels=mosaic_kernels,
         )
         with self._lock:
             self._records[(name, signature)] = rec
@@ -436,6 +442,10 @@ class ExecutableRegistry:
         metrics.counter(f"xla.exec.{name}.compile_seconds").inc(
             rec.compile_seconds
         )
+        for phase in _PHASES:
+            metrics.counter(f"xla.exec.{name}.{phase}_seconds").inc(
+                getattr(rec, f"{phase}_seconds")
+            )
         if rec.flops is not None:
             metrics.gauge(f"xla.exec.{name}.flops_per_call").set(rec.flops)
         if rec.bytes_accessed is not None:
@@ -444,10 +454,6 @@ class ExecutableRegistry:
             )
         if rec.temp_bytes is not None:
             metrics.gauge(f"xla.exec.{name}.temp_bytes").set(rec.temp_bytes)
-        if rec.mosaic_kernels:
-            metrics.gauge(f"xla.exec.{name}.mosaic_kernels").set(
-                rec.mosaic_kernels
-            )
         if prior and multi_shape:
             # expected shape set: registered and accounted, not a storm
             logger.info(
@@ -663,8 +669,9 @@ class InstrumentedFunction:
         t0 = time.monotonic()
         compiled = None
         cost = mem = None
+        phases = None
         try:
-            with accounted_compile():
+            with accounted_compile(self.name) as phases:
                 lowered = self._jit.lower(*args, **kwargs)
                 compiled = lowered.compile()
         except Exception as e:  # noqa: BLE001 — backends/args AOT cannot handle
@@ -679,13 +686,14 @@ class InstrumentedFunction:
             )
             metrics.counter("xla.fallback_calls").inc()
         dt = time.monotonic() - t0
-        mosaic = None
         if compiled is not None:
+            # telemetry's own set-up cost, after every compile
+            t1 = time.monotonic()
             cost, mem = _analyze(compiled)
-            mosaic = _mosaic_kernels(compiled)
+            metrics.counter("xla.analysis_seconds").inc(time.monotonic() - t1)
         rec = XLA_REGISTRY.record_compile(
             self.name, leaf_sig, structure, dt, cost, mem,
-            multi_shape=self._multi_shape, mosaic_kernels=mosaic,
+            multi_shape=self._multi_shape, phases=phases,
         )
         trace.add_event(
             "xla_compile",

@@ -24,8 +24,10 @@ import json
 import math
 import os
 import re
+import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -34,7 +36,10 @@ from benchmark.readers import (
     counter_delta,
     counter_ratio,
     history_seconds,
+    kept_counter_delta,
     program_span_seconds,
+    setup_span_seconds,
+    setup_unattributed,
 )
 from photon_ml_tpu import telemetry
 from photon_ml_tpu.telemetry.xla import InstrumentedFunction
@@ -57,7 +62,8 @@ SEED = 2147483653
 #: call (``^%<name>``) and not XLA's own custom-call target
 NAMING_READERS = {
     "program_span_seconds", "counter_delta", "counter_ratio", "trace_module",
-    "trace_module_roofline", "history_seconds",
+    "trace_module_roofline", "history_seconds", "kept_counter_delta",
+    "setup_span_seconds", "setup_unattributed",
 }
 KERNEL_READERS = {"trace_kernel_roofline", "trace_kernel_seconds"}
 
@@ -145,8 +151,9 @@ def _pallas_names(jaxpr) -> set:
 class CellRun:
     """What one cell's rehearsal left behind, taken before the per-test
     telemetry reset: the readers' ``ctx`` (counter marks, the window's
-    fits, the driver's shapes), the program's span trees by root, the
-    registry's executables, and the programs the window's fit dispatched."""
+    fits, the driver's shapes and spans, set-up's seconds), the program's
+    span trees by root and every span it finished, the registry's
+    executables, and the programs the window's fit dispatched."""
 
     driver: object
     ctx: dict
@@ -154,6 +161,7 @@ class CellRun:
     registry: set
     dispatched: dict  # executable name -> (InstrumentedFunction, args, kwargs)
     kernels: set  # Mosaic call names in the dispatched programs
+    spans: list  # every finished span of the run (telemetry's own objects)
 
 
 def _rehearse(cell: str) -> CellRun:
@@ -166,9 +174,13 @@ def _rehearse(cell: str) -> CellRun:
         return dict(telemetry.snapshot()["counters"])
 
     telemetry.reset()
+    t0 = time.perf_counter()
     driver = module.Driver(config, traffic, SEED, rows=ROWS, force_tiled=True)
     driver.setup()
     marks = {"setup_end": counters(), "window_start": counters()}
+    # set-up as run.py counts it, this process's import standing for the
+    # interpreter's and the package's start
+    setup_s = time.perf_counter() - t0 + marks["setup_end"]["import.seconds"]
     dispatched = {}
     real = InstrumentedFunction.__call__
 
@@ -192,12 +204,14 @@ def _rehearse(cell: str) -> CellRun:
     return CellRun(
         driver=driver,
         ctx={"counters": marks, "fits": driver.fits[first:],
-             "shapes": driver.shapes()},
+             "shapes": driver.shapes(), "setup_s": setup_s,
+             "spans": dict(driver.spans)},
         roots={root: program_span_seconds.process_roots(root)
                for root in ("build_coordinates", "coordinate_descent")},
         registry={r.name for r in telemetry.XLA_REGISTRY.executables()},
         dispatched=dispatched,
         kernels=kernels,
+        spans=telemetry.finished_spans(),
     )
 
 
@@ -322,6 +336,122 @@ def test_history_read_by_a_metric_has_the_coordinate_and_its_seconds(
         math.isfinite(s["seconds"]) and s["seconds"] > 0 for s in steps)
     value = history_seconds.read(ctx, **params)
     assert value is not None and math.isfinite(value) and value > 0
+
+
+# -- set-up seen from inside the program (PR 35) -------------------------------
+
+#: declared at 0 by the compile hooks; the rehearsal's programs compile
+#: under jax's 1 s cache-write threshold, so no load happens in it: the
+#: next test shows the counter rising on a real hit
+CACHE_LOAD = "jit_cache_load_seconds"
+
+
+@pytest.mark.parametrize("cell, metric", cases("kept_counter_delta"))
+def test_kept_counter_named_by_a_metric_is_kept_by_the_program(
+        rehearsal, cell, metric):
+    params = METRICS[metric]["params"]
+    ctx = rehearsal(cell).ctx
+    mark = ctx["counters"][params["until"]]
+    for name in params["counters"]:
+        assert name in mark, (name, sorted(mark))
+        if name != CACHE_LOAD:
+            assert mark[name] > 0, name
+    value = kept_counter_delta.read(ctx, **params)
+    assert value is not None and math.isfinite(value) and value >= 0
+
+
+def test_a_persistent_cache_hit_raises_the_cache_load_counter(tmp_path):
+    """A real hit: a fresh cache directory that keeps every program, the
+    in-memory caches cleared, the same program compiled again."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    saved = {k: getattr(jax.config, k) for k in keys}
+    salt = time.perf_counter()
+
+    def program(x):
+        return jnp.tanh(x) * salt + jnp.cos(x)
+
+    try:
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+        compilation_cache.reset_cache()
+        telemetry.reset()
+        x = jnp.ones((4, 13))
+        np.asarray(telemetry.instrumented_jit(program, name="cache_probe")(x))
+        assert telemetry.snapshot()["counters"][CACHE_LOAD] == 0
+        jax.clear_caches()
+        np.asarray(telemetry.instrumented_jit(program, name="cache_probe")(x))
+        counters = telemetry.snapshot()["counters"]
+        assert counters["jit_cache_hits"] >= 1
+        assert counters[CACHE_LOAD] > 0
+        assert counters["xla.exec.cache_probe.cache_load_seconds"] > 0
+        # the backend event holds the load on a hit
+        assert counters["jit_compile_seconds"] >= counters[CACHE_LOAD]
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("cell, metric", cases("setup_span_seconds"))
+def test_setup_span_named_by_a_metric_is_opened_in_set_up(
+        rehearsal, cell, metric, monkeypatch):
+    params = METRICS[metric]["params"]
+    cell_run = rehearsal(cell)
+    monkeypatch.setattr(telemetry, "finished_spans", lambda: cell_run.spans)
+    value = setup_span_seconds.read(cell_run.ctx, **params)
+    assert value is not None and math.isfinite(value) and value > 0
+    end = setup_span_seconds.first_root_end(cell_run.spans, params["root"])
+    found = {s.name for s in setup_span_seconds.outermost(
+        cell_run.spans, params["span"]) if s.ts < end}
+    assert {"dataset.sparse_batch", "dataset.game"} <= found, found
+
+
+@pytest.mark.parametrize("cell, metric", cases("setup_unattributed"))
+def test_setup_residual_finds_what_it_subtracts(
+        rehearsal, cell, metric, monkeypatch):
+    params = METRICS[metric]["params"]
+    cell_run = rehearsal(cell)
+    ctx = cell_run.ctx
+    assert params["counter"] in ctx["counters"]["setup_end"]
+    assert all(s in ctx["spans"] for s in params["driver_spans"])
+    monkeypatch.setattr(telemetry, "finished_spans", lambda: cell_run.spans)
+    value = setup_unattributed.read(ctx, **params)
+    assert value is not None and math.isfinite(value)
+    assert value < ctx["setup_s"]
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_set_up_opens_the_dataset_and_build_spans(rehearsal, cell):
+    """The children no metric reads yet: PERF.md section 5's rows."""
+    cell_run = rehearsal(cell)
+    by_id = {s.span_id: s for s in cell_run.spans}
+    under = {}
+    for s in cell_run.spans:
+        parent = by_id.get(s.parent_id)
+        under.setdefault(None if parent is None else parent.name, set()).add(
+            s.name)
+    assert {"dataset.validate", "dataset.pad"} <= under["dataset.sparse_batch"]
+    assert "dataset.pad_rows" in under["dataset.game"]
+    with_ids = bool(cell_run.driver.train.id_columns)
+    assert ("dataset.ids" in under["dataset.game"]) == with_ids
+    kinds = {name: shape["kind"] for name, shape in
+             cell_run.ctx["shapes"]["coordinates"].items() if "." not in name}
+    for name, kind in kinds.items():
+        built = under[f"build:{name}"]
+        if kind.startswith("fixed_effect"):
+            expected = {"build.normalization", "build.rows", "build.objective"}
+        elif name == "user-x-movie":
+            expected = {"build.table_estimate", "build.entity_map",
+                        "build.objective"}
+        else:
+            expected = {"build.table_estimate", "build.layout_report",
+                        "build.objective"}
+        assert expected <= built, (name, sorted(built))
 
 
 # -- what the drivers read off the program's objects --------------------------
