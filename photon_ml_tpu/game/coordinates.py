@@ -472,11 +472,10 @@ class FixedEffectCoordinate:
             )
         else:
             with span("validation_layout"):
+                shards = 1 if self.mesh is None else self._n_shards
                 host = pack_rows_like(
-                    self._tiled, batch,
-                    shards=1 if self.mesh is None else self._n_shards,
-                ).traced_as("validate")
-                report_layout(host, "validate.layout")
+                    self._tiled, batch, shards=shards).traced_as("validate")
+                report_layout(host, "validate.layout", shards=shards)
             design = accounted_upload(
                 host.device if self.mesh is None
                 else lambda: psharding.place_batch(

@@ -85,8 +85,8 @@ from photon_ml_tpu.optim.trackers import (
 )
 from photon_ml_tpu.ops.panels import report_layout
 from photon_ml_tpu.ops.tiled import (
+    K_SWEEP_TILES_A_STEP,
     ROWS_PER_TILE,
-    TILES_A_STEP,
     WINDOW,
     ColumnSortedTiles,
     TiledBatch,
@@ -596,7 +596,7 @@ class FactoredRandomEffectCoordinate:
                     off += e * r
                 self._shapes = tuple(shapes)
                 # whole grid steps of the K-table sweeps
-                step = ROWS_PER_TILE * max(TILES_A_STEP)
+                step = ROWS_PER_TILE * K_SWEEP_TILES_A_STEP
                 total = max(-(-off // step), 1) * step
 
                 def rows(field):

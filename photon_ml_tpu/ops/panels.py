@@ -644,14 +644,15 @@ def pack_panels(batch: SparseBatch, nonzeros, classes, order, rank,
                       stored=tuple(stored), shards=shards)
 
 
-def report_layout(design, prefix: str = "layout") -> None:
+def report_layout(design, prefix: str = "layout", shards: int = 1) -> None:
     """Counters ``<prefix>.slots`` / ``<prefix>.nnz`` and gauge
     ``<prefix>.padding_ratio`` = slots allocated / nonzeros stored, of a
     design still on the host; a :class:`PanelBatch` splits its nonzeros by
     part besides (``<prefix>.nnz.hot``, ``<prefix>.nnz.w<W>``). Counter
     ``<prefix>.tiles.strided`` or ``<prefix>.tiles.sorted``: the tiles of
     the plain design, or of a panel design's hot part, by the row assignment
-    :meth:`TiledBatch.pack_coo` gave them."""
+    :meth:`TiledBatch.pack_coo` gave them; gauge ``<prefix>.tiles_a_step``:
+    the tiles a grid step of their calls on one of ``shards`` devices."""
     if isinstance(design, PanelBatch):
         names = ["hot"] + [f"w{p.cls.window}" for p in design.parts]
         for name, part_nnz in zip(names, design.stored):
@@ -663,6 +664,8 @@ def report_layout(design, prefix: str = "layout") -> None:
         tiles = design
     how = "strided" if tiles.strided else "sorted"
     counter(f"{prefix}.tiles.{how}").inc(tiles.num_tiles)
+    gauge(f"{prefix}.tiles_a_step").set(
+        tiles.tiles_a_step(-(-tiles.num_tiles // shards)))
     counter(f"{prefix}.slots").inc(design.nnz_slots)
     counter(f"{prefix}.nnz").inc(nnz)
     gauge(f"{prefix}.padding_ratio").set(design.nnz_slots / max(nnz, 1))
@@ -686,7 +689,7 @@ def pack_design(batch: SparseBatch, shards: int = 1):
             design = TiledBatch.pack_batch(batch)
     else:
         design = pack_panels(batch, nonzeros, classes, order, rank, shards)
-    report_layout(design)
+    report_layout(design, shards=shards)
     return design
 
 

@@ -57,6 +57,13 @@ module reaches HBM/MXU speed instead by removing ALL random access:
     chunks of the matmuls, the ``wT @ hit`` contraction order (256 rows
     streamed), a ``where`` + reduce row sum over ``rt`` and the accumulator
     the other way up were all timed and are slower (Findings PR 25).
+  - SEVERAL TILES A GRID STEP. Since that ~0.2 us a step does not hide, a
+    call runs G tiles a step (:func:`tiles_a_step`: the largest divisor of
+    its tile count, a shard's under a mesh, up to ``MAX_TILES_A_STEP``
+    whose blocks fit the VMEM budget): the blocks are G tiles deep and the
+    kernel runs the tile's body for each of them in tile order, so every
+    output is the one-tile-a-step call's to the last bit. The K-table
+    sweeps below take the same rule with their own cap.
   - f32 exactness comes from bf16x2 splits (x = hi + lo in bfloat16,
     products against 0/1 masks are exact, MXU accumulates in f32). The
     split MUST happen inside the kernel: XLA's
@@ -205,8 +212,8 @@ def _slot_refs(strided: bool, refs):
     return (*refs[:4], refs[4:])
 
 
-def _tile_masks(hi_ref, lo_ref, rlo_ref, B8: int):
-    """The transposed one-hots of one tile, slots on lanes.
+def _tile_masks(hi_ref, lo_ref, rlo_ref, B8: int, t: int):
+    """The transposed one-hots of tile ``t`` of a grid step, slots on lanes.
 
     ``hit`` [B8, S] only ever feeds a ``where`` and stays boolean. A padding
     slot carries the sentinel ``hi == B``: where B8 > B that is row B of
@@ -216,11 +223,11 @@ def _tile_masks(hi_ref, lo_ref, rlo_ref, B8: int):
     ever feed the MXU and are converted once. ``rt`` is the sorted
     layout's alone: a strided tile (``rlo_ref`` None) has none, its slot
     ``k*128 + r`` IS row r's."""
-    hit = _onehot_t(hi_ref[0], B8)
-    lot = _onehot_t(lo_ref[0], LANE).astype(jnp.bfloat16)
+    hit = _onehot_t(hi_ref[t], B8)
+    lot = _onehot_t(lo_ref[t], LANE).astype(jnp.bfloat16)
     if rlo_ref is None:
         return hit, lot, None
-    rt = _onehot_t(rlo_ref[0], ROWS_PER_TILE).astype(jnp.bfloat16)
+    rt = _onehot_t(rlo_ref[t], ROWS_PER_TILE).astype(jnp.bfloat16)
     return hit, lot, rt
 
 
@@ -320,7 +327,8 @@ def _scatter_accum(out_ref, per_row, vals, hit, lot, rt):
 
 
 def _margins_kernel(use_offsets: bool, pair: bool, strided: bool, *refs):
-    """z = per-row sum of vals * w[col] (+offsets +shift).
+    """z = per-row sum of vals * w[col] (+offsets +shift), for each of the G
+    tiles of a grid step (the blocks' leading axis), one after the other.
 
     With ``pair`` a second table v is gathered in the same sweep (shares all
     masks and the row-sum matmul): used for (margins(w), dot_rows(p)) in one
@@ -334,32 +342,38 @@ def _margins_kernel(use_offsets: bool, pair: bool, strided: bool, *refs):
     else:
         (off_ref, w_ref, shift_ref, out_z_ref) = refs
         tabs = [_table2(w_ref)]
-    hit, lot, rt = _tile_masks(hi_ref, lo_ref, rlo_ref, w_ref.shape[0])
-    sums = _row_sums(tabs, vals_ref[0], hit, lot, rt)
+    for t in range(vals_ref.shape[0]):
+        hit, lot, rt = _tile_masks(hi_ref, lo_ref, rlo_ref, w_ref.shape[0], t)
+        sums = _row_sums(tabs, vals_ref[t], hit, lot, rt)
+        z = sums[0] + shift_ref[0, 0]
+        if use_offsets:
+            z = z + off_ref[t]
+        out_z_ref[t] = z
+        if pair:
+            out_u_ref[t] = sums[1] + shift_ref[0, 1]
 
-    z = sums[0] + shift_ref[0, 0]
-    if use_offsets:
-        z = z + off_ref[0, :, :]
-    out_z_ref[0, :, :] = z
-    if pair:
-        out_u_ref[0, :, :] = sums[1] + shift_ref[0, 1]
+
+def _zero_at_first_step(*out_refs):
+    """The accumulators start from zero at the call's first grid step."""
+    @pl.when(pl.program_id(0) == 0)
+    def _():
+        for ref in out_refs:
+            ref[:] = jnp.zeros_like(ref)
 
 
 def _scatter_kernel(square: bool, strided: bool, *refs):
-    """g = sum_i per_row[i] * x_i (or x_i^2): transposed one-hot matmul."""
+    """g = sum_i per_row[i] * x_i (or x_i^2): transposed one-hot matmul,
+    the G tiles of a grid step added in tile order."""
     vals_ref, hi_ref, lo_ref, rlo_ref, refs = _slot_refs(strided, refs)
     (pr_ref, out_g_ref) = refs
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _():
-        out_g_ref[:] = jnp.zeros_like(out_g_ref)
-
-    hit, lot, rt = _tile_masks(hi_ref, lo_ref, rlo_ref, out_g_ref.shape[0])
-    vals = vals_ref[0]
-    if square:
-        vals = vals * vals
-    _scatter_accum(out_g_ref, pr_ref[0], vals, hit, lot, rt)
+    _zero_at_first_step(out_g_ref)
+    for t in range(vals_ref.shape[0]):
+        hit, lot, rt = _tile_masks(
+            hi_ref, lo_ref, rlo_ref, out_g_ref.shape[0], t)
+        vals = vals_ref[t]
+        if square:
+            vals = vals * vals
+        _scatter_accum(out_g_ref, pr_ref[t], vals, hit, lot, rt)
 
 
 def _value_grad_kernel(loss_name: str, use_offsets: bool, strided: bool,
@@ -368,29 +382,25 @@ def _value_grad_kernel(loss_name: str, use_offsets: bool, strided: bool,
     vals_ref, hi_ref, lo_ref, rlo_ref, refs = _slot_refs(strided, refs)
     (lab_ref, wgt_ref, off_ref, w_ref, shift_ref, out_s_ref,
      out_g_ref) = refs
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _():
-        out_s_ref[:] = jnp.zeros_like(out_s_ref)
-        out_g_ref[:] = jnp.zeros_like(out_g_ref)
-
-    hit, lot, rt = _tile_masks(hi_ref, lo_ref, rlo_ref, w_ref.shape[0])
-    vals = vals_ref[0]
-
-    z = _row_sums([_table2(w_ref)], vals, hit, lot, rt)[0] + shift_ref[0, 0]
-    if use_offsets:
-        z = z + off_ref[0, :, :]
-
+    _zero_at_first_step(out_s_ref, out_g_ref)
+    tab = _table2(w_ref)
     loss = get_loss(loss_name)
-    y = lab_ref[0, :, :]
-    wgt = wgt_ref[0, :, :]
-    l, dz = loss.loss_and_dz(z, y)
-    g_row = wgt * dz                                   # [1, R]
-    sums = jnp.stack([jnp.sum(wgt * l), jnp.sum(g_row)]).reshape(1, 2)
-    out_s_ref[:] = out_s_ref[:] + sums
+    for t in range(vals_ref.shape[0]):
+        hit, lot, rt = _tile_masks(hi_ref, lo_ref, rlo_ref, w_ref.shape[0], t)
+        vals = vals_ref[t]
 
-    _scatter_accum(out_g_ref, g_row, vals, hit, lot, rt)
+        z = _row_sums([tab], vals, hit, lot, rt)[0] + shift_ref[0, 0]
+        if use_offsets:
+            z = z + off_ref[t]
+
+        y = lab_ref[t]
+        wgt = wgt_ref[t]
+        l, dz = loss.loss_and_dz(z, y)
+        g_row = wgt * dz                                   # [1, R]
+        sums = jnp.stack([jnp.sum(wgt * l), jnp.sum(g_row)]).reshape(1, 2)
+        out_s_ref[:] = out_s_ref[:] + sums
+
+        _scatter_accum(out_g_ref, g_row, vals, hit, lot, rt)
 
 
 def _hv_kernel(loss_name: str, use_offsets: bool, strided: bool, *refs):
@@ -401,28 +411,24 @@ def _hv_kernel(loss_name: str, use_offsets: bool, strided: bool, *refs):
     vals_ref, hi_ref, lo_ref, rlo_ref, refs = _slot_refs(strided, refs)
     (lab_ref, wgt_ref, off_ref, w_ref, v_ref, shift_ref, out_s_ref,
      out_g_ref) = refs
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _():
-        out_s_ref[:] = jnp.zeros_like(out_s_ref)
-        out_g_ref[:] = jnp.zeros_like(out_g_ref)
-
-    hit, lot, rt = _tile_masks(hi_ref, lo_ref, rlo_ref, w_ref.shape[0])
-    vals = vals_ref[0]
-
-    sums = _row_sums([_table2(w_ref), _table2(v_ref)], vals, hit, lot, rt)
-    z = sums[0] + shift_ref[0, 0]
-    if use_offsets:
-        z = z + off_ref[0, :, :]
-    u = sums[1] + shift_ref[0, 1]
-
+    _zero_at_first_step(out_s_ref, out_g_ref)
+    tabs = [_table2(w_ref), _table2(v_ref)]
     loss = get_loss(loss_name)
-    q_row = wgt_ref[0, :, :] * loss.d2z(z, lab_ref[0, :, :]) * u   # [1, R]
-    out_s_ref[:] = out_s_ref[:] + jnp.stack(
-        [jnp.sum(q_row), jnp.float32(0.0)]).reshape(1, 2)
+    for t in range(vals_ref.shape[0]):
+        hit, lot, rt = _tile_masks(hi_ref, lo_ref, rlo_ref, w_ref.shape[0], t)
+        vals = vals_ref[t]
 
-    _scatter_accum(out_g_ref, q_row, vals, hit, lot, rt)
+        sums = _row_sums(tabs, vals, hit, lot, rt)
+        z = sums[0] + shift_ref[0, 0]
+        if use_offsets:
+            z = z + off_ref[t]
+        u = sums[1] + shift_ref[0, 1]
+
+        q_row = wgt_ref[t] * loss.d2z(z, lab_ref[t]) * u   # [1, R]
+        out_s_ref[:] = out_s_ref[:] + jnp.stack(
+            [jnp.sum(q_row), jnp.float32(0.0)]).reshape(1, 2)
+
+        _scatter_accum(out_g_ref, q_row, vals, hit, lot, rt)
 
 
 def _hv_at_kernel(strided: bool, *refs):
@@ -433,22 +439,18 @@ def _hv_at_kernel(strided: bool, *refs):
     for its whole inner loop)."""
     vals_ref, hi_ref, lo_ref, rlo_ref, refs = _slot_refs(strided, refs)
     (d2_ref, v_ref, shift_ref, out_s_ref, out_g_ref) = refs
-    i = pl.program_id(0)
+    _zero_at_first_step(out_s_ref, out_g_ref)
+    tab = _table2(v_ref)
+    for t in range(vals_ref.shape[0]):
+        hit, lot, rt = _tile_masks(hi_ref, lo_ref, rlo_ref, v_ref.shape[0], t)
+        vals = vals_ref[t]
 
-    @pl.when(i == 0)
-    def _():
-        out_s_ref[:] = jnp.zeros_like(out_s_ref)
-        out_g_ref[:] = jnp.zeros_like(out_g_ref)
+        u = _row_sums([tab], vals, hit, lot, rt)[0] + shift_ref[0, 0]
+        q_row = d2_ref[t] * u  # [1, R]
+        out_s_ref[:] = out_s_ref[:] + jnp.stack(
+            [jnp.sum(q_row), jnp.float32(0.0)]).reshape(1, 2)
 
-    hit, lot, rt = _tile_masks(hi_ref, lo_ref, rlo_ref, v_ref.shape[0])
-    vals = vals_ref[0]
-
-    u = _row_sums([_table2(v_ref)], vals, hit, lot, rt)[0] + shift_ref[0, 0]
-    q_row = d2_ref[0, :, :] * u  # [1, R]
-    out_s_ref[:] = out_s_ref[:] + jnp.stack(
-        [jnp.sum(q_row), jnp.float32(0.0)]).reshape(1, 2)
-
-    _scatter_accum(out_g_ref, q_row, vals, hit, lot, rt)
+        _scatter_accum(out_g_ref, q_row, vals, hit, lot, rt)
 
 
 def _split_tables_kernel(a_ref, out_ref):
@@ -459,36 +461,27 @@ def _split_tables_kernel(a_ref, out_ref):
         out_ref[l] = jnp.concatenate(_split_bf16(a_ref[l]), axis=0)
 
 
-def _project_kernel(K: int, G: int, vals_ref, hi_ref, lo_ref, tab_ref,
-                    out_ref):
+def _project_kernel(K: int, vals_ref, hi_ref, lo_ref, tab_ref, out_ref):
     """P[l] = per-row sum of vals * A[l, col] for the K tables of ``tab``
     ([K, 2*B8, 128]: :func:`_split_tables_kernel`'s halves) over the G
     strided tiles of one grid step: a tile's masks are built once, each
     table is one :func:`_gather_slots` pass. Out [G, K, R]."""
     B8 = tab_ref.shape[1] // 2
-    for t in range(G):
-        hit = _onehot_t(hi_ref[t], B8)
-        lot = _onehot_t(lo_ref[t], LANE).astype(jnp.bfloat16)
+    for t in range(vals_ref.shape[0]):
+        hit, lot, _ = _tile_masks(hi_ref, lo_ref, None, B8, t)
         vals = vals_ref[t]
         for l in range(K):
             out_ref[t, l:l + 1, :] = _chunk_sum(
                 _gather_slots(tab_ref[l], hit, lot) * vals)
 
 
-def _scatter_k_kernel(K: int, G: int, vals_ref, hi_ref, lo_ref, g_ref,
-                      out_ref):
+def _scatter_k_kernel(K: int, vals_ref, hi_ref, lo_ref, g_ref, out_ref):
     """out[l] += sum_s g[l, row_s] * vals_s * onehot(col_s) for K per-row
     vectors ``g`` [G, K, R] over the G strided tiles of one grid step."""
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _():
-        out_ref[:] = jnp.zeros_like(out_ref)
-
+    _zero_at_first_step(out_ref)
     B8 = out_ref.shape[1]
-    for t in range(G):
-        hit = _onehot_t(hi_ref[t], B8)
-        lot = _onehot_t(lo_ref[t], LANE).astype(jnp.bfloat16)
+    for t in range(vals_ref.shape[0]):
+        hit, lot, _ = _tile_masks(hi_ref, lo_ref, None, B8, t)
         vals = vals_ref[t]
         reps = vals.shape[1] // LANE
         for l in range(K):
@@ -549,10 +542,7 @@ def _scatter_window_kernel(K: int, square: bool, w_ref, vals_ref, hi_ref,
     selects and land by ONE NT matmul against ``lot``; the [K, B8, 128]
     accumulator stays in VMEM for the whole call and a tile adds to its
     window of it."""
-    @pl.when(pl.program_id(0) == 0)
-    def _():
-        out_ref[:] = jnp.zeros_like(out_ref)
-
+    _zero_at_first_step(out_ref)
     vals = vals_ref[0]
     if square:
         vals = vals * vals
@@ -574,18 +564,21 @@ def _scatter_window_kernel(K: int, square: bool, w_ref, vals_ref, hi_ref,
 # handle by which a reduction tells these kernels apart.
 
 
-def _spec_s(S):
-    return pl.BlockSpec((1, 1, S), lambda i: (i, 0, 0), memory_space=pltpu.VMEM)
-
-
-def _slot_specs(S, strided: bool):
-    """The slot blocks of one tile: vals, hi, lo and, sorted only, rlo."""
-    return [_spec_s(S)] * (3 if strided else 4)
-
-
-def _spec_r():
-    return pl.BlockSpec((1, 1, ROWS_PER_TILE), lambda i: (i, 0, 0),
+def _spec_g(G, *tail):
+    """G tiles of a [T, *tail] array a grid step."""
+    zeros = (0,) * len(tail)
+    return pl.BlockSpec((G, *tail), lambda i: (i, *zeros),
                         memory_space=pltpu.VMEM)
+
+
+def _slot_specs(G, S, strided: bool):
+    """The slot blocks of G tiles: vals, hi, lo and, sorted only, rlo."""
+    return [_spec_g(G, 1, S)] * (3 if strided else 4)
+
+
+def _spec_r(G):
+    """A per-row array of G tiles: [G, 1, 128]."""
+    return _spec_g(G, 1, ROWS_PER_TILE)
 
 
 def _spec_whole(shape):
@@ -597,40 +590,51 @@ def _spec_w(B):
     return _spec_whole((_table_rows(B), LANE))
 
 
+def _spec_shift():
+    return pl.BlockSpec((1, 2), lambda i: (0, 0), memory_space=pltpu.SMEM)
+
+
 def _shape_w(B):
     return jax.ShapeDtypeStruct((_table_rows(B), LANE), jnp.float32)
 
 
+def _shape_rows(T):
+    return jax.ShapeDtypeStruct((T, 1, ROWS_PER_TILE), jnp.float32)
+
+
+_SUMS = jax.ShapeDtypeStruct((1, 2), jnp.float32)
+
+
+# A call over T tiles runs G of them a grid step (``tiles_a_step``): its
+# blocks hold G tiles and the kernel runs the tile's body G times over them,
+# so every output is the one-tile-a-step call's to the last bit.
+
+
 @functools.lru_cache(maxsize=None)
-def _margins_call(T, S, B, strided, use_offsets, pair, interpret,
+def _margins_call(T, G, S, B, strided, use_offsets, pair, interpret,
                   name="tiled_margins"):
     kern = functools.partial(_margins_kernel, use_offsets, pair, strided)
     n_tab = 2 if pair else 1
-    out_shape = [jax.ShapeDtypeStruct((T, 1, ROWS_PER_TILE), jnp.float32)]
-    out_specs = [_spec_r()]
-    if pair:
-        out_shape.append(jax.ShapeDtypeStruct((T, 1, ROWS_PER_TILE), jnp.float32))
-        out_specs.append(_spec_r())
     return pl.pallas_call(
         kern,
-        grid=(T,),
-        in_specs=_slot_specs(S, strided) + [_spec_r()] + [_spec_w(B)] * n_tab
-        + [pl.BlockSpec((1, 2), lambda i: (0, 0), memory_space=pltpu.SMEM)],
-        out_specs=out_specs if pair else out_specs[0],
-        out_shape=out_shape if pair else out_shape[0],
+        grid=(T // G,),
+        in_specs=_slot_specs(G, S, strided) + [_spec_r(G)]
+        + [_spec_w(B)] * n_tab + [_spec_shift()],
+        out_specs=[_spec_r(G)] * 2 if pair else _spec_r(G),
+        out_shape=[_shape_rows(T)] * 2 if pair else _shape_rows(T),
         interpret=interpret,
         name=name,
     )
 
 
 @functools.lru_cache(maxsize=None)
-def _scatter_call(T, S, B, strided, square, interpret,
+def _scatter_call(T, G, S, B, strided, square, interpret,
                   name="tiled_scatter"):
     kern = functools.partial(_scatter_kernel, square, strided)
     return pl.pallas_call(
         kern,
-        grid=(T,),
-        in_specs=_slot_specs(S, strided) + [_spec_r()],
+        grid=(T // G,),
+        in_specs=_slot_specs(G, S, strided) + [_spec_r(G)],
         out_specs=_spec_w(B),
         out_shape=_shape_w(B),
         interpret=interpret,
@@ -639,64 +643,82 @@ def _scatter_call(T, S, B, strided, square, interpret,
 
 
 @functools.lru_cache(maxsize=None)
-def _hv_call(T, S, B, strided, loss_name, use_offsets, interpret):
+def _hv_call(T, G, S, B, strided, loss_name, use_offsets, interpret):
     kern = functools.partial(_hv_kernel, loss_name, use_offsets, strided)
     return pl.pallas_call(
         kern,
-        grid=(T,),
-        in_specs=_slot_specs(S, strided) + [_spec_r()] * 3 + [_spec_w(B)] * 2
-        + [pl.BlockSpec((1, 2), lambda i: (0, 0), memory_space=pltpu.SMEM)],
+        grid=(T // G,),
+        in_specs=_slot_specs(G, S, strided) + [_spec_r(G)] * 3
+        + [_spec_w(B)] * 2 + [_spec_shift()],
         out_specs=[_spec_whole((1, 2)), _spec_w(B)],
-        out_shape=[jax.ShapeDtypeStruct((1, 2), jnp.float32), _shape_w(B)],
+        out_shape=[_SUMS, _shape_w(B)],
         interpret=interpret,
         name="tiled_hv",
     )
 
 
 @functools.lru_cache(maxsize=None)
-def _hv_at_call(T, S, B, strided, interpret):
+def _hv_at_call(T, G, S, B, strided, interpret):
     return pl.pallas_call(
         functools.partial(_hv_at_kernel, strided),
-        grid=(T,),
-        in_specs=_slot_specs(S, strided) + [_spec_r()] + [_spec_w(B)]
-        + [pl.BlockSpec((1, 2), lambda i: (0, 0), memory_space=pltpu.SMEM)],
+        grid=(T // G,),
+        in_specs=_slot_specs(G, S, strided) + [_spec_r(G)] + [_spec_w(B)]
+        + [_spec_shift()],
         out_specs=[_spec_whole((1, 2)), _spec_w(B)],
-        out_shape=[jax.ShapeDtypeStruct((1, 2), jnp.float32), _shape_w(B)],
+        out_shape=[_SUMS, _shape_w(B)],
         interpret=interpret,
         name="tiled_hv_at",
     )
 
 
 @functools.lru_cache(maxsize=None)
-def _value_grad_call(T, S, B, strided, loss_name, use_offsets, interpret):
+def _value_grad_call(T, G, S, B, strided, loss_name, use_offsets, interpret):
     kern = functools.partial(
         _value_grad_kernel, loss_name, use_offsets, strided)
     return pl.pallas_call(
         kern,
-        grid=(T,),
-        in_specs=_slot_specs(S, strided) + [_spec_r()] * 3 + [_spec_w(B)]
-        + [pl.BlockSpec((1, 2), lambda i: (0, 0), memory_space=pltpu.SMEM)],
+        grid=(T // G,),
+        in_specs=_slot_specs(G, S, strided) + [_spec_r(G)] * 3 + [_spec_w(B)]
+        + [_spec_shift()],
         out_specs=[_spec_whole((1, 2)), _spec_w(B)],
-        out_shape=[jax.ShapeDtypeStruct((1, 2), jnp.float32), _shape_w(B)],
+        out_shape=[_SUMS, _shape_w(B)],
         interpret=interpret,
         name="tiled_value_grad",
     )
 
 
-#: tiles a grid step of the K-table sweeps, where the count divides: a step
-#: costs ~0.2 us whatever it computes (PERF.md, Findings PR 29)
-TILES_A_STEP = (8, 4, 2, 1)
+#: most tiles a grid step of the standing kernels: a step costs ~0.2 us
+#: whatever it computes, and every unrolled tile body adds compile time:
+#: 25 is the smallest cap within ~1 ms a call of each benchmark design's best
+#: (PERF.md, Findings: the kernel-alone table of G against ms)
+MAX_TILES_A_STEP = 25
+#: most tiles a grid step of the K-table sweeps, whose tile is K passes
+#: (1.2 us at K = 16): a step is 2% of 8 of them
+K_SWEEP_TILES_A_STEP = 8
+#: scoped VMEM a grid step may take: half the 16 MiB a Mosaic kernel gets
+#: on a v5e by default
+STEP_VMEM_BYTES = 8 << 20
 
 
-def _tiles_a_step(T: int) -> int:
-    return next(g for g in TILES_A_STEP if T % g == 0)
+def _step_vmem_bytes(G: int, S: int, B8: int, strided: bool) -> int:
+    """Scoped VMEM of a grid step of G tiles of S slots against [B8, 128]
+    tables, for the kernel with the most blocks (``tiled_hv``): the slot
+    blocks and three per-row blocks of G tiles, double-buffered, and two
+    tables and the accumulator, once. Mosaic keeps a tile's temporaries
+    (the [2*B8, S] gather, ``hit``, ``lot``) out of it: the described-v5e
+    compile's scoped allocation is this to 2% at G = 1 and 8."""
+    slot_arrays = 3 if strided else 4
+    return 4 * (2 * G * (slot_arrays * S + 3 * ROWS_PER_TILE)
+                + 3 * B8 * LANE)
 
 
-def _spec_g(G, *tail):
-    """G tiles of a [T, *tail] array a grid step."""
-    zeros = (0,) * len(tail)
-    return pl.BlockSpec((G, *tail), lambda i: (i, *zeros),
-                        memory_space=pltpu.VMEM)
+def tiles_a_step(T: int, S: int, B8: int, strided: bool = True,
+                 most: int = MAX_TILES_A_STEP) -> int:
+    """Tiles a grid step of a call over T tiles of S slots against tables
+    of B8 rows: the largest divisor of T up to ``most`` whose step fits
+    ``STEP_VMEM_BYTES`` (1 where none above 1 does)."""
+    return max(g for g in range(1, min(T, most) + 1) if T % g == 0 and (
+        g == 1 or _step_vmem_bytes(g, S, B8, strided) <= STEP_VMEM_BYTES))
 
 
 def _spec_table_k(K, rows):
@@ -723,7 +745,7 @@ def _split_tables_call(K, B, interpret, name):
 @functools.lru_cache(maxsize=None)
 def _project_call(T, S, B, K, G, interpret, name):
     return pl.pallas_call(
-        functools.partial(_project_kernel, K, G),
+        functools.partial(_project_kernel, K),
         grid=(T // G,),
         in_specs=[_spec_g(G, 1, S)] * 3
         + [_spec_table_k(K, 2 * _table_rows(B))],
@@ -737,7 +759,7 @@ def _project_call(T, S, B, K, G, interpret, name):
 @functools.lru_cache(maxsize=None)
 def _scatter_k_call(T, S, B, K, G, interpret, name):
     return pl.pallas_call(
-        functools.partial(_scatter_k_kernel, K, G),
+        functools.partial(_scatter_k_kernel, K),
         grid=(T // G,),
         in_specs=[_spec_g(G, 1, S)] * 3 + [_spec_g(G, K, ROWS_PER_TILE)],
         out_specs=_spec_table_k(K, _table_rows(B)),
@@ -1060,6 +1082,22 @@ class TiledBatch(ContractedRows):
         """(S, B, strided): what the kernels are built for."""
         return self.vals.shape[2], self.num_blocks, self.strided
 
+    def tiles_a_step(self, num_tiles: Optional[int] = None,
+                     most: int = MAX_TILES_A_STEP) -> int:
+        """Tiles a grid step of this design's calls over ``num_tiles`` (a
+        shard's; all of them by default): :func:`tiles_a_step` of the
+        shape."""
+        S, B, strided = self._statics()
+        return tiles_a_step(
+            self.num_tiles if num_tiles is None else num_tiles, S,
+            _table_rows(B), strided, most)
+
+    def _steps(self, factory, *args):
+        """``make_call`` of :func:`run_tiles`: ``factory`` over T tiles at
+        this design's shape and its tiles a step."""
+        return lambda T: factory(T, self.tiles_a_step(T), *self._statics(),
+                                 *args)
+
     def _slot_args(self):
         if self.strided:
             return (self.vals, self.hi, self.lo)
@@ -1071,21 +1109,19 @@ class TiledBatch(ContractedRows):
 
     def margins(self, w: Array, shift: Array | float = 0.0) -> Array:
         """Per-row margins z_i = x_i . w + shift + offset_i."""
-        st = self._statics()
         sh = jnp.stack([jnp.asarray(shift, jnp.float32), jnp.float32(0)])
         z = self._run(
-            lambda T: _margins_call(
-                T, *st, True, False, _interpret(), self.margins_name),
+            self._steps(_margins_call, True, False, _interpret(),
+                        self.margins_name),
             (*self._slot_args(), self.offsets3),
             (self._w2(w), sh.reshape(1, 2)), reduce=False)
         return z.reshape(-1)
 
     def dot_rows(self, w: Array) -> Array:
         """Per-row raw dot products x_i . w (no offset/shift)."""
-        st = self._statics()
         z = self._run(
-            lambda T: _margins_call(
-                T, *st, False, False, _interpret(), self.margins_name),
+            self._steps(_margins_call, False, False, _interpret(),
+                        self.margins_name),
             (*self._slot_args(), self.offsets3),
             (self._w2(w), jnp.zeros((1, 2), jnp.float32)), reduce=False)
         return z.reshape(-1)
@@ -1094,13 +1130,12 @@ class TiledBatch(ContractedRows):
         self, w: Array, shift, p: Array, p_shift
     ) -> tuple[Array, Array]:
         """(margins(w, shift), dot_rows(p) + p_shift) in one fused sweep."""
-        st = self._statics()
         sh = jnp.stack([
             jnp.asarray(shift, jnp.float32), jnp.asarray(p_shift, jnp.float32)
         ])
         z, u = self._run(
-            lambda T: _margins_call(
-                T, *st, True, True, _interpret(), self.margins_name),
+            self._steps(_margins_call, True, True, _interpret(),
+                        self.margins_name),
             (*self._slot_args(), self.offsets3),
             (self._w2(w), self._w2(p), sh.reshape(1, 2)), reduce=False)
         return z.reshape(-1), u.reshape(-1)
@@ -1121,10 +1156,9 @@ class TiledBatch(ContractedRows):
         return self.margins_name.replace("_margins", "_scatter")
 
     def _scatter(self, per_row: Array, square: bool) -> Array:
-        st = self._statics()
         g = self._run(
-            lambda T: _scatter_call(
-                T, *st, square, _interpret(), self._scatter_name),
+            self._steps(_scatter_call, square, _interpret(),
+                        self._scatter_name),
             (*self._slot_args(), self._rows3(per_row)), (), reduce=True)
         return self._features(g)
 
@@ -1145,11 +1179,9 @@ class TiledBatch(ContractedRows):
         the caller applies normalization back-transform and regularization
         (GLMObjective.value_and_grad fast path).
         """
-        st = self._statics()
         sh = jnp.stack([jnp.asarray(shift, jnp.float32), jnp.float32(0)])
         sums, g = self._run(
-            lambda T: _value_grad_call(
-                T, *st, loss_name, True, _interpret()),
+            self._steps(_value_grad_call, loss_name, True, _interpret()),
             (*self._slot_args(), self.labels3, self.weights3, self.offsets3),
             (self._w2(w), sh.reshape(1, 2)), reduce=True)
         return sums[0, 0], self._features(g), sums[0, 1]
@@ -1160,12 +1192,11 @@ class TiledBatch(ContractedRows):
         """(raw Hv scatter sum_i wgt_i*l''(z_i)*(x_i.v)*x_i, sum of the
         per-row q = wgt*l''*u terms) in ONE fused sweep (TRON CG fast path).
         Caller applies normalization back-transform and the L2 term."""
-        st = self._statics()
         sh = jnp.stack([
             jnp.asarray(shift, jnp.float32), jnp.asarray(v_shift, jnp.float32)
         ])
         sums, g = self._run(
-            lambda T: _hv_call(T, *st, loss_name, True, _interpret()),
+            self._steps(_hv_call, loss_name, True, _interpret()),
             (*self._slot_args(), self.labels3, self.weights3, self.offsets3),
             (self._w2(w), self._w2(v), sh.reshape(1, 2)), reduce=True)
         return self._features(g), sums[0, 0]
@@ -1176,10 +1207,9 @@ class TiledBatch(ContractedRows):
         """(raw Hv scatter, sum q) with the row curvature d2 = wgt*l''(z)
         precomputed: ONE pass doing gather u + scatter q (TRON CG holds z
         fixed across its inner loop)."""
-        st = self._statics()
         sh = jnp.stack([jnp.asarray(v_shift, jnp.float32), jnp.float32(0)])
         sums, g = self._run(
-            lambda T: _hv_at_call(T, *st, _interpret()),
+            self._steps(_hv_at_call, _interpret()),
             (*self._slot_args(), self._rows3(d2_row)),
             (self._w2(v_eff), sh.reshape(1, 2)), reduce=True)
         return self._features(g), sums[0, 0]
@@ -1196,7 +1226,7 @@ class TiledBatch(ContractedRows):
         if not self.strided or self.shard is not None:
             return jax.lax.map(self.dot_rows, a)
         S, B, _ = self._statics()
-        G = _tiles_a_step(self.num_tiles)
+        G = self.tiles_a_step(most=K_SWEEP_TILES_A_STEP)
         tabs = _split_tables(
             a, self.num_features,
             self.margins_name.replace("_margins", "_tables") + "_k")
@@ -1216,7 +1246,8 @@ class TiledBatch(ContractedRows):
         g3 = g.astype(jnp.float32).reshape(
             K, self.num_tiles, ROWS_PER_TILE).transpose(1, 0, 2)
         out = _scatter_k_call(
-            self.num_tiles, S, B, K, _tiles_a_step(self.num_tiles),
+            self.num_tiles, S, B, K,
+            self.tiles_a_step(most=K_SWEEP_TILES_A_STEP),
             _interpret(), self._scatter_name + "_k",
         )(*self._slot_args(), g3)
         return out.reshape(K, -1)[:, : self.num_features]

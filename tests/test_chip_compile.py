@@ -86,15 +86,16 @@ S_SMALL, B_SMALL = 384, 3
 
 
 def _kernel_cases(S=S, B=B, T=T, strided=True):
-    """name -> (pallas_call built with interpret=False, argument shapes).
-    A strided design hands the kernels three slot arrays, a sorted one four
-    (``rlo``)."""
+    """name -> (pallas_call built with interpret=False, argument shapes), at
+    the tiles a grid step the design's rule gives. A strided design hands
+    the kernels three slot arrays, a sorted one four (``rlo``)."""
     slot = [((T, 1, S), jnp.float32)] + [((T, 1, S), jnp.int32)] * (
         2 if strided else 3)
     row = ((T, 1, ROWS_PER_TILE), jnp.float32)
     w2 = ((tiled._table_rows(B), LANE), jnp.float32)
     sh = ((1, 2), jnp.float32)
-    st = (T, S, B, strided)
+    st = (T, tiled.tiles_a_step(T, S, tiled._table_rows(B), strided), S, B,
+          strided)
     return {
         "margins": (
             tiled._margins_call(*st, True, False, False),
@@ -166,9 +167,13 @@ def test_kernel_family_compiles_sorted_for_v5e(name, kernel, one_chip):
         kernel, *_kernel_cases(strided=False)[name], one_chip)
 
 
-# the benchmark's two cells, strided: glm_fe.lbfgs_fit's plain design and
-# criteo_fe.lbfgs_fit's hot panel at 39 slots a row
-CELL_SHAPES = {"glm_fe": (46_875, 2_560, 79), "criteo_hot": (54_784, 4_992, 32)}
+# the benchmark cells' training designs, strided: glm_fe.lbfgs_fit's plain
+# design, criteo_fe.lbfgs_fit's hot panel at 39 slots a row and the MovieLens
+# cells' fixed effect (ragged rows of 2 to 10 nonzeros): (T, S, B) and the
+# tiles a grid step of their calls
+CELL_SHAPES = {"glm_fe": (46_875, 2_560, 79, 25),
+               "criteo_hot": (54_784, 4_992, 32, 16),
+               "ml20m_fe": (140_625, 1_280, 1, 25)}
 
 
 @pytest.mark.parametrize("cell", sorted(CELL_SHAPES))
@@ -178,7 +183,10 @@ CELL_SHAPES = {"glm_fe": (46_875, 2_560, 79), "criteo_hot": (54_784, 4_992, 32)}
 ])
 def test_strided_kernel_compiles_at_the_cells_shapes_for_v5e(
         name, kernel, cell, one_chip):
-    tiles, slots, blocks = CELL_SHAPES[cell]
+    """Each at the tiles a grid step the rule picks: its blocks fit the
+    chip's VMEM."""
+    tiles, slots, blocks, step = CELL_SHAPES[cell]
+    assert tiled.tiles_a_step(tiles, slots, tiled._table_rows(blocks)) == step
     call, shapes = _kernel_cases(slots, blocks, tiles)[name]
     assert sum(shape == (tiles, 1, slots) for shape, _ in shapes) == 3
     _compiles_as(kernel, call, shapes, one_chip)

@@ -201,6 +201,41 @@ def test_sharded_tiled_kernel_matches_one_device(case, tiled_pair, rng):
         np.testing.assert_allclose(b, a, rtol=2e-5, atol=2e-5)
 
 
+def test_a_shard_takes_the_tiles_a_step_of_its_own_count(rng, multichip,
+                                                         monkeypatch):
+    """60 tiles: one device runs 20 a grid step, a shard of 15 (batch=4)
+    runs 15. The per-row passes equal the one device's to the last bit;
+    the accumulators are four partial sums added, so to float32 rounding."""
+    from photon_ml_tpu.ops import tiled
+    from photon_ml_tpu.ops.tiled import TiledBatch
+
+    steps = []
+    for name in ("_margins_call", "_scatter_call"):
+        real = getattr(tiled, name)
+        monkeypatch.setattr(
+            tiled, name,
+            lambda T, G, *a, real=real, **k: steps.append((T, G)) or real(
+                T, G, *a, **k))
+    n, d = 60 * 128, 150
+    rows = np.repeat(np.arange(n), 2)
+    cols = rng.integers(0, d, size=len(rows))
+    tb = TiledBatch.from_coo(
+        rng.normal(size=len(rows)), rows, cols,
+        (rng.random(n) > 0.5).astype(float), d)
+    placed = psharding.place_batch(tb, make_mesh({"batch": 4, "model": 2}))
+    assert tb.tiles_a_step() == 20 and placed.tiles_a_step(15) == 15
+    w = jnp.asarray(rng.normal(size=d), jnp.float32)
+    r = jnp.asarray(rng.normal(size=n), jnp.float32)
+    z1, g1 = tb.margins(w, 0.3), tb.scatter_features(r)
+    assert steps == [(60, 20), (60, 20)]
+    z4, g4 = jax.jit(
+        lambda b: (b.margins(w, 0.3), b.scatter_features(r)))(placed)
+    assert steps[2:] == [(15, 15), (15, 15)]
+    np.testing.assert_array_equal(np.asarray(z4), np.asarray(z1))
+    np.testing.assert_allclose(
+        np.asarray(g4), np.asarray(g1), rtol=2e-5, atol=2e-5)
+
+
 def test_sharded_tiled_solve_matches_one_device(tiled_pair):
     """The whole LBFGS while-loop over the sharded tiles (gspmd_solve) lands
     on the one-device optimum, replicated over the mesh."""

@@ -501,6 +501,9 @@ def test_layout_counters_and_spans():
     assert "layout.tiles.sorted" not in c
     assert g["layout.padding_ratio"] == pytest.approx(
         packed.nnz_slots / len(vals))
+    # the hot part's 16 tiles (1,500 rows padded to whole steps of the
+    # widest class): all of them a grid step
+    assert g["layout.tiles_a_step"] == packed.hot.tiles_a_step() == 16
     names = {s.name for s in telemetry.finished_spans()}
     assert {"layout.histogram", "layout.rank", "layout.hot",
             "layout.tail"} <= names

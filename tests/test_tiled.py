@@ -210,7 +210,15 @@ _EDGE_SHAPES = {
     "S256_B79": (200, 10_000, [200, 256]),
     # B = 16: the table has no spare row, the sentinel matches none
     "S128_B16_empty_tile": (256, 2_048, [50, 0]),
+    # tile counts of the cells' kinds, several tiles a grid step: an odd
+    # count (75 = 3 x 5^2), a power of two, and a prime over the cap (one)
+    "T75_odd_count": (75 * 128, 300, [150] * 75),
+    "T16_power_of_two": (16 * 128, 300, [150] * 16),
+    "T37_prime_count": (37 * 128 - 50, 300, [150] * 37),
 }
+
+# the tiles a grid step each edge design's calls run, by tile count
+_TILES_A_STEP = {2: 2, 3: 3, 16: 16, 37: 1, 75: 25}
 
 
 @pytest.fixture(scope="module", params=[
@@ -231,6 +239,9 @@ def _edge_design(shape, how):
         rows.append(rng.integers(t * 128, hi_row, size=c))
         cols.append(rng.integers(0, f, size=c))
     rows, cols = np.concatenate(rows), np.concatenate(cols)
+    # one nonzero a cell: X * X is then the square of every slot's value
+    first = np.sort(np.unique(rows * f + cols, return_index=True)[1])
+    rows, cols = rows[first], cols[first]
     values = rng.normal(size=len(rows)) * np.exp(2 * rng.normal(size=len(rows)))
     tb = TiledBatch.from_coo(
         values=values, rows=rows, cols=cols, num_features=f,
@@ -328,6 +339,20 @@ def test_exact_against_float64(edge, entry):
         assert err < EXACT_REL, err
 
 
+@pytest.mark.parametrize("entry", _entry_points(), ids=lambda f: f.__name__)
+def test_tiles_a_step_is_bit_identical_to_one(edge, entry, monkeypatch):
+    """G tiles a grid step run the tile's body G times in tile order, so
+    every output is the one-tile-a-step call's to the last bit."""
+    tb, inputs = edge
+    X = tb.to_dense()
+    assert tb.tiles_a_step() == _TILES_A_STEP[tb.num_tiles]
+    got, _ = entry(tb, X, inputs)
+    monkeypatch.setattr(TiledBatch, "tiles_a_step", lambda *_, **__: 1)
+    one, _ = entry(tb, X, inputs)
+    for g, o in zip(got, one):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(o))
+
+
 def test_exactness_limit_rejects_a_single_bfloat16_pass(edge):
     """The limit has teeth: the same products with the coefficients rounded
     once to bfloat16 miss it by two orders."""
@@ -408,6 +433,79 @@ def test_the_rule_weighs_slots_against_the_pass_saved():
     assert tiled.strided_is_cheaper(4992, 4352, 32)
     assert tiled.strided_is_cheaper(8704, 4352, 32)
     assert not tiled.strided_is_cheaper(8832, 4352, 32)
+
+
+def test_tiles_a_step_rule():
+    """The largest divisor of the tile count up to the cap whose grid step
+    fits the VMEM budget; one where none above one does."""
+    rule = tiled.tiles_a_step
+    assert tiled.MAX_TILES_A_STEP == 25
+    # the cells' training designs (T, S, B8), strided: glm_fe (3 x 5^6),
+    # the criteo hot panel (2^9 x 107), the MovieLens fixed effect (3^2 x 5^6)
+    assert rule(46_875, 2_560, 80) == 25
+    assert rule(54_784, 4_992, 32) == 16
+    assert rule(140_625, 1_280, 16) == 25
+    # their validation designs, and the chip smoke's 7,813 = 13 x 601
+    assert rule(4_688, 2_560, 80) == 16
+    assert rule(5_632, 4_992, 32) == 22
+    assert rule(7_813, 2_560, 80) == 13
+    # a prime count over the cap, and glm_fe's per-shard count on four
+    # devices (46,875 padded to 46,876): the call as it was
+    assert rule(7_919, 2_560, 80) == rule(11_719, 2_560, 80) == 1
+    # sorted: one slot array more, the same choice at the cells' widths
+    assert rule(46_875, 2_560, 80, strided=False) == 25
+
+
+def test_tiles_a_step_stops_at_the_vmem_budget():
+    """Rows of 200 nonzeros: 25,600 slots a tile, so 13 tiles' double-buffered
+    blocks fill the budget and a count of 75 takes 5 a step."""
+    S = 128 * 200
+    step = tiled._step_vmem_bytes
+    assert step(13, S, 16, True) <= tiled.STEP_VMEM_BYTES < step(14, S, 16, True)
+    assert tiled.tiles_a_step(75, S, 16) == 5
+    # the fourth slot array of a sorted design costs a fifth of a step
+    assert tiled.tiles_a_step(75, S, 16, strided=False) == 5
+    assert step(5, S, 16, False) > step(5, S, 16, True)
+    # a tile too wide for the budget alone still runs, one a step
+    assert tiled.tiles_a_step(75, 128 * 3_000, 16) == 1
+    # at the cells' shapes the blocks are far inside it (described-v5e
+    # compile: 1.53 MB at G = 25 in glm_fe)
+    assert step(25, 2_560, 80, True) < tiled.STEP_VMEM_BYTES / 4
+
+
+def test_k_sweeps_keep_eight_tiles_a_step(rng):
+    """The K-table sweeps take the same rule with their own cap: the
+    factored coordinate pads its rows to whole steps of 8, so its
+    projection runs 8 a step at ml20m_mf.cd_fit's 212,296 tiles."""
+    assert tiled.K_SWEEP_TILES_A_STEP == 8
+    assert tiled.tiles_a_step(
+        212_296, 128, 224, most=tiled.K_SWEEP_TILES_A_STEP) == 8
+    for T, G in [(8, 8), (24, 8), (12, 6), (7, 7), (13, 1)]:
+        assert tiled.tiles_a_step(
+            T, 128, 224, most=tiled.K_SWEEP_TILES_A_STEP) == G
+    rows = np.arange(24 * 128)
+    tb = TiledBatch.pack_coo(np.ones(len(rows)), rows, rows % 300,
+                             np.zeros(len(rows)), 300)
+    assert tb.tiles_a_step(most=tiled.K_SWEEP_TILES_A_STEP) == 8
+    assert tb.tiles_a_step() == 24
+
+
+def test_plain_design_reports_its_tiles_a_step(rng):
+    """Gauge ``layout.tiles_a_step``: the G of the training design's calls,
+    per shard where the design is packed for a mesh."""
+    from photon_ml_tpu import telemetry
+    from photon_ml_tpu.ops.panels import pack_design
+
+    n = 60 * 128
+    rows = np.repeat(np.arange(n), 3)
+    cols = rng.integers(0, 1_000, size=len(rows))
+    batch = SparseBatch.from_coo(rng.normal(size=len(rows)), rows, cols,
+                                 np.zeros(n), 1_000)
+    design = pack_design(batch)
+    assert isinstance(design, TiledBatch) and design.num_tiles == 60
+    assert telemetry.snapshot()["gauges"]["layout.tiles_a_step"] == 20
+    pack_design(batch, shards=4)
+    assert telemetry.snapshot()["gauges"]["layout.tiles_a_step"] == 15
 
 
 def test_unsorted_coo_packs_strided(rng, monkeypatch):

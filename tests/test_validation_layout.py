@@ -152,6 +152,11 @@ def test_plain_tiles_score_validation_rows():
     assert c["layout.nnz"] == 1500 * 12 and c["validate.layout.nnz"] == 333 * 12
     assert c["layout.slots"] == coord._tiled.nnz_slots
     assert c["validate.layout.slots"] == design.nnz_slots
+    # 12 training tiles and 3 validation tiles: each design's calls run all
+    # of its tiles in one grid step
+    g = telemetry.snapshot()["gauges"]
+    assert g["layout.tiles_a_step"] == coord._tiled.tiles_a_step() == 12
+    assert g["validate.layout.tiles_a_step"] == design.tiles_a_step() == 3
     spans = {s.span_id: s for s in telemetry.finished_spans()}
     for name in ("validation_layout", "validation_upload"):
         (s,) = [s for s in spans.values() if s.name == name]
