@@ -54,13 +54,16 @@ from photon_ml_tpu.ops.panels import (
 )
 from photon_ml_tpu.ops.tiled import ROWS_PER_TILE
 from photon_ml_tpu.optim.adapter import glm_adapter
-from photon_ml_tpu.optim.common import BoxConstraints
+from photon_ml_tpu.optim.common import BoxConstraints, plain_lane_counts
 from photon_ml_tpu.optim.factory import (
     OptimizerConfig,
     OptimizerType,
     dispatch_solve,
+    newton_config,
+    newton_problem,
 )
 from photon_ml_tpu.optim.guard import damped_objective, solve_health
+from photon_ml_tpu.optim.newton import newton_lanes
 from photon_ml_tpu.optim.spd_solve import takes_hand_solve
 from photon_ml_tpu.parallel.distributed import gspmd_solve
 from photon_ml_tpu.parallel import sharding as psharding
@@ -496,36 +499,44 @@ class FixedEffectCoordinate:
 _VARIANCE_EPS = 1e-12
 
 
+def _variances(obj, batch, w: Array) -> Array:
+    """The Hessian-diagonal inverse at the solution (the computeVariances
+    path of SingleNodeOptimizationProblem.scala:57-88)."""
+    return 1.0 / (obj.hessian_diagonal(w, batch) + _VARIANCE_EPS)
+
+
 def _make_solve_one(config: OptimizerConfig, compute_variances: bool):
-    """One entity's solve (+optional Hessian-diagonal-inverse variances, the
-    computeVariances path of SingleNodeOptimizationProblem.scala:57-88).
-    Returns ``(SolveResult, variances-or-None)``."""
+    """One entity's solve (+optional variances). Returns
+    ``(SolveResult, variances-or-None)``."""
 
     def solve_one(obj, batch, w0, l1, constraints):
         res = dispatch_solve(
             glm_adapter(obj, batch), w0, config, l1, constraints=constraints
         )
-        if not compute_variances:
-            return res, None
-        var = 1.0 / (obj.hessian_diagonal(res.w, batch) + _VARIANCE_EPS)
-        return res, var
+        return res, _variances(obj, batch, res.w) if compute_variances else None
 
     return solve_one
 
 
+def _lane_batch(packed: bool, kmajor: bool, k: int):
+    """One entity's batch from its lane of a bucket: ``packed`` reassembles
+    a DenseBatch from the flat packed design inside jit
+    (_packed_dense_batch): row-major [R*K], or with ``kmajor``
+    feature-major [K*R] (what the factored coordinate's projection pass
+    writes)."""
+    if not packed:
+        return lambda batch: batch
+    return lambda batch: _packed_dense_batch(batch, k, kmajor)
+
+
 def _adapt_solve_one(config, compute_variances: bool, packed: bool,
                      kmajor: bool = False):
-    """Per-entity solve body; ``packed`` reassembles a DenseBatch from the
-    flat packed design inside jit (_packed_dense_batch): row-major [R*K],
-    or with ``kmajor`` feature-major [K*R] (what the factored coordinate's
-    projection pass writes)."""
+    """Per-entity solve body over a lane of the bucket's batch."""
     base_one = _make_solve_one(config, compute_variances)
-    if not packed:
-        return base_one
 
     def solve_one(obj, batch, w0, l1, constraints):
-        return base_one(
-            obj, _packed_dense_batch(batch, w0, kmajor), w0, l1, constraints)
+        lane = _lane_batch(packed, kmajor, w0.shape[0])(batch)
+        return base_one(obj, lane, w0, l1, constraints)
 
     return solve_one
 
@@ -538,17 +549,54 @@ def _re_solver(
     compute_variances: bool = False,
     packed: bool = False,
     kmajor: bool = False,
+    sharded: bool = False,
 ):
-    solve_one = _adapt_solve_one(config, compute_variances, packed, kmajor)
+    """A bucket's per-entity solves as one program. Newton on one device
+    (``sharded`` False) leaves a lane behind when it is done
+    (``optim/newton.py::newton_lanes``); under a mesh, whose compiler
+    partitions the lanes, a global gather of the open lanes would cross
+    devices, so there and for L-BFGS / TRON it is the plain vmapped loop.
+    The result's ``lanes`` says what the lanes cost (``SolveResult``)."""
     # obj, l1 broadcast; batch leaves, w0 (and per-entity constraint boxes,
     # when present) map over the entity axis. constrained="shared" keeps one
     # [K] box broadcast to every entity (the streaming table's dense local
     # space) instead of materializing [E, K] bounds.
     c_axis = 0 if constrained is True else None
+    if config.optimizer_type != OptimizerType.NEWTON or sharded:
+        plain = jax.vmap(
+            _adapt_solve_one(config, compute_variances, packed, kmajor),
+            in_axes=(None, 0, 0, None, c_axis),
+        )
+
+        def solve(obj, batch, w0, l1, constraints):
+            res, var = plain(obj, batch, w0, l1, constraints)
+            return res._replace(lanes=plain_lane_counts(res.iterations)), var
+
+    else:
+
+        def solve(obj, batch, w0, l1, constraints):
+            lane_batch = _lane_batch(packed, kmajor, w0.shape[1])
+
+            def problem(lane):
+                b, per_lane = lane
+                box = per_lane if c_axis == 0 else constraints
+                return newton_problem(glm_adapter(obj, b), box)
+
+            res = newton_lanes(
+                problem, (batch, constraints if c_axis == 0 else None), w0,
+                newton_config(config),
+                prepare=lambda lane: (lane_batch(lane[0]), lane[1]))
+            var = None
+            if compute_variances:
+                var = jax.vmap(
+                    lambda b, w: _variances(obj, lane_batch(b), w))(
+                        batch, res.w)
+            return res, var
+
     # multi_shape: each geometry bucket (entity count, rows, K) is its
     # own signature by construction
     return instrumented_jit(
-        jax.vmap(solve_one, in_axes=(None, 0, 0, None, c_axis)),
+        solve,
         name="re_solve_dense" if packed else "re_solve",
         multi_shape=True,
     )
@@ -669,7 +717,7 @@ def _re_offsets():
     return instrumented_jit(offsets, name="re_offsets", multi_shape=True)
 
 
-def _packed_dense_batch(packed, w0, kmajor: bool = False):
+def _packed_dense_batch(packed, k: int, kmajor: bool = False):
     """Reassemble a DenseBatch from the PACKED per-entity design INSIDE
     jit: the design is stored flat [R*K] per entity (TPU pads a resident
     [E, R, K] array's K lanes to 128 — 128/K-fold HBM bloat; the flat
@@ -679,7 +727,6 @@ def _packed_dense_batch(packed, w0, kmajor: bool = False):
     from photon_ml_tpu.ops.dense import DenseBatch
 
     x_flat, labels, offsets, weights = packed
-    k = w0.shape[0]
     return DenseBatch(
         x=x_flat.reshape(k, -1).T if kmajor else x_flat.reshape(-1, k),
         labels=labels,
@@ -792,12 +839,14 @@ class RandomEffectCoordinate:
             self._axis = psharding.model_axis(self.mesh)
             if self._axis is None:
                 self.mesh = None  # batch-only mesh: no entity axis to use
+        sharded = self.mesh is not None
         self._solver = _re_solver(
-            key_cfg, self.loss_name, constrained, self.compute_variances
+            key_cfg, self.loss_name, constrained, self.compute_variances,
+            sharded=sharded,
         )
         self._dense_solver = _re_solver(
             key_cfg, self.loss_name, constrained, self.compute_variances,
-            packed=True,
+            packed=True, sharded=sharded,
         )
         self._scorer = _re_scorer()
         with span("build.objective"):  # its eager one-op programs
@@ -899,26 +948,22 @@ class RandomEffectCoordinate:
         res, var = solver(obj, bb_p, w0_p, self._l1, cons_p)
         return res, res.w[:num_e], None if var is None else var[:num_e]
 
-    def _report_stragglers(self, iterations: np.ndarray) -> None:
+    def _report_stragglers(self, tracker) -> None:
         """Counters ``re.<name>.lane_iterations`` (what every entity's own
-        solve needed, summed) and ``.lane_iterations_run`` (a vmapped
-        ``while_loop`` runs every lane of a bucket to its slowest entity:
-        entities x the bucket's longest solve, summed), with their sums
-        over the coordinates ``re.lane_iterations*``; ``.pass_cells`` is
-        each entity's iterations x its own rows x its own local features,
-        the design cells its passes had to read. From the tracker's
-        per-entity iterations, which lie bucket after bucket."""
-        needed = run = cells = lo = 0
+        solve needed, summed), ``.pass_cells`` (each entity's iterations x
+        its own rows x its own local features, the design cells its passes
+        had to read) and what the buckets' lanes cost (``SolveResult.lanes``):
+        ``.lane_iterations_run`` (the lane-iterations the device ran),
+        ``.compacted_lanes`` and ``.compact_rounds``, with their sums over
+        the coordinates ``re.*``. From the tracker, whose per-entity
+        iterations lie bucket after bucket."""
+        cells = lo = 0
         for own in self._entity_cells:
-            its = iterations[lo:lo + len(own)]
+            its = tracker.iterations[lo:lo + len(own)]
             lo += len(own)
-            if len(its):
-                needed += int(its.sum())
-                run += int(its.max()) * len(its)
-                cells += int(np.dot(its.astype(np.int64), own))
+            cells += int(np.dot(its.astype(np.int64), own))
+        tracker.report_lanes((f"re.{self.name}", "re"))
         for scope in (f"re.{self.name}", "re"):
-            counter(f"{scope}.lane_iterations").inc(needed)
-            counter(f"{scope}.lane_iterations_run").inc(run)
             counter(f"{scope}.pass_cells").inc(cells)
 
     def _report_solve_route(self, b) -> None:
@@ -945,6 +990,7 @@ class RandomEffectCoordinate:
         tracker_its = []
         tracker_reasons = []
         tracker_vals = []
+        tracker_lanes = []
         healths = []
         obj = damped_objective(self._obj, self.extra_l2)
         for i, (b, bm) in enumerate(zip(self._buckets, model.buckets)):
@@ -963,6 +1009,7 @@ class RandomEffectCoordinate:
             tracker_its.append(res.iterations[:n_real])
             tracker_reasons.append(res.reason[:n_real])
             tracker_vals.append(res.value[:n_real])
+            tracker_lanes.append(res.lanes)
             if self.health_check:
                 # mesh-padded entities are all-zero problems (value 0 at
                 # w=0), so the full padded res passes the reduce harmlessly
@@ -978,10 +1025,10 @@ class RandomEffectCoordinate:
         with span("re_tracker"):  # the host's wait on every bucket's solve
             self.last_tracker = (
                 RandomEffectOptimizationTracker.from_device_parts(
-                    tracker_its, tracker_reasons, tracker_vals
+                    tracker_its, tracker_reasons, tracker_vals, tracker_lanes
                 )
             )
-        self._report_stragglers(self.last_tracker.iterations)
+        self._report_stragglers(self.last_tracker)
         if new_buckets:
             _record_placement(
                 f"{self.name}.coefficients", new_buckets[0].coefficients

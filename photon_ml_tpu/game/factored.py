@@ -555,7 +555,7 @@ class FactoredRandomEffectCoordinate:
         # designs: one lru_cache entry with every coordinate of this config
         self._re_solver = _re_solver(
             _solver_key(self.re_config), self.loss_name, packed=True,
-            kmajor=True)
+            kmajor=True, sharded=self.mesh is not None)
         with span("build.objective"):  # their eager one-op programs
             self._re_obj, self._re_l1 = _objective_and_l1(
                 self.loss_name, self.re_config)
@@ -800,8 +800,10 @@ class FactoredRandomEffectCoordinate:
             self.re_config.max_iterations,
         )
         res = self._re_solver(obj, bb_p, w0_p, self._re_l1, None)[0]
-        return jax.tree.map(
-            lambda x: x[:lanes] if getattr(x, "ndim", 0) else x, res)
+        per_lane = jax.tree.map(
+            lambda x: x[:lanes] if getattr(x, "ndim", 0) else x,
+            res._replace(lanes=None))
+        return per_lane._replace(lanes=res.lanes)
 
     def latent_designs(self, a: Array) -> tuple:
         """Every bucket's latent design [E, K*R] (feature-major) under the
@@ -814,7 +816,7 @@ class FactoredRandomEffectCoordinate:
         k = self._proj_rows
         with span("latent_design"):
             designs = self.latent_designs(a)
-        parts, t_its, t_reasons, t_vals = [], [], [], []
+        parts, t_its, t_reasons, t_vals, t_lanes = [], [], [], [], []
         buckets = self.re_data.device_buckets_stripped()
         for b_idx, b in enumerate(buckets):
             e, r = b.num_entities, b.rows_per_entity
@@ -832,28 +834,13 @@ class FactoredRandomEffectCoordinate:
             t_its.append(res.iterations)
             t_reasons.append(res.reason)
             t_vals.append(res.value)
+            t_lanes.append(res.lanes)
         new_latent = jnp.concatenate(parts, axis=0) if parts else latent
         with span("latent_tracker"):  # the host's wait on every bucket
             tracker = RandomEffectOptimizationTracker.from_device_parts(
-                t_its, t_reasons, t_vals)
-        self._report_stragglers(tracker.iterations)
+                t_its, t_reasons, t_vals, t_lanes)
+        tracker.report_lanes((f"mf.{self.name}", "mf"))
         return new_latent, tracker
-
-    def _report_stragglers(self, iterations: np.ndarray) -> None:
-        """Counters ``mf.<name>.lane_iterations`` / ``.lane_iterations_run``
-        (and their sums ``mf.*``): every entity's own Newton iterations, and
-        entities x its bucket's longest solve (a vmapped ``while_loop`` runs
-        every lane to the slowest)."""
-        needed = run = 0
-        for i in range(len(self.re_data.buckets)):
-            its = iterations[
-                int(self._flat_offsets[i]):int(self._flat_offsets[i + 1])]
-            if len(its):
-                needed += int(its.sum())
-                run += int(its.max()) * len(its)
-        for scope in (f"mf.{self.name}", "mf"):
-            counter(f"{scope}.lane_iterations").inc(needed)
-            counter(f"{scope}.lane_iterations_run").inc(run)
 
     def _latent_matrix_step(self, latent: Array, a: Array, offsets: tuple):
         """Refit vec(A) as one GLM over :class:`LatentRefitBatch`. Returns

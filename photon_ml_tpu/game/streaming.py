@@ -326,7 +326,8 @@ class StreamingRandomEffectTrainer:
             self._n_dev = psharding.axis_size(mesh, self._axis)
         key_cfg = dataclasses.replace(config, regularization_weight=0.0)
         self._solver = _re_solver(
-            key_cfg, loss_name, constrained_mode, compute_variances
+            key_cfg, loss_name, constrained_mode, compute_variances,
+            sharded=mesh is not None,
         )
         self._obj = make_objective(
             loss_name,

@@ -147,6 +147,19 @@ class SolveResult(NamedTuple):
     # runs one Hv pass per CG step) report throughput comparably with
     # single-pass-per-iteration optimizers.
     data_passes: Array = 0
+    # of a batch of problems solved in one program, what its lanes cost:
+    # i32[3] (run, compacted, rounds) = the lane-iterations its loops
+    # computed (every slot of every iteration, lanes that had stopped and a
+    # round's fill slots included), the lanes that finished in a compacted
+    # round and those rounds (``optim/newton.py::newton_lanes``)
+    lanes: Optional[Array] = None
+
+
+def plain_lane_counts(iterations: Array) -> Array:
+    """``SolveResult.lanes`` of a plain vmapped loop over
+    ``iterations.shape[0]`` lanes: it runs every lane to the slowest one."""
+    run = iterations.shape[0] * jnp.max(iterations).astype(jnp.int32)
+    return jnp.stack([run, jnp.int32(0), jnp.int32(0)])
 
 
 def convergence_reason(

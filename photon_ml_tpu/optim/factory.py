@@ -22,7 +22,11 @@ from photon_ml_tpu.ops.sparse import SparseBatch
 from photon_ml_tpu.optim.adapter import glm_adapter
 from photon_ml_tpu.optim.common import BoxConstraints, SolveResult
 from photon_ml_tpu.optim.lbfgs import LBFGSConfig, lbfgs_solve
-from photon_ml_tpu.optim.newton import NewtonConfig, newton_solve
+from photon_ml_tpu.optim.newton import (
+    NewtonConfig,
+    NewtonProblem,
+    solve_newton_problem,
+)
 from photon_ml_tpu.optim.owlqn import owlqn_solve
 from photon_ml_tpu.optim.tron import TRONConfig, tron_solve
 
@@ -181,6 +185,31 @@ def build_objective(
     )
 
 
+def newton_config(config: OptimizerConfig) -> NewtonConfig:
+    return NewtonConfig(
+        max_iterations=config.max_iterations, tolerance=config.tolerance
+    )
+
+
+def newton_problem(
+    adapter, constraints: Optional[BoxConstraints] = None
+) -> NewtonProblem:
+    """The Newton oracles of a prebuilt objective adapter."""
+    if adapter.hessian is None:
+        raise ValueError(
+            "NEWTON needs a dense-Hessian adapter (small-d layouts only; "
+            "the tiled layout cannot densify)"
+        )
+    return NewtonProblem(
+        adapter.value_and_grad,
+        adapter.hessian,
+        constraints,
+        adapter.ls_prepare,
+        adapter.ls_eval,
+        adapter.value_scale,
+    )
+
+
 def dispatch_solve(
     adapter,
     w0: Array,
@@ -213,24 +242,12 @@ def dispatch_solve(
             init_grad_norm=init_grad_norm,
         )
     if config.optimizer_type == OptimizerType.NEWTON:
-        if adapter.hessian is None:
-            raise ValueError(
-                "NEWTON needs a dense-Hessian adapter (small-d layouts only; "
-                "the tiled layout cannot densify)"
-            )
-        return newton_solve(
-            adapter.value_and_grad,
-            adapter.hessian,
+        return solve_newton_problem(
+            newton_problem(adapter, constraints),
             w0,
-            NewtonConfig(
-                max_iterations=config.max_iterations, tolerance=config.tolerance
-            ),
-            constraints=constraints,
+            newton_config(config),
             init_value=init_value,
             init_grad_norm=init_grad_norm,
-            ls_prepare=adapter.ls_prepare,
-            ls_eval=adapter.ls_eval,
-            value_scale=adapter.value_scale,
         )
 
     lcfg = LBFGSConfig(

@@ -103,15 +103,19 @@ class RandomEffectOptimizationTracker:
     iterations: np.ndarray  # i32[n_entities]
     reasons: np.ndarray  # i32[n_entities]
     final_values: np.ndarray | None = None  # f32[n_entities]
+    # i32[n_buckets, 3]: each bucket solve's ``SolveResult.lanes`` (run,
+    # compacted, rounds), where the solver reported them
+    lane_counts: np.ndarray | None = None
 
     @staticmethod
     def from_device_parts(
-        its: list, reasons: list, vals: list
+        its: list, reasons: list, vals: list, lanes: list | None = None
     ) -> "RandomEffectOptimizationTracker":
         """Build from per-bucket DEVICE arrays (padding already sliced off)
         with ONE packed host fetch: the f32 terminal values ride the i32
         concat via bitcast — every device->host fetch is a host wait, so
-        all three telemetry vectors cross together (and the crossing is
+        all the telemetry crosses together, each bucket's i32[3]
+        ``SolveResult.lanes`` (``lanes``) too (and the crossing is
         accounted by telemetry.sync_fetch)."""
         import jax
         import jax.numpy as jnp
@@ -123,6 +127,7 @@ class RandomEffectOptimizationTracker:
             return RandomEffectOptimizationTracker(
                 iterations=z, reasons=z, final_values=np.zeros(0, np.float32)
             )
+        lanes = list(lanes or ())
         packed = sync_fetch(
             jnp.concatenate(
                 [
@@ -131,15 +136,17 @@ class RandomEffectOptimizationTracker:
                     jax.lax.bitcast_convert_type(
                         jnp.concatenate(vals).astype(jnp.float32), jnp.int32
                     ),
+                    *lanes,
                 ]
             ),
             label="re_tracker",
         )
-        n = len(packed) // 3
+        n = (len(packed) - 3 * len(lanes)) // 3
         tracker = RandomEffectOptimizationTracker(
             iterations=packed[:n],
             reasons=packed[n : 2 * n],
-            final_values=packed[2 * n :].view(np.float32),
+            final_values=packed[2 * n : 3 * n].view(np.float32),
+            lane_counts=packed[3 * n :].reshape(-1, 3) if lanes else None,
         )
         _metrics.counter("re_solved_entities").inc(n)
         # per-entity solve-iteration distribution, the registry-level view
@@ -148,6 +155,21 @@ class RandomEffectOptimizationTracker:
             tracker.iterations
         )
         return tracker
+
+    def report_lanes(self, scopes) -> None:
+        """Counters ``<scope>.lane_iterations`` (every entity's own
+        iterations, summed) and, from the bucket solves' ``lane_counts``,
+        ``.lane_iterations_run`` (the lane-iterations the device ran),
+        ``.compacted_lanes`` and ``.compact_rounds``."""
+        run, compacted, rounds = (
+            (0, 0, 0) if self.lane_counts is None
+            else self.lane_counts.astype(np.int64).sum(axis=0).tolist())
+        needed = int(self.iterations.astype(np.int64).sum())
+        for scope in scopes:
+            _metrics.counter(f"{scope}.lane_iterations").inc(needed)
+            _metrics.counter(f"{scope}.lane_iterations_run").inc(run)
+            _metrics.counter(f"{scope}.compacted_lanes").inc(compacted)
+            _metrics.counter(f"{scope}.compact_rounds").inc(rounds)
 
     def count_convergence_reasons(self) -> dict[str, int]:
         """countConvergenceReasons analog: reason name -> entity count."""

@@ -126,3 +126,25 @@ def _telemetry_isolation():
     from photon_ml_tpu import telemetry
 
     telemetry.reset()
+
+
+@pytest.fixture
+def lane_counts_model():
+    """``its -> (run, compacted, rounds)``: the ``SolveResult.lanes`` the width
+    rule of ``optim/newton.py::newton_lanes`` gives a batch whose lanes
+    stop after ``its`` Newton iterations: full width until no more than
+    ``compact_width`` lanes are open, then one round of the rest, run to
+    its slowest lane; a batch no wider than the width is the plain loop."""
+    from photon_ml_tpu.optim.newton import compact_width
+
+    def model(its):
+        its = np.asarray(its)
+        num, width = len(its), compact_width(len(its))
+        if width >= num:
+            return num * int(its.max()), 0, 0
+        full = next(t for t in range(int(its.max()) + 1)
+                    if np.count_nonzero(its > t) <= width)
+        late = int(np.count_nonzero(its > full))
+        return num * full + width * (int(its.max()) - full), late, int(late > 0)
+
+    return model

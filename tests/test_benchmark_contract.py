@@ -555,12 +555,28 @@ def test_random_effect_layout_gives_the_driver_its_buckets(rehearsal, name):
     assert flops > 0 and nbytes == 12000.0
 
 
+def _bucket_lanes_hold(tracker, sizes, lane_counts_model) -> None:
+    """Each bucket solve's lane counts in the tracker of the last
+    update: the lane-iterations its lanes needed <= what the device ran <=
+    its lanes x its slowest lane's, and the compacted lanes and rounds the
+    width rule gives its lanes' iterations (> 0 where a bucket straggles
+    past the switch)."""
+    assert tracker.lane_counts.shape == (len(sizes), 3)
+    lo = 0
+    for size, counts in zip(sizes, tracker.lane_counts.tolist()):
+        its = tracker.iterations[lo:lo + size]
+        lo += size
+        assert its.sum() <= counts[0] <= size * its.max()
+        assert counts == list(lane_counts_model(its))
+
+
 @pytest.mark.parametrize("name", RANDOM_EFFECTS)
 def test_random_effect_counters_hold_what_the_layout_and_a_fit_cost(
-        rehearsal, name):
+        rehearsal, lane_counts_model, name):
     """``re.<coordinate>.*``: the layout's at the end of set-up, the
     stragglers' after every update; ``re.*`` are their sums."""
-    ctx = rehearsal(MIXED).ctx
+    cell_run = rehearsal(MIXED)
+    ctx = cell_run.ctx
     shape = ctx["shapes"]["coordinates"][name]
     at_setup, at_end = ctx["counters"]["setup_end"], ctx["counters"][
         "window_end"]
@@ -574,10 +590,15 @@ def test_random_effect_counters_hold_what_the_layout_and_a_fit_cost(
     assert get(at_end, "lane_iterations") <= get(
         at_end, "lane_iterations_run")
     assert get(at_end, "lane_iterations") <= get(at_end, "pass_cells")
-    for key in ("rows", "rows_padded", "lane_iterations", "pass_cells"):
-        mark = at_end if "iterations" in key or "cells" in key else at_setup
+    assert get(at_end, "compact_rounds") <= get(at_end, "compacted_lanes")
+    for key in ("rows", "rows_padded", "lane_iterations", "pass_cells",
+                "lane_iterations_run", "compacted_lanes", "compact_rounds"):
+        mark = at_setup if key.startswith("rows") else at_end
         assert mark[f"re.{key}"] == sum(
             mark[f"re.{other}.{key}"] for other in RANDOM_EFFECTS)
+    _bucket_lanes_hold(
+        cell_run.driver.coordinates[name].last_tracker,
+        [b[0] for b in shape["buckets"]], lane_counts_model)
 
 
 def test_fixed_effect_of_the_mixed_cell_reports_its_own_nonzeros(rehearsal):
@@ -640,10 +661,13 @@ def test_factored_layout_gives_the_driver_its_shape(rehearsal):
     assert len(list(fit.per_fit(cell_run.ctx["shapes"], steps))) == len(steps)
 
 
-def test_factored_counters_say_what_was_not_built_and_which_route(rehearsal):
+def test_factored_counters_say_what_was_not_built_and_which_route(
+        rehearsal, lane_counts_model):
     """``mf.<coordinate>.*``: nothing of Kronecker length, every lane on
-    the hand solve, an evaluation more than the refit's iterations."""
-    ctx = rehearsal(MF).ctx
+    the hand solve, an evaluation more than the refit's iterations, and
+    the latent solves' lanes as the random effects' (``mf.*`` the sums)."""
+    cell_run = rehearsal(MF)
+    ctx = cell_run.ctx
     at_setup, at_end = ctx["counters"]["setup_end"], ctx["counters"][
         "window_end"]
     shape = ctx["shapes"]["coordinates"][FACTORED]
@@ -659,6 +683,13 @@ def test_factored_counters_say_what_was_not_built_and_which_route(rehearsal):
         f"mf.{FACTORED}.refit_iterations"] > 0
     assert at_end[f"mf.{FACTORED}.lane_iterations"] <= at_end[
         f"mf.{FACTORED}.lane_iterations_run"]
+    for key in ("lane_iterations", "lane_iterations_run", "compacted_lanes",
+                "compact_rounds"):
+        assert at_end[f"mf.{key}"] == at_end[f"mf.{FACTORED}.{key}"]
+    re_tracker, _ = cell_run.driver.coordinates[
+        FACTORED].last_tracker.steps[-1]
+    _bucket_lanes_hold(re_tracker, [b[0] for b in shape["buckets"]],
+                       lane_counts_model)
     assert at_end.get("xla.fallback_calls", 0) == 0
 
 

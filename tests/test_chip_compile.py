@@ -440,7 +440,7 @@ def _single_pass_products(text: str) -> list:
     return found
 
 
-def _entity_solver(packed: bool, kmajor: bool = False):
+def _entity_solver(packed: bool, kmajor: bool = False, sharded: bool = False):
     import dataclasses
 
     from photon_ml_tpu.config import parse_optimizer_config
@@ -451,7 +451,7 @@ def _entity_solver(packed: bool, kmajor: bool = False):
         "regularization": "l2", "regularization_weight": 1.0})
     return _re_solver(
         dataclasses.replace(config, regularization_weight=0.0), "logistic",
-        False, False, packed=packed, kmajor=kmajor)
+        False, False, packed=packed, kmajor=kmajor, sharded=sharded)
 
 
 def _objective_shapes(one_chip):
@@ -500,7 +500,8 @@ def test_dense_entity_solve_is_float32_grade_for_v5e(E, R, K, one_chip):
 
 def test_entity_sharded_dense_solve_compiles_for_v5e_2x2(topo):
     """A bucket's entity axis sharded over the four chips
-    (``place_entity_solve``): the hand solve's ``[.., E]`` slabs partition
+    (``place_entity_solve``, with the solver a mesh's coordinate builds:
+    the plain vmapped loop): the hand solve's ``[.., E]`` slabs partition
     with the rest of the vmapped solve, no chip gathers the entity axis."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -513,7 +514,7 @@ def test_entity_sharded_dense_solve_compiles_for_v5e_2x2(topo):
         return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding)
 
     packed = (s((E, R * K)), s((E, R)), s((E, R)), s((E, R)))
-    text = _entity_solver(True).lower(
+    text = _entity_solver(True, sharded=True).lower(
         _objective_shapes(whole), packed, s((E, K)), s((), whole), None
     ).compile().as_text()
     assert re.match(r"HloModule jit_re_solve_dense\b", text)

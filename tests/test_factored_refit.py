@@ -354,8 +354,17 @@ def test_update_reports_its_routes_spans_and_counters(rng):
         "mf.uxm.refit_iterations"] + 1
     assert 0 < counters["mf.uxm.lane_iterations"] <= counters[
         "mf.uxm.lane_iterations_run"]
-    assert counters["mf.lane_iterations"] == counters[
-        "mf.uxm.lane_iterations"]
+    # every latent pass's bucket solves: what the device ran, at most
+    # every lane to its bucket's slowest
+    lanes = entities = 0
+    for re_tracker, _ in coord.last_tracker.steps:
+        assert re_tracker.lane_counts.shape == (len(coord.re_data.buckets), 3)
+        lanes += re_tracker.lane_counts[:, 0].sum()
+        entities += len(re_tracker.iterations) * re_tracker.iterations.max()
+    assert counters["mf.uxm.lane_iterations_run"] == lanes <= entities
+    for key in ("lane_iterations", "lane_iterations_run", "compacted_lanes",
+                "compact_rounds"):
+        assert counters[f"mf.{key}"] == counters[f"mf.uxm.{key}"]
     names = [s.name for s in telemetry.finished_spans()]
     for span in ("mf_layout", "mf_layout.group", "mf_layout.design",
                  "mf_upload", "mf_iteration:0", "latent_design",
